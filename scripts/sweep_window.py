@@ -1,6 +1,6 @@
 """Interleaved sweep of the in-flight dispatch window (the hold threshold
-that gates reactive coalescing) and queue depth — the VERDICT r3 item-1
-sweep, judged on the same per-run wire diagnostics as the bench.
+that gates reactive coalescing) and queue depth, judged on the same
+per-run launch diagnostics as the bench.
 
 Usage: python scripts/sweep_window.py [n_million] [rounds]
 """
@@ -16,7 +16,7 @@ import bench
 import numpy as np
 
 CONFIGS = [
-    {"dw": 8, "depth": 48},                        # r4 default (anchor)
+    {"dw": 8, "depth": 48},                        # bench.py default (anchor)
     {"dw": 8, "depth": 48, "flush": 1 << 18},
     {"dw": 16, "depth": 96},
     {"dw": 8, "depth": 48, "no_overlap": True},

@@ -148,7 +148,9 @@ def run_roll(root, n_epochs=8, verbose=False):
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))) + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the workers run host-only graphs: pinned off the accelerator, so no
+    # child ever contends for a chip its parent (or a sibling) may hold
+    env["JAX_PLATFORMS"] = "cpu"
 
     import socket
     ports = {}

@@ -111,28 +111,79 @@ def test_incremental_rejected_on_device():
         core.use_incremental()
 
 
-def test_pallas_windowed_reduce_interpret():
-    """The pallas kernel (interpret mode on CPU) against numpy."""
-    from windflow_tpu.ops.pallas_kernels import windowed_reduce_pallas
+@pytest.mark.parametrize("op,pad,B", [("sum", 32, 64), ("max", 256, 200),
+                                      ("min", 512, 130), ("sum", 8, 8)])
+def test_pallas_windowed_reduce_interpret(op, pad, B):
+    """The pallas kernel (interpret mode on CPU) against numpy: windows
+    start at any offset inside a 128-lane row, lengths 0..pad, batch sizes
+    off the 128 grid (tests/test_tpu_lowering.py compiles the same kernel
+    for a v5e)."""
+    from windflow_tpu.ops.monoid import identity
+    from windflow_tpu.ops.pallas_kernels import (flat_slack,
+                                                 windowed_reduce_pallas)
 
     rng = np.random.default_rng(0)
-    flat = rng.integers(0, 100, size=256).astype(np.int32)
-    starts = np.arange(0, 128, 2, dtype=np.int32)   # 64 windows
-    lens = rng.integers(0, 17, size=64).astype(np.int32)
-    out = np.asarray(windowed_reduce_pallas(
-        np.concatenate([flat, np.zeros(32, np.int32)]), starts, lens, 32,
-        "sum", interpret=True))
-    want = np.array([flat[s:s + l].sum() for s, l in zip(starts, lens)],
-                    dtype=np.int32)
+    n = 3000
+    flat = rng.integers(-100, 100, size=n).astype(np.int32)
+    starts = rng.integers(0, n - pad, size=B).astype(np.int32)
+    lens = rng.integers(0, pad + 1, size=B).astype(np.int32)
+    padded = np.zeros(4096 + flat_slack(pad), dtype=np.int32)
+    padded = padded[:len(padded) // 128 * 128]
+    padded[:n] = flat
+    out = np.asarray(windowed_reduce_pallas(padded, starts, lens, pad, op,
+                                            interpret=True))
+    red = {"sum": np.sum, "max": np.max, "min": np.min}[op]
+    want = np.array([red(flat[s:s + l]) if l else identity(op, np.int32)
+                     for s, l in zip(starts, lens)], dtype=np.int32)
     assert np.array_equal(out, want)
 
 
+def test_pallas_kernel_refuses_what_it_cannot_hold():
+    from windflow_tpu.ops.pallas_kernels import (MAX_FLAT_BYTES,
+                                                 windowed_reduce_pallas)
+    z = np.zeros(8, np.int32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        windowed_reduce_pallas(np.zeros(1000, np.int32), z, z, 8, "sum",
+                               interpret=True)
+    with pytest.raises(ValueError, match="32-bit"):
+        windowed_reduce_pallas(np.zeros(1024, np.int8), z, z, 8, "sum",
+                               interpret=True)
+    with pytest.raises(ValueError, match="exceeds"):
+        windowed_reduce_pallas(
+            np.zeros(MAX_FLAT_BYTES // 4 + 128, np.int32), z, z, 8, "sum",
+            interpret=True)
+
+
 def test_win_seq_tpu_pallas_matches():
-    got = run_windowed(
-        WinSeqTPU(Reducer("sum"), 12, 5, WinType.CB, batch_len=64,
-                  use_pallas=True),
-        cb_stream_batches(2, 200))
+    stage = WinSeqTPU(Reducer("sum"), 12, 5, WinType.CB, batch_len=64,
+                      use_pallas=True)
+    got = run_windowed(stage, cb_stream_batches(2, 200))
     assert got == ref(12, 5, WinType.CB, cb_stream_batches(2, 200))
+    assert stage.make_core().executor.use_pallas
+
+
+def test_pallas_compile_failure_raises(monkeypatch):
+    """use_pallas=True is an explicit request: a kernel the compiler
+    refuses raises out of the launch — nothing swaps in the XLA gather
+    path behind the caller's back."""
+    from windflow_tpu.ops import device, pallas_kernels
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pallas_kernels, "windowed_reduce_pallas", refused)
+    monkeypatch.setattr(device, "_JIT_CACHE", {})
+    ex = device.DeviceWindowExecutor(
+        device.builtin_batch_fn("sum"), use_pallas=True, op="sum")
+    z = np.zeros(8, np.int64)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        ex.launch(None, {"value": np.arange(64)}, z, z + 4, z, z)
+    assert ex.use_pallas and ex.launches == 0
+    # the same launch without the request is served by the gather path
+    ok = device.DeviceWindowExecutor(device.builtin_batch_fn("sum"),
+                                     op="sum")
+    ok.launch(None, {"value": np.arange(64)}, z, z + 4, z, z)
+    assert ok.drain()[0][1]["value"].tolist() == [6] * 8
 
 
 @pytest.mark.parametrize("pardegree", [2, 3])
@@ -212,11 +263,10 @@ def test_empty_windows_match_host_identity(op):
 
 
 def test_budget_aware_routing_fake_ema(monkeypatch):
-    """VERDICT r4 item 4: a latency budget under ~2x the measured
-    per-launch wire service routes the stage to the HOST core (the
-    device path cannot meet it by construction); generous budgets, an
-    unmeasured wire, or an explicit use_resident force keep the device.
-    Faked EMA — no wire needed."""
+    """A latency budget under ~2x the measured per-launch service routes
+    the stage to the HOST core (the device path cannot meet it by
+    construction); generous budgets, an unmeasured service, or an explicit
+    use_resident force keep the device.  Faked EMA — no device needed."""
     from windflow_tpu.core.windows import WindowSpec
     from windflow_tpu.ops import resident
     from windflow_tpu.patterns.win_seq_tpu import make_core_for
@@ -260,3 +310,24 @@ def test_budget_aware_routing_fake_ema(monkeypatch):
                               use_pallas=True)) == "device"
     # and with no budget at all the heuristic never engages
     assert kind(make_core_for(spec, red)) == "device"
+
+
+def test_default_device_needs_a_tpu_or_an_explicit_platform(monkeypatch):
+    """A backend JAX fell back to on its own is refused; one named in
+    JAX_PLATFORMS (as conftest does) is the caller's choice.  bench.py and
+    chip_smoke.py need a TPU whatever the variable says."""
+    from windflow_tpu.core.windows import WindowSpec
+    from windflow_tpu.ops import backend
+
+    assert backend.default_device().platform == "cpu"     # conftest named it
+    assert backend.device_info() == {"platform": "cpu", "kind": "cpu",
+                                     "count": 8}
+    with pytest.raises(RuntimeError, match="needs a TPU, found platform"):
+        backend.require_tpu()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="no TPU.*JAX_PLATFORMS is not"):
+        backend.default_device()
+    with pytest.raises(RuntimeError, match="no TPU"):
+        WinSeqTPU(Reducer("sum"), 4, 2, WinType.CB).make_core()
+    with pytest.raises(RuntimeError, match="no TPU"):
+        DeviceWinSeqCore(WindowSpec(4, 2, WinType.CB), Reducer("sum"))

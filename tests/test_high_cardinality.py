@@ -1,4 +1,4 @@
-"""High-key-cardinality hardening (VERDICT r1 weak #4): the emitter /
+"""High-key-cardinality hardening: the emitter /
 accumulator / keyed-state hot paths must scale to 1e5 distinct keys —
 vectorised group-by instead of a full-batch mask per key.  Budgeted: each
 scenario must finish in seconds, and results stay differentially correct

@@ -207,7 +207,7 @@ def test_opt_level_survives_nesting_clone():
 @pytest.mark.parametrize("wt", [WinType.CB, WinType.TB], ids=["cb", "tb"])
 @pytest.mark.parametrize("level", [LEVEL1, LEVEL2])
 def test_pane_farm_tpu_opt_matches_seq(wt, level):
-    """VERDICT r2 item 6: LEVEL1/LEVEL2 fusion over device-core PaneFarm
+    """LEVEL1/LEVEL2 fusion over device-core PaneFarm
     stages (optimize_PaneFarmGPU, pane_farm_gpu.hpp:488-529) — the LEVEL2
     path mutates stage2.n_emitters and fronts workers with OrderingCores,
     which must compose with device-batched workers."""

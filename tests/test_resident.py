@@ -306,7 +306,7 @@ def test_max_delay_flushes_partial_batches():
         # use_resident=True pins the DEVICE path: this test covers the
         # device cores' force-flush timer, and the budget-aware routing
         # would otherwise (correctly) send a 1 ms budget to the host
-        # core once any earlier test seeded the global weather record
+        # core once any earlier test seeded the global service record
         core = make_core_for(WindowSpec(4, 4, WinType.CB), Reducer("sum"),
                              batch_len=1 << 20, flush_rows=1 << 20,
                              max_delay_ms=1, use_resident=True)
@@ -528,7 +528,7 @@ def test_host_free_multireducer_ignores_pallas_flag():
 
 
 def test_acc_dtype_warning_gated_on_value_range():
-    """VERDICT r2 hygiene: the int32-accumulate wrap warning must not fire
+    """The int32-accumulate wrap warning must not fire
     when the Reducer's declared value_range plus the CB window length prove
     the results fit (bench/YSB configs run warning-clean); it still fires
     when no range is declared or the range genuinely overflows."""

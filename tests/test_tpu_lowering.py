@@ -1,0 +1,140 @@
+"""AOT-compile every device step family for a TPU v5e, on the CPU.
+
+``jax.experimental.topologies`` describes a v5e:2x2 host to the installed
+libtpu without a chip, so XLA:TPU and Mosaic run their whole pipeline here:
+a step or kernel the compiler refuses fails this suite instead of waiting for
+a chip run.  Shapes are the main-path buckets (bench.py / apps/pipe.py: CB
+256/64, 64 keys, flush_rows 2^19) and the ones chip_smoke.py drives.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from windflow_tpu.ops import resident
+from windflow_tpu.ops.device import DeviceWindowExecutor, builtin_batch_fn
+from windflow_tpu.ops.pallas_kernels import windowed_reduce_pallas
+
+KP, CAP, RB, C, SLIDE = 64, 16384, 8192, 128, 64
+I8, I32, F32 = np.dtype(np.int8).str, np.dtype(np.int32).str, \
+    np.dtype(np.float32).str
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _one(devices):
+    sh = SingleDeviceSharding(devices[0])
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+
+def _mesh(devices):
+    mesh = Mesh(np.array(devices).reshape(4, 1, 1), ("kf", "wf", "sp"))
+
+    def S(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+    return mesh, S
+
+
+@pytest.mark.parametrize("cap", [CAP, 2 * CAP])
+def test_regular_step_compiles(v5e, cap):
+    S = _one(v5e)
+    fn = resident._make_regular_step(
+        ("reg", "sum", cap, RB, KP, C, I8, I32, SLIDE))
+    k = S((KP,), jnp.int32)
+    fn.lower(S((KP, cap), jnp.int32), S((KP, RB), jnp.int8), k, k, k,
+             k).compile()
+
+
+@pytest.mark.parametrize("ops,pad", [(("sum",), 0), (("max",), 256),
+                                     (("sum", "max"), 256),
+                                     (("min",), 4096)])
+def test_irregular_step_compiles(v5e, ops, pad):
+    S = _one(v5e)
+    B = 8192
+    fn = resident._make_step((ops, CAP, RB, B, KP, I8, I32, pad))
+    b = S((B,), jnp.int32)
+    fn.lower(S((KP, CAP), jnp.int32), S((KP, RB), jnp.int8),
+             S((KP,), jnp.int32), b, b, b).compile()
+
+
+def test_multi_step_compiles(v5e):
+    S = _one(v5e)
+    B = 8192
+    key = (("a", "b"), (("sum", "a"), ("max", "b")), None, CAP, RB, B, KP,
+           (I8, I8), (I32, I32), 256)
+    fn = resident._make_multi_step(key, None)
+    ring, blk = S((KP, CAP), jnp.int32), S((KP, RB), jnp.int8)
+    b = S((B,), jnp.int32)
+    fn.lower((ring, ring), (blk, blk), S((KP,), jnp.int32), b, b, b, b,
+             b).compile()
+
+
+def test_mesh_regular_step_compiles(v5e):
+    mesh, S = _mesh(v5e)
+    fn = resident._make_mesh_regular_step(
+        ("mesh-reg", "sum", CAP, RB, KP, C, I8, I32, SLIDE, mesh, "kf"))
+    k = S((KP,), jnp.int32, "kf")
+    fn.lower(S((KP, CAP), jnp.int32, "kf", None),
+             S((KP, RB), jnp.int8, "kf", None), k, k, k, k).compile()
+
+
+def test_mesh_irregular_step_compiles(v5e):
+    mesh, S = _mesh(v5e)
+    Bs = 2048
+    fn = resident._make_mesh_step(
+        ("mesh", ("max",), CAP, RB, Bs, KP, I8, I32, 256, mesh, "kf"))
+    d = S((4, Bs), jnp.int32, "kf", None)
+    fn.lower(S((KP, CAP), jnp.int32, "kf", None),
+             S((KP, RB), jnp.int8, "kf", None), S((KP,), jnp.int32, "kf"),
+             d, d, d).compile()
+
+
+def test_restaging_gather_compiles(v5e):
+    """The segment-restaging executor's gather + reduce (ops/device.py) at
+    B 32768, pad 256, N 2^20."""
+    S = _one(v5e)
+    B, pad, N = 32768, 256, 1 << 20
+    ex = DeviceWindowExecutor(builtin_batch_fn("mean"), op="mean")
+    b = S((B,), jnp.int32)
+    ex._compiled(B, pad, N).lower({"value": S((N,), jnp.int32)}, b, b, b,
+                                  b).compile()
+
+
+def test_skyline_step_compiles(v5e):
+    """apps/spatial.py's device skyline, the (B, pad, pad) dominance test,
+    on the multi-field resident step it runs on (use_resident=True)."""
+    from windflow_tpu.apps.spatial import device_skyline
+    S = _one(v5e)
+    kp, cap, rb, B, pad = 8, 8192, 2048, 256, 512
+    key = (("x", "y"), (), None, cap, rb, B, kp, (F32, F32), (F32, F32), pad)
+    fn = resident._make_multi_step(key, device_skyline())
+    ring, blk = S((kp, cap), jnp.float32), S((kp, rb), jnp.float32)
+    b = S((B,), jnp.int32)
+    fn.lower((ring, ring), (blk, blk), S((kp,), jnp.int32), b, b, b, b,
+             b).compile()
+
+
+@pytest.mark.parametrize("B,pad,N", [(8, 8, 1024), (8192, 256, 1 << 20),
+                                     (32768, 256, 1 << 22)])
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_pallas_kernel_compiles(v5e, B, pad, N, op):
+    """Mosaic accepts the window kernel (no lane-unaligned dynamic slice);
+    the largest shape is chip_smoke.py's leg D at batch_len 32768."""
+    S = _one(v5e)
+    fn = functools.partial(windowed_reduce_pallas, pad=pad, op=op)
+    b = S((B,), jnp.int32)
+    jax.jit(fn).lower(S((N,), jnp.int32), b, b).compile()

@@ -185,7 +185,7 @@ def test_rich_stats_min_ts_is_host_free():
     column: the device half collapses back to the single revenue ring
     and BOTH extremes ride the pos-extrema split.  (The multi-field
     device path stays exercised by tests/test_native.py's multifield
-    suite and the recorded on-chip A/B, BASELINE.md round 5.)"""
+    suite and chip_smoke.py's leg C.)"""
     import warnings
 
     from windflow_tpu.apps.ysb import device_aggregate

@@ -88,17 +88,19 @@ def run(duration_sec=5.0, chunk=4096, pardegree=1, capacity=2):
     sent, rcv, lat_sum = (counters["sent"], counters["rcv"],
                           counters["lat_sum"])
     from ..ops import resident
+    from ..ops.backend import device_info
     resident.stats_snapshot(reset=True)
     t0 = time.perf_counter()
     pipe.run_and_wait_end()
     elapsed = time.perf_counter() - t0
     return {
+        "device": device_info(),
         "sent": sent[0],
         "received": rcv[0],
         "tuples_per_sec": round(sent[0] / elapsed, 1),
         "avg_latency_us": round(lat_sum[0] / max(rcv[0], 1), 1),
         "elapsed_sec": round(elapsed, 3),
-        # wire diagnostics (bench.py discipline; zeros: no device stage)
+        # launch diagnostics (bench.py discipline; zeros: no device stage)
         **resident.stats_snapshot(reset=True),
     }
 
@@ -112,6 +114,8 @@ def main(argv=None):
     ap.add_argument("--capacity", type=int, default=2,
                     help="per-queue chunk capacity (latency knob)")
     a = ap.parse_args(argv)
+    from ..ops.backend import cli_start
+    cli_start()
     m = run(a.length, a.chunk, a.pardegree, a.capacity)
     for k, v in m.items():
         print(f"[micro] {k}: {v}")

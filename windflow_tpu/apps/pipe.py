@@ -14,8 +14,10 @@ carries its generation wall-clock in ``ts``; a CB window result's ts is its
 last contributing tuple's, so ``now - result.ts`` at the sink is the
 per-window close-to-delivery latency (ysb_nodes.hpp:231-238).
 
-Prints one JSON line with tuples/sec, latency, and the wire diagnostics
-(dispatches / merges / mean launch service) of each timed run.
+Prints one JSON line with the device it ran on, tuples/sec, latency, and
+the launch diagnostics (dispatches / merges / mean launch service) of each
+timed run.  Fails instead of running when JAX finds no TPU, unless
+``JAX_PLATFORMS`` names another backend on purpose.
 """
 
 from __future__ import annotations
@@ -29,6 +31,7 @@ from ..api import MultiPipe
 from ..core.tuples import Schema, batch_from_columns
 from ..core.windows import WinType
 from ..ops import resident
+from ..ops.backend import cli_start, default_devices, device_info
 from ..ops.functions import Reducer
 from ..patterns.basic import Filter, Map, Sink, Source
 from ..patterns.win_seq_tpu import WinFarmTPU
@@ -44,9 +47,8 @@ def make_values(n_tuples: int, chunk: int, seed: int = 7):
     """Deterministic keyed value TEMPLATE batches (sum_cb.hpp:89-117
     shape), prebuilt as full structured arrays outside the timed loop:
     the per-run source memcpys a template and stamps ``ts`` — assembling
-    columns into the interleaved record layout per push was 0.21 s of
-    the timed 8M-row run (r4 profile), pure setup cost masquerading as
-    streaming work."""
+    columns into the interleaved record layout per push is pure setup
+    cost that would masquerade as streaming work."""
     rng = np.random.default_rng(seed)
     per_key = n_tuples // N_KEYS
     rows_per_chunk = max(chunk // N_KEYS, 1)
@@ -103,11 +105,13 @@ def expected(chunks) -> tuple[int, int]:
 
 
 def build_pipe(chunks, pardegree, flush_rows, depth, capacity,
-               max_delay_ms=None, rate=None, trace=None, trace_dir=None):
+               max_delay_ms=None, rate=None, trace=None, trace_dir=None,
+               farm=WinFarmTPU):
     """Assemble the pipe_test_tpu MultiPipe without running it; returns
     ``(pipe, state)`` where ``state`` is the sink's result-accumulator
-    dict — shared by the timed ``run_once`` and the static analyzer
-    (scripts/wf_lint.py).  ``trace`` (a sample-rate fraction or
+    dict — shared by the timed ``run_once``, the static analyzer
+    (scripts/wf_lint.py) and chip_smoke.py, whose four-chip leg passes
+    ``farm=KeyFarmTPU`` (one ring per chip, same stream, same oracle).  ``trace`` (a sample-rate fraction or
     obs.trace.TracePolicy) + ``trace_dir`` opt the run into end-to-end
     span tracing: <trace_dir>/trace.jsonl feeds scripts/wf_trace.py
     (docs/OBSERVABILITY.md §tracing)."""
@@ -154,10 +158,10 @@ def build_pipe(chunks, pardegree, flush_rows, depth, capacity,
             # depress the measured pipeline throughput)
             .chain(Map(transform_inplace, vectorized=True))
             .chain(Filter(lambda b: keep(b["value"]), vectorized=True))
-            .add(WinFarmTPU(red, WIN, SLIDE, WinType.CB,
-                            pardegree=pardegree, batch_len=1 << 15,
-                            flush_rows=flush_rows, depth=depth,
-                            max_delay_ms=max_delay_ms))
+            .add(farm(red, WIN, SLIDE, WinType.CB,
+                      pardegree=pardegree, batch_len=1 << 15,
+                      flush_rows=flush_rows, depth=depth,
+                      max_delay_ms=max_delay_ms))
             .chain_sink(Sink(consume, vectorized=True)))
     return pipe, state
 
@@ -215,8 +219,7 @@ def run(n_tuples=8_000_000, pardegree=2, chunk=1 << 20,
     # warmup (compiles every shape bucket) + the coalescing shape ladder,
     # on every device the farm's workers own (jit caches per placement)
     run_once(chunks, pardegree, flush_rows, depth, capacity, max_delay_ms)
-    import jax
-    devs = jax.devices()
+    devs = default_devices()
     resident.prewarm_regular_ladder(devices=list(dict.fromkeys(
         devs[i % len(devs)] for i in range(pardegree))))
     best = None
@@ -250,6 +253,7 @@ def run(n_tuples=8_000_000, pardegree=2, chunk=1 << 20,
                      if max_delay_ms is not None else "") + ")",
         "value": best["tps"],
         "unit": "tuples/sec",
+        "device": device_info(),
         **{k: v for k, v in best.items() if k != "tps"},
         "runs": all_runs,
     }
@@ -261,9 +265,8 @@ def main(argv=None):
     ap.add_argument("-n", "--tuples", type=int, default=8_000_000)
     ap.add_argument("-p", "--pardegree", type=int, default=2)
     ap.add_argument("--chunk", type=int, default=1 << 20)
-    # same-session A/B: 2^19 -> 26 dispatches / ~1.6M tps vs 2^18 ->
-    # 40-43 dispatches / ~1.16M in identical weather (each dispatch costs
-    # an amortized wire RTT; two farm workers halve the per-core cadence)
+    # rows per fused dispatch: each dispatch costs one launch service,
+    # and two farm workers halve the per-core cadence
     ap.add_argument("--flush-rows", type=int, default=1 << 19)
     ap.add_argument("--depth", type=int, default=48)
     ap.add_argument("--capacity", type=int, default=4)
@@ -283,6 +286,7 @@ def main(argv=None):
                     help="span/telemetry output directory (defaults to "
                          "WF_LOG_DIR)")
     a = ap.parse_args(argv)
+    cli_start()
     out = run(a.tuples, a.pardegree, a.chunk, a.flush_rows, a.depth,
               a.capacity, a.runs, a.max_delay_ms, a.rate,
               trace=a.trace, trace_dir=a.trace_dir)

@@ -12,7 +12,7 @@ The pane payload (the reference's container-valued ``result_t``) rides
 FIXED-WIDTH SoA columns — ``sk_x``/``sk_y`` sub-array fields of
 ``PANE_CAP`` slots plus a ``sk_n`` count — not an object-dtype column:
 the one schema shape every engine path (vectorised emitters, ordering,
-channels, device staging) already speaks (VERDICT r3 weak #6).  A pane
+channels, device staging) already speaks.  A pane
 skyline of uniform points is O(log n) expected, so the default cap of 64
 is deep; an overflow raises loudly rather than truncating a result.
 """
@@ -440,8 +440,9 @@ def run(variant="wf", duration_sec=8.0, pardegree=2, win_ms=50.0,
         slide_ms=12.5, chunk=2048, rate=80_000.0, warm=True,
         max_delay_ms=None):
     """Run one spatial benchmark variant; returns the reference's metric
-    pair (events/sec + per-window latency) with wire diagnostics."""
+    pair (events/sec + per-window latency) with launch diagnostics."""
     from ..ops import resident
+    from ..ops.backend import device_info
     if warm:
         # short warm pass: compiles the device buckets (wf-tpu) and
         # first-touches every composition path outside the timed window
@@ -459,7 +460,8 @@ def run(variant="wf", duration_sec=8.0, pardegree=2, win_ms=50.0,
     pipe.run_and_wait_end()
     elapsed = _time.perf_counter() - t0
     diag = resident.stats_snapshot(reset=True)
-    out = {"variant": variant, "generated": n_gen[0],
+    out = {"variant": variant, "device": device_info(),
+           "generated": n_gen[0],
            "elapsed_sec": round(elapsed, 3),
            "events_per_sec": round(n_gen[0] / max(elapsed, 1e-9), 1),
            # sustained ingest during the generation window (ysb.py's
@@ -488,7 +490,8 @@ def main(argv=None):
                     help="generator pace, points/sec (window cardinality "
                          "= rate * win)")
     ap.add_argument("--rounds", type=int, default=2,
-                    help="interleaved rounds per variant (weather fairness)")
+                    help="interleaved rounds per variant (fair to drift "
+                         "over the run)")
     ap.add_argument("--budget-ms", type=float, default=None,
                     help="sustainable-throughput mode: step through "
                          "--rates ascending per variant and report the "
@@ -502,12 +505,14 @@ def main(argv=None):
                     help="device-core force-flush bound (wf-tpu); "
                          "defaults to budget/2 in --budget-ms mode")
     a = ap.parse_args(argv)
+    from ..ops.backend import cli_start
+    cli_start()
     variants = [v.strip() for v in a.variants.split(",") if v.strip()]
     if a.budget_ms is not None:
-        # sustainable throughput under a latency budget (VERDICT r4
-        # item 5): per variant, climb the rate ladder while p95 meets
-        # the budget; a first violation ends that variant's climb (the
-        # saturated regime only gets worse with rate)
+        # sustainable throughput under a latency budget: per variant,
+        # climb the rate ladder while p95 meets the budget; a first
+        # violation ends that variant's climb (the saturated regime only
+        # gets worse with rate)
         rates = [float(r) for r in a.rates.split(",") if r.strip()]
         for v in variants:
             dly = a.max_delay_ms

@@ -1,11 +1,14 @@
 """ctypes bindings for the C++ native host runtime (native/wf_native.cpp).
 
 The shared library is built on demand with ``make -C native`` (g++ only, no
-third-party dependencies) and cached; if the toolchain is unavailable the
-framework falls back to the pure-Python cores transparently.  Every call
-into the library releases the GIL, so farm workers running native cores get
-true multicore host parallelism — the FastFlow-pinned-threads property the
-reference gets for free from being a C++ library.
+third-party dependencies).  ``WF_NO_NATIVE=1`` is the explicit opt-out to
+the pure-Python cores; a checkout that ships no native source runs on them
+too.  A build or bind *failure* with the source present is an error
+(:class:`NativeBuildError`, carrying make's stderr), never a quiet switch
+of cores.  Every call into the library releases the GIL, so farm workers
+running native cores get true multicore host parallelism — the
+FastFlow-pinned-threads property the reference gets for free from being a
+C++ library.
 """
 
 from __future__ import annotations
@@ -22,6 +25,7 @@ _SO = os.path.join(_DIR, "libwfnative.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_error = None
 
 i64 = ctypes.c_longlong
 p_i64 = ctypes.POINTER(i64)
@@ -29,40 +33,55 @@ p_i32 = ctypes.POINTER(ctypes.c_int32)
 p_int = ctypes.POINTER(ctypes.c_int)
 
 
+class NativeBuildError(RuntimeError):
+    """The native source is present but did not build or bind."""
+
+
 def _build() -> bool:
-    src = os.path.join(_DIR, "wf_native.cpp")
-    if not os.path.exists(src):
+    """Run make; False when the checkout ships no native source."""
+    if not os.path.exists(os.path.join(_DIR, "wf_native.cpp")):
         return False
     # always invoke make: it no-ops when up to date and rebuilds when the
     # host fingerprint changed (host.tag — a -march=native .so cached on
     # another CPU would SIGILL; mtime alone cannot see that)
     try:
-        subprocess.run(["make", "-C", _DIR], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_SO)
-    except Exception:
-        # no toolchain: only trust an existing .so that is not stale
-        # relative to the source (the pre-host.tag safety rule)
-        return (os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(src))
+        proc = subprocess.run(["make", "-C", _DIR], capture_output=True,
+                              text=True, timeout=120)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(
+            f"make -C {_DIR} could not run: {e} (set WF_NO_NATIVE=1 to "
+            "run the pure-Python cores on purpose)") from e
+    if proc.returncode != 0 or not os.path.exists(_SO):
+        raise NativeBuildError(
+            f"make -C {_DIR} failed (exit {proc.returncode}); set "
+            "WF_NO_NATIVE=1 to run the pure-Python cores on purpose\n"
+            f"{proc.stderr.strip()}")
+    return True
 
 
 def load():
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _tried
+    """Load (building if needed) the native library; None when the checkout
+    ships no native source.  Raises :class:`NativeBuildError` — on every
+    call, the first failure is kept — when the build or the bind fails."""
+    global _tried, _error
     with _lock:
+        if _error is not None:
+            raise _error
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not _build():
-            return None
         try:
-            return _bind(ctypes.CDLL(_SO))
-        except Exception:
-            # dlopen failure or missing symbol (e.g. a truncated or
-            # older-ABI .so that survived a failed rebuild): fall back to
-            # the pure-Python cores instead of crashing the dataflow
-            return None
+            if not _build():
+                return None
+            try:
+                return _bind(ctypes.CDLL(_SO))
+            except (OSError, AttributeError) as e:
+                # dlopen failure or a missing required symbol
+                raise NativeBuildError(
+                    f"{_SO} built but does not bind: {e}") from e
+        except NativeBuildError as e:
+            _error = e
+            raise
 
 
 def _bind(lib):
@@ -196,9 +215,9 @@ def available() -> bool:
 
 
 def enabled():
-    """The native library, or None when unavailable or opted out via
-    WF_NO_NATIVE=1 — the single selection gate for every native-vs-Python
-    choice (cores, engine channels)."""
+    """The native library, or None when the source is absent or opted out
+    via WF_NO_NATIVE=1 — the single selection gate for every
+    native-vs-Python choice (cores, engine channels)."""
     if os.environ.get("WF_NO_NATIVE", "") == "1":
         return None
     return load()

@@ -14,8 +14,8 @@ frame, parallel/channel.py) across hosts.  The device ship phases the
 profile timers already bracket (``device_put`` / ``dispatch`` /
 ``harvest_wait``, ops/resident.py, patterns/native_core.py) become
 *child spans* of the service span that ran them, via the
-``utils/profile.py`` recorder hook — the T(L) launch-weather relation
-per launch instead of in aggregate.  Checkpoint and rescale seals appear
+``utils/profile.py`` recorder hook — launch service per launch instead
+of in aggregate.  Checkpoint and rescale seals appear
 as control-plane spans (kind ``ctrl``).
 
 Mechanics (all engine-driven, see runtime/engine.py):
@@ -430,7 +430,7 @@ class Tracer:
         """One device ship phase (profile span) that ran inside a traced
         ``svc`` call: a child span of that hop.  Attribution note: async
         cores dispatch/harvest launches while servicing LATER batches,
-        so a launch child quantifies the launch weather the traced batch
+        so a launch child quantifies the launch service the traced batch
         *experienced*, not necessarily its own rows' launch."""
         if self.metrics is not None:
             h = self._launch_hists.get(phase)

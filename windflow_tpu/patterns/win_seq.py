@@ -115,6 +115,23 @@ class WinSeqNode(Node):
             self.emit(out)
 
 
+def window_cores(df) -> list:
+    """The window cores of a built Dataflow, chained stages included —
+    what a run asserts on to know WHICH core did the work (a ``*TPU``
+    stage can legitimately route to a host core, make_core_for)."""
+    cores = []
+
+    def walk(node):
+        for stage in getattr(node, "stages", ()):
+            walk(stage)
+        if isinstance(node, WinSeqNode):
+            cores.append(node.core)
+
+    for node in df.nodes:
+        walk(node)
+    return cores
+
+
 class WinSeq(_Pattern):
     """Sequential window pattern (parallelism is always 1; farms build
     parallelism around it, win_farm.hpp:134)."""
