@@ -1,0 +1,10 @@
+"""A percentile of the event-time latency over every due result."""
+
+import numpy as np
+
+
+def read(obs, params):
+    lat = obs["latency_ms"]
+    if lat is None or not len(lat):
+        return None
+    return float(np.percentile(lat, params["percentile"]))
