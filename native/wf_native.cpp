@@ -1484,6 +1484,17 @@ int wf_launch_peek(void *h, i64 *K, i64 *R, i64 *B, int *wire, int *rebase,
     return 1;
 }
 
+// live rows of the front launch's rectangle, before padding: the sum of
+// its per-key row counts (the padded K*R is what crosses the wire)
+i64 wf_launch_live_rows(void *h) {
+    Core *c = (Core *)h;
+    std::lock_guard<std::mutex> lk(c->qmu);
+    if (c->queue.empty()) return 0;
+    i64 n = 0;
+    for (int32_t r : c->queue.front().rows) n += r;
+    return n;
+}
+
 // regular-descriptor metadata of the front launch (call between peek and
 // take): returns 0 when the front launch is irregular
 int wf_launch_peek_regular(void *h, i64 *cmax) {

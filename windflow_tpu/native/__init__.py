@@ -135,6 +135,8 @@ def _bind(lib):
         p_i64]
     lib.wf_launch_pending.restype = i64
     lib.wf_launch_pending.argtypes = [ctypes.c_void_p]
+    lib.wf_launch_live_rows.restype = i64
+    lib.wf_launch_live_rows.argtypes = [ctypes.c_void_p]
     lib.wf_launch_peek.restype = ctypes.c_int
     lib.wf_launch_peek.argtypes = [ctypes.c_void_p, p_i64, p_i64, p_i64,
                                    p_int, p_int, p_i64, p_i64]

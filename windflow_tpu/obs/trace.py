@@ -223,10 +223,11 @@ def export() -> dict | None:
             "elapsed_us": round((_pc_ns() - ctx.t0_ns) / 1e3, 1)}
 
 
-def _profile_recorder(name: str, dt_ns: int):
+def _profile_recorder(name: str, dt_ns: int, launch: int = None):
     """utils/profile.py span-exit observer: when the calling thread is
     inside a traced ``svc``, the just-finished ship phase becomes a
-    child span of the active hop.  Outside a traced batch it is two
+    child span of the active hop, under the id of the launch it belongs
+    to where the span names one.  Outside a traced batch it is two
     attribute reads and a return."""
     ctx = getattr(_TLS, "ctx", None)
     if ctx is None:
@@ -235,7 +236,7 @@ def _profile_recorder(name: str, dt_ns: int):
     if tr is None or tr._closed or not tr.policy.launch:
         return
     tr.record_launch(ctx, getattr(_TLS, "span", None),
-                     getattr(_TLS, "node", None), name, dt_ns)
+                     getattr(_TLS, "node", None), name, dt_ns, launch)
 
 
 #: live-Tracer refcount for the profile recorder: while any tracer is
@@ -426,7 +427,7 @@ class Tracer:
                       "rows": int(rows)})
 
     def record_launch(self, ctx: SpanCtx, parent, node_id, phase: str,
-                      dt_ns: int):
+                      dt_ns: int, launch: int = None):
         """One device ship phase (profile span) that ran inside a traced
         ``svc`` call: a child span of that hop.  Attribution note: async
         cores dispatch/harvest launches while servicing LATER batches,
@@ -444,12 +445,17 @@ class Tracer:
                         self._launch_hists[phase] = h
             h.observe(dt_ns / 1e9)
             self._c_spans.inc()
-        self._append({"t": time.time(), "kind": "launch",
-                      "trace": ctx.trace_id, "span": _new_id(),
-                      "parent": parent, "dataflow": self.dataflow,
-                      "node": node_id, "phase": phase,
-                      "dur_us": round(dt_ns / 1e3, 1),
-                      "end_us": round((_pc_ns() - ctx.t0_ns) / 1e3, 1)})
+        rec = {"t": time.time(), "kind": "launch",
+               "trace": ctx.trace_id, "span": _new_id(),
+               "parent": parent, "dataflow": self.dataflow,
+               "node": node_id, "phase": phase,
+               "dur_us": round(dt_ns / 1e3, 1),
+               "end_us": round((_pc_ns() - ctx.t0_ns) / 1e3, 1)}
+        if launch is not None:
+            # the id the ship path gave the launch (utils/profile.py):
+            # the same one its wf. annotations and launches.jsonl carry
+            rec["launch"] = launch
+        self._append(rec)
 
     def record_ctrl(self, node_id: str, name: str, epoch: int,
                     dur_s: float, **extra):

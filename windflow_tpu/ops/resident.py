@@ -31,7 +31,6 @@ executor is a dumb, replayable launch queue, like the reference's per-worker
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 
 import numpy as np
@@ -54,7 +53,9 @@ _PREWARMED = set()
 # -- launch diagnostics (always on: one lock round-trip per dispatch) -------
 # Every resident dispatch feeds these process-wide counters: dispatch count,
 # merge count (launches fused by wf_launch_coalesce), and wall service time
-# from dispatch to result-ready — a run's result carries them so a slow
+# from the end of dispatch to the end of the harvest — the stamps the
+# launch's own ``dispatch`` and ``harvest_wait`` spans took (utils/profile),
+# not a second reading of the clock — a run's result carries them so a slow
 # launch service can be told from a slow host loop.
 
 _STATS_MU = threading.Lock()
@@ -67,8 +68,9 @@ def stats_add(name: str, value=1):
 
 
 def stats_max(name: str, value):
-    """High-water gauge (e.g. the deepest proactive flush multiple a run
-    reached) — snapshot/reset like the counters."""
+    """High-water gauge (the deepest proactive flush multiple a run
+    reached, which scripts/ab_proactive.py prints per arm) —
+    snapshot/reset like the counters."""
     with _STATS_MU:
         if value > _STATS.get(name, 0):
             _STATS[name] = value
@@ -87,6 +89,20 @@ def stats_snapshot(reset: bool = False) -> dict:
     return snap
 
 _REDUCE_OPS = ("sum", "min", "max", "prod")
+
+#: (launch id, shard, cause) of a launch nobody named (the Python resident
+#: core's): its spans carry no id
+_NO_TAG = (None, None, None)
+
+
+def _named_jit(fn, name: str):
+    """``jax.jit`` of `fn` under a name that says the step's family, so
+    the trace's ``XLA Modules`` line reads ``jit_<name>(...)`` per family
+    instead of one ``jit_step`` for all."""
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call)
 
 #: process-global launch-service record: an EMA of RAW per-dispatch launch
 #: service in ms, deliberately NOT normalized by dispatch size — the
@@ -204,7 +220,7 @@ def _make_regular_step(key):
         return _regular_body(cap, C, slide, acc_dt, ring, blk, offs,
                              rstart0, rlen)
 
-    return jax.jit(step)
+    return _named_jit(step, "wf_step_regular")
 
 
 def _make_mesh_regular_step(key):
@@ -225,7 +241,7 @@ def _make_mesh_regular_step(key):
         in_specs=(P(axis, None), P(axis, None), P(axis), P(axis), P(axis),
                   P(axis)),
         out_specs=(P(axis, None), P(axis, None)))
-    return jax.jit(mapped)
+    return _named_jit(mapped, "wf_step_regular_mesh")
 
 
 def _ring_append(ring, blk, offs, acc_dt):
@@ -276,7 +292,7 @@ def _make_step(key):
                                   wrows, wstarts, wlens)
         return ring, (outs[0] if len(outs) == 1 else outs)
 
-    return jax.jit(step)
+    return _named_jit(step, "wf_step_append_eval")
 
 
 def _make_mesh_step(key):
@@ -303,7 +319,7 @@ def _make_mesh_step(key):
         in_specs=(P(axis, None), P(axis, None), P(axis),
                   P(axis, None), P(axis, None), P(axis, None)),
         out_specs=(P(axis, None), P(axis, None)))
-    return jax.jit(mapped)
+    return _named_jit(mapped, "wf_step_append_eval_mesh")
 
 
 class ResidentWindowExecutor:
@@ -336,7 +352,8 @@ class ResidentWindowExecutor:
         self.cap = 0          # ring columns (set on first reset)
         self.KP = 0           # ring rows (padded key count)
         self._ring = None
-        self._inflight = deque()   # (meta, sel, device_out, t_dispatch)
+        # (meta, sel, device_out, t_dispatched_ns, (launch, shard, cause))
+        self._inflight = deque()
         self._ready = []
         self._svc = deque(maxlen=32)   # recent dispatch→ready seconds
         self._svc_mean = 0.0
@@ -429,13 +446,15 @@ class ResidentWindowExecutor:
         # construction when the result dtype exceeds the accumulate dtype
 
     def launch(self, meta, blk: np.ndarray, offs: np.ndarray,
-               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray):
+               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
+               tag=_NO_TAG):
         """One fused append+eval dispatch.
 
         blk: (K, R) new rows per dense key (narrow dtype, zero-padded);
         offs: (K,) per-key ring write offsets; wrows/wstarts/wlens: (B,)
         fired-window descriptors in ring coordinates.  `meta` is returned
         with the results at harvest.  Caller guarantees offs + R <= cap.
+        `tag` is the launch's (id, shard, cause), carried on its spans.
         """
         K, R = blk.shape
         if K > self.KP:
@@ -451,7 +470,7 @@ class ResidentWindowExecutor:
         fn = _STEP_CACHE.get(key)
         if fn is None:
             fn = _STEP_CACHE[key] = _make_step(key)
-        with profile.span("device_put"):
+        with profile.span("device_put", *tag):
             blkp = (blk if blk.shape == (self.KP, Rb)
                     else _pad2(blk, self.KP, Rb))
             args = jax.device_put(
@@ -461,19 +480,16 @@ class ResidentWindowExecutor:
         profile.add("bytes_shipped", blk.nbytes)
         profile.add("rows_shipped", blk.size)
         profile.add("windows", B)
-        with profile.span("dispatch"):
+        with profile.span("dispatch", *tag) as sp:
             self._ring, out = fn(self._ring_arr(), *args)
             for o in (out if isinstance(out, tuple) else (out,)):
                 o.copy_to_host_async()
-        self._count_dispatch()
-        self._inflight.append((meta, B, out, time.perf_counter()))
-        while len(self._inflight) > self.depth:
-            self._harvest_one()
+        self._dispatched(meta, B, out, sp, tag)
 
     def launch_regular(self, meta, blk: np.ndarray, offs: np.ndarray,
                        rcount: np.ndarray, rstart0: np.ndarray,
                        rlen: np.ndarray, slide: int, wrows: np.ndarray,
-                       widx: np.ndarray, cmax: int = 0):
+                       widx: np.ndarray, cmax: int = 0, tag=_NO_TAG):
         """Fused append+eval with *regular* window descriptors: per ring
         row, windows i in [0, rcount[r]) start at rstart0[r] + i*slide with
         length rlen[r] — only 3 per-key scalars cross the wire instead of
@@ -494,7 +510,7 @@ class ResidentWindowExecutor:
         fn = _STEP_CACHE.get(key)
         if fn is None:
             fn = _STEP_CACHE[key] = _make_regular_step(key)
-        with profile.span("device_put"):
+        with profile.span("device_put", *tag):
             blkp = (blk if blk.shape == (self.KP, Rb)
                     else _pad2(blk, self.KP, Rb))
             args = jax.device_put(
@@ -505,23 +521,31 @@ class ResidentWindowExecutor:
         profile.add("bytes_shipped", blk.nbytes)
         profile.add("rows_shipped", blk.size)
         profile.add("windows", len(wrows))
-        with profile.span("dispatch"):
+        with profile.span("dispatch", *tag) as sp:
             self._ring, out = fn(self._ring_arr(), *args)
             out.copy_to_host_async()
-        self._count_dispatch()
-        self._inflight.append((meta, (np.asarray(wrows), np.asarray(widx)),
-                               out, time.perf_counter()))
+        self._dispatched(meta, (np.asarray(wrows), np.asarray(widx)), out,
+                         sp, tag)
+
+    def _dispatched(self, meta, sel, out, sp, tag):
+        """Queue a dispatched launch for harvest, stamped with the end of
+        its ``dispatch`` span `sp`; harvest beyond the depth bound."""
+        self.dispatches += 1
+        stats_add("dispatches")
+        self._inflight.append((meta, sel, out, sp.end_ns(), tag))
         while len(self._inflight) > self.depth:
             self._harvest_one()
 
-    def _count_dispatch(self):
-        self.dispatches += 1
-        stats_add("dispatches")
-
     # -------------------------------------------------------------- harvest
 
-    def _note_service(self, t0: float):
-        dt = time.perf_counter() - t0
+    def _note_service(self, dt_ns: int, ready: bool):
+        """One launch closed: `dt_ns` from the end of its dispatch to the
+        end of its harvest, `ready` whether its result was there before
+        the harvest began."""
+        dt = dt_ns / 1e9
+        profile.add("launches")
+        if ready:
+            profile.add("launches_ready_at_poll")
         self._svc.append(dt)
         # fold the window mean here, on the harvesting thread: readers on
         # OTHER threads (the proactive flush sizer runs on the node
@@ -544,23 +568,34 @@ class ResidentWindowExecutor:
         thread."""
         return self._svc_mean
 
-    def _harvest_one(self):
-        meta, sel, out, t0 = self._inflight.popleft()
+    def _harvest_one(self, ready: bool = None):
+        meta, sel, out, t_dispatched, tag = self._inflight.popleft()
+        if ready is None:
+            # read once, before blocking: a launch whose result was
+            # already there spent the rest of its service waiting for
+            # this poll, not for the device
+            ready = self._is_ready(out)
+        with profile.span("harvest_wait", *tag) as sp:
+            sp.extra = {"ready": ready}
+            res = self._fetch(sel, out)
+        self._note_service(sp.end_ns() - t_dispatched, ready)
+        self._ready.append((meta, res))
+
+    @staticmethod
+    def _fetch(sel, out):
+        """Block on one launch's device result and cut it to its windows."""
         multi = isinstance(out, tuple)
-        with profile.span("harvest_wait"):
-            arrs = ([np.asarray(o) for o in out] if multi
-                    else [np.asarray(out)])
-        self._note_service(t0)
+        arrs = [np.asarray(o) for o in out] if multi else [np.asarray(out)]
         if isinstance(sel, tuple):   # regular/mesh: index map -> flat (B,)
             arrs = [a[sel[0], sel[1]] for a in arrs]
         else:
             arrs = [a[:sel] for a in arrs]
-        self._ready.append((meta, tuple(arrs) if multi else arrs[0]))
+        return tuple(arrs) if multi else arrs[0]
 
     def poll(self):
         """Harvest completed launches without blocking on the rest."""
         while self._inflight and self._is_ready(self._inflight[0][2]):
-            self._harvest_one()
+            self._harvest_one(ready=True)
         ready, self._ready = self._ready, []
         return ready
 
@@ -623,7 +658,7 @@ def _make_multi_step(key, jax_fn):
             outs.extend(res if isinstance(res, tuple) else (res,))
         return rings, tuple(outs)
 
-    return jax.jit(step)
+    return _named_jit(step, "wf_step_multi")
 
 
 class MultiFieldResidentExecutor(ResidentWindowExecutor):
@@ -719,7 +754,8 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
 
     def launch(self, meta, blks: dict, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
-               wkeys: np.ndarray = None, wgwids: np.ndarray = None):
+               wkeys: np.ndarray = None, wgwids: np.ndarray = None,
+               tag=_NO_TAG):
         """One fused dispatch: per-field rectangles `blks[f]` (K, R) append
         at `offs`, then every stat / the JAX fn evaluates the described
         windows.  `wkeys`/`wgwids` are required when a JAX fn is bound."""
@@ -745,7 +781,7 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         fn = cache.get(key)
         if fn is None:
             fn = cache[key] = _make_multi_step(key, self.jax_fn)
-        with profile.span("device_put"):
+        with profile.span("device_put", *tag):
             blkps = tuple(
                 (blks[f] if blks[f].shape == (self.KP, Rb)
                  else _pad2(blks[f], self.KP, Rb)) for f in self.fields)
@@ -761,21 +797,11 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
-        with profile.span("dispatch"):
+        with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:
                 o.copy_to_host_async()
-        self._count_dispatch()
-        self._inflight.append((meta, B, out, time.perf_counter()))
-        while len(self._inflight) > self.depth:
-            self._harvest_one()
-
-    def _harvest_one(self):
-        meta, B, out, t0 = self._inflight.popleft()
-        with profile.span("harvest_wait"):
-            arrs = tuple(np.asarray(o)[:B] for o in out)
-        self._note_service(t0)
-        self._ready.append((meta, arrs))
+        self._dispatched(meta, B, out, sp, tag)
 
 
 def _make_mesh_multi_step(key, jax_fn):
@@ -822,7 +848,7 @@ def _make_mesh_multi_step(key, jax_fn):
                   P(axis), P(axis, None), P(axis, None), P(axis, None),
                   P(axis, None), P(axis, None)),
         out_specs=((P(axis, None),) * n_f, P(axis, None)))
-    return jax.jit(mapped)
+    return _named_jit(mapped, "wf_step_multi_mesh")
 
 
 class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
@@ -870,7 +896,8 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
 
     def launch(self, meta, blks: dict, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
-               wkeys: np.ndarray = None, wgwids: np.ndarray = None):
+               wkeys: np.ndarray = None, wgwids: np.ndarray = None,
+               tag=_NO_TAG):
         S = self.n_shards
         K, R = next(iter(blks.values())).shape
         if K > self.KP:
@@ -923,36 +950,27 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
         offsp = np.zeros(self.KP, dtype=np.int32)
         offsp[prow] = offs
         blkps = []
+        with profile.span("device_put", *tag):
+            for f in self.fields:
+                bp = np.zeros((self.KP, Rb), dtype=blks[f].dtype)
+                bp[prow, :R] = blks[f]
+                blkps.append(jax.device_put(bp, self._sharding(self.axis,
+                                                               None)))
+            s2 = self._sharding(self.axis, None)
+            args = (tuple(blkps),
+                    jax.device_put(offsp, self._sharding(self.axis)),
+                    jax.device_put(lrows, s2), jax.device_put(lstarts, s2),
+                    jax.device_put(llens, s2), jax.device_put(lkeys, s2),
+                    jax.device_put(lgwids, s2))
         for f in self.fields:
-            bp = np.zeros((self.KP, Rb), dtype=blks[f].dtype)
-            bp[prow, :R] = blks[f]
-            blkps.append(jax.device_put(bp, self._sharding(self.axis,
-                                                           None)))
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
-        s2 = self._sharding(self.axis, None)
-        args = (tuple(blkps),
-                jax.device_put(offsp, self._sharding(self.axis)),
-                jax.device_put(lrows, s2), jax.device_put(lstarts, s2),
-                jax.device_put(llens, s2), jax.device_put(lkeys, s2),
-                jax.device_put(lgwids, s2))
-        with profile.span("dispatch"):
+        with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:
                 o.copy_to_host_async()
-        self._count_dispatch()
-        self._inflight.append((meta, (shard, slots), out,
-                               time.perf_counter()))
-        while len(self._inflight) > self.depth:
-            self._harvest_one()
-
-    def _harvest_one(self):
-        meta, sel, out, t0 = self._inflight.popleft()
-        with profile.span("harvest_wait"):
-            arrs = tuple(np.asarray(o)[sel[0], sel[1]] for o in out)
-        self._note_service(t0)
-        self._ready.append((meta, arrs))
+        self._dispatched(meta, (shard, slots), out, sp, tag)
 
 
 class MeshResidentExecutor(ResidentWindowExecutor):
@@ -997,7 +1015,8 @@ class MeshResidentExecutor(ResidentWindowExecutor):
         return self._ring
 
     def launch(self, meta, blk: np.ndarray, offs: np.ndarray,
-               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray):
+               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
+               tag=_NO_TAG):
         S = self.n_shards
         K, R = blk.shape
         if K > self.KP:
@@ -1044,24 +1063,23 @@ class MeshResidentExecutor(ResidentWindowExecutor):
         blkp[prow, :R] = blk
         offsp = np.zeros(self.KP, dtype=np.int32)
         offsp[prow] = offs
-        args = (jax.device_put(blkp, self._sharding(self.axis, None)),
-                jax.device_put(offsp, self._sharding(self.axis)),
-                jax.device_put(lrows, self._sharding(self.axis, None)),
-                jax.device_put(lstarts, self._sharding(self.axis, None)),
-                jax.device_put(llens, self._sharding(self.axis, None)))
-        self._ring, out = fn(self._ring_arr(), *args)
-        for o in (out if isinstance(out, tuple) else (out,)):
-            o.copy_to_host_async()
-        self._count_dispatch()
+        with profile.span("device_put", *tag):
+            args = (jax.device_put(blkp, self._sharding(self.axis, None)),
+                    jax.device_put(offsp, self._sharding(self.axis)),
+                    jax.device_put(lrows, self._sharding(self.axis, None)),
+                    jax.device_put(lstarts, self._sharding(self.axis, None)),
+                    jax.device_put(llens, self._sharding(self.axis, None)))
+        with profile.span("dispatch", *tag) as sp:
+            self._ring, out = fn(self._ring_arr(), *args)
+            for o in (out if isinstance(out, tuple) else (out,)):
+                o.copy_to_host_async()
         # harvest indexes the (S, Bs) result back to flat window order
-        self._inflight.append((meta, (shard, slots), out, time.perf_counter()))
-        while len(self._inflight) > self.depth:
-            self._harvest_one()
+        self._dispatched(meta, (shard, slots), out, sp, tag)
 
     def launch_regular(self, meta, blk: np.ndarray, offs: np.ndarray,
                        rcount: np.ndarray, rstart0: np.ndarray,
                        rlen: np.ndarray, slide: int, wrows: np.ndarray,
-                       widx: np.ndarray, cmax: int = 0):
+                       widx: np.ndarray, cmax: int = 0, tag=_NO_TAG):
         """Regular-descriptor dispatch on the sharded ring: the per-key
         (count, start0, len) scalars shard with their rows, and each device
         expands its own arithmetic window sequences — the native core's
@@ -1092,19 +1110,18 @@ class MeshResidentExecutor(ResidentWindowExecutor):
             out = np.zeros(self.KP, dtype=dtype)
             out[prow] = a[:K]
             return out
-        args = (jax.device_put(blkp, self._sharding(self.axis, None)),
-                jax.device_put(scat(offs), self._sharding(self.axis)),
-                jax.device_put(scat(rcount), self._sharding(self.axis)),
-                jax.device_put(scat(rstart0), self._sharding(self.axis)),
-                jax.device_put(scat(rlen), self._sharding(self.axis)))
-        self._ring, out = fn(self._ring_arr(), *args)
-        out.copy_to_host_async()
-        self._count_dispatch()
+        with profile.span("device_put", *tag):
+            args = (jax.device_put(blkp, self._sharding(self.axis, None)),
+                    jax.device_put(scat(offs), self._sharding(self.axis)),
+                    jax.device_put(scat(rcount), self._sharding(self.axis)),
+                    jax.device_put(scat(rstart0), self._sharding(self.axis)),
+                    jax.device_put(scat(rlen), self._sharding(self.axis)))
+        with profile.span("dispatch", *tag) as sp:
+            self._ring, out = fn(self._ring_arr(), *args)
+            out.copy_to_host_async()
         wr = np.asarray(wrows, dtype=np.int64)
         sel = ((wr % S) * rps + wr // S, np.asarray(widx))
-        self._inflight.append((meta, sel, out, time.perf_counter()))
-        while len(self._inflight) > self.depth:
-            self._harvest_one()
+        self._dispatched(meta, sel, out, sp, tag)
 
 
 def prewarm_regular_ladder(mults=(2, 4, 8, 16), devices=None,

@@ -87,14 +87,16 @@ class NativeStateSnapshot:
         return {"kind": "native", "abi": self.abi, "blobs": self.blobs}
 
 
-def _ship_loop(core_ref, ship_q, shard):
+def _ship_loop(core_ref, ship_q, shard, shard_id):
     """Ship-thread main: one thread per key shard, so the shards'
     device_put / dispatch / harvest overlap (a single thread would
     serialize all shards' transfers).  Resolves
     the core weakref per token so the thread never pins the core's
     lifetime (a dead core ends the loop)."""
     while True:
-        tok = ship_q.get()
+        # nothing to launch: the ship thread's share of an idle chip
+        with profile.span("ship_idle", shard=shard_id):
+            tok = ship_q.get()
         if tok is None:
             return
         core = core_ref()
@@ -255,6 +257,13 @@ class NativeResidentCore:
                     depth=depth, acc_dtype=acc)
                 for t in range(self.shards)]
         self.executor = self.executors[0]
+        #: process-wide number of shard 0's ship thread: spans and launch
+        #: records name a shard as _shard_base + t, so two farm workers'
+        #: shards stay apart
+        self._shard_base = int(worker_index) * self.shards
+        #: id of the _process_rows call that last fed the cores — the
+        #: `cause` on the launches shipped after it
+        self._cause = None
         self._batch_len = int(batch_len)
         self._acc_wire = 3 if acc.itemsize >= 8 else 2
         self._flush_base = int(flush_rows)
@@ -376,8 +385,9 @@ class NativeResidentCore:
         self._ship_threads = [
             threading.Thread(
                 target=_ship_loop,
-                args=(weakref.ref(self), self._ship_qs[t], t),
-                daemon=True, name=f"wf-ship.{t}")
+                args=(weakref.ref(self), self._ship_qs[t], t,
+                      self._shard_base + t),
+                daemon=True, name=f"wf-ship.{self._shard_base + t}")
             for t in range(self.shards)]
         for th in self._ship_threads:
             th.start()
@@ -776,7 +786,8 @@ class NativeResidentCore:
         launched = 0
         if b is not None:
             itemsize, o_key, o_id, o_ts, o_mk, o_val = self._offsets
-            with profile.span("native_bookkeeping"):
+            cause = self._cause = profile.next_id()
+            with profile.span("native_bookkeeping", cause=cause):
                 if self._multi:
                     from ..native import p_i64
                     launched = self._lib.wf_cores_process_mt_f(
@@ -828,7 +839,7 @@ class NativeResidentCore:
             # wait for the ship threads to work the C++ queues down
             # (re-poking them each beat: a ship thread that held a launch
             # for coalescing has no other wake-up once tokens stop)
-            with profile.span("backpressure_wait"):
+            with profile.span("backpressure_wait", cause=self._cause):
                 beats = 0
                 while (self._ship_exc is None
                        and max(self._lib.wf_launch_pending(h)
@@ -843,20 +854,9 @@ class NativeResidentCore:
         """EOS every shard core, then ship + drain everything; returns
         the raw per-launch harvest entries (flush/flush_batches share
         this tail)."""
-        from ..ops.resident import stats_add, stats_max
-        t_eos = time.monotonic()
-        backlog = 0
         for h in self._hs:
             self._lib.wf_core_eos(h)
-            backlog += self._lib.wf_launch_pending(h)
-        backlog += sum(len(ex._inflight) for ex in self.executors)
-        out = self._drain_entries()
-        # EOS drain accounting: how long the finite-run tail waits on
-        # in-flight launches and how deep the backlog was — the
-        # end-to-end-vs-ingest gap is exactly this number
-        stats_add("drain_ms", 1e3 * (time.monotonic() - t_eos))
-        stats_max("drain_backlog_max", backlog)
-        return out
+        return self._drain_entries()
 
     def flush(self) -> np.ndarray:
         if self._delegate is not None:
@@ -907,8 +907,10 @@ class NativeResidentCore:
             # ring was provisioned for
             max_mult = min(max_mult,
                            max(1, _FLUSH_MULT_MAX // self._flush_mult))
-            merged = lib.wf_launch_coalesce(handle, self._coalesce_cells,
-                                            16, max_mult)
+            with profile.span("launch_coalesce",
+                              shard=self._shard_base + shard):
+                merged = lib.wf_launch_coalesce(
+                    handle, self._coalesce_cells, 16, max_mult)
             if merged:
                 from ..ops.resident import stats_add
                 stats_add("merges", merged)
@@ -925,6 +927,9 @@ class NativeResidentCore:
                                   ctypes.byref(cap)):
             return False
         K, R, B = K.value, R.value, B.value
+        # the launch's identity from here to _harvest: a process-wide id,
+        # its ship thread, and the bookkeeping call that last fed the core
+        tag = (profile.next_id(), self._shard_base + shard, self._cause)
         # allocate the device-ready zero-padded rectangle(s) and let the
         # C++ take fill them directly (no _pad2 re-copy on this thread)
         from ..ops.device import _bucket
@@ -974,7 +979,15 @@ class NativeResidentCore:
             wlens = np.empty(max(B, 1), dtype=np.int32)
             wstarts_p = wstarts.ctypes.data_as(p32)
             wlens_p = wlens.ctypes.data_as(p32)
-        with profile.span("launch_take"):
+        with profile.span("launch_take", *tag) as sp:
+            if profile.ENABLED:
+                # the rows the C++ core holds for this rectangle, before
+                # it pads them to (KPp, Rb): rows_shipped counts the padding
+                live = (lib.wf_launch_live_rows(handle)
+                        * len(self._ship_fields))
+                profile.add("rows_live", live)
+                sp.extra = {"rows_live": live,
+                            "rows_shipped": KPp * Rb * len(self._ship_fields)}
             if self._multi:
                 ptrs = (ctypes.c_void_p * len(self._ship_fields))(
                     *[b.ctypes.data for b in blks.values()])
@@ -1007,16 +1020,18 @@ class NativeResidentCore:
                 blks = {f: b[:K] for f, b in blks.items()}
         meta = (hkey[:B], hid[:B], hts[:B], hlen[:B],
                 hpm[:B] if hpm is not None else None,
-                hpmn[:B] if hpmn is not None else None)
+                hpmn[:B] if hpmn is not None else None, tag)
         if self._multi:
-            ex.launch(meta, blks, offs, wrows[:B], wstarts[:B], wlens[:B])
+            ex.launch(meta, blks, offs, wrows[:B], wstarts[:B], wlens[:B],
+                      tag=tag)
         elif regular:
             # per-key arithmetic descriptors instead of 3x B int32 arrays
             ex.launch_regular(meta, blk, offs, rcount, rstart0, rlen,
                               self.spec.slide_len, wrows[:B], widx[:B],
-                              cmax=cmax.value)
+                              cmax=cmax.value, tag=tag)
         else:
-            ex.launch(meta, blk, offs, wrows[:B], wstarts[:B], wlens[:B])
+            ex.launch(meta, blk, offs, wrows[:B], wstarts[:B], wlens[:B],
+                      tag=tag)
         return True
 
     def _harvest(self, harvested) -> np.ndarray:
@@ -1024,20 +1039,22 @@ class NativeResidentCore:
             return np.zeros(0, dtype=self._result_dtype)
         from .win_seq_tpu import finalize_window_values
         outs = []
-        for (hkey, hid, hts, hlen, hpm, hpmn), out in harvested:
-            # multi executors return one array per stat (dev_parts
-            # order); the single path returns the stat array itself
-            arrs = out if isinstance(out, tuple) else (out,)
-            res = np.zeros(len(arrs[0]), dtype=self._result_dtype)
-            res["key"] = hkey
-            res["id"] = hid
-            res["ts"] = hts
-            for part, a in zip(self._dev_parts, arrs):
-                res[part.out_field] = finalize_window_values(part, a, hlen)
-            for part in self._count_parts:
-                res[part.out_field] = hlen.astype(part.dtype)
-            for part in self._pos_max_parts:
-                res[part.out_field] = finalize_window_values(
-                    part, hpm if part.op == "max" else hpmn, hlen)
+        for (hkey, hid, hts, hlen, hpm, hpmn, tag), out in harvested:
+            with profile.span("harvest_finalize", *tag):
+                # multi executors return one array per stat (dev_parts
+                # order); the single path returns the stat array itself
+                arrs = out if isinstance(out, tuple) else (out,)
+                res = np.zeros(len(arrs[0]), dtype=self._result_dtype)
+                res["key"] = hkey
+                res["id"] = hid
+                res["ts"] = hts
+                for part, a in zip(self._dev_parts, arrs):
+                    res[part.out_field] = finalize_window_values(part, a,
+                                                                 hlen)
+                for part in self._count_parts:
+                    res[part.out_field] = hlen.astype(part.dtype)
+                for part in self._pos_max_parts:
+                    res[part.out_field] = finalize_window_values(
+                        part, hpm if part.op == "max" else hpmn, hlen)
             outs.append(res)
         return outs[0] if len(outs) == 1 else np.concatenate(outs)

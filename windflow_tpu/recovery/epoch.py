@@ -114,10 +114,12 @@ class NodeRecovery:
 
     # ------------------------------------------------------------- producer
 
-    def emit(self, outputs, batch):
+    def emit(self, outputs, batch, stats=None):
         """Tagged broadcast to every output channel; sources then check
         the epoch triggers (markers ride *behind* the batch that tripped
-        them, so an epoch is a closed prefix of the stream)."""
+        them, so an epoch is a closed prefix of the stream).  `stats` is
+        the emitting node's NodeStats when it has one: the puts are then
+        timed as its blocked time, as on the seed path."""
         seq = self.out_seq
         for i, (inbox, src) in enumerate(outputs):
             seq[i] += 1
@@ -125,12 +127,14 @@ class NodeRecovery:
                 # a source forwarding wire-driven epochs (channel.py
                 # epoch frames): policy-exempt like EOS
                 inbox.put_ctrl(src, Tagged(seq[i], batch))
+            elif stats is not None:
+                stats.timed_put(inbox, src, Tagged(seq[i], batch))
             else:
                 inbox.put(src, Tagged(seq[i], batch))
         if self.is_source and type(batch) is not EpochMarker:
             self._after_source_emit(outputs)
 
-    def emit_to(self, outputs, out: int, batch):
+    def emit_to(self, outputs, out: int, batch, stats=None):
         inbox, src = outputs[out]
         self.out_seq[out] += 1
         if type(batch) is EpochMarker:
@@ -139,7 +143,10 @@ class NodeRecovery:
             # downstream alignment; a counted one would self-trigger)
             inbox.put_ctrl(src, Tagged(self.out_seq[out], batch))
             return
-        inbox.put(src, Tagged(self.out_seq[out], batch))
+        if stats is not None:
+            stats.timed_put(inbox, src, Tagged(self.out_seq[out], batch))
+        else:
+            inbox.put(src, Tagged(self.out_seq[out], batch))
         if self.is_source:
             self._after_source_emit(outputs)
 

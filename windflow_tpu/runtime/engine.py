@@ -57,6 +57,9 @@ class Inbox:
                                   and policy.reshapes_put) else None
         self.shed = 0
         self._shed_lock = threading.Lock()
+        #: name of the node that reads this inbox (set by Dataflow.add):
+        #: NodeStats names the inbox a producer's longest put was on
+        self.owner = None
         #: occupancy high-water mark, maintained only when the dataflow
         #: is observed (metrics/sample_period): the put-side cost is a
         #: single predictable `_track` branch when off.  Updated without
@@ -207,6 +210,7 @@ class NativeInbox:
                                   and policy.reshapes_put) else None
         self.shed = 0
         self._shed_lock = threading.Lock()
+        self.owner = None    # see Inbox
         self.hwm = 0         # see Inbox: observed-dataflow occupancy mark
         self._track = False
 
@@ -350,6 +354,16 @@ def _make_inbox(capacity: int, failed: threading.Event,
             # back to the Python queue
             return NativeInbox(capacity, failed, lib=lib, policy=policy)
     return Inbox(capacity, failed, policy)
+
+
+def _timed_get(inbox, stats):
+    """``inbox.get()`` booked as the node's idle time: waiting for input
+    is neither service nor blocked (utils/tracing.py, the three-way
+    split).  Both receive loops take it when the node has stats."""
+    t0 = _pc_ns()
+    got = inbox.get()
+    stats.idle_ns += _pc_ns() - t0
+    return got
 
 
 class Dataflow:
@@ -597,6 +611,7 @@ class Dataflow:
         self.nodes.append(node)
         inbox = _make_inbox(self.capacity, self._failed,
                             self._inbox_policy(node))
+        inbox.owner = node.name
         if self.metrics is not None or self.sample_period is not None:
             inbox._track = True  # maintain the occupancy high-water mark
         self._inboxes[id(node)] = inbox
@@ -716,7 +731,15 @@ class Dataflow:
                     # (recovery/epoch.py); sources are not restartable —
                     # a generate() failure propagates exactly as today
                     node._recov.begin(len(node._outputs), 0, 0)
-                node.generate()
+                if node.stats is not None:
+                    # a source's service is generate(): its puts are the
+                    # blocked part, stages fused into its thread are
+                    # booked per stage (NodeStats.timed_put)
+                    t0 = _pc_ns()
+                    node.generate()
+                    node.stats.svc_time_ns_total += _pc_ns() - t0
+                else:
+                    node.generate()
             elif supervised:
                 self._run_supervised(node, events)
             else:
@@ -725,7 +748,8 @@ class Dataflow:
                 stats = node.stats
                 budget = self._error_budget_of(node)
                 while live > 0:
-                    src, item = inbox.get()
+                    src, item = (inbox.get() if stats is None
+                                 else _timed_get(inbox, stats))
                     if item is _EOS:
                         live -= 1
                         if tracer is not None:
@@ -866,7 +890,8 @@ class Dataflow:
                     restoring = False
                     self._restore_and_replay(node, rec, events)
                 while rec.live > 0:
-                    src, item = inbox.get()
+                    src, item = (inbox.get() if node.stats is None
+                                 else _timed_get(inbox, node.stats))
                     if self._dispatch_supervised(node, rec, events, src,
                                                  item):
                         self._complete_barriers(node, rec, events)
@@ -1265,6 +1290,12 @@ class Dataflow:
                 self._supervisor.stop(wait_s=1.0 if timed_out else 30.0)
             if self.tracer is not None:
                 self.tracer.close()     # flush buffered spans to disk
+            if self.trace_dir:
+                # the ship path's launch records (utils/profile.py's ring:
+                # empty, and no file, unless profiling was on)
+                from ..utils import profile
+                profile.write_records(
+                    os.path.join(self.trace_dir, "launches.jsonl"))
             if self.events is not None and not self._stop_logged:
                 self._stop_logged = True
                 self.events.emit("dataflow_stop", dataflow=self.name,

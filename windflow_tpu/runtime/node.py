@@ -193,8 +193,6 @@ class Node:
         several outputs that need routing use emit_to)."""
         if batch is None:
             return
-        if self.stats is not None:
-            self.stats.record_departure()
         tr = self._tracer
         if tr is not None:
             # span tracing (obs/trace.py): sources decide sampling here;
@@ -205,7 +203,12 @@ class Node:
         if self._recov is not None:
             # recovery layer on: sequence-tag the emission per edge (and
             # let sources trail epoch markers) — recovery/epoch.py
-            self._recov.emit(self._outputs, batch)
+            self._recov.emit(self._outputs, batch, self.stats)
+            return
+        st = self.stats
+        if st is not None:
+            for inbox, src in self._outputs:
+                st.timed_put(inbox, src, batch)
             return
         for inbox, src in self._outputs:
             inbox.put(src, batch)
@@ -214,15 +217,17 @@ class Node:
         """Send to one specific output channel (ff_send_out_to)."""
         if batch is None:
             return
-        if self.stats is not None:
-            self.stats.record_departure()
         tr = self._tracer
         if tr is not None:
             batch = tr.outgoing(batch, self)
         if self._recov is not None:
-            self._recov.emit_to(self._outputs, out, batch)
+            self._recov.emit_to(self._outputs, out, batch, self.stats)
             return
         inbox, src = self._outputs[out]
+        st = self.stats
+        if st is not None:
+            st.timed_put(inbox, src, batch)
+            return
         inbox.put(src, batch)
 
     @property

@@ -5,6 +5,16 @@ dispatch and harvest blocking; these timers split it so.  Timers are process-wid
 disabled; ``report()`` returns {phase: (seconds, calls)} and ``counters()``
 plain accumulators (bytes shipped, launches, rows).
 
+When enabled a span is recorded three ways from ONE pair of clock reads:
+the accumulators above; a ``jax.profiler.TraceAnnotation("wf.<phase>",
+launch=, shard=)`` held open for the span's life, so the phase lies on the
+host plane of the profiler's own trace, on the device events' clock; and a
+record ``(phase, t0_ns, t1_ns, launch, shard, cause, extra)`` in a bounded
+ring (``records()``, ``write_records()`` — the engine writes it to
+``<trace_dir>/launches.jsonl``).  ``launch`` is the id ``next_id()`` gave
+the launch when the ship thread took it, ``cause`` the id of the
+``_process_rows`` call that last fed its core.
+
 Enablement is *not* frozen at import: ``WF_PROFILE`` is re-read lazily at
 every ``span`` entry (spans bracket ms-scale ship phases, so the environ
 lookup is noise there), and the parsed value is cached so ``add()`` —
@@ -19,10 +29,12 @@ kept for introspection and refreshed by every span entry.
 
 from __future__ import annotations
 
+import itertools
+import json
 import os
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 
 _FORCED: bool | None = None   # enable()/disable() override; None = env
 
@@ -81,7 +93,23 @@ _val: dict[str, float] = defaultdict(float)
 #: read-add-store on the accumulators must not lose updates
 _mu = threading.Lock()
 
+#: the spans of the run, oldest dropped first: (phase, t0_ns, t1_ns,
+#: launch, shard, cause, extra) on the perf_counter_ns clock.  Sized for a
+#: benchmark run (a few hundred launches and chunks a second for a minute)
+_RING = deque(maxlen=1 << 17)
+
+#: one id sequence for launches and for the bookkeeping calls that cause
+#: them, so an id orders what it names and a cause is always smaller than
+#: the launches it fed (next() on a count is atomic under the GIL)
+_ids = itertools.count(1)
+
+
+def next_id() -> int:
+    return next(_ids)
+
+
 #: per-exit observer hook (obs/trace.py): called as ``fn(name, dt_ns)``
+#: — ``fn(name, dt_ns, launch)`` for a span that names its launch —
 #: after every completed span, INDEPENDENTLY of the WF_PROFILE
 #: accumulators — the bridge that turns the ship-path phase spans
 #: (device_put / dispatch / harvest_wait, ops/resident.py) into
@@ -91,42 +119,89 @@ _RECORDER = None
 
 
 def set_recorder(fn):
-    """Install the span-exit observer (``fn(name, dt_ns)``).  The
-    recorder must be cheap and must not raise — it runs inside the
+    """Install the span-exit observer (``fn(name, dt_ns[, launch])``).
+    The recorder must be cheap and must not raise — it runs inside the
     device ship hot path.  Installing one makes every span stamp its
     clock even with profiling disabled; pass ``None`` to uninstall."""
     global _RECORDER
     _RECORDER = fn
 
 
+_annotation_cls = None
+
+
+def _annotation(name, launch, shard):
+    """An entered ``TraceAnnotation`` (a no-op costing one atomic read
+    while no profiler session runs)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    kw = {}
+    if launch is not None:
+        kw["launch"] = launch
+    if shard is not None:
+        kw["shard"] = shard
+    ann = _annotation_cls("wf." + name, **kw)
+    ann.__enter__()
+    return ann
+
+
 class span:
-    """``with span("device_put"): ...`` — accumulates wall time per phase."""
+    """``with span("device_put", launch=7, shard=0): ...`` — one ship
+    phase: wall time per phase, and with profiling on a ``wf.`` annotation
+    and a ring record (module docstring).  ``extra`` may be set inside the
+    block (a dict) and rides on the record."""
 
-    __slots__ = ("name", "t0", "_acc_on")
+    __slots__ = ("name", "launch", "shard", "cause", "extra", "t0", "t1",
+                 "_acc_on", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, launch: int = None, shard: int = None,
+                 cause: int = None):
         self.name = name
+        self.launch = launch
+        self.shard = shard
+        self.cause = cause
+        self.extra = None
+        self.t1 = None
 
     def __enter__(self):
         # the span brackets ONE decision per sink: __exit__ accumulates
         # iff _acc_on, and calls the recorder iff t0 was stamped while
         # one was installed — a mid-span toggle cannot read a stale t0
         self._acc_on = _enabled()
-        self.t0 = (time.perf_counter_ns()
-                   if (self._acc_on or _RECORDER is not None) else None)
+        if self._acc_on:
+            self._ann = _annotation(self.name, self.launch, self.shard)
+            self.t0 = time.perf_counter_ns()
+        else:
+            self.t0 = (time.perf_counter_ns()
+                       if _RECORDER is not None else None)
         return self
 
     def __exit__(self, *exc):
-        if self.t0 is not None:
-            dt_ns = time.perf_counter_ns() - self.t0
+        t0 = self.t0
+        if t0 is not None:
+            t1 = self.t1 = time.perf_counter_ns()
             if self._acc_on:
+                self._ann.__exit__(*exc)
                 with _mu:
-                    _acc[self.name] += dt_ns / 1e9
+                    _acc[self.name] += (t1 - t0) / 1e9
                     _cnt[self.name] += 1
+                    _RING.append((self.name, t0, t1, self.launch,
+                                  self.shard, self.cause, self.extra))
             rec = _RECORDER
             if rec is not None:
-                rec(self.name, dt_ns)
+                if self.launch is None:
+                    rec(self.name, t1 - t0)
+                else:
+                    rec(self.name, t1 - t0, self.launch)
         return False
+
+    def end_ns(self) -> int:
+        """The clock at this span's exit: the stamp the span took if it
+        timed itself, read now otherwise — so a launch boundary that is
+        also a span's edge is stamped once."""
+        return self.t1 if self.t1 is not None else time.perf_counter_ns()
 
 
 def add(name: str, value: float = 1.0):
@@ -155,17 +230,32 @@ def counters() -> dict:
     return {k: val[k] for k in sorted(val)}
 
 
+def records() -> list:
+    """The ring's spans, oldest first (a snapshot)."""
+    with _mu:
+        return list(_RING)
+
+
+def write_records(path: str) -> int:
+    """Write the ring as one JSON object per span; returns how many.
+    Nothing recorded, nothing written."""
+    recs = records()
+    if not recs:
+        return 0
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    with open(path, "w") as f:
+        for phase, t0, t1, launch, shard, cause, extra in recs:
+            line = {"phase": phase, "t0_ns": t0, "t1_ns": t1,
+                    "launch": launch, "shard": shard, "cause": cause}
+            if extra:
+                line.update(extra)
+            f.write(json.dumps(line) + "\n")
+    return len(recs)
+
+
 def reset():
     with _mu:
         _acc.clear()
         _cnt.clear()
         _val.clear()
-
-
-def dump() -> str:
-    lines = ["phase                      seconds    calls"]
-    for k, (s, c) in report().items():
-        lines.append(f"{k:<25} {s:>9.3f} {c:>8d}")
-    for k, v in counters().items():
-        lines.append(f"{k:<25} {v:>14.0f}")
-    return "\n".join(lines)
+        _RING.clear()
