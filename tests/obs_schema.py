@@ -37,6 +37,11 @@ NODE_OPTIONAL_FIELDS = {
     "rcv_tuples": (int,),
     "ewma_service_us_per_batch": (int, float),
     "avg_service_us_per_batch": (int, float),
+    # the gather's counters (Filter stages, keyed StandardEmitter)
+    "filter_rows_in": (int,),
+    "filter_rows_out": (int,),
+    "split_batches": (int,),
+    "single_dest_batches": (int,),
     # span-tracing latency fields (obs/trace.py; only on traced graphs)
     "q_p50_us": (int, float),
     "q_p95_us": (int, float),

@@ -172,3 +172,111 @@ def test_ordering_renumbering():
     merged = np.concatenate(outs)
     assert merged["ts"].tolist() == [10, 20, 30, 40]   # ts-ordered
     assert merged["id"].tolist() == [0, 1, 2, 3]       # densely renumbered
+
+
+# ------------------------------------------------ StandardEmitter keyed split
+
+class _Tap:
+    """An inbox that keeps what it is handed, in arrival order."""
+
+    def __init__(self):
+        self.got = []
+
+    def put(self, src, batch):
+        self.got.append(batch)
+
+
+def _keyed_emitter(n_dest, traced):
+    from windflow_tpu.runtime.emitters import StandardEmitter, default_routing
+    from windflow_tpu.utils.tracing import NodeStats
+
+    em = StandardEmitter(n_dest, default_routing, name="em")
+    taps = [_Tap() for _ in range(n_dest)]
+    em._outputs = [(t, 0) for t in taps]
+    if traced:
+        em.stats = NodeStats("em")
+    return em, taps
+
+
+def _split_stream(n_dest, seed):
+    """Mixed batches, two whose keys all route to one destination, and an
+    empty one."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(6):
+        n = int(rng.integers(50, 400))
+        keys = rng.integers(0, 23, n)
+        if i in (2, 4):
+            keys = keys * n_dest + (i % n_dest)      # one destination
+        ids = np.arange(n) + 1000 * i
+        out.append(batch_from_columns(SCHEMA, key=keys, id=ids, ts=ids,
+                                      value=rng.integers(0, 99, n)))
+    out.append(batch_from_columns(SCHEMA, key=[], id=[], ts=[], value=[]))
+    return out
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("n_dest", [2, 3, 4])
+def test_standard_emitter_keyed_split_is_the_boolean_mask(n_dest, traced):
+    """Every destination gets exactly ``batch[dest == d]`` of every batch,
+    in arrival order, each piece an array of its own; a batch whose keys
+    all route one way is forwarded whole (the same object); the counters
+    read what was pushed when tracing is on and do not exist when off."""
+    em, taps = _keyed_emitter(n_dest, traced)
+    stream = _split_stream(n_dest, seed=n_dest)
+    want = [[] for _ in range(n_dest)]
+    n_single = n_split = 0
+    for b in stream:
+        before = b.tobytes()
+        em.svc(b)
+        assert b.tobytes() == before
+        dest = b["key"] % n_dest
+        hit = np.unique(dest)
+        if len(hit) == 1:
+            n_single += 1
+            assert taps[int(hit[0])].got[-1] is b
+        elif len(hit):
+            n_split += 1
+        for d in hit:
+            want[int(d)].append(b[dest == d])
+    assert (n_single, n_split) == (2, 4)
+    for d in range(n_dest):
+        assert len(taps[d].got) == len(want[d])
+        for got, ref in zip(taps[d].got, want[d]):
+            assert got.dtype == ref.dtype
+            assert got.tobytes() == ref.tobytes()
+            assert got.flags.c_contiguous and got.flags.writeable
+            assert not any(np.shares_memory(got, b) for b in stream
+                           if got is not b)
+    if traced:
+        snap = em.stats.snapshot()
+        assert snap["single_dest_batches"] == n_single
+        assert snap["split_batches"] == n_split
+    else:
+        assert em.stats is None
+
+
+def test_filter_counts_rows_in_and_out_when_traced():
+    from windflow_tpu.patterns.basic import _FilterNode
+    from windflow_tpu.utils.tracing import NodeStats
+
+    b = batch_from_columns(SCHEMA, key=np.arange(40) % 4, id=np.arange(40),
+                           ts=np.arange(40), value=np.arange(40))
+    for traced in (False, True):
+        node = _FilterNode(lambda x: x["value"] % 5 != 0, "f", False, True)
+        tap = _Tap()
+        node._outputs = [(tap, 0)]
+        if traced:
+            node.stats = NodeStats("f")
+        node.svc(b)
+        node.svc(b[:0])
+        node.svc(b[b["value"] % 5 == 0])           # nothing survives
+        assert len(tap.got) == 1
+        assert tap.got[0].tobytes() == b[b["value"] % 5 != 0].tobytes()
+        assert tap.got[0].flags.owndata
+        if traced:
+            snap = node.stats.snapshot()
+            assert snap["filter_rows_in"] == 48
+            assert snap["filter_rows_out"] == 32
+        else:
+            assert node.stats is None

@@ -384,3 +384,40 @@ def test_latency_summarize_p50_and_n():
     assert m["n_latency_samples"] == 4
     assert {"avg_latency_us", "p50_latency_us",
             "p95_latency_us", "p99_latency_us"} <= set(m)
+
+
+def test_gather_counters_reach_the_log_and_metrics_jsonl(tmp_path):
+    """The Filter's selectivity and the keyed emitter's split tally
+    (docs/OBSERVABILITY.md) land where every other NodeStats counter
+    does: the node's ``.log`` and the node's entry in ``metrics.jsonl``."""
+    from windflow_tpu.patterns.basic import Filter
+
+    d = str(tmp_path / "gather")
+    rows = 10
+    batches = [batch_from_columns(
+        SCHEMA, key=np.arange(rows) + i, id=np.arange(rows),
+        ts=np.arange(rows), value=np.arange(rows)) for i in range(12)]
+    df = Dataflow("g", capacity=4, trace_dir=d, sample_period=0.005)
+    build_pipeline(df, [Source(batches=batches, schema=SCHEMA),
+                        Filter(lambda b: b["value"] % 5 != 0,
+                               vectorized=True),
+                        Map(lambda b: None, vectorized=True, parallelism=2,
+                            keyed=True),
+                        Sink(lambda r: None, vectorized=True)])
+    df.run_and_wait_end()
+    logs = [json.load(open(os.path.join(d, f)))
+            for f in sorted(os.listdir(d)) if f.endswith(".log")]
+    filt = [v for v in logs if "filter_rows_in" in v]
+    assert len(filt) == 1
+    assert filt[0]["filter_rows_in"] == 12 * rows
+    assert filt[0]["filter_rows_out"] == 12 * (rows - 2)
+    split = [v for v in logs if "split_batches" in v]
+    assert len(split) == 1 and split[0]["split_batches"] == 12
+    assert "single_dest_batches" not in split[0]      # never bumped
+    validate_file(os.path.join(d, "metrics.jsonl"), validate_sample)
+    last = [json.loads(line)
+            for line in open(os.path.join(d, "metrics.jsonl"))][-1]
+    by = {k: n[k] for n in last["nodes"] for k in
+          ("filter_rows_in", "filter_rows_out", "split_batches") if k in n}
+    assert by == {"filter_rows_in": 120, "filter_rows_out": 96,
+                  "split_batches": 12}

@@ -145,6 +145,11 @@ class Sampler:
             entry["rcv_tuples"] = stats.rcv_tuples
             entry["ewma_service_us_per_batch"] = round(stats.ewma_ts_us, 3)
             entry["avg_service_us_per_batch"] = round(stats.avg_ts_us, 3)
+            # node-specific extras as they stand (filter_rows_in/_out,
+            # split_batches, windows_fired, ...); the live fields above
+            # win — `shed` is folded into the counters only at node end
+            for k, v in dict(stats.counters).items():
+                entry.setdefault(k, v)
         tracer = getattr(self.df, "tracer", None)
         if tracer is not None:
             # span-tracing latency sensors (obs/trace.py): per-node

@@ -21,7 +21,8 @@ import sys
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD, Schema
+from ..core.tuples import (MARKER_FIELD, Schema, group_by_key, select_rows,
+                           take_rows)
 from ..runtime.emitters import Collector, StandardEmitter, default_routing
 from ..runtime.node import Node, RuntimeContext, SourceNode
 
@@ -290,7 +291,11 @@ class _FilterNode(Node):
         else:
             mask = np.fromiter((bool(self.fn(row, *args)) for row in batch),
                                dtype=bool, count=len(batch))
-        out = batch[mask]
+        out = select_rows(batch, mask)
+        st = self.stats
+        if st is not None:
+            st.bump("filter_rows_in", len(batch))
+            st.bump("filter_rows_out", len(out))
         if len(out):
             self.emit(out)
 
@@ -423,14 +428,13 @@ class _AccumulatorNode(Node):
         args = (self.ctx,) if self.rich else ()
         # group rows by key once (sorted contiguous slices): one state
         # lookup per distinct key per chunk instead of per row
-        from ..core.tuples import group_by_key
         keys = batch["key"]
         order, starts, ends = group_by_key(keys)
         sk = keys[order]
         for s, e in zip(starts, ends):
             idx = order[s:e]
             acc = self._acc(int(sk[s]))
-            rows = batch[idx]
+            rows = take_rows(batch, idx)
             if self.vectorized:
                 # vectorised fold: fn(rows, acc) -> per-row snapshots of
                 # the result fields (len(rows) records)

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..core.tuples import select_rows, take_rows
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..runtime.emitters import Collector, KeyedStreamState
 from ..runtime.node import Node, RuntimeContext
@@ -85,7 +86,7 @@ class WFEmitterNode(Node):
         if spec.is_hopping:
             keep &= spec.in_any_window(np.maximum(rel, 0))
         if not np.all(keep):
-            batch = batch[keep]
+            batch = select_rows(batch, keep)
             rel = rel[keep]
             keys = keys[keep]
         if len(batch) == 0:
@@ -110,7 +111,7 @@ class WFEmitterNode(Node):
             # satisfies (key%n + w) % n == d
             r = (d - start_dst - first_w) % n
             m = (count >= n) | (r < count)
-            sub = batch[m]
+            sub = select_rows(batch, m)
             if len(sub):
                 self.emit_to(d, sub)
 
@@ -170,10 +171,12 @@ class WFCollectorNode(Node):
             # rest of the pending buffer untouched instead of re-sorting it
             touched = np.isin(self._pend_slots, slots)
             if touched.any():
-                rows = np.concatenate((self._pend_rows[touched], batch))
+                rows = np.concatenate(
+                    (select_rows(self._pend_rows, touched), batch))
                 slots = np.concatenate((self._pend_slots[touched], slots))
                 unt = ~touched
-                self._pend_rows = self._pend_rows[unt] if unt.any() else None
+                self._pend_rows = (select_rows(self._pend_rows, unt)
+                                   if unt.any() else None)
                 self._pend_slots = (self._pend_slots[unt] if unt.any()
                                     else np.zeros(0, dtype=np.int64))
             else:
@@ -198,14 +201,14 @@ class WFCollectorNode(Node):
             n_rel = np.add.reduceat(release, starts)
             u = s[starts]
             self._next[u] += n_rel
-            out = rows[order[release]]
+            out = take_rows(rows, order[release])
             keep = ~release
-            held = rows[order[keep]] if keep.any() else None
+            held = take_rows(rows, order[keep]) if keep.any() else None
             held_slots = slots[order[keep]] if keep.any() else None
             self._stash(held, held_slots)
             self.emit(out)
         else:
-            self._stash(rows[order], s)
+            self._stash(take_rows(rows, order), s)
 
     def _stash(self, held, held_slots):
         """Park unreleased rows, joining any untouched pending buffer."""

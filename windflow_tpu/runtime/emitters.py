@@ -13,7 +13,7 @@ import ctypes
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD
+from ..core.tuples import MARKER_FIELD, select_rows, take_rows
 from .node import Node
 
 _NEG_INF = np.int64(-(2 ** 62))
@@ -133,7 +133,7 @@ class KeyedStreamState:
         s = slots_of_rows[order]
         last = np.ones(len(s), dtype=bool)
         last[:-1] = s[1:] != s[:-1]
-        buf[s[last]] = rows[order[last]]
+        buf[s[last]] = take_rows(rows, order[last])
 
     def filter(self, batch: np.ndarray) -> np.ndarray:
         """Absorb marker rows and drop out-of-order rows; returns the
@@ -141,19 +141,20 @@ class KeyedStreamState:
         self.pos_cache = None
         mk = batch[MARKER_FIELD]
         if np.any(mk):
-            mrows = batch[mk]
+            mrows = select_rows(batch, mk)
             mpos = mrows[self.pos_field].astype(np.int64)
             mslots = self._lookup(mrows["key"])
             ok = mpos >= self._last_pos[mslots]
             if not ok.all():
-                mrows, mpos, mslots = mrows[ok], mpos[ok], mslots[ok]
+                mrows = select_rows(mrows, ok)
+                mpos, mslots = mpos[ok], mslots[ok]
             if len(mrows):
                 # order by pos so the stored last row is the max-pos
                 # marker (ties: later arrival wins, like the dict form)
                 mo = np.argsort(mpos, kind="stable")
-                self._store_last(mslots[mo], mrows[mo])
+                self._store_last(mslots[mo], take_rows(mrows, mo))
                 np.maximum.at(self._last_pos, mslots, mpos)
-            batch = batch[~mk]
+            batch = select_rows(batch, ~mk)
         if len(batch) == 0:
             return batch
         slots = self._lookup(batch["key"])
@@ -170,7 +171,7 @@ class KeyedStreamState:
                 # in-order: capture each touched slot's last row + pos
                 # (tiny gathers — one row per distinct key)
                 buf = self._rows_buf(batch.dtype)
-                buf[t] = batch[li]
+                buf[t] = take_rows(batch, li)
                 self._last_pos[t] = pos[li]
                 self.pos_cache = pos
                 return batch
@@ -205,11 +206,11 @@ class KeyedStreamState:
         if len(liv):
             ls, le = segments(s[liv])
             self._last_pos[s[liv[ls]]] = ps[liv[le - 1]]
-            self._store_last(slots[order[liv]], batch[order[liv]],
+            self._store_last(slots[order[liv]], take_rows(batch, order[liv]),
                              sorted_order=np.arange(len(liv)))
         keep = np.empty(len(batch), dtype=bool)
         keep[order] = keep_sorted
-        return batch if keep.all() else batch[keep]
+        return batch if keep.all() else select_rows(batch, keep)
 
     def state_snapshot(self):
         """Recovery snapshot of the per-key bookkeeping, numpy path only
@@ -241,7 +242,7 @@ class KeyedStreamState:
         seen = self._last_pos[:self._n] > _NEG_INF
         if not seen.any():
             return None
-        markers = self._rows[:self._n][seen].copy()
+        markers = select_rows(self._rows[:self._n], seen)
         markers[MARKER_FIELD] = True
         return markers
 
@@ -286,14 +287,22 @@ class StandardEmitter(Node):
             self.emit_to(self._rr, batch)
             self._rr = (self._rr + 1) % n
             return
+        if len(batch) == 0:
+            return
+        st = self.stats
         dest = np.asarray(self.routing(batch["key"], n))
-        if len(batch) and (dest[0] == dest[-1]) and not np.any(dest != dest[0]):
+        if dest[0] == dest[-1] and not np.any(dest != dest[0]):
+            if st is not None:
+                st.bump("single_dest_batches")
             self.emit_to(int(dest[0]), batch)
             return
+        if st is not None:
+            st.bump("split_batches")
+        # one owned array per destination: a consumer may write its batch
         for d in range(n):
-            sub = batch[dest == d]
-            if len(sub):
-                self.emit_to(d, sub)
+            idx = np.flatnonzero(dest == d)
+            if len(idx):
+                self.emit_to(d, take_rows(batch, idx))
 
 
 class Collector(Node):

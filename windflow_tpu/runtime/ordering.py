@@ -25,7 +25,7 @@ import enum
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD
+from ..core.tuples import MARKER_FIELD, select_rows, take_rows
 from .node import Node
 
 _NEG_INF = -(2 ** 62)
@@ -180,7 +180,7 @@ class OrderingCore:
             return None
         merged = take[0] if len(take) == 1 else np.concatenate(take)
         order = np.argsort(merged[self.pos_field], kind="stable")
-        merged = merged[order]     # advanced indexing: always a fresh array
+        merged = take_rows(merged, order)     # always a fresh array
         if self.mode is OrderingMode.TS_RENUMBERING:
             merged["id"] = kb.emit_counter + np.arange(len(merged))
             kb.emit_counter += len(merged)
@@ -250,13 +250,13 @@ class OrderingCore:
         out = []
         marker = batch[MARKER_FIELD]
         if np.any(marker):
-            for row in batch[marker]:
+            for row in select_rows(batch, marker):
                 kb = self._buf(int(row["key"]))
                 p = int(row[self.pos_field])
                 if p > kb.marker_pos or kb.marker_row is None:
                     kb.marker_pos = p
                     kb.marker_row = row.copy()
-            batch = batch[~marker]
+            batch = select_rows(batch, ~marker)
         if len(batch) == 0:
             return out
         if (self.n_channels == 1 and not self.per_key
@@ -272,7 +272,7 @@ class OrderingCore:
         for grp in np.split(order, bounds):
             key = int(keys[grp[0]])
             kb = self._buf(key)
-            rows = batch[grp]
+            rows = take_rows(batch, grp)
             kb.chans[channel].append(rows)
             if self.per_key:
                 # per-key watermark advance (orderingNode.hpp:151-152);
