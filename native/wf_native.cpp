@@ -46,6 +46,11 @@ static const i64 kMaxRingCells = 1LL << 25;
 // MultiFieldResidentExecutor); richer aggregates fall back to the Python
 // core.  4 covers every tracked workload (YSB --rich-stats ships 2).
 static const int kMaxFields = 4;
+// arg-extremum cores (wf_core_set_arg) archive up to this many further
+// int64 columns per row that never ship: the tie-break id and the fields
+// of the winning row that the result carries (read back at harvest,
+// wf_core_arg_gather)
+static const int kMaxCarry = 8;
 
 static inline i64 bucket(i64 n, i64 lo = 8) {
     i64 b = lo;
@@ -114,6 +119,10 @@ struct KeyState {
     // The dense row itself stays registered — queued launches and wrow
     // entries index rows by position, so rows are never renumbered.
     bool neutral = false;
+    // arg-extremum cores: absolute start row of every fired window whose
+    // winning row has not been read back yet (wf_core_arg_gather pops it);
+    // purge() keeps the archive from the oldest of them on
+    std::deque<i64> held;
 
     inline void note_vals(int nf, const i64 *vs) {
         if (!pend_any) {
@@ -148,8 +157,16 @@ struct KeyState {
         if (purge_pos <= NEG_INF) return;
         const i64 *p = pos.data() + start;
         size_t cut = std::lower_bound(p, p + live(), purge_pos) - p;
+        bool limited = false;
+        if (!held.empty()) {
+            const i64 lim = held.front() - (appended - (i64)live());
+            if (lim < (i64)cut) {
+                cut = lim > 0 ? (size_t)lim : 0;
+                limited = true;   // the rest goes at a later flush
+            }
+        }
         start += cut;
-        purge_pos = NEG_INF;
+        if (!limited) purge_pos = NEG_INF;
         // amortised compaction (archive.py:purge_below)
         if (start > 4096 && start > live()) {
             pos.erase(pos.begin(), pos.begin() + start);
@@ -192,6 +209,13 @@ struct Launch {
     std::vector<i64> hpmin;   // B per-window MIN position: the window's
                               // FIRST row, free by the same ordering —
                               // first-update style stats never ship
+    // arg-extremum cores only: each window's absolute start row (the
+    // harvest reads the winning row at habs + its ring-relative index);
+    // rebase == 2 marks an on-device compaction — every ring row slides
+    // left by shifts[k] before the append, nothing re-ships; Rb is the
+    // padded rectangle width the core reserved ring room for
+    std::vector<i64> habs, shifts;
+    i64 Rb = 0;
 };
 
 struct Core {
@@ -206,6 +230,17 @@ struct Core {
     // max_wire so the per-field logic has one source of truth)
     int n_fields = 1;
     int max_wire_f[kMaxFields];
+    // arg-extremum mode (wf_core_set_arg): n_carry archived-only columns
+    // after the shipped ones, ring rows bucketed from 1 (a key-less stream
+    // is one row), a ring that never shrinks and starts at cap_floor (from
+    // the window_rows the function declared, reserve_declared_window), a
+    // steady rectangle width (rb_floor), purge at the NEXT window's start
+    // and compaction on the device instead of a re-shipping rebase.
+    // arg_field is the ship field the extremum runs over (ties are resolved
+    // on its archive column)
+    int arg_mode = 0, n_carry = 0, arg_field = 0;
+    i64 window_rows = 0, cap_floor = 0, rb_floor = 0, kp_lo = 8;
+    int n_cols() const { return n_fields + n_carry; }
     bool hopping;
 
     std::unordered_map<i64, int> rowmap;
@@ -256,7 +291,7 @@ struct Core {
         keys.emplace_back();
         KeyState &st = keys.back();
         st.row = r;
-        if (n_fields > 1) st.xval.resize((size_t)(n_fields - 1));
+        if (n_cols() > 1) st.xval.resize((size_t)(n_cols() - 1));
         // farm distribution math (windows.py PatternConfig,
         // reference win_seq.hpp:307-314)
         i64 a = pymod(id_inner - pymod(key, n_inner), n_inner);
@@ -317,14 +352,40 @@ struct Core {
             hts.push_back(out_ts);
             hpm.push_back(hi > lo ? p[hi - 1] : 0);
             hpmn.push_back(hi > lo ? p[lo] : 0);
-            if (!eos) st.purge_pos = std::max(st.purge_pos, s_abs);
+            if (arg_mode) st.held.push_back(abs_lo);
+            // an arg-extremum window's rows are dead once its winner is
+            // read back: nothing below the NEXT window's start is needed
+            if (!eos)
+                st.purge_pos = std::max(st.purge_pos,
+                                        arg_mode ? s_abs + slide : s_abs);
+        }
+    }
+
+    // arg-extremum, first flush, where the function declared its window's
+    // rows (ArgReducer window_rows: a time-based window's length in rows is
+    // not in its spec): the ring starts twice as wide as one such window
+    // plus one rectangle (`slack`; live rows and the rectangle never pass
+    // half the ring: flush() widens it first) and the archives of the keys
+    // met so far reserve one ring half, so the stream meets in its first
+    // windows neither a growth of the ring (a compile, an HBM copy and a
+    // fresh zero ring each) nor the doubling copies of gigabyte columns.
+    // Only a start: a stream that outgrows the declaration still widens
+    // its ring.
+    void reserve_declared_window(i64 slack) {
+        cap_floor = bucket(2 * (window_rows + slack), 16);
+        const size_t rows = (size_t)(cap_floor / 2);
+        for (auto &st : keys) {
+            st.pos.reserve(rows);
+            st.ts.reserve(rows);
+            st.val.reserve(rows);
+            for (auto &xv : st.xval) xv.reserve(rows);
         }
     }
 
     void flush() {
         if (hkey.empty() && pend_rows == 0) return;
         const i64 K = (i64)keys.size();
-        const i64 KPb = bucket(std::max<i64>(K, 1));
+        const i64 KPb = bucket(std::max<i64>(K, 1), kp_lo);
         // a row-triggered FIRST flush marks a throughput stream: provision
         // the full coalescing ladder's ring room up front, so the steady
         // state has no room-growth rebases at all (each one is an
@@ -337,8 +398,38 @@ struct Core {
         i64 maxpend = 0;
         for (auto &st : keys)
             maxpend = std::max(maxpend, st.appended - st.launched);
+        Launch L;
         if (!rebase) {
-            const i64 Rb = bucket(std::max<i64>(maxpend, 1));
+            const i64 Rb = std::max(bucket(std::max<i64>(maxpend, 1)),
+                                    rb_floor);
+            if (arg_mode) {
+                // an arg-extremum ring is never re-shipped.  It is kept at
+                // least twice as wide as the live rows plus one rectangle
+                // (a harvest that lags holds rows back, and they must not
+                // overrun it): past that the device widens it in place
+                // (ArgExtResidentExecutor.grow) to at least four times, so
+                // that a stream has to double before it grows again (each
+                // growth is a compile of the step at the new width, an HBM
+                // copy and a fresh zero ring) ...
+                i64 need = 0;
+                for (auto &st : keys)
+                    need = std::max(need, st.launched
+                                    - (st.appended - (i64)st.live()) + Rb);
+                if (2 * need > cap) cap = std::max(cap, bucket(4 * need));
+                // ... and when its tail is reached the live rows slide to
+                // the front on the device (one HBM copy)
+                bool full = false;
+                for (auto &st : keys)
+                    if (st.launched - st.ring_base + Rb > cap) full = true;
+                if (full) {
+                    L.shifts.assign((size_t)K, 0);
+                    for (auto &st : keys) {
+                        const i64 live_start = st.appended - (i64)st.live();
+                        L.shifts[(size_t)st.row] = live_start - st.ring_base;
+                        st.ring_base = live_start;
+                    }
+                }
+            }
             for (auto &st : keys) {
                 if (st.launched - st.ring_base + Rb > cap) {
                     rebase = true;
@@ -360,6 +451,7 @@ struct Core {
             i64 slack =
                 std::max<i64>(flush_rows / std::max<i64>(K, 1), 64);
             KP = KPb;
+            if (cap == 0 && window_rows > 0) reserve_declared_window(slack);
             // ring room for room_mult launch widths per key: try_merge's
             // offset guard (maxoff + bucket(newR) <= cap) can only admit
             // merges the ring has room for, so coalescing depth is capped
@@ -374,7 +466,10 @@ struct Core {
                           2 * maxlive + room_mult * slack, 16))
                           > kMaxRingCells / n_fields)
                 room_mult /= 2;
-            cap = bucket(std::max<i64>(2 * maxlive + room_mult * slack, 16));
+            cap = std::max(bucket(std::max<i64>(
+                               2 * maxlive + room_mult * slack, 16)),
+                           arg_mode ? std::max(cap, cap_floor) : (i64)0);
+            L.shifts.clear();
             R = maxlive;
             for (auto &st : keys) {
                 st.ring_base = st.appended - (i64)st.live();
@@ -426,7 +521,6 @@ struct Core {
                 }
             }
         }
-        Launch L;
         for (int f = 0; f < n_fields; ++f) {
             int w;
             if (!anyv || (vmin[f] >= -128 && vmax[f] <= 127)) w = 0;
@@ -513,7 +607,12 @@ struct Core {
         L.hpmax = std::move(hpm);
         L.hpmin = std::move(hpmn);
         L.K = K; L.R = Rr; L.B = B; L.KP = KP; L.cap = cap;
-        L.rebase = rebase ? 1 : 0;
+        L.rebase = rebase ? 1 : (L.shifts.empty() ? 0 : 2);
+        if (arg_mode) {
+            L.habs = wlo;
+            L.Rb = std::max(bucket(Rr), rb_floor);
+            rb_floor = std::min(L.Rb, bucket(std::max<i64>(flush_rows, 1)));
+        }
         {
             std::lock_guard<std::mutex> lk(qmu);
             queue.push_back(std::move(L));
@@ -541,7 +640,7 @@ struct Core {
         // bench hot loop and stays specialized; multi-field streams (none
         // of which are key-periodic in the tracked workloads) take the
         // general loop
-        if (kind != CB || hopping || n < 2 || n_fields > 1) return 0;
+        if (kind != CB || hopping || n < 2 || n_cols() > 1) return 0;
         i64 key0;
         std::memcpy(&key0, base + o_key, 8);
         i64 P = -1;
@@ -712,7 +811,7 @@ struct Core {
         // a multi-field core driven through the single-field entry points
         // has no extra offsets: refuse (defined error) instead of
         // dereferencing null per appended row
-        if (n_fields > 1 && o_xval == nullptr) return -1;
+        if (n_cols() > 1 && o_xval == nullptr) return -1;
         const u8 sid = (u8)shard_id;
         for (i64 i = 0; i < n; ++i) {
             const u8 *rp = base + i * itemsize;
@@ -745,11 +844,11 @@ struct Core {
                 st.val.push_back(val);
                 i64 vrow[kMaxFields];
                 vrow[0] = val;
-                for (int f = 1; f < n_fields; ++f) {
+                for (int f = 1; f < n_cols(); ++f) {
                     i64 v;
                     std::memcpy(&v, rp + o_xval[f - 1], 8);
                     st.xval[(size_t)(f - 1)].push_back(v);
-                    vrow[f] = v;
+                    if (f < n_fields) vrow[f] = v;
                 }
                 st.note_vals(n_fields, vrow);
                 st.appended++;
@@ -974,6 +1073,79 @@ i64 wf_core_set_fields(void *h, i64 n_fields, const int *max_wires) {
     return nf;
 }
 
+// Arg-extremum mode (ops/functions.py ArgReducer on the native resident
+// core).  Same contract as wf_core_set_fields: once, before any process
+// call, after wf_core_set_fields.  The core then archives `n_carry` more
+// int64 columns per row (offsets follow the shipped fields' in the _f
+// entry point; never shipped) and keeps every fired window's rows until
+// wf_core_arg_gather has read its winner.  `arg_field` is the index among
+// the shipped fields of the one the extremum runs over; `window_rows` the
+// rows one key's window is declared to hold on this core (0: not declared,
+// the ring grows as the stream shows).  Returns the carry count accepted (a
+// short return, or -1 for an arg_field that is no shipped field, is a
+// refusal).
+i64 wf_core_set_arg(void *h, i64 n_carry, i64 arg_field, i64 window_rows) {
+    Core *c = (Core *)h;
+    if (arg_field < 0 || arg_field >= c->n_fields) return -1;
+    c->window_rows = window_rows > 0 ? window_rows : 0;
+    int nc = (int)(n_carry < 0 ? 0 : n_carry);
+    if (nc > kMaxCarry) nc = kMaxCarry;
+    c->arg_mode = 1;
+    c->n_carry = nc;
+    c->arg_field = (int)arg_field;
+    c->kp_lo = 1;
+    return nc;
+}
+
+// The winning row of each window of a harvested arg-extremum launch, in
+// launch order (node thread only: it reads the archives).  Window i spans
+// `hlen[i]` rows from absolute row `habs[i]`; the device found `ext[i]`
+// first at relative index `idx[i]`, `nties[i]` times in all.  With ties the
+// window's rows at the extremum (the archive column of ship field
+// `arg_field`) are scanned for the lowest value in carry column 0 (the
+// tie-break id, by the caller's contract).  Writes the row's ts and carry columns
+// (out_cols is n_carry x B, column-major by carry), releases the window's
+// hold on the archive, and returns how many windows had ties (-1: a row
+// was no longer archived).
+i64 wf_core_arg_gather(void *h, i64 B, const i64 *hkey, const i64 *habs,
+                       const i64 *hlen, const i64 *ext, const int32_t *idx,
+                       const int32_t *nties, i64 *out_ts, i64 *out_cols) {
+    Core *c = (Core *)h;
+    i64 tied = 0;
+    bool lost = false;
+    for (i64 i = 0; i < B; ++i) {
+        KeyState &st = c->state(hkey[i]);
+        if (!st.held.empty()) st.held.pop_front();
+        out_ts[i] = 0;
+        for (int k = 0; k < c->n_carry; ++k) out_cols[k * B + i] = 0;
+        if (hlen[i] <= 0) continue;
+        const i64 live_start = st.appended - (i64)st.live();
+        const i64 j0 = habs[i] - live_start;
+        if (j0 < 0 || j0 + hlen[i] > (i64)st.live()) { lost = true; continue; }
+        i64 rel = idx[i];
+        if (rel < 0 || rel >= hlen[i]) rel = 0;
+        size_t j = st.start + (size_t)(j0 + rel);
+        if (nties[i] > 1) {
+            ++tied;
+            if (c->n_carry > 0) {
+                const std::vector<i64> &vals =
+                    c->arg_field == 0
+                        ? st.val : st.xval[(size_t)(c->arg_field - 1)];
+                const std::vector<i64> &ids =
+                    st.xval[(size_t)(c->n_fields - 1)];
+                const size_t lo = st.start + (size_t)j0;
+                for (size_t q = lo; q < lo + (size_t)hlen[i]; ++q)
+                    if (vals[q] == ext[i] && ids[q] < ids[j]) j = q;
+            }
+        }
+        out_ts[i] = st.ts[j];
+        for (int k = 0; k < c->n_carry; ++k)
+            out_cols[k * B + i] =
+                st.xval[(size_t)(c->n_fields - 1 + k)][j];
+    }
+    return lost ? -1 : tied;
+}
+
 // Persistent shard worker pool: threads park on a condvar between chunks
 // instead of being spawned/joined per call (the hot path runs one
 // wf_cores_process_mt per engine batch).  Leaked at process exit on
@@ -1103,6 +1275,22 @@ i64 wf_cores_process_mt_f(void **hs, i64 n_shards, const void *base, i64 n,
 }
 
 i64 wf_core_eos(void *h) { return ((Core *)h)->eos(); }
+
+// After the end of the stream has been harvested nothing reads the
+// archives again: give their memory back now (a long window's archives are
+// gigabytes, and the core may outlive its stream by as long as the graph's
+// objects do).  Node thread, queue drained.
+void wf_core_release(void *h) {
+    Core *c = (Core *)h;
+    for (auto &st : c->keys) {
+        std::vector<i64>().swap(st.pos);
+        std::vector<i64>().swap(st.ts);
+        std::vector<i64>().swap(st.val);
+        for (auto &xv : st.xval) std::vector<i64>().swap(xv);
+        st.start = 0;
+        st.held.clear();
+    }
+}
 
 // --------------------------------------------------------------- renumber
 // Per-key dense id renumbering for the ordering layer's single-channel
@@ -1273,7 +1461,9 @@ static bool try_merge(Launch &A, Launch &B, i64 slide, i64 max_cells,
     if (K2 * bucket(newR) > max_cells) return false;
     // the Python-side overflow guard is offs.max() + bucket(R) <= cap;
     // respect the same conservative bound so a merged launch never trips it
-    if (maxoff + bucket(newR) > A.cap) return false;
+    // (an arg-extremum launch pads to at least its reserved width)
+    if (maxoff + std::max(bucket(newR), std::max(A.Rb, B.Rb)) > A.cap)
+        return false;
     if (regular) {
         // regular dispatch shapes are keyed on (bucket(R), bucket(cmax)).
         // Small per-key window counts can grow the row bucket while the
@@ -1401,6 +1591,8 @@ static bool try_merge(Launch &A, Launch &B, i64 slide, i64 max_cells,
     cat64(A.hlen, B.hlen);
     cat64(A.hpmax, B.hpmax);
     cat64(A.hpmin, B.hpmin);
+    cat64(A.habs, B.habs);
+    A.Rb = std::max(A.Rb, B.Rb);
     A.blk = std::move(nblks[0]);
     for (int f = 1; f < n_fields; ++f)
         A.xblk[(size_t)(f - 1)] = std::move(nblks[(size_t)f]);
@@ -1481,6 +1673,23 @@ int wf_launch_peek(void *h, i64 *K, i64 *R, i64 *B, int *wire, int *rebase,
     Launch &L = c->queue.front();
     *K = L.K; *R = L.R; *B = L.B; *wire = L.wire; *rebase = L.rebase;
     *KP = L.KP; *cap = L.cap;
+    return 1;
+}
+
+// arg-extremum extras of the front launch (call between peek and take):
+// the padded rectangle width the core reserved room for, each window's
+// absolute start row (B values) and, when peek said rebase == 2, each ring
+// row's compaction shift (K values)
+int wf_launch_peek_arg(void *h, i64 *Rb, i64 *habs, i64 *shifts) {
+    Core *c = (Core *)h;
+    std::lock_guard<std::mutex> lk(c->qmu);
+    if (c->queue.empty()) return 0;
+    Launch &L = c->queue.front();
+    *Rb = L.Rb;
+    if (!L.habs.empty())
+        std::memcpy(habs, L.habs.data(), L.habs.size() * 8);
+    if (!L.shifts.empty())
+        std::memcpy(shifts, L.shifts.data(), L.shifts.size() * 8);
     return 1;
 }
 
@@ -1771,7 +1980,7 @@ inline int find_row(Core *c, i64 key) {
 }
 
 inline i64 key_rec_i64s(const Core *c, const KeyState &st) {
-    return 11 + (i64)st.live() * (2 + c->n_fields);
+    return 11 + (i64)st.live() * (2 + c->n_cols());
 }
 
 void export_key(const Core *c, const KeyState &st, i64 key, StateWr &w) {
@@ -1790,7 +1999,7 @@ void export_key(const Core *c, const KeyState &st, i64 key, StateWr &w) {
     w.put_arr(st.pos.data() + st.start, (size_t)L);
     w.put_arr(st.ts.data() + st.start, (size_t)L);
     w.put_arr(st.val.data() + st.start, (size_t)L);
-    for (int f = 1; f < c->n_fields; ++f)
+    for (int f = 1; f < c->n_cols(); ++f)
         w.put_arr(st.xval[(size_t)(f - 1)].data() + st.start, (size_t)L);
 }
 
@@ -1813,7 +2022,7 @@ bool import_key(Core *c, StateRd &r) {
     if (!r.get_arr(st.pos.data(), (size_t)L)) return false;
     if (!r.get_arr(st.ts.data(), (size_t)L)) return false;
     if (!r.get_arr(st.val.data(), (size_t)L)) return false;
-    for (int f = 1; f < c->n_fields; ++f) {
+    for (int f = 1; f < c->n_cols(); ++f) {
         auto &xv = st.xval[(size_t)(f - 1)];
         xv.assign((size_t)L, 0);
         if (!r.get_arr(xv.data(), (size_t)L)) return false;
@@ -1863,7 +2072,7 @@ i64 wf_core_state_export(void *h, void *buf, i64 cap) {
     w.put(c->slide);
     w.put((i64)c->kind);
     w.put((i64)c->role);
-    w.put((i64)c->n_fields);
+    w.put((i64)c->n_cols());
     w.put(c->room_mult);
     w.put(c->launches_made);
     i64 nk = 0;
@@ -1895,7 +2104,7 @@ i64 wf_core_state_import(void *h, const void *buf, i64 nbytes) {
     if (r.get() != kStateAbiVersion) return -4;
     if (r.get() != c->win || r.get() != c->slide
         || r.get() != (i64)c->kind || r.get() != (i64)c->role
-        || r.get() != (i64)c->n_fields)
+        || r.get() != (i64)c->n_cols())
         return -5;
     c->room_mult = r.get();
     c->launches_made = r.get();
@@ -1947,7 +2156,7 @@ i64 wf_core_key_export(void *h, i64 key, void *buf, i64 cap) {
     StateWr w{(u8 *)buf, (const u8 *)buf + cap};
     w.put(kStateMagicKey);
     w.put(kStateAbiVersion);
-    w.put((i64)c->n_fields);
+    w.put((i64)c->n_cols());
     export_key(c, c->keys[(size_t)row], key, w);
     if (!w.ok) return -1;
     return (i64)(w.p - (u8 *)buf);
@@ -1976,6 +2185,7 @@ i64 wf_core_key_neutralize(void *h, i64 key) {
     st.marker_pos = NEG_INF;
     st.marker_ts = 0;
     st.purge_pos = NEG_INF;
+    st.held.clear();
     st.pend_any = false;
     st.next_create = st.initial_id;
     st.fire_pos = st.initial_id + c->win;
@@ -1989,7 +2199,7 @@ i64 wf_core_key_import(void *h, const void *buf, i64 nbytes) {
     StateRd r{(const u8 *)buf, (const u8 *)buf + nbytes};
     if (r.get() != kStateMagicKey) return -3;
     if (r.get() != kStateAbiVersion) return -4;
-    if (r.get() != (i64)c->n_fields) return -5;
+    if (r.get() != (i64)c->n_cols()) return -5;
     if (!r.ok || !import_key(c, r)) return -6;
     // the imported rows are in no ring: force a rebase at the next flush
     c->KP = 0;

@@ -150,3 +150,29 @@ class OracleWinSeq:
                     out.append(self._emit(key, kd, w, w["acc"]))
             kd["wins"] = []
         return out
+
+
+def highest_bid_windows(rows, win_len, value="price", ts="ts", rid="id",
+                        carry=("auction", "bidder")):
+    """Plain reference of NEXMark Q7 over one key-less stream of bids (dicts
+    or structured rows): per tumbling event-time window of ``win_len`` the
+    row with the highest ``value``, ties to the lowest ``rid``, as
+    ``{wid: (value, carried..., its ts, count, last ts)}``.  A loop, the twin
+    of ``benchmarks/configs/q7_highest_bid_oracle.py``."""
+    out = {}
+    for r in rows:
+        w = int(r[ts]) // win_len
+        cur = out.get(w)
+        if cur is None:
+            cur = out[w] = {"best": None, "count": 0, "last": -1}
+        b = cur["best"]
+        if (b is None or int(r[value]) > int(b[value])
+                or (int(r[value]) == int(b[value])
+                    and int(r[rid]) < int(b[rid]))):
+            cur["best"] = r
+        cur["count"] += 1
+        cur["last"] = max(cur["last"], int(r[ts]))
+    return {w: ((int(c["best"][value]),)
+                + tuple(int(c["best"][f]) for f in carry)
+                + (int(c["best"][ts]), c["count"], c["last"]))
+            for w, c in out.items()}

@@ -354,6 +354,11 @@ def _family(name):
                KP, (I8, I8), (I32, I32), 16)
         fn = resident._make_multi_step(key, None)
         return fn, ((ring, ring), (blk, blk), k, b, b, b, b, b)
+    if name == "wf_step_argext":
+        key = ("argext", ("a", "b"), (("argmax", "a"), ("sum", "b")), CAP,
+               RB, B, KP, (I8, I8), (I32, I32), 0, 256)
+        fn = resident._make_argext_step(key)
+        return fn, ((ring, ring), (blk, blk), k, k, b, b, b)
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:4]), ("kf",))
     d = _S((4, B), jnp.int32)
@@ -374,7 +379,7 @@ def _family(name):
 
 @pytest.mark.parametrize("name", [
     "wf_step_regular", "wf_step_append_eval", "wf_step_multi",
-    "wf_step_regular_mesh", "wf_step_append_eval_mesh",
+    "wf_step_argext", "wf_step_regular_mesh", "wf_step_append_eval_mesh",
     "wf_step_multi_mesh"])
 def test_step_executable_is_named_by_its_family(name):
     fn, args = _family(name)

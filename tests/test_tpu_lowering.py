@@ -83,6 +83,35 @@ def test_multi_step_compiles(v5e):
              b).compile()
 
 
+@pytest.mark.parametrize("stage", ["map", "reduce"])
+def test_argext_step_compiles_and_fits(v5e, stage):
+    """The arg-extremum family at NEXMark Q7's widths: a MAP worker's one
+    ring row of 2^26 cells appended 2^20 rows at a time, and the REDUCE
+    worker's three small rings.  The ring is donated: the step may hold one
+    more ring for a compaction, never a copy per launch."""
+    S = _one(v5e)
+    if stage == "map":
+        fields, stats, cap, rb = ("price",), (("argmax", "price"),), \
+            1 << 26, 1 << 20
+    else:
+        fields = ("price", "count", "lastUpdate")
+        stats = (("argmax", "price"), ("sum", "count"),
+                 ("max", "lastUpdate"))
+        cap, rb = 1 << 22, 8
+    n = len(fields)
+    key = ("argext", fields, stats, cap, rb, 8, 1, (I32,) * n, (I32,) * n,
+           0, min(resident.ARGEXT_BLOCK, cap))
+    fn = resident._make_argext_step(key)
+    k, b = S((1,), jnp.int32), S((8,), jnp.int32)
+    compiled = fn.lower((S((1, cap), jnp.int32),) * n,
+                        (S((1, rb), jnp.int32),) * n, k, k, b, b,
+                        b).compile()
+    mem = compiled.memory_analysis()
+    ring_bytes = n * cap * 4
+    assert mem.alias_size_in_bytes >= ring_bytes        # appended in place
+    assert mem.temp_size_in_bytes <= ring_bytes + (64 << 20)
+
+
 def test_mesh_regular_step_compiles(v5e):
     mesh, S = _mesh(v5e)
     fn = resident._make_mesh_regular_step(

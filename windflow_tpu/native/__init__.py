@@ -133,6 +133,16 @@ def _bind(lib):
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p), i64, i64,
         p_i64, p_i32, p_i32, p_i32, p_i64, p_i64, p_i64, p_i64, p_i64,
         p_i64]
+    lib.wf_core_release.restype = None
+    lib.wf_core_release.argtypes = [ctypes.c_void_p]
+    lib.wf_core_set_arg.restype = i64
+    lib.wf_core_set_arg.argtypes = [ctypes.c_void_p, i64, i64, i64]
+    lib.wf_core_arg_gather.restype = i64
+    lib.wf_core_arg_gather.argtypes = [
+        ctypes.c_void_p, i64, p_i64, p_i64, p_i64, p_i64, p_i32, p_i32,
+        p_i64, p_i64]
+    lib.wf_launch_peek_arg.restype = ctypes.c_int
+    lib.wf_launch_peek_arg.argtypes = [ctypes.c_void_p, p_i64, p_i64, p_i64]
     lib.wf_launch_pending.restype = i64
     lib.wf_launch_pending.argtypes = [ctypes.c_void_p]
     lib.wf_launch_live_rows.restype = i64

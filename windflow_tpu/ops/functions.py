@@ -155,9 +155,15 @@ class Reducer(WindowFunction, WindowUpdate):
         return {self.out_field: self.ufunc.reduce(vals, axis=1)}
 
     # --- INC ---
+    def _acc_fields(self):
+        return self.result_fields
+
+    def _init_into(self, acc):
+        acc[self.out_field] = self._identity()
+
     def init(self, key, gwid):
         acc = np.zeros((), dtype=np.dtype([(self.out_field, self.dtype)]))
-        acc[self.out_field] = self._identity()
+        self._init_into(acc)
         return acc
 
     def update(self, key, gwid, row, acc):
@@ -180,12 +186,182 @@ class Reducer(WindowFunction, WindowUpdate):
         return True
 
 
+#: the tie-break id of an empty window: it loses every tie
+NO_ARG_ID = np.iinfo(np.int64).max
+
+
+class ArgReducer(WindowFunction, WindowUpdate):
+    """Arg-extremum over one integer field: the *row* at a window's ``max``
+    (or ``min``) of ``field``, ties to the lowest ``id_field`` — the monoid
+    "lexicographic extremum over ``(value, -id)`` carrying its payload"
+    (NEXMark Q7's highest bid is the bid, not the price).
+
+    The result holds the extremum (``out_field``), the fields of the winning
+    row named in ``carry`` (names, ``(in, out)`` pairs or a mapping; int64)
+    and, with ``id_out=``, the winning row's tie-break id.  One class serves
+    NIC (``apply``), batched (``apply_batch``) and INC
+    (``init``/``update``/``update_many``) evaluation, a Win_MapReduce's MAP
+    stage and — fed its own partials, ``id_field`` naming the carried id —
+    its REDUCE stage, and the resident device path (ops/resident.py
+    ``wf_step_argext``: extremum, first ring index and tie count per
+    window; the row's fields are read from the host archive at that index).
+
+    An empty window gives the identity: the first integer outside
+    ``value_range`` on the losing side when the range is declared (so a
+    partial of an empty window fits whatever accumulate dtype the range
+    proves and never beats a real row), the dtype's extreme otherwise;
+    carried fields 0 and the id :data:`NO_ARG_ID`.
+
+    ``window_rows`` declares, like ``value_range``, what the caller knows of
+    the stream and the spec does not say: the rows one key's window holds on
+    one worker (a time-based window's length in rows is its rate).  The
+    device path then sizes its ring and archive for such a window up front
+    instead of growing into it over the first windows; the results do not
+    depend on it.
+    """
+
+    def __init__(self, op: str, field: str = "value", out_field: str = None,
+                 carry=(), id_field: str = "id", id_out: str = None,
+                 dtype=np.int64, value_range=None, window_rows: int = None):
+        if op not in ("max", "min"):
+            raise ValueError(f"arg-extremum op is 'max' or 'min', not {op!r}")
+        self.base_op = op
+        #: never a plain monoid op: routing and the position-field shortcuts
+        #: key on ``op``, and an arg-extremum is neither host-free nor a
+        #: value-only reduction
+        self.op = "arg" + op
+        self.field = field
+        self.out_field = out_field or field
+        self.id_field = id_field
+        self.id_out = id_out
+        self.dtype = np.dtype(dtype)
+        if self.dtype.kind not in "iu":
+            raise TypeError("arg-extremum runs over an integer field")
+        if isinstance(carry, dict):
+            pairs = tuple(carry.items())
+        else:
+            pairs = tuple((c, c) if isinstance(c, str) else tuple(c)
+                          for c in carry)
+        self.carry = pairs
+        self.value_range = value_range
+        if window_rows is not None and int(window_rows) <= 0:
+            raise ValueError(f"window_rows must be positive: {window_rows}")
+        self.window_rows = None if window_rows is None else int(window_rows)
+        self.result_fields = {self.out_field: self.dtype}
+        if id_out:
+            self.result_fields[id_out] = np.dtype(np.int64)
+        for _src, dst in pairs:
+            self.result_fields[dst] = np.dtype(np.int64)
+        if len(self.result_fields) != 1 + bool(id_out) + len(pairs):
+            raise ValueError(f"duplicate result fields in {self!r}")
+        self.required_fields = tuple(dict.fromkeys(
+            (field, id_field) + tuple(src for src, _d in pairs)))
+        #: accumulator slot of the tie-break id (hidden unless ``id_out``)
+        self._id_slot = id_out or f"_arg_id.{self.out_field}"
+        self._ufunc = _UFUNCS[op]
+
+    def __repr__(self):
+        return (f"ArgReducer({self.base_op!r}, {self.field!r}, carry="
+                f"{self.carry}, id_field={self.id_field!r})")
+
+    def _identity(self):
+        if self.value_range is not None:
+            lo, hi = self.value_range
+            return self.dtype.type(int(lo) - 1 if self.base_op == "max"
+                                   else int(hi))
+        return _monoid_identity(self.base_op, self.dtype)
+
+    def _acc_fields(self):
+        out = dict(self.result_fields)
+        out.setdefault(self._id_slot, np.dtype(np.int64))
+        return out
+
+    def _beats(self, v, i, best_v, best_i):
+        """Whether ``(v, i)`` wins over ``(best_v, best_i)`` (arrays or
+        scalars): strictly better value, or the same value and a lower id."""
+        better = v > best_v if self.base_op == "max" else v < best_v
+        return better | ((v == best_v) & (i < best_i))
+
+    def _pick(self, vals, ids):
+        """Index of the winner among rows (1-D, non-empty)."""
+        ext = self._ufunc.reduce(vals)
+        cand = np.flatnonzero(vals == ext)
+        return int(cand[np.argmin(ids[cand])]) if len(cand) > 1 \
+            else int(cand[0])
+
+    # --- NIC ---
+    def apply(self, key, gwid, rows):
+        if len(rows) == 0:
+            return ((self._identity(),)
+                    + ((NO_ARG_ID,) if self.id_out else ())
+                    + (0,) * len(self.carry))
+        j = self._pick(rows[self.field].astype(self.dtype),
+                       rows[self.id_field])
+        row = rows[j]
+        return ((self.dtype.type(row[self.field]),)
+                + ((int(row[self.id_field]),) if self.id_out else ())
+                + tuple(int(row[src]) for src, _d in self.carry))
+
+    def apply_batch(self, keys, gwids, cols, lens):
+        vals = cols[self.field].astype(self.dtype)
+        n, pad = vals.shape
+        mask = np.arange(pad)[None, :] < lens[:, None]
+        ident = self._identity()
+        vals = np.where(mask, vals, ident)
+        ext = self._ufunc.reduce(vals, axis=1)
+        tied = mask & (vals == ext[:, None])
+        ids = np.where(tied, cols[self.id_field].astype(np.int64), NO_ARG_ID)
+        pick = np.argmin(ids, axis=1)
+        rows = np.arange(n)
+        live = lens > 0
+        out = {self.out_field: ext}      # an empty window's is the identity
+        if self.id_out:
+            out[self.id_out] = ids[rows, pick]
+        for src, dst in self.carry:
+            out[dst] = np.where(live, cols[src][rows, pick],
+                                0).astype(np.int64)
+        return out
+
+    # --- INC ---
+    def _init_into(self, acc):
+        acc[self.out_field] = self._identity()
+        acc[self._id_slot] = NO_ARG_ID
+
+    def init(self, key, gwid):
+        acc = np.zeros((), dtype=np.dtype(list(self._acc_fields().items())))
+        self._init_into(acc)
+        return acc
+
+    def _take(self, row, acc):
+        acc[self.out_field] = row[self.field]
+        acc[self._id_slot] = row[self.id_field]
+        for src, dst in self.carry:
+            acc[dst] = row[src]
+
+    def update(self, key, gwid, row, acc):
+        if self._beats(self.dtype.type(row[self.field]),
+                       int(row[self.id_field]),
+                       acc[self.out_field], int(acc[self._id_slot])):
+            self._take(row, acc)
+
+    def update_many(self, key, gwid, rows, acc):
+        if len(rows):
+            j = self._pick(rows[self.field].astype(self.dtype),
+                           rows[self.id_field])
+            self.update(key, gwid, rows[j], acc)
+
+    @property
+    def supports_batch(self):
+        return True
+
+
 class MultiReducer(WindowFunction, WindowUpdate):
     """Several monoid stats over the same windows in one evaluation — e.g.
     YSB's per-campaign COUNT(*) + MAX(ts) (yahoo_app.hpp:150-156), or
     count + sum + max of one value column.
 
-    ``stats`` are (op, field, out_field) triples or ready Reducers.  Like
+    ``stats`` are (op, field, out_field) triples, ready Reducers or
+    :class:`ArgReducer`s (the row at an extremum beside its counts).  Like
     :class:`Reducer` it serves as NIC function, INC update, and batched
     function; the resident device path evaluates every non-count stat over
     ONE shipped column set in one fused dispatch (count is answered
@@ -195,7 +371,7 @@ class MultiReducer(WindowFunction, WindowUpdate):
     def __init__(self, *stats, dtype=np.int64):
         parts = []
         for s in stats:
-            if isinstance(s, Reducer):
+            if isinstance(s, (Reducer, ArgReducer)):
                 parts.append(s)
             else:
                 op, field, out_field = s
@@ -234,11 +410,12 @@ class MultiReducer(WindowFunction, WindowUpdate):
 
     # --- INC ---
     def init(self, key, gwid):
-        acc = np.zeros((), dtype=np.dtype(
-            [(k, v) for k, v in self.result_fields.items()]))
+        fields = {}
         for p in self.parts:
-            if p.op != "count":
-                acc[p.out_field] = p._identity()
+            fields.update(p._acc_fields())
+        acc = np.zeros((), dtype=np.dtype(list(fields.items())))
+        for p in self.parts:
+            p._init_into(acc)
         return acc
 
     def update(self, key, gwid, row, acc):

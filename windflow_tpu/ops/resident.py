@@ -95,14 +95,14 @@ _REDUCE_OPS = ("sum", "min", "max", "prod")
 _NO_TAG = (None, None, None)
 
 
-def _named_jit(fn, name: str):
+def _named_jit(fn, name: str, **jit_kw):
     """``jax.jit`` of `fn` under a name that says the step's family, so
     the trace's ``XLA Modules`` line reads ``jit_<name>(...)`` per family
     instead of one ``jit_step`` for all."""
     def call(*args):
         return fn(*args)
     call.__name__ = call.__qualname__ = name
-    return jax.jit(call)
+    return jax.jit(call, **jit_kw)
 
 #: process-global launch-service record: an EMA of RAW per-dispatch launch
 #: service in ms, deliberately NOT normalized by dispatch size — the
@@ -681,7 +681,7 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         self.stats = tuple(stats)
         self.jax_fn = jax_fn
         for op, f in self.stats:
-            if op not in _REDUCE_OPS:
+            if op not in self._OPS:
                 raise ValueError(f"unsupported resident op {op!r}")
             if f not in self.fields:
                 raise ValueError(f"stat field {f!r} not in ring fields")
@@ -704,6 +704,8 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         self.dispatches = 0
         self._step_cache = {}   # per-executor cache for fn-bound steps
 
+    #: the stats this executor's step evaluates
+    _OPS = _REDUCE_OPS
     # single-field plumbing from the base class that does not apply
     op = property(lambda self: tuple(op for op, _f in self.stats))
     single = False
@@ -797,6 +799,180 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
+        with profile.span("dispatch", *tag) as sp:
+            self._rings, out = fn(self._rings_arr(), *args)
+            for o in out:
+                o.copy_to_host_async()
+        self._dispatched(meta, B, out, sp, tag)
+
+
+# -- the arg-extremum family ---------------------------------------------------
+# ops/functions.ArgReducer on the native resident core: windows of 10^7 rows
+# of ONE ring row (a key-less stream split over a Win_MapReduce).  The
+# (Bb, pad) gather of _ring_eval would materialise Bb x bucket(longest
+# window) cells to reduce one row; this family walks each window in blocks of
+# the ring row instead, O(window) cells read once whatever Bb and cap, and
+# returns where the extremum first sits.
+
+_ARG_OPS = ("argmax", "argmin")
+#: cells of a ring row one step of the blockwise evaluation reads
+ARGEXT_BLOCK = 1 << 20
+
+
+def _ring_extremum(op, cap, eb, acc_dt, ring, rows, starts, lens):
+    """Per described window: the extremum of `op` (``max``/``min``), the
+    window-relative index of its first occurrence and how many cells hold
+    it.  Each window walks its own cells of its ring row in blocks of `eb`
+    (a ``fori_loop`` over the blocks it spans inside a ``lax.map`` over the
+    windows): an empty window, padding included, reads nothing."""
+    ident = jnp.asarray(_identity(op, acc_dt), dtype=acc_dt)
+    red = jnp.max if op == "max" else jnp.min
+    iota = jnp.arange(eb, dtype=jnp.int32)
+
+    def one(w):
+        r, s, l = w
+        e = s + l
+
+        def body(b, st):
+            ext, first, n = st
+            p0 = b * eb
+            seg = lax.dynamic_slice(ring, (r, p0), (1, eb))[0]
+            pos = p0 + iota
+            inside = (pos >= s) & (pos < e)
+            bext = red(jnp.where(inside, seg, ident))
+            hit = inside & (seg == bext)
+            bfirst = jnp.min(jnp.where(hit, pos, cap))
+            bn = jnp.sum(hit, dtype=jnp.int32)
+            better = bext > ext if op == "max" else bext < ext
+            same = bext == ext
+            return (jnp.where(better, bext, ext),
+                    jnp.where(better, bfirst,
+                              jnp.where(same, jnp.minimum(first, bfirst),
+                                        first)),
+                    jnp.where(better, bn, jnp.where(same, n + bn, n)))
+
+        ext, first, n = lax.fori_loop(
+            s // eb, jnp.where(l > 0, (e + eb - 1) // eb, s // eb), body,
+            (ident, jnp.int32(cap), jnp.int32(0)))
+        return ext, jnp.where(n > 0, first - s, 0), n
+
+    return lax.map(one, (rows, starts, lens))
+
+
+def _ring_compact(ring, shifts):
+    """Slide every ring row left by its shift (the core dropped that many
+    dead cells from the row's head); rows that do not move are not read."""
+    def slide(r):
+        return jax.vmap(lambda row, sh: lax.dynamic_slice(
+            jnp.concatenate([row, jnp.zeros_like(row)]), (sh,),
+            (row.shape[0],)))(r, shifts)
+    return lax.cond(jnp.any(shifts != 0), slide, lambda r: r, ring)
+
+
+def _make_argext_step(key):
+    """Fused compact + append + blockwise evaluation: one ring per field,
+    donated so that the append is in place (a ring of 2^26 cells is not
+    copied per launch); ``argmax``/``argmin`` stats return (extremum, first
+    index, count of cells at the extremum), ``max``/``min`` the extremum by
+    the same walk, ``sum``/``prod`` as :func:`_ring_eval`."""
+    (_tag, fields, stats, cap, Rb, Bb, KP, wires, accs, pad, eb) = key
+    acc_dts = tuple(np.dtype(a) for a in accs)
+    fidx = {f: i for i, f in enumerate(fields)}
+
+    def step(rings, blks, offs, shifts, wrows, wstarts, wlens):
+        rings = tuple(_ring_append(_ring_compact(r, shifts), b, offs, dt)
+                      for r, b, dt in zip(rings, blks, acc_dts))
+        outs = []
+        for op, f in stats:
+            ring, dt = rings[fidx[f]], acc_dts[fidx[f]]
+            if op in _ARG_OPS:
+                outs.extend(_ring_extremum(op[3:], cap, eb, dt, ring, wrows,
+                                           wstarts, wlens))
+            elif op in ("max", "min"):
+                outs.append(_ring_extremum(op, cap, eb, dt, ring, wrows,
+                                           wstarts, wlens)[0])
+            else:
+                outs.append(_ring_eval(op, cap, pad, dt, ring, wrows,
+                                       wstarts, wlens))
+        return rings, tuple(outs)
+
+    return _named_jit(step, "wf_step_argext", donate_argnums=0)
+
+
+class ArgExtResidentExecutor(MultiFieldResidentExecutor):
+    """Resident launch queue of the arg-extremum family
+    (``jit_wf_step_argext``): per-field rings whose rows are bucketed from 1
+    (a key-less stream is one row, not eight), donated to every step,
+    compacted and grown on the device (:meth:`launch`'s ``shifts``,
+    :meth:`grow`) instead of re-shipped from the host, and evaluated
+    blockwise (:func:`_ring_extremum`).  The native resident core drives it
+    (patterns/native_core.py); it serves no mesh and no JAX window
+    function."""
+
+    _OPS = _REDUCE_OPS + _ARG_OPS
+
+    def __init__(self, fields, stats, acc_dtypes, device=None,
+                 depth: int = 8):
+        super().__init__(fields, stats=stats, acc_dtypes=acc_dtypes,
+                         device=device, depth=depth)
+        self.eval_block = ARGEXT_BLOCK
+
+    def reset(self, n_keys: int, cap: int):
+        self.KP = _bucket(max(n_keys, 1), lo=1)
+        self.cap = _bucket(max(cap, 16))
+        self._rings = None
+
+    def grow(self, cap: int):
+        """Widen every ring to `cap` cells a row on the device, contents
+        kept (the core asks when its live rows outgrow half the ring)."""
+        if cap <= self.cap:
+            return
+        old = self._rings_arr()
+        self.cap = cap
+        self._rings = None
+        # fresh rings as :meth:`_rings_arr` makes them, the old contents
+        # written at the front
+        self._rings = tuple(lax.dynamic_update_slice(z, r, (0, 0))
+                            for z, r in zip(self._rings_arr(), old))
+
+    def launch(self, meta, blks: dict, offs: np.ndarray,
+               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
+               shifts: np.ndarray = None, tag=_NO_TAG):
+        """One fused dispatch: slide the ring rows left by `shifts` (None:
+        nothing moves), append the per-field rectangles at `offs` (already
+        in the slid coordinates, like the window descriptors), evaluate."""
+        K, R = next(iter(blks.values())).shape
+        if K > self.KP:
+            raise ValueError("rectangle exceeds ring rows; reset() first")
+        B = len(wstarts)
+        Rb = _bucket(max(R, 1))
+        Bb = _bucket(max(B, 1))
+        _check_ring_overflow(offs, Rb, self.cap)
+        pad = (_bucket(int(wlens.max()) if B else 1)
+               if any(op == "prod" for op, _f in self.stats) else 0)
+        eb = min(self.eval_block, self.cap)
+        key = ("argext", self.fields, self.stats, self.cap, Rb, Bb, self.KP,
+               tuple(blks[f].dtype.str for f in self.fields),
+               tuple(self.acc_dtypes[f].str for f in self.fields), pad, eb)
+        fn = _STEP_CACHE.get(key)
+        if fn is None:
+            fn = _STEP_CACHE[key] = _make_argext_step(key)
+        with profile.span("device_put", *tag):
+            blkps = tuple(
+                (blks[f] if blks[f].shape == (self.KP, Rb)
+                 else _pad2(blks[f], self.KP, Rb)) for f in self.fields)
+            args = jax.device_put(
+                (blkps, _pad1(offs, self.KP),
+                 _pad1(shifts if shifts is not None else (), self.KP),
+                 _pad1(wrows, Bb), _pad1(wstarts, Bb), _pad1(wlens, Bb)),
+                self.device)
+        for f in self.fields:
+            profile.add("bytes_shipped", blks[f].nbytes)
+            profile.add("rows_shipped", blks[f].size)
+        profile.add("windows", B)
+        if B:
+            profile.add("eval_windows", B)
+            profile.add("eval_rows", int(np.sum(wlens, dtype=np.int64)))
         with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:

@@ -22,7 +22,7 @@ import numpy as np
 
 from ..core.tuples import MARKER_FIELD, Schema
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
-from ..ops.functions import Reducer
+from ..ops.functions import NO_ARG_ID, ArgReducer, Reducer
 from ..utils import profile
 
 _ROLE_CODE = {Role.SEQ: 0, Role.PLQ: 1, Role.WLQ: 2, Role.MAP: 3,
@@ -141,20 +141,41 @@ class NativeResidentCore:
             self._dev_parts = dev
             self._pos_max_parts = pos
             self._count_parts = reducer.count_parts
-        elif isinstance(reducer, Reducer):
+        elif isinstance(reducer, (Reducer, ArgReducer)):
             self._dev_parts = [reducer]
             self._pos_max_parts = []
             self._count_parts = []
         else:
             raise TypeError("native resident core needs a builtin "
-                            "(Multi)Reducer")
+                            "(Multi)Reducer or ArgReducer")
         self._dev_part = self._dev_parts[0]
         self._ship_fields = tuple(dict.fromkeys(
             p.field for p in self._dev_parts))
+        #: the arg-extremum stat, if the function has one: its ring is
+        #: evaluated by the wf_step_argext family and its winning row read
+        #: back from the C++ archive at harvest (_gather_payload)
+        args = [p for p in self._dev_parts if isinstance(p, ArgReducer)]
+        if len(args) > 1:
+            raise TypeError("one arg-extremum per window function (each "
+                            f"names its own winning row): got {args}")
+        self._arg = args[0] if args else None
+        #: columns the C++ archive keeps beside the shipped ones and never
+        #: ships: the tie-break id first, then what the result carries
+        #: (``ts`` is archived anyway)
+        self._carry_cols = ()
+        if self._arg is not None:
+            if mesh is not None or int(shards) > 1:
+                raise ValueError(
+                    "the arg-extremum family runs one shard on one device "
+                    "(no mesh, shards=1)")
+            self._carry_cols = tuple(dict.fromkeys(
+                (self._arg.id_field,) + tuple(
+                    src for src, _d in self._arg.carry if src != "ts")))
         #: >1 device stat (several fields, or several ops over one field):
         #: per-field rings via MultiFieldResidentExecutor; the single-stat
-        #: path keeps its regular-descriptor compression
-        self._multi = len(self._dev_parts) > 1
+        #: path keeps its regular-descriptor compression.  An arg-extremum
+        #: always takes the per-field form (its own executor)
+        self._multi = len(self._dev_parts) > 1 or self._arg is not None
         max_fields = int(self._lib.wf_max_fields())
         if len(self._ship_fields) > max_fields:
             raise TypeError(
@@ -212,7 +233,15 @@ class NativeResidentCore:
         # *byte* array (wf_native.cpp:wf_cores_process_mt), so ids beyond
         # u8 would alias and double-process rows
         self.shards = max(min(int(shards), 256), 1)
-        if self._multi:
+        if self._arg is not None:
+            from ..ops.resident import ArgExtResidentExecutor
+            self.executors = [ArgExtResidentExecutor(
+                self._ship_fields,
+                tuple((p.op, p.field) for p in self._dev_parts),
+                self._acc_by_field,
+                device=resolve_worker_device(device, worker_index),
+                depth=depth)]
+        elif self._multi:
             stats = tuple((p.op, p.field) for p in self._dev_parts)
             if mesh is not None:
                 # mesh-sharded per-field rings (P(kf, None)): the pod
@@ -368,6 +397,19 @@ class NativeResidentCore:
                     raise TypeError(
                         f"native core accepted {got} fields, "
                         f"need {len(self._ship_fields)}")
+        if self._arg is not None:
+            # ties are resolved on the archive column of the field the
+            # extremum runs over, wherever it stands among the shipped ones;
+            # a declared window sizes ring and archives up front
+            for h in self._hs:
+                got = self._lib.wf_core_set_arg(
+                    h, len(self._carry_cols),
+                    self._ship_fields.index(self._arg.field),
+                    self._arg.window_rows or 0)
+                if got != len(self._carry_cols):
+                    raise TypeError(
+                        f"native core archives {got} carried columns, the "
+                        f"arg-extremum needs {self._carry_cols}")
         if self._flush_mult > 1:
             for h in self._hs:
                 self._lib.wf_core_set_flush_rows(
@@ -445,6 +487,11 @@ class NativeResidentCore:
 
     def _fall_back(self):
         """Switch to the pure-Python resident core (non-int64 payloads)."""
+        if self._arg is not None:
+            raise TypeError(
+                "the arg-extremum runs on the native resident core only: "
+                f"fields {self._ship_fields + self._carry_cols} must be "
+                "int64 columns of the stream")
         from .win_seq_tpu import ResidentWinSeqCore
         self._delegate = ResidentWinSeqCore(self.spec, self.reducer,
                                             **self._args)
@@ -458,15 +505,15 @@ class NativeResidentCore:
     def _field_offsets(self, batch):
         if self._offsets is None:
             f = batch.dtype.fields
-            if (any(fl not in f or f[fl][0] != np.int64
-                    for fl in self._ship_fields)
+            cols = self._ship_fields + self._carry_cols
+            if (any(fl not in f or f[fl][0] != np.int64 for fl in cols)
                     or batch.dtype[MARKER_FIELD] != np.bool_):
                 return None
             self._offsets = (batch.dtype.itemsize, f["key"][1], f["id"][1],
                              f["ts"][1], f[MARKER_FIELD][1],
                              f[self._ship_fields[0]][1])
             #: payload-column offsets, ship_fields order (the _f ABI)
-            self._voffs = np.array([f[fl][1] for fl in self._ship_fields],
+            self._voffs = np.array([f[fl][1] for fl in cols],
                                    dtype=np.int64)
         return self._offsets
 
@@ -861,7 +908,12 @@ class NativeResidentCore:
     def flush(self) -> np.ndarray:
         if self._delegate is not None:
             return self._delegate.flush()
-        return self._harvest(self._eos_and_drain())
+        out = self._harvest(self._eos_and_drain())
+        # the stream is over and harvested: the archives go now, not when
+        # the graph's objects are collected
+        for h in self._hs:
+            self._lib.wf_core_release(h)
+        return out
 
     def use_incremental(self):
         raise TypeError("the device path is non-incremental only "
@@ -879,8 +931,10 @@ class NativeResidentCore:
         # recovery mode never coalesces: merged launches would make the
         # per-launch emission boundaries wall-clock-dependent (replay
         # would regroup differently and break the per-edge seq dedup)
+        # (nor does the arg-extremum family: nothing prewarms its merged
+        # shapes, so a merge would compile cold in mid-run)
         coalesce = (not os.environ.get("WF_NO_COALESCE")
-                    and not self._recovery_mode)
+                    and not self._recovery_mode and self._arg is None)
         if (coalesce and not force and pending <= self._max_pending
                 and self.max_delay_s is None):
             # (beyond _max_pending the hold is skipped: the producer's
@@ -934,6 +988,19 @@ class NativeResidentCore:
         # C++ take fill them directly (no _pad2 re-copy on this thread)
         from ..ops.device import _bucket
         KPp, Rb = KP.value, _bucket(max(R, 1))
+        habs = shifts = None
+        if self._arg is not None:
+            # the width the core reserved ring room for, each window's
+            # absolute first row, and the rows' slide when it compacts
+            rb = ctypes.c_longlong()
+            habs = np.zeros(max(B, 1), dtype=np.int64)
+            shifts = np.zeros(max(K, 1), dtype=np.int64)
+            lib.wf_launch_peek_arg(handle, ctypes.byref(rb),
+                                   habs.ctypes.data_as(
+                                       ctypes.POINTER(ctypes.c_longlong)),
+                                   shifts.ctypes.data_as(
+                                       ctypes.POINTER(ctypes.c_longlong)))
+            Rb = max(Rb, rb.value)
         blks = blk = None
         if self._multi:
             # one rectangle per ship field, each in the per-field wire
@@ -1008,8 +1075,10 @@ class NativeResidentCore:
                     hts.ctypes.data_as(p64), hlen.ctypes.data_as(p64),
                     hpm.ctypes.data_as(p64) if hpm is not None else None,
                     hpmn.ctypes.data_as(p64) if hpmn is not None else None)
-        if rebase.value:
+        if rebase.value == 1:
             ex.reset(max(K, 1), cap.value)
+        elif cap.value > ex.cap:
+            ex.grow(cap.value)      # arg-extremum rings grow on the device
         if getattr(ex, "mesh", None) is not None:
             # the mesh executors re-scatter rows onto their own (shard-
             # rounded) KP; hand them the live rows only, not the C++
@@ -1021,7 +1090,12 @@ class NativeResidentCore:
         meta = (hkey[:B], hid[:B], hts[:B], hlen[:B],
                 hpm[:B] if hpm is not None else None,
                 hpmn[:B] if hpmn is not None else None, tag)
-        if self._multi:
+        if self._arg is not None:
+            ex.launch(meta + (habs[:B],), blks, offs, wrows[:B],
+                      wstarts[:B], wlens[:B],
+                      shifts=shifts[:K] if rebase.value == 2 else None,
+                      tag=tag)
+        elif self._multi:
             ex.launch(meta, blks, offs, wrows[:B], wstarts[:B], wlens[:B],
                       tag=tag)
         elif regular:
@@ -1034,23 +1108,77 @@ class NativeResidentCore:
                       tag=tag)
         return True
 
+    def _gather_payload(self, res, meta, arrs):
+        """Fill the arg-extremum's result fields of one harvested launch:
+        the extremum from the device, the winning row's fields from the
+        C++ archive at the index the device found (node thread: the
+        archives are its own).  Ties go to the lowest id there."""
+        part = self._arg
+        hkey, _hid, _hts, hlen, _pm, _pmn, tag, habs = meta
+        ext, first, nties = arrs
+        B = len(hkey)
+        vals = ext.astype(part.dtype)
+        empty = hlen == 0
+        if empty.any():
+            vals[empty] = part._identity()
+        res[part.out_field] = vals
+        if not B:
+            return
+        p64 = ctypes.POINTER(ctypes.c_longlong)
+        p32 = ctypes.POINTER(ctypes.c_int32)
+        out_ts = np.zeros(max(B, 1), dtype=np.int64)
+        cols = np.zeros((max(len(self._carry_cols), 1), max(B, 1)),
+                        dtype=np.int64)
+        with profile.span("payload_gather", *tag):
+            ext64 = np.ascontiguousarray(ext, dtype=np.int64)
+            first = np.ascontiguousarray(first, dtype=np.int32)
+            nties = np.ascontiguousarray(nties, dtype=np.int32)
+            hkey, habs, hlen = (np.ascontiguousarray(a, dtype=np.int64)
+                                for a in (hkey, habs, hlen))
+            tied = self._lib.wf_core_arg_gather(
+                self._hs[tag[1] - self._shard_base], B,
+                hkey.ctypes.data_as(p64), habs.ctypes.data_as(p64),
+                hlen.ctypes.data_as(p64), ext64.ctypes.data_as(p64),
+                first.ctypes.data_as(p32), nties.ctypes.data_as(p32),
+                out_ts.ctypes.data_as(p64), cols.ctypes.data_as(p64))
+        if tied < 0:
+            raise RuntimeError(
+                "arg-extremum: a window's winning row was no longer in the "
+                "native archive at harvest")
+        if tied:
+            profile.add("argext_ties", tied)
+        col_of = {c: cols[i, :B] for i, c in enumerate(self._carry_cols)}
+        col_of["ts"] = out_ts[:B]
+        if part.id_out:
+            res[part.id_out] = np.where(empty, NO_ARG_ID,
+                                        col_of[part.id_field])
+        for src, dst in part.carry:
+            res[dst] = col_of[src]
+
     def _harvest(self, harvested) -> np.ndarray:
         if not harvested:
             return np.zeros(0, dtype=self._result_dtype)
         from .win_seq_tpu import finalize_window_values
         outs = []
-        for (hkey, hid, hts, hlen, hpm, hpmn, tag), out in harvested:
+        for meta, out in harvested:
+            hkey, hid, hts, hlen, hpm, hpmn, tag = meta[:7]
             with profile.span("harvest_finalize", *tag):
                 # multi executors return one array per stat (dev_parts
-                # order); the single path returns the stat array itself
-                arrs = out if isinstance(out, tuple) else (out,)
+                # order; an arg-extremum three: extremum, first index,
+                # count at the extremum); the single path returns the stat
+                # array itself
+                arrs = list(out if isinstance(out, tuple) else (out,))
                 res = np.zeros(len(arrs[0]), dtype=self._result_dtype)
                 res["key"] = hkey
                 res["id"] = hid
                 res["ts"] = hts
-                for part, a in zip(self._dev_parts, arrs):
-                    res[part.out_field] = finalize_window_values(part, a,
-                                                                 hlen)
+                for part in self._dev_parts:
+                    if part is self._arg:
+                        self._gather_payload(res, meta, arrs[:3])
+                        del arrs[:3]
+                    else:
+                        res[part.out_field] = finalize_window_values(
+                            part, arrs.pop(0), hlen)
                 for part in self._count_parts:
                     res[part.out_field] = hlen.astype(part.dtype)
                 for part in self._pos_max_parts:

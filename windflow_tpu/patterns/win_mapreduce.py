@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..core.tuples import group_by_key, take_rows
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..runtime.emitters import KeyedStreamState
 from ..runtime.node import Node, RuntimeContext
@@ -61,28 +62,42 @@ class WinMapEmitterNode(Node):
         if len(batch) == 0:
             return
         keys = batch["key"]
-        # sort-by-key + segmented arange: O(n log n + K) instead of a
-        # full-batch mask per distinct key (collapses at 1e5 keys)
-        from ..core.tuples import group_by_key
-        order, starts, ends = group_by_key(keys)
-        sk = keys[order]
-        counts = ends - starts
-        base = np.empty(len(starts), dtype=np.int64)
-        nd = self._next_dst
-        for i, s in enumerate(starts):     # O(K) scalar dict ops
-            k = int(sk[s])
-            b = nd.get(k)
-            if b is None:
-                b = k % n
-            base[i] = b
-            nd[k] = (b + int(counts[i])) % n
-        rank = np.arange(len(sk), dtype=np.int64) - np.repeat(starts, counts)
-        dest = np.empty(len(batch), dtype=np.int64)
-        dest[order] = (np.repeat(base, counts) + rank) % n
-        for d in range(n):
-            sub = batch[dest == d]
-            if len(sub):
-                self.emit_to(d, sub)
+        nd = self._next_dst   # key -> next round-robin destination
+        if keys[0] == keys[-1] and not np.any(keys != keys[0]):
+            # one key (all a key-less stream ever sends): destination d
+            # takes every n-th row from its turn on
+            k = int(keys[0])
+            b = nd.get(k, k % n)
+            nd[k] = (b + len(batch)) % n
+            idxs = [np.arange((d - b) % n, len(batch), n) for d in range(n)]
+        else:
+            # sort-by-key + segmented arange: O(n log n + K) instead of a
+            # full-batch mask per distinct key (collapses at 1e5 keys)
+            order, starts, ends = group_by_key(keys)
+            sk = keys[order]
+            counts = ends - starts
+            base = np.empty(len(starts), dtype=np.int64)
+            for i, s in enumerate(starts):     # O(K) scalar dict ops
+                k = int(sk[s])
+                b = nd.get(k)
+                if b is None:
+                    b = k % n
+                base[i] = b
+                nd[k] = (b + int(counts[i])) % n
+            rank = (np.arange(len(sk), dtype=np.int64)
+                    - np.repeat(starts, counts))
+            dest = np.empty(len(batch), dtype=np.int64)
+            dest[order] = (np.repeat(base, counts) + rank) % n
+            idxs = [np.flatnonzero(dest == d) for d in range(n)]
+        st = self.stats
+        if st is not None:
+            st.bump("rr_batches")
+            st.bump("rr_rows", len(batch))
+        # one owned array per destination (core/tuples.take_rows: the
+        # boolean subscript holds the interpreter lock for the whole copy)
+        for d, idx in enumerate(idxs):
+            if len(idx):
+                self.emit_to(d, take_rows(batch, idx))
 
     def eosnotify(self):
         markers = self._state.marker_batch()

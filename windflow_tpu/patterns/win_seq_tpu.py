@@ -27,7 +27,7 @@ from ..core.tuples import Schema
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..core.winseq import WinSeqCore
 from ..ops.device import DeviceWindowExecutor, builtin_batch_fn
-from ..ops.functions import MultiReducer, Reducer
+from ..ops.functions import ArgReducer, MultiReducer, Reducer
 from ..runtime.node import RuntimeContext
 from .basic import _Pattern
 from .key_farm import KeyFarm
@@ -77,7 +77,7 @@ class JaxWindowFunction:
 def _host_standin(winfunc):
     """Host-side function object carrying the result schema for the
     core/farm template plumbing (the device path never calls it)."""
-    if isinstance(winfunc, (Reducer, MultiReducer)):
+    if isinstance(winfunc, (Reducer, MultiReducer, ArgReducer)):
         return winfunc
     if isinstance(winfunc, JaxWindowFunction):
         r = Reducer("count")
@@ -291,7 +291,9 @@ def _acc_range_safe(reducer: Reducer, acc: np.dtype, spec) -> bool:
     if vr is None or acc.kind == "f":
         return False
     m = max(abs(int(vr[0])), abs(int(vr[1])))
-    if reducer.op in ("min", "max"):
+    if getattr(reducer, "base_op", reducer.op) in ("min", "max"):
+        # (an arg-extremum's identity is the first integer outside the
+        # range, ops/functions.ArgReducer: inside the bound too)
         bound = m
     elif (reducer.op == "sum" and spec is not None
           and spec.win_type is WinType.CB):
@@ -865,6 +867,12 @@ def _multi_resident_ok(winfunc: MultiReducer, use_pallas: bool) -> bool:
                         for p in dev))
 
 
+def _has_arg_extremum(winfunc) -> bool:
+    return isinstance(winfunc, ArgReducer) or (
+        isinstance(winfunc, MultiReducer)
+        and any(isinstance(p, ArgReducer) for p in winfunc.parts))
+
+
 def _native_core_lib():
     """The native library handle for core routing, or None — also None
     under WF_NO_NATIVE_CORE=1, which pins the Python resident core
@@ -905,6 +913,38 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                       map_indexes=map_indexes,
                       result_ts_slide=result_ts_slide).make_core()
 
+    if _has_arg_extremum(winfunc):
+        # the row at a window's extremum: one executor family
+        # (ops/resident.py wf_step_argext) under the native resident core,
+        # for every role (SEQ, a Win_MapReduce's MAP and REDUCE alike).
+        # Whatever that cannot run raises -- there is no host route here
+        parts = winfunc.parts if isinstance(winfunc, MultiReducer) \
+            else [winfunc]
+        others = [p for p in parts if not isinstance(p, ArgReducer)]
+        why = None
+        if use_pallas or use_resident is False:
+            why = "it runs on the resident path (no use_pallas, no " \
+                  "use_resident=False)"
+        elif mesh is not None or shards != 1:
+            why = "it runs one shard on one device (no mesh, shards=1)"
+        elif any(p.op not in _RESIDENT_OPS + ("count",) for p in others) \
+                or any(p.op == "sum" and np.issubdtype(p.dtype, np.floating)
+                       for p in others):
+            why = f"its sibling stats must be count or {_RESIDENT_OPS} " \
+                  "over integers"
+        elif _native_core_lib() is None:
+            why = "the native resident core is unavailable or opted out"
+        if why is not None:
+            raise ValueError(f"arg-extremum window function {winfunc!r} "
+                             f"cannot run on the device: {why}")
+        from .native_core import NativeResidentCore
+        return NativeResidentCore(
+            spec, winfunc, batch_len=batch_len, flush_rows=flush_rows,
+            config=config, role=role, map_indexes=map_indexes,
+            result_ts_slide=result_ts_slide, device=device,
+            depth=depth if depth is not None else 8,
+            compute_dtype=compute_dtype, worker_index=worker_index,
+            max_delay_ms=max_delay_ms)
     if (max_delay_ms is not None and use_resident is None
             and mesh is None and not use_pallas
             and isinstance(winfunc, (Reducer, MultiReducer))
