@@ -212,11 +212,19 @@ struct Launch {
     // arg-extremum cores only: each window's absolute start row (the
     // harvest reads the winning row at habs + its ring-relative index);
     // rebase == 2 marks an on-device compaction — every ring row slides
-    // left by shifts[k] before the append, nothing re-ships; Rb is the
-    // padded rectangle width the core reserved ring room for
+    // left by shifts[k] before the append, nothing re-ships
     std::vector<i64> habs, shifts;
+    // the padded rectangle width the core reserved ring room for, where
+    // that is more than bucket(R): arg-extremum and early launches
     i64 Rb = 0;
+    int trigger = 0;   // what cut the launch (FlushTrigger); a merged
+                       // launch keeps its first part's
 };
+
+// what cut a launch: a row or window count reached (flush_rows, batch_len),
+// the device-following flush below them (wf_core_flush_early), the caller
+// (the max-delay timer, a checkpoint barrier), the end of the stream
+enum FlushTrigger { NATURAL = 0, EARLY = 1, FORCED = 2, EOS = 3 };
 
 struct Core {
     i64 win, slide;
@@ -241,6 +249,12 @@ struct Core {
     int arg_mode = 0, n_carry = 0, arg_field = 0;
     i64 window_rows = 0, cap_floor = 0, rb_floor = 0, kp_lo = 8;
     int n_cols() const { return n_fields + n_carry; }
+    // the shape of the last natural launch: rectangle width, windows a key
+    // (regular launches) and wire dtypes.  An early flush pads itself up to
+    // it, so a launch cut short of flush_rows runs the step executable the
+    // natural path compiled and meets no shape of its own
+    i64 nat_rb = 0, nat_cmax = 0;
+    int nat_wire[kMaxFields] = {0};
     bool hopping;
 
     std::unordered_map<i64, int> rowmap;
@@ -382,8 +396,16 @@ struct Core {
         }
     }
 
-    void flush() {
+    // An EARLY flush (wf_core_flush_early) ships what the core holds below
+    // flush_rows / batch_len because a fired window waits and the ring is
+    // idle.  It is made only where it costs no shape and no ring of its own:
+    // after a natural launch has set both, padded to that launch's shape,
+    // and never as a rebase — where the ring is full or a key is new it
+    // leaves everything pending for the natural trigger.
+    void flush(int trigger = NATURAL) {
         if (hkey.empty() && pend_rows == 0) return;
+        const bool early = trigger == EARLY;
+        if (early && (hkey.empty() || arg_mode || nat_rb == 0)) return;
         const i64 K = (i64)keys.size();
         const i64 KPb = bucket(std::max<i64>(K, 1), kp_lo);
         // a row-triggered FIRST flush marks a throughput stream: provision
@@ -395,13 +417,14 @@ struct Core {
         if (cap == 0 && pend_rows >= flush_rows)
             room_mult = kCoalesceLadderMax + 2;
         bool rebase = (cap == 0) || (KP < KPb);
+        if (early && rebase) return;
         i64 maxpend = 0;
         for (auto &st : keys)
             maxpend = std::max(maxpend, st.appended - st.launched);
         Launch L;
         if (!rebase) {
             const i64 Rb = std::max(bucket(std::max<i64>(maxpend, 1)),
-                                    rb_floor);
+                                    early ? nat_rb : rb_floor);
             if (arg_mode) {
                 // an arg-extremum ring is never re-shipped.  It is kept at
                 // least twice as wide as the live rows plus one rectangle
@@ -432,6 +455,7 @@ struct Core {
             }
             for (auto &st : keys) {
                 if (st.launched - st.ring_base + Rb > cap) {
+                    if (early) return;
                     rebase = true;
                     // the stream keeps outrunning the ring: provision more
                     // append room next time, up to the full coalescing
@@ -529,7 +553,7 @@ struct Core {
                      || (vmin[f] >= INT32_MIN && vmax[f] <= INT32_MAX))
                 w = 2;
             else w = 3;   // int64 wire (64-bit accumulate dtype)
-            L.xwire[f] = w;
+            L.xwire[f] = early ? std::max(w, nat_wire[f]) : w;
         }
         L.wire = L.xwire[0];
         const i64 Rr = std::max<i64>(R, 1);
@@ -608,10 +632,20 @@ struct Core {
         L.hpmin = std::move(hpmn);
         L.K = K; L.R = Rr; L.B = B; L.KP = KP; L.cap = cap;
         L.rebase = rebase ? 1 : (L.shifts.empty() ? 0 : 2);
+        L.trigger = trigger;
         if (arg_mode) {
             L.habs = wlo;
             L.Rb = std::max(bucket(Rr), rb_floor);
             rb_floor = std::min(L.Rb, bucket(std::max<i64>(flush_rows, 1)));
+        } else if (early) {
+            L.Rb = std::max(bucket(Rr), nat_rb);
+            if (L.regular) L.cmax = std::max(L.cmax, nat_cmax);
+        } else if (trigger == NATURAL) {
+            // (a rebase re-ships the live rows too: the steady shape is
+            // that of the pending ones)
+            nat_rb = bucket(std::max<i64>(maxpend, 1));
+            nat_cmax = L.regular ? L.cmax : 0;
+            for (int f = 0; f < n_fields; ++f) nat_wire[f] = L.xwire[f];
         }
         {
             std::lock_guard<std::mutex> lk(qmu);
@@ -885,7 +919,7 @@ struct Core {
                 emit_windows(st, rowkey[r], from, st.next_lwid, true);
             }
         }
-        flush();
+        flush(EOS);
         return launches_made - q0;
     }
 };
@@ -1353,7 +1387,25 @@ void wf_core_set_flush_rows(void *h, i64 rows) {
 i64 wf_core_force_flush(void *h) {
     Core *c = (Core *)h;
     const i64 q0 = c->launches_made;
-    c->flush();
+    c->flush(FORCED);
+    return c->launches_made - q0;
+}
+
+// fired windows the core holds that no launch carries yet (read-only;
+// producer thread): without one an early flush buys no latency
+i64 wf_core_fired_pending(void *h) {
+    return (i64)((Core *)h)->hkey.size();
+}
+
+// device-following flush (NativeResidentCore._flush_early): launch what is
+// pending below flush_rows / batch_len, padded to the last natural launch's
+// shape (Core::flush).  Returns the launches made, 0 or 1; *rows the rows
+// that launch carries
+i64 wf_core_flush_early(void *h, i64 *rows) {
+    Core *c = (Core *)h;
+    const i64 q0 = c->launches_made;
+    *rows = c->pend_rows;
+    c->flush(EARLY);
     return c->launches_made - q0;
 }
 
@@ -1676,16 +1728,28 @@ int wf_launch_peek(void *h, i64 *K, i64 *R, i64 *B, int *wire, int *rebase,
     return 1;
 }
 
-// arg-extremum extras of the front launch (call between peek and take):
-// the padded rectangle width the core reserved room for, each window's
-// absolute start row (B values) and, when peek said rebase == 2, each ring
-// row's compaction shift (K values)
-int wf_launch_peek_arg(void *h, i64 *Rb, i64 *habs, i64 *shifts) {
+// how the front launch was cut (call between peek and take): its trigger
+// (FlushTrigger) and the rectangle width the core reserved ring room for,
+// 0 where that is bucket(R) — an arg-extremum launch keeps a steady width,
+// an early launch is as wide as the last natural one
+int wf_launch_peek_cut(void *h, int *trigger, i64 *Rb) {
     Core *c = (Core *)h;
     std::lock_guard<std::mutex> lk(c->qmu);
     if (c->queue.empty()) return 0;
     Launch &L = c->queue.front();
+    *trigger = L.trigger;
     *Rb = L.Rb;
+    return 1;
+}
+
+// arg-extremum extras of the front launch (call between peek and take):
+// each window's absolute start row (B values) and, when peek said
+// rebase == 2, each ring row's compaction shift (K values)
+int wf_launch_peek_arg(void *h, i64 *habs, i64 *shifts) {
+    Core *c = (Core *)h;
+    std::lock_guard<std::mutex> lk(c->qmu);
+    if (c->queue.empty()) return 0;
+    Launch &L = c->queue.front();
     if (!L.habs.empty())
         std::memcpy(habs, L.habs.data(), L.habs.size() * 8);
     if (!L.shifts.empty())

@@ -142,7 +142,13 @@ def _bind(lib):
         ctypes.c_void_p, i64, p_i64, p_i64, p_i64, p_i64, p_i32, p_i32,
         p_i64, p_i64]
     lib.wf_launch_peek_arg.restype = ctypes.c_int
-    lib.wf_launch_peek_arg.argtypes = [ctypes.c_void_p, p_i64, p_i64, p_i64]
+    lib.wf_launch_peek_arg.argtypes = [ctypes.c_void_p, p_i64, p_i64]
+    lib.wf_launch_peek_cut.restype = ctypes.c_int
+    lib.wf_launch_peek_cut.argtypes = [ctypes.c_void_p, p_int, p_i64]
+    lib.wf_core_fired_pending.restype = i64
+    lib.wf_core_fired_pending.argtypes = [ctypes.c_void_p]
+    lib.wf_core_flush_early.restype = i64
+    lib.wf_core_flush_early.argtypes = [ctypes.c_void_p, p_i64]
     lib.wf_launch_pending.restype = i64
     lib.wf_launch_pending.argtypes = [ctypes.c_void_p]
     lib.wf_launch_live_rows.restype = i64

@@ -333,6 +333,10 @@ class ResidentWindowExecutor:
     answered by the segment-restaging path, ops/device.py).
     """
 
+    #: the newest dispatch's device result, replaced by the dispatching
+    #: thread: the one value ring_idle() reads from any other thread
+    _last_out = None
+
     def __init__(self, op, device=None, depth: int = 8,
                  acc_dtype=np.int32):
         # `op` is one reduce op or a tuple of them: every op evaluates over
@@ -407,6 +411,7 @@ class ResidentWindowExecutor:
         data = snap.resolve() if isinstance(snap, RingSnapshot) else snap
         self._inflight.clear()
         self._ready = []
+        self._last_out = None
         self.KP = data["KP"]
         self.cap = data["cap"]
         rings = data["rings"]
@@ -419,6 +424,7 @@ class ResidentWindowExecutor:
         archive rows (the no-ring-snapshot restore path)."""
         self._inflight.clear()
         self._ready = []
+        self._last_out = None
         self._rings_assign(None)
         self.KP = 0
         self.cap = 0
@@ -532,6 +538,7 @@ class ResidentWindowExecutor:
         its ``dispatch`` span `sp`; harvest beyond the depth bound."""
         self.dispatches += 1
         stats_add("dispatches")
+        self._last_out = out
         self._inflight.append((meta, sel, out, sp.end_ns(), tag))
         while len(self._inflight) > self.depth:
             self._harvest_one()
@@ -601,9 +608,17 @@ class ResidentWindowExecutor:
 
     def unready_count(self) -> int:
         """Dispatches still being serviced by the device (the ship
-        throttle's saturation signal)."""
+        throttle's saturation signal).  For the thread that dispatches
+        only: it walks the in-flight queue."""
         return sum(1 for entry in self._inflight
                    if not self._is_ready(entry[2]))
+
+    def ring_idle(self) -> bool:
+        """Whether the device has served everything this executor sent
+        it: a ring's launches run in dispatch order, so the newest one's
+        result being there says all are.  Safe to read from any thread."""
+        out = self._last_out
+        return out is None or self._is_ready(out)
 
     @staticmethod
     def _is_ready(out) -> bool:

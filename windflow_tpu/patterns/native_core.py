@@ -54,6 +54,13 @@ def _pick_flush_mult(svc_ms) -> int:
     return mult
 
 
+#: the share of a ring's time early flushes may take (_flush_early): one is
+#: made no sooner than its executor's mean launch service divided by this
+#: after the one before
+_EARLY_SHARE = 0.5
+#: FlushTrigger (wf_native.cpp): what cut a launch, on its launch record
+_TRIGGERS = ("natural", "early", "forced", "eos")
+
 _U64 = (1 << 64) - 1
 
 
@@ -297,6 +304,8 @@ class NativeResidentCore:
         self._acc_wire = 3 if acc.itemsize >= 8 else 2
         self._flush_base = int(flush_rows)
         self._flush_mult = 1
+        #: per shard, when its last early flush was made (_flush_early)
+        self._early_t = [0.0] * self.shards
         self._new_handles()
         #: recovery/rescale support requires the state-ABI symbols in the
         #: loaded .so (stale-library detection: snapshots decline loudly,
@@ -879,6 +888,9 @@ class NativeResidentCore:
                     for h in self._hs:
                         self._lib.wf_core_set_flush_rows(
                             h, self._flush_base * desired)
+        if (b is not None and not launched and self.max_delay_s is None
+                and not self._recovery_mode):
+            self._flush_early()
         if self._overlap:
             for q in self._ship_qs:
                 q.put(("ship", None))
@@ -896,6 +908,34 @@ class NativeResidentCore:
                     if beats % 20 == 0:
                         for q in self._ship_qs:
                             q.put(("ship", None))
+
+    def _flush_early(self):
+        """Device-following flush: `flush_rows` and `batch_len` are the
+        upper bounds of a launch; below them a shard ships what it holds
+        when its C++ core holds a fired window no launch carries yet, the
+        call made no natural launch, and its ring is idle — nothing queued
+        for the ship thread, nothing unserved on the device.  The executor's
+        own measured service keeps such launches to `_EARLY_SHARE` of the
+        ring's time, so a slow step is not fired at every small batch.
+        Never in recovery mode (replayed launch boundaries may not depend
+        on the clock) nor under `max_delay_ms` (that path keeps its
+        timer).  The C++ side (`Core::flush`) pads an early launch to the
+        last natural one's shape — no step executable of its own — and
+        declines where it would have to rebase the ring."""
+        lib = self._lib
+        now = time.monotonic()
+        rows = ctypes.c_longlong()
+        for t, h in enumerate(self._hs):
+            ex = self.executors[t]
+            if (not lib.wf_core_fired_pending(h)
+                    or lib.wf_launch_pending(h) or not ex.ring_idle()
+                    or (now - self._early_t[t]) * _EARLY_SHARE
+                    < ex.mean_service_s()):
+                continue
+            if lib.wf_core_flush_early(h, ctypes.byref(rows)):
+                self._early_t[t] = now
+                profile.add("flush_early")
+                profile.add("flush_early_rows", rows.value)
 
     def _eos_and_drain(self):
         """EOS every shard core, then ship + drain everything; returns
@@ -987,20 +1027,24 @@ class NativeResidentCore:
         # allocate the device-ready zero-padded rectangle(s) and let the
         # C++ take fill them directly (no _pad2 re-copy on this thread)
         from ..ops.device import _bucket
-        KPp, Rb = KP.value, _bucket(max(R, 1))
+        # what cut the launch, and the width the core reserved ring room
+        # for where that is more than the rows' own bucket
+        trigger = ctypes.c_int()
+        rb = ctypes.c_longlong()
+        lib.wf_launch_peek_cut(handle, ctypes.byref(trigger),
+                               ctypes.byref(rb))
+        KPp, Rb = KP.value, max(_bucket(max(R, 1)), rb.value)
         habs = shifts = None
         if self._arg is not None:
-            # the width the core reserved ring room for, each window's
-            # absolute first row, and the rows' slide when it compacts
-            rb = ctypes.c_longlong()
+            # each window's absolute first row, and the rows' slide when
+            # the ring compacts
             habs = np.zeros(max(B, 1), dtype=np.int64)
             shifts = np.zeros(max(K, 1), dtype=np.int64)
-            lib.wf_launch_peek_arg(handle, ctypes.byref(rb),
+            lib.wf_launch_peek_arg(handle,
                                    habs.ctypes.data_as(
                                        ctypes.POINTER(ctypes.c_longlong)),
                                    shifts.ctypes.data_as(
                                        ctypes.POINTER(ctypes.c_longlong)))
-            Rb = max(Rb, rb.value)
         blks = blk = None
         if self._multi:
             # one rectangle per ship field, each in the per-field wire
@@ -1054,7 +1098,8 @@ class NativeResidentCore:
                         * len(self._ship_fields))
                 profile.add("rows_live", live)
                 sp.extra = {"rows_live": live,
-                            "rows_shipped": KPp * Rb * len(self._ship_fields)}
+                            "rows_shipped": KPp * Rb * len(self._ship_fields),
+                            "trigger": _TRIGGERS[trigger.value]}
             if self._multi:
                 ptrs = (ctypes.c_void_p * len(self._ship_fields))(
                     *[b.ctypes.data for b in blks.values()])
