@@ -1369,18 +1369,6 @@ i64 wf_renum_next(void *h, i64 key) {
     return r->sparse[key]++;
 }
 
-// proactive dispatch sizing: the host adjusts the natural launch size to
-// the measured wire service (a power-of-2 multiple of the configured
-// flush_rows, so natural shapes stay on the prewarmed bucket ladder) —
-// the up-front form of what wf_launch_coalesce does reactively after the
-// queue has already deepened.  Caller contract: invoked from the producer
-// thread between process() calls (flush_rows is producer-read-only, so no
-// lock); takes effect at the next flush; ring re-provisioning happens on
-// the next rebase via the ordinary ring-full path.
-void wf_core_set_flush_rows(void *h, i64 rows) {
-    ((Core *)h)->flush_rows = rows;
-}
-
 // latency-bounded flushing: ship whatever windows/rows are pending even
 // though neither batch_len nor flush_rows has been reached (the host core
 // calls this when its max-delay timer expires; no-op when nothing pends)

@@ -262,56 +262,6 @@ def test_empty_windows_match_host_identity(op):
     assert got == want
 
 
-def test_budget_aware_routing_fake_ema(monkeypatch):
-    """A latency budget under ~2x the measured per-launch service routes
-    the stage to the HOST core (the device path cannot meet it by
-    construction); generous budgets, an unmeasured service, or an explicit
-    use_resident force keep the device.  Faked EMA — no device needed."""
-    from windflow_tpu.core.windows import WindowSpec
-    from windflow_tpu.ops import resident
-    from windflow_tpu.patterns.win_seq_tpu import make_core_for
-
-    spec = WindowSpec(16, 4, WinType.CB)
-    red = Reducer("sum", value_range=(0, 100))
-
-    def kind(core):
-        name = type(core).__name__
-        return "host" if "Resident" not in name and "Device" not in name \
-            else "device"
-
-    from collections import deque
-
-    def seed(*obs):
-        monkeypatch.setitem(resident._WEATHER, "recent", deque(maxlen=16))
-        monkeypatch.setitem(resident._WEATHER, "floor_ms", None)
-        monkeypatch.setitem(resident._WEATHER, "ema_ms", None)
-        for ms in obs:   # the public feed path recomputes the floor
-            resident.note_wire_service_ms(ms)
-
-    # recent-best service 700 ms: a 250 ms budget is unmeetable on device
-    seed(900.0, 700.0, 1100.0)
-    assert kind(make_core_for(spec, red, max_delay_ms=250)) == "host"
-    # a >= 2x-floor budget stays on the device path
-    assert kind(make_core_for(spec, red, max_delay_ms=2000)) == "device"
-    # the floor ignores compile-inflated outliers: one good launch among
-    # terrible ones keeps a 2x-floor budget on the device
-    seed(5000.0, 120.0, 4000.0)
-    assert kind(make_core_for(spec, red, max_delay_ms=250)) == "device"
-    # no observation yet: device keeps the benefit of the doubt
-    seed()
-    assert kind(make_core_for(spec, red, max_delay_ms=250)) == "device"
-    # explicit force outranks the budget heuristic
-    seed(700.0)
-    assert kind(make_core_for(spec, red, max_delay_ms=250,
-                              use_resident=True)) == "device"
-    # an explicit use_pallas benchmarking request is never silently
-    # rerouted to the host core
-    assert kind(make_core_for(spec, red, max_delay_ms=250,
-                              use_pallas=True)) == "device"
-    # and with no budget at all the heuristic never engages
-    assert kind(make_core_for(spec, red)) == "device"
-
-
 def test_default_device_needs_a_tpu_or_an_explicit_platform(monkeypatch):
     """A backend JAX fell back to on its own is refused; one named in
     JAX_PLATFORMS (as conftest does) is the caller's choice.  bench.py and

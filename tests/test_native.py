@@ -683,44 +683,6 @@ def test_native_irregular_coalescing_tb_matches_host():
     assert sum(merges) > 0, "TB (irregular) launches never merged"
 
 
-def test_proactive_flush_sizing_is_opt_in(monkeypatch):
-    """Proactive flush sizing engages ONLY under WF_PROACTIVE, seeds its
-    multiple from the process-global launch-service EMA, and '0' means
-    off."""
-    from windflow_tpu.ops import resident as res
-    from windflow_tpu.patterns.native_core import (NativeResidentCore,
-                                                   _pick_flush_mult)
-
-    spec = WindowSpec(16, 4, WinType.CB)
-    saved = dict(res._WEATHER)
-    try:
-        res._WEATHER["ema_ms"] = 500.0          # deep-stall service
-        # rule boundaries
-        for ms, want in [(None, 1), (30, 1), (31, 2), (120, 4), (241, 16)]:
-            assert _pick_flush_mult(ms) == want, (ms, want)
-
-        def mk():
-            return make_native(spec, Reducer("sum"), batch_len=64,
-                               flush_rows=256, overlap=False)
-
-        monkeypatch.delenv("WF_PROACTIVE", raising=False)
-        assert mk()._flush_mult == 1            # default: off
-        monkeypatch.setenv("WF_PROACTIVE", "0")
-        assert mk()._flush_mult == 1            # '0' means off
-        monkeypatch.setenv("WF_PROACTIVE", "1")
-        core = mk()
-        assert core._flush_mult == _pick_flush_mult(500.0) == 16
-        # the sized core still computes correctly, with the stream long
-        # enough (3*2000 rows > 256*16) that at least one SIZED natural
-        # flush fires mid-stream rather than everything draining at EOS
-        batches = cb_stream(3, 2000, chunk=97, seed=5)
-        want = run_core(WinSeqCore(spec, Reducer("sum")), batches)
-        assert_equal_results(want, run_core(core, batches))
-    finally:
-        res._WEATHER.clear()
-        res._WEATHER.update(saved)
-
-
 # ------------------------------------------------- multi-field staging (r5)
 
 MF_SCHEMA = Schema(rev=np.int64, amt=np.int64)

@@ -376,7 +376,12 @@ def _scan_code(fn, depth, seen, findings):
         elif op in ("CALL", "CALL_KW"):      # 3.11+
             argc = ins.arg or 0
             extra = 3 if op == "CALL_KW" else 2
-            record(callee_at(argc + extra), argc, line)
+            # a method call keeps its callee at argc+2 (self above it); a
+            # plain call keeps the NULL there and its callable one higher
+            callee = callee_at(argc + extra)
+            if callee is _OPAQUE:
+                callee = callee_at(argc + extra - 1)
+            record(callee, argc, line)
             pop(argc + extra)
             stack.append(_OPAQUE)
         elif op == "PRECALL" or op == "KW_NAMES":

@@ -67,15 +67,6 @@ def stats_add(name: str, value=1):
         _STATS[name] = _STATS.get(name, 0) + value
 
 
-def stats_max(name: str, value):
-    """High-water gauge (the deepest proactive flush multiple a run
-    reached, which scripts/ab_proactive.py prints per arm) —
-    snapshot/reset like the counters."""
-    with _STATS_MU:
-        if value > _STATS.get(name, 0):
-            _STATS[name] = value
-
-
 def stats_snapshot(reset: bool = False) -> dict:
     """{"dispatches", "merges", "mean_launch_ms"} since the last reset."""
     with _STATS_MU:
@@ -103,43 +94,6 @@ def _named_jit(fn, name: str, **jit_kw):
         return fn(*args)
     call.__name__ = call.__qualname__ = name
     return jax.jit(call, **jit_kw)
-
-#: process-global launch-service record: an EMA of RAW per-dispatch launch
-#: service in ms, deliberately NOT normalized by dispatch size — the
-#: sizing rule's thresholds (_pick_flush_mult) are calibrated for raw
-#: values.  It outlives executors, so a timed run can size its first
-#: dispatches from the warmup run's measured service instead of
-#: discovering a stall one small launch at a time — the proactive half
-#: of dispatch sizing (the reactive half is wf_launch_coalesce).
-_WEATHER = {"ema_ms": None, "recent": deque(maxlen=16), "floor_ms": None}
-_WEATHER_MU = threading.Lock()
-
-
-def note_wire_service_ms(ms: float, weight: float = 0.2):
-    """Fold one raw per-dispatch launch-service observation (ms) into the
-    global launch-service EMA and the recent-window floor.  Mutation and
-    the floor recompute happen under one lock (harvests run on ship
-    threads AND node threads concurrently); readers get atomic floats."""
-    with _WEATHER_MU:
-        prev = _WEATHER["ema_ms"]
-        _WEATHER["ema_ms"] = ms if prev is None else (
-            (1.0 - weight) * prev + weight * ms)
-        _WEATHER["recent"].append(ms)
-        _WEATHER["floor_ms"] = min(_WEATHER["recent"])
-
-
-def wire_weather_ms():
-    """Current launch-service EMA in ms (None before any observation)."""
-    return _WEATHER["ema_ms"]
-
-
-def wire_service_floor_ms():
-    """BEST per-launch service among the recent observations (None before
-    any) — the feasibility statistic for budget-aware routing: a latency
-    budget the launch path cannot meet even at its recent best is
-    unmeetable by construction, while mean-based statistics get poisoned
-    by the one-off compile launches a warmup run necessarily pays."""
-    return _WEATHER["floor_ms"]
 
 
 class RingSnapshot:
@@ -555,17 +509,12 @@ class ResidentWindowExecutor:
             profile.add("launches_ready_at_poll")
         self._svc.append(dt)
         # fold the window mean here, on the harvesting thread: readers on
-        # OTHER threads (the proactive flush sizer runs on the node
-        # thread) then see one atomic float instead of iterating a deque
-        # that a ship thread is appending to
+        # OTHER threads (the early-flush guard runs on the node thread)
+        # then see one atomic float instead of iterating a deque that a
+        # ship thread is appending to
         self._svc_mean = sum(self._svc) / len(self._svc)
         stats_add("svc_s_sum", dt)
         stats_add("svc_n", 1)
-        # always-on launch-service record: the budget-aware core routing
-        # (win_seq_tpu.make_core_for) reads this EMA at construction
-        # time, so a warmup run must seed it unconditionally — not only
-        # when the opt-in proactive sizer is enabled
-        note_wire_service_ms(1e3 * dt)
 
     def mean_service_s(self) -> float:
         """Mean dispatch→ready wall time of recent launches (slightly
@@ -1313,6 +1262,36 @@ class MeshResidentExecutor(ResidentWindowExecutor):
         wr = np.asarray(wrows, dtype=np.int64)
         sel = ((wr % S) * rps + wr // S, np.asarray(widx))
         self._dispatched(meta, sel, out, sp, tag)
+
+
+def make_executor(family: str, fields, stats, acc_dtypes, *, jax_fn=None,
+                  mesh=None, device=None, depth: int = 8):
+    """The resident executor of one step family — the one place that names
+    the executor classes.  ``family`` is ``"regular"`` (one ring, every op
+    of ``stats`` over it), ``"multi"`` (a ring per field; the only family
+    that takes a ``jax_fn``) or ``"argext"``; ``stats`` are (op, field)
+    pairs and ``acc_dtypes`` maps each field to its ring dtype.  With
+    ``mesh`` the rings shard ``P(kf, None)`` over it, else they live on
+    ``device``."""
+    if family == "argext":
+        return ArgExtResidentExecutor(fields, stats, acc_dtypes,
+                                      device=device, depth=depth)
+    if family == "multi":
+        if mesh is not None:
+            return MeshMultiFieldResidentExecutor(
+                fields, stats=stats, jax_fn=jax_fn, acc_dtypes=acc_dtypes,
+                mesh=mesh, depth=depth)
+        return MultiFieldResidentExecutor(
+            fields, stats=stats, jax_fn=jax_fn, acc_dtypes=acc_dtypes,
+            device=device, depth=depth)
+    (field,) = fields
+    ops = tuple(op for op, _f in stats)
+    op = ops[0] if len(ops) == 1 else ops
+    if mesh is not None:
+        return MeshResidentExecutor(op, mesh, depth=depth,
+                                    acc_dtype=acc_dtypes[field])
+    return ResidentWindowExecutor(op, device=device, depth=depth,
+                                  acc_dtype=acc_dtypes[field])
 
 
 def prewarm_regular_ladder(mults=(2, 4, 8, 16), devices=None,

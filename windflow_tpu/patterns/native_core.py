@@ -3,8 +3,8 @@
 Same contract as ``ResidentWinSeqCore`` (process/flush producing result
 batches), but the per-row window bookkeeping and staging-rectangle assembly
 run in ``native/wf_native.cpp`` with the GIL released — the C++ hot loop the
-reference runs per tuple (win_seq.hpp:268-474), feeding the same
-``ResidentWindowExecutor`` device path.  Hands the stream to the pure-Python
+reference runs per tuple (win_seq.hpp:268-474), feeding the same resident
+executors (ops/resident.make_executor).  Hands the stream to the pure-Python
 core when the payload field is not int64 (the native ABI ships int64
 columns).
 """
@@ -29,31 +29,11 @@ _ROLE_CODE = {Role.SEQ: 0, Role.PLQ: 1, Role.WLQ: 2, Role.MAP: 3,
               Role.REDUCE: 4}
 _WIRE_DTYPES = (np.int8, np.int16, np.int32, np.int64)
 
-#: per-natural-flush launch service (ms) below which dispatching at the
-#: configured flush_rows is taken to keep pace with the host loop; above
-#: it, each doubling of measured service doubles the proactive flush
-#: multiple.  An assumed figure, not fitted to any measured device: it
-#: feeds only the off-by-default WF_PROACTIVE path.
-_FLUSH_SVC_MS = 30.0
-_FLUSH_MULT_MAX = 16   # the prewarmed shape ladder's depth
-
-
-def _pick_flush_mult(svc_ms) -> int:
-    """Natural-dispatch size multiple for the measured per-natural-flush
-    launch service: 1 while launches keep pace, doubling with service so a
-    stalled run issues ~flush_mult-times fewer, larger natural launches UP
-    FRONT instead of discovering the stall one small launch at a time (the
-    reactive coalescer only engages once the queue is already deep).
-    Power-of-2 multiples keep natural shapes on the exact bucket ladder
-    prewarm_regular_ladder compiles."""
-    if not svc_ms or svc_ms <= _FLUSH_SVC_MS:
-        return 1
-    mult = 1
-    while mult < _FLUSH_MULT_MAX and svc_ms > _FLUSH_SVC_MS * mult:
-        mult *= 2
-    return mult
-
-
+#: adaptive launch coalescing (wf_launch_coalesce): keep at most this many
+#: dispatches in flight un-serviced; beyond it a ship thread holds, so the
+#: C++ queue deepens and queued launches fuse into fewer, larger dispatches
+#: (each dispatch costs one launch service, so under stall fewer of them win)
+_DISPATCH_WINDOW = 8
 #: the share of a ring's time early flushes may take (_flush_early): one is
 #: made no sooner than its executor's mean launch service divided by this
 #: after the one before
@@ -124,29 +104,23 @@ class NativeResidentCore:
                  overlap: bool = True, worker_index: int = 0,
                  max_delay_ms=None, mesh=None):
         from ..native import load
-        from ..ops.resident import (MeshResidentExecutor,
-                                    ResidentWindowExecutor)
+        from ..ops.functions import MultiReducer
+        from ..ops.resident import make_executor
+        from .win_seq_tpu import (_ARGEXT_PLACEMENT, _arg_parts,
+                                  _argext_misplaced, _executor_family,
+                                  _native_refusal, acc_dtypes_by_field,
+                                  resolve_worker_device, split_pos_max)
         self._lib = load()
         if self._lib is None:
             raise RuntimeError("native library unavailable")
-        from ..ops.functions import MultiReducer
         if isinstance(reducer, MultiReducer):
             # counts come from window lengths and MAX over the position
             # field from the C++ archive's per-window last row (hpmax) —
-            # e.g. YSB's COUNT + MAX(ts) + SUM(revenue) ships only revenue
-            # while the whole hot loop stays in C++.  Remaining
-            # device-worthy stats stage one int64 column per distinct
-            # field (C++ kMaxFields = 4) into per-field device rings
-            # (MultiFieldResidentExecutor) — the rich-aggregate form that
-            # would otherwise re-pay the Python hot loop.
-            from .win_seq_tpu import split_pos_max
-            dev, pos = split_pos_max(spec, reducer)
-            if not dev:
-                raise TypeError(
-                    "native resident core needs >=1 device-worthy stat "
-                    "after the pos-max split")
-            self._dev_parts = dev
-            self._pos_max_parts = pos
+            # e.g. YSB's COUNT + MAX(ts) + SUM(revenue) ships only revenue;
+            # the other stats stage one int64 column per distinct field
+            # (C++ kMaxFields = 4) into per-field device rings
+            self._dev_parts, self._pos_max_parts = \
+                split_pos_max(spec, reducer)
             self._count_parts = reducer.count_parts
         elif isinstance(reducer, (Reducer, ArgReducer)):
             self._dev_parts = [reducer]
@@ -155,13 +129,10 @@ class NativeResidentCore:
         else:
             raise TypeError("native resident core needs a builtin "
                             "(Multi)Reducer or ArgReducer")
-        self._dev_part = self._dev_parts[0]
-        self._ship_fields = tuple(dict.fromkeys(
-            p.field for p in self._dev_parts))
         #: the arg-extremum stat, if the function has one: its ring is
         #: evaluated by the wf_step_argext family and its winning row read
         #: back from the C++ archive at harvest (_gather_payload)
-        args = [p for p in self._dev_parts if isinstance(p, ArgReducer)]
+        args = _arg_parts(self._dev_parts)
         if len(args) > 1:
             raise TypeError("one arg-extremum per window function (each "
                             f"names its own winning row): got {args}")
@@ -171,28 +142,23 @@ class NativeResidentCore:
         #: (``ts`` is archived anyway)
         self._carry_cols = ()
         if self._arg is not None:
-            if mesh is not None or int(shards) > 1:
+            if _argext_misplaced(mesh, shards):
                 raise ValueError(
-                    "the arg-extremum family runs one shard on one device "
-                    "(no mesh, shards=1)")
+                    f"the arg-extremum family runs {_ARGEXT_PLACEMENT}")
             self._carry_cols = tuple(dict.fromkeys(
                 (self._arg.id_field,) + tuple(
                     src for src, _d in self._arg.carry if src != "ts")))
-        #: >1 device stat (several fields, or several ops over one field):
-        #: per-field rings via MultiFieldResidentExecutor; the single-stat
-        #: path keeps its regular-descriptor compression.  An arg-extremum
-        #: always takes the per-field form (its own executor)
-        self._multi = len(self._dev_parts) > 1 or self._arg is not None
-        max_fields = int(self._lib.wf_max_fields())
-        if len(self._ship_fields) > max_fields:
-            raise TypeError(
-                f"native resident core stages at most {max_fields} "
-                f"payload columns (got fields {self._ship_fields})")
-        if self._multi and any(np.issubdtype(p.dtype, np.floating)
-                               for p in self._dev_parts):
-            raise TypeError(
-                "native multi-field staging ships int64 columns; float "
-                "stats run on the Python resident core")
+        # the limits the router routes around (win_seq_tpu.plan_core)
+        refusal = _native_refusal(self._dev_parts,
+                                  int(self._lib.wf_max_fields()))
+        if refusal is not None:
+            raise TypeError(refusal)
+        self._dev_part = self._dev_parts[0]
+        self._ship_fields = tuple(dict.fromkeys(
+            p.field for p in self._dev_parts))
+        family = _executor_family("native", self._dev_parts)
+        #: beyond ``regular`` the C++ core stages per-field rectangles
+        self._multi = family != "regular"
         self.spec = spec
         self.reducer = reducer
         self.field = self._dev_part.field
@@ -214,20 +180,9 @@ class NativeResidentCore:
         self.max_delay_s = (None if max_delay_ms is None
                             else max_delay_ms / 1e3)
         self._last_flush_t = None
-        from .win_seq_tpu import resolve_worker_device, select_acc_dtype
-        acc = select_acc_dtype(self._dev_part, compute_dtype, spec)
-        #: per-field ring dtypes for the multi path (same rules as
-        #: ResidentWinSeqCore: widest acc per field, consistent kind)
-        self._acc_by_field = {}
-        for p in self._dev_parts:
-            a = select_acc_dtype(p, compute_dtype, spec)
-            prev = self._acc_by_field.get(p.field)
-            if prev is not None and prev.kind != a.kind:
-                raise ValueError(
-                    f"stats over field {p.field!r} disagree on "
-                    f"accumulate kind ({prev} vs {a})")
-            if prev is None or a.itemsize > prev.itemsize:
-                self._acc_by_field[p.field] = a
+        #: ring dtype per shipped field
+        self._acc_by_field = acc_dtypes_by_field(self._dev_parts,
+                                                 compute_dtype, spec)
         # key-sharded multithreading: shard t owns keys with
         # mix64(key) %% S == t (a hash decorrelated from the farm routing
         # modulus — see wf_native.cpp), each with an independent sub-core,
@@ -235,63 +190,23 @@ class NativeResidentCore:
         # processes a chunk on S pool threads.  Shard rings spread over the
         # visible chips (worker_index * S + t round-robin) so a sharded
         # core on a multi-chip host keeps each shard's archive on its own
-        # device, like the farms' per-worker device ownership.
+        # device, like the farms' per-worker device ownership.  With a
+        # mesh every shard's ring is itself sharded P(kf, None) over every
+        # chip: a multicore host spreads the hot loop over its cores while
+        # each shard's dispatches still serve all key groups in one SPMD
+        # program.
         # cap at 256: the C++ MT path routes rows via a per-row shard-id
         # *byte* array (wf_native.cpp:wf_cores_process_mt), so ids beyond
         # u8 would alias and double-process rows
         self.shards = max(min(int(shards), 256), 1)
-        if self._arg is not None:
-            from ..ops.resident import ArgExtResidentExecutor
-            self.executors = [ArgExtResidentExecutor(
-                self._ship_fields,
-                tuple((p.op, p.field) for p in self._dev_parts),
-                self._acc_by_field,
-                device=resolve_worker_device(device, worker_index),
-                depth=depth)]
-        elif self._multi:
-            stats = tuple((p.op, p.field) for p in self._dev_parts)
-            if mesh is not None:
-                # mesh-sharded per-field rings (P(kf, None)): the pod
-                # deployment shape keeps the C++ hot loop for rich
-                # aggregates too — same composition rule as the
-                # single-stat mesh path
-                from ..ops.resident import MeshMultiFieldResidentExecutor
-                self.executors = [
-                    MeshMultiFieldResidentExecutor(
-                        self._ship_fields, stats=stats,
-                        acc_dtypes=self._acc_by_field, mesh=mesh,
-                        depth=depth)
-                    for _t in range(self.shards)]
-            else:
-                from ..ops.resident import MultiFieldResidentExecutor
-                self.executors = [
-                    MultiFieldResidentExecutor(
-                        self._ship_fields, stats=stats,
-                        acc_dtypes=self._acc_by_field,
-                        device=resolve_worker_device(
-                            device, worker_index * self.shards + t),
-                        depth=depth)
-                    for t in range(self.shards)]
-        elif mesh is not None:
-            # mesh execution composes with host key-sharding: shard t's
-            # sub-core keeps its own C++ bookkeeping AND its own
-            # mesh-sharded ring (each P(kf, None) over every chip), so a
-            # multicore host spreads the hot loop over its cores while
-            # every shard's dispatches still serve all key groups in one
-            # SPMD program (a pin to shards=1 would re-pay the
-            # single-threaded bookkeeping on exactly the pod config)
-            self.executors = [
-                MeshResidentExecutor(self._dev_part.op, mesh, depth=depth,
-                                     acc_dtype=acc)
-                for _t in range(self.shards)]
-        else:
-            self.executors = [
-                ResidentWindowExecutor(
-                    self._dev_part.op,
-                    device=resolve_worker_device(
-                        device, worker_index * self.shards + t),
-                    depth=depth, acc_dtype=acc)
-                for t in range(self.shards)]
+        stats = tuple((p.op, p.field) for p in self._dev_parts)
+        self.executors = [
+            make_executor(
+                family, self._ship_fields, stats, self._acc_by_field,
+                mesh=mesh, depth=depth,
+                device=(None if mesh is not None else resolve_worker_device(
+                    device, worker_index * self.shards + t)))
+            for t in range(self.shards)]
         self.executor = self.executors[0]
         #: process-wide number of shard 0's ship thread: spans and launch
         #: records name a shard as _shard_base + t, so two farm workers'
@@ -301,9 +216,9 @@ class NativeResidentCore:
         #: `cause` on the launches shipped after it
         self._cause = None
         self._batch_len = int(batch_len)
-        self._acc_wire = 3 if acc.itemsize >= 8 else 2
-        self._flush_base = int(flush_rows)
-        self._flush_mult = 1
+        self._acc_wire = (
+            3 if self._acc_by_field[self.field].itemsize >= 8 else 2)
+        self._flush_rows = int(flush_rows)
         #: per shard, when its last early flush was made (_flush_early)
         self._early_t = [0.0] * self.shards
         self._new_handles()
@@ -320,31 +235,9 @@ class NativeResidentCore:
         self._obs_metrics = None
         #: recovery-mode latch (process_batches and friends): pins
         #: deterministic launch boundaries — no reactive coalescing, no
-        #: proactive flush resizing — so a replayed run's per-launch
-        #: emission regroups exactly like the original's
+        #: early flush — so a replayed run's per-launch emission regroups
+        #: exactly like the original's
         self._recovery_mode = False
-        # proactive dispatch sizing: seed the natural flush size from the
-        # process-global launch-service EMA (a warmup run's harvests populate
-        # it), then retune per chunk from this core's own measured
-        # service.  Latency-bounded cores keep their configured cadence —
-        # growing flushes there would spend the max_delay budget on
-        # purpose-built queueing.
-        from ..ops import resident as _res
-        # proactive sizing is OPT-IN (WF_PROACTIVE=1): upsized naturals
-        # raise per-dispatch service (the transfer component grows with
-        # the rectangle), which can cost more than the round trips they
-        # save; scripts/ab_proactive.py is the A/B that decides it on a
-        # given machine.
-        self._proactive = (self.max_delay_s is None
-                           and os.environ.get("WF_PROACTIVE", "")
-                           not in ("", "0"))
-        if self._proactive:
-            self._flush_mult = _pick_flush_mult(_res.wire_weather_ms())
-            if self._flush_mult > 1:
-                for h in self._hs:
-                    self._lib.wf_core_set_flush_rows(
-                        h, self._flush_base * self._flush_mult)
-        _res.stats_max("flush_mult_max", self._flush_mult)
         self._delegate = None
         self._offsets = None
         self._salvaged = []  # results drained during a raise, returned to
@@ -361,14 +254,6 @@ class NativeResidentCore:
         #: throttles — restores the backpressure the synchronous ship loop
         #: provided (each queued Launch holds a staged K*R block)
         self._max_pending = 2 * depth
-        #: adaptive launch coalescing (wf_launch_coalesce): keep at most
-        #: this many dispatches in flight un-serviced; beyond it, hold so
-        #: the C++ queue deepens and queued launches fuse into fewer,
-        #: larger dispatches (each dispatch costs one launch service, so
-        #: under stall fewer of them win).  Default 8; scripts/
-        #: sweep_window.py sweeps it and WF_DISPATCH_WINDOW overrides it.
-        self._dispatch_window = int(
-            os.environ.get("WF_DISPATCH_WINDOW", "8"))
         #: absolute merged-rectangle area guard (cells = K * bucket(R)):
         #: stops pathological padded rectangles (one hot key at huge
         #: flush_rows) from blowing host memory; must admit a full
@@ -389,7 +274,7 @@ class NativeResidentCore:
             int(cfg.id_outer), int(cfg.n_outer), int(cfg.slide_outer),
             int(cfg.id_inner), int(cfg.n_inner), int(cfg.slide_inner),
             int(self.map_indexes[0]), int(self.map_indexes[1]),
-            int(self.result_ts_slide), self._batch_len, self._flush_base,
+            int(self.result_ts_slide), self._batch_len, self._flush_rows,
             self._acc_wire) for _ in range(self.shards)]
         if self._multi:
             # per-field widest wire dtype (ship_fields order): the C++
@@ -419,10 +304,6 @@ class NativeResidentCore:
                     raise TypeError(
                         f"native core archives {got} carried columns, the "
                         f"arg-extremum needs {self._carry_cols}")
-        if self._flush_mult > 1:
-            for h in self._hs:
-                self._lib.wf_core_set_flush_rows(
-                    h, self._flush_base * self._flush_mult)
         self._harr = (ctypes.c_void_p * self.shards)(*self._hs)
 
     def _start_ship_threads(self):
@@ -554,19 +435,14 @@ class NativeResidentCore:
 
     def _enter_recovery_mode(self):
         """Pin deterministic launch boundaries for recovery-mode runs:
-        reactive coalescing fuses queued launches by measured wire
-        service and proactive sizing rescales flush_rows by that service
-        — both wall-clock-driven, so a replayed run's launch boundaries
-        (and with them the per-launch emission seqs) would diverge from
-        the original's.  Natural flushes alone are count-triggered."""
+        reactive coalescing fuses queued launches by measured launch
+        service and the early flush follows the ring's idleness — both
+        wall-clock-driven, so a replayed run's launch boundaries (and
+        with them the per-launch emission seqs) would diverge from the
+        original's.  Natural flushes alone are count-triggered."""
         if self._recovery_mode:
             return
         self._recovery_mode = True
-        self._proactive = False
-        if self._flush_mult > 1:
-            self._flush_mult = 1
-            for h in self._hs:
-                self._lib.wf_core_set_flush_rows(h, self._flush_base)
         if self._overlap:
             # ship threads drain into ONE completion-ordered queue, so a
             # multi-shard core's emission interleaving is wall-clock —
@@ -836,7 +712,7 @@ class NativeResidentCore:
 
     def _process_rows(self, batch):
         """Feed one chunk through the C++ bookkeeping (flush cadence,
-        proactive sizing, ship-thread pokes + backpressure included);
+        ship-thread pokes + backpressure included);
         harvest collection is the caller's (process vs process_batches)."""
         b = np.ascontiguousarray(batch) if len(batch) else None
         launched = 0
@@ -865,29 +741,6 @@ class NativeResidentCore:
                 for h in self._hs:
                     self._lib.wf_core_force_flush(h)
                 self._last_flush_t = now
-        elif self._proactive and self._hs:
-            # proactive flush sizing, chunk cadence: retune from the
-            # global launch-service EMA.  The service is NOT normalized
-            # by dispatch size: the rule assumes a latency-dominated
-            # launch, where a slow launch at mult 4 argues for BIGGER
-            # dispatches, not for downsizing.
-            from ..ops import resident as _res
-            _res.stats_max("flush_mult_max", self._flush_mult)
-            svc = max(ex.mean_service_s() for ex in self.executors)
-            if svc > 0.0:
-                # the global service EMA is fed per harvested launch
-                # (resident._note_service, always-on) — folding the
-                # chunk-cadence MEAN here again would both double-feed
-                # the EMA and flood the 16-slot floor window with mean
-                # values, evicting the genuine fast-launch minima the
-                # budget routing keys on
-                desired = _pick_flush_mult(_res.wire_weather_ms())
-                if desired != self._flush_mult:
-                    self._flush_mult = desired
-                    _res.stats_max("flush_mult_max", desired)
-                    for h in self._hs:
-                        self._lib.wf_core_set_flush_rows(
-                            h, self._flush_base * desired)
         if (b is not None and not launched and self.max_delay_s is None
                 and not self._recovery_mode):
             self._flush_early()
@@ -973,8 +826,7 @@ class NativeResidentCore:
         # would regroup differently and break the per-edge seq dedup)
         # (nor does the arg-extremum family: nothing prewarms its merged
         # shapes, so a merge would compile cold in mid-run)
-        coalesce = (not os.environ.get("WF_NO_COALESCE")
-                    and not self._recovery_mode and self._arg is None)
+        coalesce = not self._recovery_mode and self._arg is None
         if (coalesce and not force and pending <= self._max_pending
                 and self.max_delay_s is None):
             # (beyond _max_pending the hold is skipped: the producer's
@@ -982,7 +834,7 @@ class NativeResidentCore:
             # would livelock — and the memory bound outranks RTT savings.
             # A latency-bounded core never holds: a launch parked behind
             # a stalled device would blow the max_delay budget by design.)
-            if ex.unready_count() >= self._dispatch_window:
+            if ex.unready_count() >= _DISPATCH_WINDOW:
                 # launches saturated: hold this one so the queue deepens and
                 # the next ship fuses the backlog into one dispatch
                 return False
@@ -995,12 +847,6 @@ class NativeResidentCore:
             # pre-compile the deep buckets via prewarm_regular_ladder().
             svc = ex.mean_service_s()
             max_mult = 16 if svc >= 0.05 else (8 if svc >= 0.02 else 4)
-            # proactively upsized naturals are already flush_mult flushes
-            # wide: cap the reactive ladder so total dispatch size stays
-            # within the 16x of a BASE flush that prewarm compiled and the
-            # ring was provisioned for
-            max_mult = min(max_mult,
-                           max(1, _FLUSH_MULT_MAX // self._flush_mult))
             with profile.span("launch_coalesce",
                               shard=self._shard_base + shard):
                 merged = lib.wf_launch_coalesce(

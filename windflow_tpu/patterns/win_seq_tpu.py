@@ -21,6 +21,8 @@ covers the reference's "host-callable device functor" testing trick).
 
 from __future__ import annotations
 
+from typing import NamedTuple, Optional
+
 import numpy as np
 
 from ..core.tuples import Schema
@@ -342,6 +344,26 @@ def select_acc_dtype(reducer: Reducer, compute_dtype,
     return acc
 
 
+def acc_dtypes_by_field(parts, compute_dtype, spec: WindowSpec = None) -> dict:
+    """Ring dtype per field for the resident cores: every stat picks its
+    accumulate dtype (:func:`select_acc_dtype`), stats over one field share
+    its ring at the widest of them, and must agree on the kind — a float
+    ring would silently round sibling integer sums (float32 spacing > 1
+    above 2^24)."""
+    by_field = {}
+    for p in parts:
+        a = select_acc_dtype(p, compute_dtype, spec)
+        prev = by_field.get(p.field)
+        if prev is not None and prev.kind != a.kind:
+            raise ValueError(
+                f"stats over field {p.field!r} disagree on accumulate "
+                f"kind ({prev} vs {a}): split the stats or pass an "
+                "explicit compute_dtype")
+        if prev is None or a.itemsize > prev.itemsize:
+            by_field[p.field] = a
+    return by_field
+
+
 def finalize_window_values(reducer: Reducer, vals: np.ndarray,
                            lens: np.ndarray) -> np.ndarray:
     """Shared harvest step: cast device outputs to the reducer's result
@@ -387,9 +409,7 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                  result_ts_slide=None, device=None, depth: int = 8,
                  compute_dtype=None, worker_index: int = 0, mesh=None,
                  max_delay_ms=None):
-        from ..ops.resident import (MeshResidentExecutor,
-                                    MultiFieldResidentExecutor,
-                                    ResidentWindowExecutor)
+        from ..ops.resident import make_executor
         self._jax_fn = None
         self._pos_max_parts = []
         if isinstance(reducer, JaxWindowFunction):
@@ -399,7 +419,6 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
             self._device_parts = []
             self._count_parts = []
             self._jax_fn = reducer
-            field = None
         elif isinstance(reducer, MultiReducer):
             # multi-stat: every DEVICE-WORTHY stat evaluates over its
             # field's resident ring in one fused dispatch; counts come
@@ -420,8 +439,6 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                 # host core unless forced)
                 self._device_parts, self._pos_max_parts = \
                     self._pos_max_parts, []
-            fields = {p.field for p in self._device_parts}
-            field = fields.pop() if len(fields) == 1 else None
             if not self._device_parts:
                 raise ValueError(
                     "resident MultiReducer needs >=1 non-count stat "
@@ -429,7 +446,6 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         elif isinstance(reducer, Reducer):
             self._device_parts = [reducer]
             self._count_parts = []
-            field = reducer.field
         else:
             raise TypeError("resident device path needs a builtin Reducer, "
                             "MultiReducer, or JaxWindowFunction")
@@ -438,81 +454,39 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                          map_indexes=map_indexes,
                          result_ts_slide=result_ts_slide)
         self.reducer = reducer
-        self.field = field
+        family = _executor_family(
+            "resident_py", None if self._jax_fn is not None
+            else self._device_parts)
+        self._ship_fields = (
+            tuple(self._jax_fn.fields) if self._jax_fn is not None
+            else tuple(dict.fromkeys(p.field for p in self._device_parts)))
+        #: the one ring's field; None with a ring per field
+        self.field = self._ship_fields[0] if family == "regular" else None
+        # ring dtypes: reducer parts pick theirs (acc_dtypes_by_field);
+        # fn-only fields use the fn's declared field_dtypes (default int32)
+        acc_by_field = acc_dtypes_by_field(self._device_parts, compute_dtype,
+                                           spec)
         if self._jax_fn is not None:
-            self._ship_fields = tuple(self._jax_fn.fields)
-        elif field is not None:
-            self._ship_fields = (field,)
-        else:
-            self._ship_fields = tuple(dict.fromkeys(
-                p.field for p in self._device_parts))
-        multi = field is None
-        if multi:
-            # per-field ring dtypes: reducer parts pick theirs via
-            # select_acc_dtype; fn-only fields use the fn's declared
-            # field_dtypes (default int32)
-            acc_by_field = {}
-            for p in self._device_parts:
-                a = select_acc_dtype(p, compute_dtype, spec)
-                prev = acc_by_field.get(p.field)
-                if prev is not None and prev.kind != a.kind:
-                    raise ValueError(
-                        f"stats over field {p.field!r} disagree on "
-                        f"accumulate kind ({prev} vs {a})")
-                if prev is None or a.itemsize > prev.itemsize:
-                    acc_by_field[p.field] = a
-            if self._jax_fn is not None:
-                declared = getattr(self._jax_fn, "field_dtypes", None) or {}
-                for f in self._ship_fields:
-                    dt = np.dtype(declared.get(f, np.int32))
-                    if dt.itemsize >= 8:
-                        # same guard select_acc_dtype applies: without x64
-                        # jax silently canonicalizes the ring to 32 bits
-                        import jax
-                        if not jax.config.jax_enable_x64:
-                            raise ValueError(
-                                f"field_dtypes[{f!r}]={dt} needs jax x64 "
-                                "enabled (jax.config.update("
-                                "'jax_enable_x64', True))")
-                    acc_by_field.setdefault(f, dt)
-            if mesh is not None:
-                from ..ops.resident import MeshMultiFieldResidentExecutor
-                self.executor = MeshMultiFieldResidentExecutor(
-                    self._ship_fields,
-                    stats=tuple((p.op, p.field)
-                                for p in self._device_parts),
-                    jax_fn=self._jax_fn, acc_dtypes=acc_by_field,
-                    mesh=mesh, depth=depth)
-            else:
-                self.executor = MultiFieldResidentExecutor(
-                    self._ship_fields,
-                    stats=tuple((p.op, p.field) for p in self._device_parts),
-                    jax_fn=self._jax_fn, acc_dtypes=acc_by_field,
-                    device=resolve_worker_device(device, worker_index),
-                    depth=depth)
-        else:
-            accs = [select_acc_dtype(p, compute_dtype, spec)
-                    for p in self._device_parts]
-            kinds = {d.kind for d in accs}
-            if len(kinds) > 1:
-                # one shared ring, one accumulate dtype: a float ring would
-                # silently round sibling integer sums (float32 spacing > 1
-                # above 2^24) — refuse instead
-                raise ValueError(
-                    "multi-stat parts disagree on accumulate kind "
-                    f"({sorted(str(a) for a in accs)}): split the stats or "
-                    "pass an explicit compute_dtype")
-            acc = max(accs, key=lambda d: d.itemsize)
-            ops = tuple(p.op for p in self._device_parts)
-            op_arg = ops[0] if len(ops) == 1 else ops
-            if mesh is not None:
-                self.executor = MeshResidentExecutor(
-                    op_arg, mesh, depth=depth, acc_dtype=acc)
-            else:
-                self.executor = ResidentWindowExecutor(
-                    op_arg,
-                    device=resolve_worker_device(device, worker_index),
-                    depth=depth, acc_dtype=acc)
+            declared = getattr(self._jax_fn, "field_dtypes", None) or {}
+            for f in self._ship_fields:
+                dt = np.dtype(declared.get(f, np.int32))
+                if dt.itemsize >= 8:
+                    # same guard select_acc_dtype applies: without x64
+                    # jax silently canonicalizes the ring to 32 bits
+                    import jax
+                    if not jax.config.jax_enable_x64:
+                        raise ValueError(
+                            f"field_dtypes[{f!r}]={dt} needs jax x64 "
+                            "enabled (jax.config.update("
+                            "'jax_enable_x64', True))")
+                acc_by_field.setdefault(f, dt)
+        self.executor = make_executor(
+            family, self._ship_fields,
+            tuple((p.op, p.field) for p in self._device_parts),
+            acc_by_field, jax_fn=self._jax_fn, mesh=mesh,
+            device=(None if mesh is not None
+                    else resolve_worker_device(device, worker_index)),
+            depth=depth)
         self.batch_len = batch_len
         self.flush_rows = flush_rows
         # latency bound: ship pending windows/rows after this many ms even
@@ -664,8 +638,7 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
             wlens = np.concatenate([lens for _k, _a, lens, _g in self._wdesc])
         else:
             wrows = wstarts = wlens = np.zeros(0, dtype=np.int64)
-        from ..ops.resident import MultiFieldResidentExecutor
-        if isinstance(ex, MultiFieldResidentExecutor):
+        if self.field is None:
             # multi-field executor: ships every ring's rectangle + the
             # (keys, gwids) header columns the JAX fn contract receives
             if self._jax_fn is not None and self._wdesc:
@@ -840,6 +813,10 @@ def split_pos_max(spec: WindowSpec, reducer: MultiReducer):
     return [p for p in dev if p not in pos], pos
 
 
+def _stat_parts(winfunc):
+    return winfunc.parts if isinstance(winfunc, MultiReducer) else [winfunc]
+
+
 def _host_free(spec: WindowSpec, winfunc) -> bool:
     """True when every stat is free on the host: counts come from window
     lengths, and ``max``/``min`` over the POSITION field (ts for TB, id
@@ -848,10 +825,9 @@ def _host_free(spec: WindowSpec, winfunc) -> bool:
     already holds the answers.  Such aggregates have no device-worthy
     compute at all."""
     pos_field = "id" if spec.win_type is WinType.CB else "ts"
-    parts = winfunc.parts if isinstance(winfunc, MultiReducer) else [winfunc]
     return all(p.op == "count"
                or (p.op in ("max", "min") and p.field == pos_field)
-               for p in parts)
+               for p in _stat_parts(winfunc))
 
 
 def _multi_resident_ok(winfunc: MultiReducer, use_pallas: bool) -> bool:
@@ -867,22 +843,192 @@ def _multi_resident_ok(winfunc: MultiReducer, use_pallas: bool) -> bool:
                         for p in dev))
 
 
-def _has_arg_extremum(winfunc) -> bool:
-    return isinstance(winfunc, ArgReducer) or (
-        isinstance(winfunc, MultiReducer)
-        and any(isinstance(p, ArgReducer) for p in winfunc.parts))
+def _arg_parts(parts):
+    """The arg-extremum stats among `parts` (ops/functions.ArgReducer)."""
+    return [p for p in parts if isinstance(p, ArgReducer)]
 
 
-def _native_core_lib():
-    """The native library handle for core routing, or None — also None
-    under WF_NO_NATIVE_CORE=1, which pins the Python resident core
-    (e.g. for recovery snapshots: the C++ core's archives live in
-    native tables with no snapshot API, docs/ROBUSTNESS.md)."""
+#: where the arg-extremum family runs, as the router's refusal and the
+#: native core's own both say it
+_ARGEXT_PLACEMENT = "one shard on one device (no mesh, shards=1)"
+
+
+def _argext_misplaced(mesh, shards) -> bool:
+    return mesh is not None or int(shards) != 1
+
+
+def _native_refusal(dev_parts, max_fields: int):
+    """Why the native resident core cannot take these device-worthy stats
+    (what is left of a function after :func:`split_pos_max`), or None when
+    it can.  The router routes around a refusal (the Python resident core
+    takes what this one does not); the core itself raises it as a
+    ``TypeError``.  ``max_fields`` is the library's ``wf_max_fields()``."""
+    if not dev_parts:
+        # a fully host-free aggregate forced onto the device: only the
+        # Python core has the ship-the-position-column fallback
+        return ("native resident core needs >=1 device-worthy stat "
+                "after the pos-max split")
+    fields = tuple(dict.fromkeys(p.field for p in dev_parts))
+    if len(fields) > max_fields:
+        return (f"native resident core stages at most {max_fields} "
+                f"payload columns (got fields {fields})")
+    if (_executor_family("native", dev_parts) != "regular"
+            and any(np.issubdtype(p.dtype, np.floating) for p in dev_parts)):
+        return ("native multi-field staging ships int64 columns; float "
+                "stats run on the Python resident core")
+    return None
+
+
+def _executor_family(core: str, parts) -> str:
+    """Step family (ops/resident.make_executor) the resident core `core`
+    evaluates these device stats with; ``parts`` None is a
+    JaxWindowFunction.  The native core gives every stat beyond the first
+    its field's own ring (its single-stat form keeps the regular-descriptor
+    compression) and an arg-extremum its own family; the Python core lets
+    stats over ONE field share one ring."""
+    if parts is None:
+        return "multi"
+    if core == "native":
+        if _arg_parts(parts):
+            return "argext"
+        return "multi" if len(parts) > 1 else "regular"
+    return "multi" if len({p.field for p in parts}) > 1 else "regular"
+
+
+class CorePlan(NamedTuple):
+    """What :func:`plan_core` decided for one window stage."""
+    #: ``host`` (no device work: core/winseq and its vectorised kin),
+    #: ``native`` (NativeResidentCore), ``resident_py`` (ResidentWinSeqCore)
+    #: or ``restage`` (DeviceWinSeqCore)
+    core: str
+    #: the resident executor's step family — ``regular``, ``multi``,
+    #: ``argext`` — or None off the resident path
+    family: Optional[str]
+    #: rings sharded over a mesh rather than placed on one device
+    mesh: bool
+
+
+def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
+              mesh=None, shards=1, native=None) -> CorePlan:
+    """Which window core and executor family run ``winfunc`` over ``spec``
+    — decided here and nowhere else, from the arguments alone: the same
+    arguments give the same plan whatever ran earlier in the process.
+
+    Resident-archive (each row crosses the wire once) when the function is
+    a built-in monoid the resident executors evaluate, segment-restaging
+    otherwise, the host core when there is no device work at all.
+    ``native`` is what :func:`_native_core_fields` reports: the payload
+    columns the native resident core stages, or None without it.  Raises
+    ``ValueError`` for a request no device path can run."""
+    on_mesh = mesh is not None
+    parts = _stat_parts(winfunc)
+    if _arg_parts(parts):
+        # the row at a window's extremum: one executor family
+        # (ops/resident.py wf_step_argext) under the native resident core,
+        # for every role (SEQ, a Win_MapReduce's MAP and REDUCE alike).
+        # Whatever that cannot run raises -- there is no host route here
+        others = [p for p in parts if not isinstance(p, ArgReducer)]
+        why = None
+        if use_pallas or use_resident is False:
+            why = "it runs on the resident path (no use_pallas, no " \
+                  "use_resident=False)"
+        elif _argext_misplaced(mesh, shards):
+            why = f"it runs {_ARGEXT_PLACEMENT}"
+        elif any(p.op not in _RESIDENT_OPS + ("count",) for p in others) \
+                or any(p.op == "sum" and np.issubdtype(p.dtype, np.floating)
+                       for p in others):
+            why = f"its sibling stats must be count or {_RESIDENT_OPS} " \
+                  "over integers"
+        elif native is None:
+            why = "the native resident core is unavailable or opted out"
+        if why is not None:
+            raise ValueError(f"arg-extremum window function {winfunc!r} "
+                             f"cannot run on the device: {why}")
+        return CorePlan("native", "argext", False)
+    if (isinstance(winfunc, (Reducer, MultiReducer))
+            and use_resident is None and not on_mesh
+            and (isinstance(winfunc, MultiReducer) or not use_pallas)
+            and _host_free(spec, winfunc)):
+        # every stat is answerable from host bookkeeping (count from
+        # window lengths; max over the position field from the
+        # position-ordered archive) — shipping the column to the device
+        # buys nothing but transfer traffic (YSB's count+MAX(ts) lost to
+        # the host path for exactly this reason).  use_resident=True
+        # forces the device; a Reducer with use_pallas=True keeps the
+        # Pallas/restaging path (benchmarking) — MultiReducer has no
+        # Pallas path, so the flag does not block its host routing.
+        return CorePlan("host", None, False)
+    if isinstance(winfunc, MultiReducer):
+        # multi-stat windows are resident-only (the restaging executor has
+        # no multi-output contract); count-only MultiReducers should be a
+        # plain Reducer("count")
+        if use_resident is False or not _multi_resident_ok(winfunc,
+                                                           use_pallas):
+            raise ValueError(
+                "MultiReducer runs on the resident device path only: "
+                "needs >=1 non-count stat, ops in "
+                f"{_RESIDENT_OPS}, no float sum (got {winfunc.parts})")
+        # the C++ core carries the whole hot loop where it can; float
+        # stats and wider functions keep the Python core, by its design.
+        # With a mesh the rings shard P(kf, None) under either
+        dev_parts, pos_parts = split_pos_max(spec, winfunc)
+        core = ("native" if native is not None
+                and _native_refusal(dev_parts, native) is None
+                else "resident_py")
+        return CorePlan(core, _executor_family(core, dev_parts or pos_parts),
+                        on_mesh)
+    jax_fn = isinstance(winfunc, JaxWindowFunction)
+    if jax_fn and (use_resident or on_mesh) and not use_pallas:
+        # arbitrary JAX window fns evaluate over multi-field resident
+        # rings on request (use_resident=True); the default stays the
+        # segment-restaging executor, whose staged columns carry each
+        # launch's exact dtypes (rings are typed at allocation —
+        # JaxWindowFunction.field_dtypes declares them).  The resident
+        # path is the only one with a sharded-archive form, so mesh
+        # implies it
+        return CorePlan("resident_py", "multi", on_mesh)
+    resident = use_resident
+    if resident is None:
+        resident = (not use_pallas and isinstance(winfunc, Reducer)
+                    and winfunc.op in _RESIDENT_OPS
+                    # a float cumsum accumulates rounding error the host
+                    # path's per-window reduction does not; floats keep the
+                    # segment-restaging path unless the user opts in
+                    and not (winfunc.op == "sum"
+                             and np.issubdtype(winfunc.dtype, np.floating)))
+    if on_mesh:
+        if not (isinstance(winfunc, Reducer)
+                and winfunc.op in _RESIDENT_OPS):
+            raise ValueError(
+                "mesh execution needs a resident-path Reducer "
+                f"(one of {_RESIDENT_OPS}); got {winfunc!r}")
+        if not resident:
+            raise ValueError(
+                "mesh execution requires the resident path: drop "
+                "use_pallas, and for float sums opt in explicitly with "
+                "use_resident=True (cumsum rounding differs from the "
+                "host's per-window reduction)")
+    if not resident:
+        return CorePlan("restage", None, False)
+    # the C++ bookkeeping feeds the ring, sharded or not: a real pod's
+    # multi-chip path must not re-pay the Python hot loop the native core
+    # was built to kill; host key-shards compose with it — each shard owns
+    # its own ring
+    core = "native" if native is not None else "resident_py"
+    return CorePlan(core, _executor_family(core, None if jax_fn
+                                           else [winfunc]), on_mesh)
+
+
+def _native_core_fields():
+    """The payload columns the native resident core stages
+    (``wf_max_fields``), or None when the library is unavailable — also
+    None under WF_NO_NATIVE_CORE=1, which pins the Python resident core."""
     import os
     if os.environ.get("WF_NO_NATIVE_CORE", "") == "1":
         return None
     from ..native import enabled
-    return enabled()
+    lib = enabled()
+    return None if lib is None else int(lib.wf_max_fields())
 
 
 def make_device_core(worker, fn, dev_kw, index=0):
@@ -901,207 +1047,34 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                   compute_dtype=None, use_resident=None,
                   flush_rows=1 << 20, shards=1, worker_index=0, mesh=None,
                   max_delay_ms=None):
-    """Choose the device core implementation: resident-archive (preferred —
-    each row crosses the wire once) when the function is a built-in monoid
-    the resident executor evaluates; segment-restaging otherwise.  With
-    ``mesh`` the resident ring is sharded ``P('kf', None)`` across the mesh
-    devices (one dispatch serves every key group over ICI)."""
-    def _host_core():
+    """Build the window core :func:`plan_core` names.  With ``mesh`` the
+    resident ring is sharded ``P('kf', None)`` across the mesh devices (one
+    dispatch serves every key group over ICI); ``max_delay_ms`` is a timer
+    on that core, whichever it is."""
+    plan = plan_core(spec, winfunc, use_pallas=use_pallas,
+                     use_resident=use_resident, mesh=mesh, shards=shards,
+                     native=_native_core_fields())
+    if plan.core == "host":
         from .win_seq import WinSeq
         return WinSeq(winfunc, spec.win_len, spec.slide_len,
                       spec.win_type, config=config, role=role,
                       map_indexes=map_indexes,
                       result_ts_slide=result_ts_slide).make_core()
-
-    if _has_arg_extremum(winfunc):
-        # the row at a window's extremum: one executor family
-        # (ops/resident.py wf_step_argext) under the native resident core,
-        # for every role (SEQ, a Win_MapReduce's MAP and REDUCE alike).
-        # Whatever that cannot run raises -- there is no host route here
-        parts = winfunc.parts if isinstance(winfunc, MultiReducer) \
-            else [winfunc]
-        others = [p for p in parts if not isinstance(p, ArgReducer)]
-        why = None
-        if use_pallas or use_resident is False:
-            why = "it runs on the resident path (no use_pallas, no " \
-                  "use_resident=False)"
-        elif mesh is not None or shards != 1:
-            why = "it runs one shard on one device (no mesh, shards=1)"
-        elif any(p.op not in _RESIDENT_OPS + ("count",) for p in others) \
-                or any(p.op == "sum" and np.issubdtype(p.dtype, np.floating)
-                       for p in others):
-            why = f"its sibling stats must be count or {_RESIDENT_OPS} " \
-                  "over integers"
-        elif _native_core_lib() is None:
-            why = "the native resident core is unavailable or opted out"
-        if why is not None:
-            raise ValueError(f"arg-extremum window function {winfunc!r} "
-                             f"cannot run on the device: {why}")
+    kw = dict(batch_len=batch_len, config=config, role=role,
+              map_indexes=map_indexes, result_ts_slide=result_ts_slide,
+              compute_dtype=compute_dtype)
+    if plan.core == "restage":
+        return DeviceWinSeqCore(
+            spec, winfunc, use_pallas=use_pallas,
+            device=resolve_worker_device(device, worker_index),
+            depth=depth if depth is not None else 4, **kw)
+    kw.update(flush_rows=flush_rows, device=device,
+              depth=depth if depth is not None else 8,
+              worker_index=worker_index, mesh=mesh, max_delay_ms=max_delay_ms)
+    if plan.core == "native":
         from .native_core import NativeResidentCore
-        return NativeResidentCore(
-            spec, winfunc, batch_len=batch_len, flush_rows=flush_rows,
-            config=config, role=role, map_indexes=map_indexes,
-            result_ts_slide=result_ts_slide, device=device,
-            depth=depth if depth is not None else 8,
-            compute_dtype=compute_dtype, worker_index=worker_index,
-            max_delay_ms=max_delay_ms)
-    if (max_delay_ms is not None and use_resident is None
-            and mesh is None and not use_pallas
-            and isinstance(winfunc, (Reducer, MultiReducer))
-            # a MultiReducer invalid on EVERY device path must fall
-            # through to the deterministic ValueError below — routing it
-            # host only when some earlier run seeded the service record
-            # would make raise-vs-success depend on hidden global state
-            and not (isinstance(winfunc, MultiReducer)
-                     and not _multi_resident_ok(winfunc, use_pallas))):
-        # budget-aware routing: every device result pays at least one
-        # launch service (dispatch -> result ready), so a latency budget
-        # under ~2x the MEASURED per-launch service is unmeetable on the
-        # device path by construction, while the host core has no launch
-        # in its path.  The statistic is the recent-best service FLOOR,
-        # not the EMA: a warmup run's compile launches inflate the mean,
-        # and feasibility is about the launch path's best, not its
-        # average.  The record outlives executors (ops/resident.py), so
-        # a warmup teaches the routing what launches cost in this
-        # process; with no observation yet the device keeps the benefit
-        # of the doubt.  ANY explicit path pin — use_resident=True/False,
-        # use_pallas — outranks the heuristic.
-        from ..ops.resident import wire_service_floor_ms
-        floor = wire_service_floor_ms()
-        if floor is not None and max_delay_ms < 2.0 * floor:
-            return _host_core()
-    if (isinstance(winfunc, (Reducer, MultiReducer))
-            and use_resident is None and mesh is None
-            and (isinstance(winfunc, MultiReducer) or not use_pallas)
-            and _host_free(spec, winfunc)):
-        # every stat is answerable from host bookkeeping (count from
-        # window lengths; max over the position field from the
-        # position-ordered archive) — shipping the column to the device
-        # buys nothing but transfer traffic (YSB's count+MAX(ts) lost to
-        # the host path for exactly this reason).
-        # Route to the host core.  use_resident=True forces the device;
-        # a Reducer with use_pallas=True keeps the Pallas/restaging path
-        # (benchmarking) — MultiReducer has no Pallas path, so the flag
-        # does not block its host routing.
-        return _host_core()
-    if isinstance(winfunc, MultiReducer):
-        # multi-stat windows are resident-only (the restaging executor has
-        # no multi-output contract); count-only MultiReducers should be a
-        # plain Reducer("count")
-        if use_resident is False or not _multi_resident_ok(winfunc,
-                                                           use_pallas):
-            raise ValueError(
-                "MultiReducer runs on the resident device path only: "
-                "needs >=1 non-count stat, ops in "
-                f"{_RESIDENT_OPS}, no float sum (got {winfunc.parts})")
-        dev_parts, _pos = split_pos_max(spec, winfunc)
-        _nat = _native_core_lib()
-        if (_nat is not None and dev_parts
-                # dev_parts empty = a fully pos-free aggregate FORCED onto
-                # the device (use_resident=True/mesh past the host route):
-                # only the Python core has the ship-the-position-column
-                # fallback for that shape
-                and (len(dev_parts) == 1
-                     or (len({p.field for p in dev_parts})
-                         <= int(_nat.wf_max_fields())
-                         and not any(np.issubdtype(p.dtype, np.floating)
-                                     for p in dev_parts)))):
-            # the C++ core carries the whole hot loop: counts and
-            # max-over-position are answered host-side (window lengths /
-            # the archive's per-window last row), and the remaining
-            # device-worthy stats stage one narrowed int64 column per
-            # distinct field — up to the C++ kMaxFields=4 — into
-            # per-field device rings (rich multi-field aggregates
-            # previously re-paid the Python hot loop; float stats still
-            # do, by the Python core's design).  With a mesh the rings
-            # shard P(kf, None) (Mesh[MultiField]ResidentExecutor) — the
-            # pod shape keeps the C++ bookkeeping for every aggregate
-            # form
-            from .native_core import NativeResidentCore
-            return NativeResidentCore(
-                spec, winfunc, batch_len=batch_len, flush_rows=flush_rows,
-                config=config, role=role, map_indexes=map_indexes,
-                result_ts_slide=result_ts_slide, device=device,
-                depth=depth if depth is not None else 8,
-                compute_dtype=compute_dtype, shards=shards,
-                worker_index=worker_index, max_delay_ms=max_delay_ms,
-                mesh=mesh)
-        return ResidentWinSeqCore(
-            spec, winfunc, batch_len=batch_len, flush_rows=flush_rows,
-            config=config, role=role, map_indexes=map_indexes,
-            result_ts_slide=result_ts_slide, device=device,
-            depth=depth if depth is not None else 8,
-            compute_dtype=compute_dtype, worker_index=worker_index,
-            mesh=mesh, max_delay_ms=max_delay_ms)
-    if (isinstance(winfunc, JaxWindowFunction)
-            and (use_resident or mesh is not None) and not use_pallas):
-        # arbitrary JAX window fns evaluate over multi-field resident
-        # rings on request (use_resident=True); the default stays the
-        # segment-restaging executor, whose staged columns carry each
-        # launch's exact dtypes (rings are typed at allocation —
-        # JaxWindowFunction.field_dtypes declares them).  With a mesh the
-        # rings shard P(kf, None) (MeshMultiFieldResidentExecutor) — the
-        # resident path is the only one with a sharded-archive form, so
-        # mesh implies it
-        return ResidentWinSeqCore(
-            spec, winfunc, batch_len=batch_len, flush_rows=flush_rows,
-            config=config, role=role, map_indexes=map_indexes,
-            result_ts_slide=result_ts_slide, device=device,
-            depth=depth if depth is not None else 8,
-            compute_dtype=compute_dtype, worker_index=worker_index,
-            mesh=mesh, max_delay_ms=max_delay_ms)
-    resident = use_resident
-    if resident is None:
-        resident = (not use_pallas and isinstance(winfunc, Reducer)
-                    and winfunc.op in _RESIDENT_OPS
-                    # a float cumsum accumulates rounding error the host
-                    # path's per-window reduction does not; floats keep the
-                    # segment-restaging path unless the user opts in
-                    and not (winfunc.op == "sum"
-                             and np.issubdtype(winfunc.dtype, np.floating)))
-    if mesh is not None:
-        if not (isinstance(winfunc, Reducer)
-                and winfunc.op in _RESIDENT_OPS):
-            raise ValueError(
-                "mesh execution needs a resident-path Reducer "
-                f"(one of {_RESIDENT_OPS}); got {winfunc!r}")
-        if not resident:
-            raise ValueError(
-                "mesh execution requires the resident path: drop "
-                "use_pallas, and for float sums opt in explicitly with "
-                "use_resident=True (cumsum rounding differs from the "
-                "host's per-window reduction)")
-        kw = dict(batch_len=batch_len, flush_rows=flush_rows,
-                  config=config, role=role, map_indexes=map_indexes,
-                  result_ts_slide=result_ts_slide,
-                  depth=depth if depth is not None else 8,
-                  compute_dtype=compute_dtype, mesh=mesh,
-                  max_delay_ms=max_delay_ms)
-        if _native_core_lib() is not None:
-            # the C++ bookkeeping feeds the sharded ring: a real pod's
-            # multi-chip path must not re-pay the Python hot loop the
-            # native core was built to kill; host key-shards compose with
-            # it — each shard owns its own sharded ring
-            from .native_core import NativeResidentCore
-            return NativeResidentCore(spec, winfunc, shards=shards, **kw)
-        return ResidentWinSeqCore(spec, winfunc, **kw)
-    if resident:
-        kw = dict(batch_len=batch_len, flush_rows=flush_rows, config=config,
-                  role=role, map_indexes=map_indexes,
-                  result_ts_slide=result_ts_slide, device=device,
-                  depth=depth if depth is not None else 8,
-                  compute_dtype=compute_dtype, worker_index=worker_index,
-                  max_delay_ms=max_delay_ms)
-        if _native_core_lib() is not None:
-            from .native_core import NativeResidentCore
-            return NativeResidentCore(spec, winfunc, shards=shards, **kw)
-        return ResidentWinSeqCore(spec, winfunc, **kw)
-    return DeviceWinSeqCore(
-        spec, winfunc, batch_len=batch_len, config=config, role=role,
-        map_indexes=map_indexes, result_ts_slide=result_ts_slide,
-        device=resolve_worker_device(device, worker_index),
-        depth=depth if depth is not None else 4,
-        use_pallas=use_pallas, compute_dtype=compute_dtype)
+        return NativeResidentCore(spec, winfunc, shards=shards, **kw)
+    return ResidentWinSeqCore(spec, winfunc, **kw)
 
 
 class _DeviceCoreFactory:

@@ -1207,11 +1207,6 @@ class Dataflow:
             self.events.emit("dataflow_start", dataflow=self.name,
                              nodes=len(self.nodes),
                              sample_period=self.sample_period)
-        for node in self.nodes:
-            t = threading.Thread(target=self._run_node, args=(node,),
-                                 name=f"{self.name}/{node.name}", daemon=True)
-            self._threads.append(t)
-            t.start()
         period = self.sample_period
         if period is None and self._controller is not None:
             # control without an explicit cadence: the sampler is the
@@ -1222,7 +1217,10 @@ class Dataflow:
             # federation without an explicit cadence: the shipper rides
             # the sampler, so run it at the ship period
             period = self.federate.period
-        if period is not None and self._sampler is None:
+        # built before any node thread runs: a node that fails on its
+        # first batch must already find the flight recorder
+        sampled = period is not None and self._sampler is None
+        if sampled:
             from ..obs.sampler import Sampler
             self._sampler = Sampler(self, period)
             if self._controller is not None:
@@ -1242,6 +1240,12 @@ class Dataflow:
                     self._blackbox = BlackBox(
                         self.trace_dir, self.name, events=self.events,
                         tracer=self.tracer, shipper=self.federation)
+        for node in self.nodes:
+            t = threading.Thread(target=self._run_node, args=(node,),
+                                 name=f"{self.name}/{node.name}", daemon=True)
+            self._threads.append(t)
+            t.start()
+        if sampled:
             self._sampler.start()
 
     def wait(self, timeout: float = None):
