@@ -13,9 +13,11 @@ counts ``flush_early`` from ``profile.counters()``:
   once after a close;
 * recovery mode and ``max_delay_ms`` keep the launches they made before;
 * a busy ring, and the cost guard under a slow measured service, hold it
-  back.
+  back: the service is the executor's own, measured by a ship thread that
+  waits on its launches (ISSUE 29).
 """
 
+import threading
 import time
 import warnings
 
@@ -93,12 +95,41 @@ def make_core(spec=CB, service_s=0.0, **kw):
     return core
 
 
+def slow_device(core, step_s=0.0):
+    """Make every launch of `core` what it is on the chip: not ready at the
+    poll that follows its dispatch (a CPU step of these sizes is), and
+    fetched `step_s` later.  Only a blocking fetch then harvests it."""
+    for ex in core.executors:
+        def fetch(sel, out, fetch=ex._fetch):
+            time.sleep(step_s)
+            return fetch(sel, out)
+
+        def is_ready(out, ex=ex):       # once it is harvested
+            return not any(e[2] is out for e in list(ex._inflight))
+
+        ex._fetch, ex._is_ready = fetch, is_ready
+    return core
+
+
 def settle(core, timeout=30.0):
-    """Wait until nothing is queued for the ship threads and every ring has
-    served what it was sent: the executor idle between two chunks."""
+    """Wait until the executor is idle between two chunks: every ship thread
+    has worked off the tokens it was sent (one more, with an event, closes
+    the gap between `launch_take` and the dispatch, where nothing is pending
+    and nothing in flight yet), then nothing is queued, nothing is in flight
+    — a ship thread harvests its launches itself — and every ring has
+    served what it was sent.  The synchronous path launches inside
+    `process()` and harvests at its next poll, so there the ring alone says."""
     t_end = time.monotonic() + timeout
+    if core._overlap:
+        evs = [threading.Event() for _ in core._ship_qs]
+        for q, ev in zip(core._ship_qs, evs):
+            q.put(("ship", ev))
+        for ev in evs:
+            assert ev.wait(timeout), "a ship thread never answered"
     while time.monotonic() < t_end:
         if (all(core._lib.wf_launch_pending(h) == 0 for h in core._hs)
+                and not (core._overlap
+                         and any(ex._inflight for ex in core.executors))
                 and all(ex.ring_idle() for ex in core.executors)):
             return
         time.sleep(0.0005)
@@ -282,9 +313,16 @@ def test_a_busy_ring_is_left_alone():
 
 
 def test_cost_guard_keeps_early_flushes_to_their_share():
-    service_s = 0.05
+    """The guard reads the service the executor measures, which a ship
+    thread that waits on its launches keeps honest: a ring whose results
+    take 30 ms gets an early flush per 60 ms, however fast the chunks come."""
+    fetch_s = 0.03
     chunks = cb_chunks(72, seed=17)
-    core = make_core(service_s=service_s)
+    want = oracle(CB, chunks)
+    # the step shapes compiled beforehand: a compile is no launch service
+    assert_same(feed(make_core(), chunks), want)
+    profile.reset()
+    core = slow_device(make_core(service_s=None), fetch_s)
     t0 = []
 
     def pace(i):
@@ -294,9 +332,10 @@ def test_cost_guard_keeps_early_flushes_to_their_share():
 
     got = feed(core, chunks, after_chunk=pace)
     elapsed = time.monotonic() - t0[0]
-    assert_same(got, oracle(CB, chunks))
+    assert_same(got, want)
+    assert core.executor.mean_service_s() >= fetch_s
     n_early = early()
     # every chunk would have flushed (the first test); the guard lets one
     # through per service / share of wall time, and no more
-    assert 0 < n_early <= elapsed * native_core._EARLY_SHARE / service_s + 1
+    assert 0 < n_early <= elapsed * native_core._EARLY_SHARE / fetch_s + 1
     assert n_early < len(chunks) // 2

@@ -134,9 +134,9 @@ def test_service_is_computed_from_the_spans_own_stamps(monkeypatch):
     seen = []
     real = resident.ResidentWindowExecutor._note_service
 
-    def spy(self, dt_ns, ready):
+    def spy(self, dt_ns, ready, how):
         seen.append(dt_ns)
-        return real(self, dt_ns, ready)
+        return real(self, dt_ns, ready, how)
 
     monkeypatch.setattr(resident.ResidentWindowExecutor, "_note_service", spy)
     _drive_core(1, False)

@@ -495,18 +495,20 @@ class ResidentWindowExecutor:
         self._last_out = out
         self._inflight.append((meta, sel, out, sp.end_ns(), tag))
         while len(self._inflight) > self.depth:
-            self._harvest_one()
+            self._harvest_one("depth")
 
     # -------------------------------------------------------------- harvest
 
-    def _note_service(self, dt_ns: int, ready: bool):
+    def _note_service(self, dt_ns: int, ready: bool, how: str):
         """One launch closed: `dt_ns` from the end of its dispatch to the
         end of its harvest, `ready` whether its result was there before
-        the harvest began."""
+        the harvest began, `how` what made the thread harvest it."""
         dt = dt_ns / 1e9
         profile.add("launches")
         if ready:
             profile.add("launches_ready_at_poll")
+        if how == "wait":
+            profile.add("harvest_waited")
         self._svc.append(dt)
         # fold the window mean here, on the harvesting thread: readers on
         # OTHER threads (the early-flush guard runs on the node thread)
@@ -517,24 +519,33 @@ class ResidentWindowExecutor:
         stats_add("svc_n", 1)
 
     def mean_service_s(self) -> float:
-        """Mean dispatch→ready wall time of recent launches (slightly
-        overestimates when results sit ready before the next harvest poll;
-        the poll cadence is the chunk cadence, well under the ~20 ms
-        threshold the adaptive coalescer keys on).  Safe to read from any
-        thread."""
+        """Mean wall time of recent launches from the end of dispatch to
+        the end of harvest.  Under a thread that waits on its oldest launch
+        (harvest_oldest: the native core's ship threads) that is the ring's
+        own service — the device step, the copy to the host and the fetch;
+        where launches are harvested only at a caller's poll (the
+        synchronous path, the Python resident core) it also holds the wait
+        for that poll.  Safe to read from any thread."""
         return self._svc_mean
 
-    def _harvest_one(self, ready: bool = None):
-        meta, sel, out, t_dispatched, tag = self._inflight.popleft()
+    def _harvest_one(self, how: str, ready: bool = None):
+        """Close the oldest launch; `how` names what made the thread do it
+        (the ``harvest`` field of the launch's ``harvest_wait`` record):
+        ``wait`` the thread's own wait on it, ``poke`` a caller's poll that
+        found it ready, ``depth`` more than `depth` in flight, ``drain``."""
+        meta, sel, out, t_dispatched, tag = self._inflight[0]
         if ready is None:
             # read once, before blocking: a launch whose result was
             # already there spent the rest of its service waiting for
-            # this poll, not for the device
+            # this harvest, not for the device
             ready = self._is_ready(out)
         with profile.span("harvest_wait", *tag) as sp:
-            sp.extra = {"ready": ready}
+            sp.extra = {"ready": ready, "harvest": how}
             res = self._fetch(sel, out)
-        self._note_service(sp.end_ns() - t_dispatched, ready)
+        # only now: a fetch that raised leaves its launch in flight, for
+        # the next harvest to try again
+        self._inflight.popleft()
+        self._note_service(sp.end_ns() - t_dispatched, ready, how)
         self._ready.append((meta, res))
 
     @staticmethod
@@ -551,9 +562,17 @@ class ResidentWindowExecutor:
     def poll(self):
         """Harvest completed launches without blocking on the rest."""
         while self._inflight and self._is_ready(self._inflight[0][2]):
-            self._harvest_one(ready=True)
+            self._harvest_one("poke", ready=True)
         ready, self._ready = self._ready, []
         return ready
+
+    def harvest_oldest(self):
+        """Block on the oldest launch in flight (its copy to the host was
+        started at dispatch; the wait releases the interpreter lock), then
+        poll().  For the thread that dispatches only, with a launch in
+        flight."""
+        self._harvest_one("wait")
+        return self.poll()
 
     def unready_count(self) -> int:
         """Dispatches still being serviced by the device (the ship
@@ -586,7 +605,7 @@ class ResidentWindowExecutor:
             for o in (out if isinstance(out, tuple) else (out,)):
                 o.copy_to_host_async()
         while self._inflight:
-            self._harvest_one()
+            self._harvest_one("drain")
         ready, self._ready = self._ready, []
         return ready
 

@@ -77,19 +77,35 @@ class NativeStateSnapshot:
 def _ship_loop(core_ref, ship_q, shard, shard_id):
     """Ship-thread main: one thread per key shard, so the shards'
     device_put / dispatch / harvest overlap (a single thread would
-    serialize all shards' transfers).  Resolves
-    the core weakref per token so the thread never pins the core's
+    serialize all shards' transfers).  With nothing to send and a launch in
+    flight it waits on the oldest one and hands the result over when it
+    lands, instead of sleeping until the next poke; a poke that arrives
+    during the wait is served when the wait returns, the rest of one device
+    step at most.  Resolves the core weakref per token or harvest and holds
+    only the executor through a wait, so the thread never pins the core's
     lifetime (a dead core ends the loop)."""
+    ex = None   # the executor whose oldest launch to wait on, if any
     while True:
-        # nothing to launch: the ship thread's share of an idle chip
-        with profile.span("ship_idle", shard=shard_id):
-            tok = ship_q.get()
-        if tok is None:
+        waited = ex is not None and ship_q.empty()
+        if waited:
+            try:
+                turn = (ex.harvest_oldest(), None)
+            except BaseException as e:  # surfaced on the node thread
+                turn = (None, e)
+        elif ex is not None:
+            tok = ship_q.get()      # a poke came meanwhile: it goes first
+        else:
+            # nothing to launch, nothing in flight: the ship thread's
+            # share of an idle chip
+            with profile.span("ship_idle", shard=shard_id):
+                tok = ship_q.get()
+        if not waited and tok is None:
             return
         core = core_ref()
         if core is None:
             return
-        core._ship_token(tok, shard)
+        ex = (core._ship_waited(shard, *turn) if waited
+              else core._ship_token(tok, shard))
         del core
 
 
@@ -325,10 +341,15 @@ class NativeResidentCore:
             th.start()
 
     def _stop_worker(self):
+        me = threading.current_thread()
         for t, th in enumerate(getattr(self, "_ship_threads", ()) or ()):
             if th is not None and th.is_alive():
                 self._ship_qs[t].put(None)
-                th.join(timeout=10)
+                # (a core's last reference can be the one its own ship
+                # thread holds over a token: that thread reads its None
+                # when it gets back to its loop)
+                if th is not me:
+                    th.join(timeout=10)
         self._ship_threads = []
 
     def __del__(self):
@@ -341,6 +362,8 @@ class NativeResidentCore:
     # ------------------------------------------------------------ ship thread
 
     def _ship_token(self, tok, shard):
+        """Serve one poke or drain on a ship thread.  Returns what the
+        thread waits on next (_wait_target)."""
         kind, ev = tok
         try:
             while self._ship_launch(shard, force=(kind == "drain")):
@@ -351,9 +374,30 @@ class NativeResidentCore:
                 self._out_q.put(item)
         except BaseException as e:  # surfaced on the node thread
             self._ship_exc = e
+            return None
         finally:
             if ev is not None:
                 ev.set()
+        return self._wait_target(shard)
+
+    def _ship_waited(self, shard, harvested, exc):
+        """Hand over what a ship thread's own wait harvested, or the
+        failure it met, as under a token."""
+        if exc is not None:
+            self._ship_exc = exc
+            return None
+        for item in harvested:
+            self._out_q.put(item)
+        return self._wait_target(shard)
+
+    def _wait_target(self, shard):
+        """The shard's executor, if its ship thread should now wait on its
+        oldest launch: one is in flight and nothing is queued to send.
+        (Not after a failure: the next token tries that path again.)"""
+        ex = self.executors[shard]
+        if ex._inflight and not self._lib.wf_launch_pending(self._hs[shard]):
+            return ex
+        return None
 
     def _raise_ship_exc(self, drained):
         """Surface a ship-thread failure; results already drained are
