@@ -4,9 +4,6 @@ this configuration that imports the program."""
 
 from __future__ import annotations
 
-import ctypes
-import os
-
 import numpy as np
 
 from windflow_tpu.api import MultiPipe
@@ -35,30 +32,7 @@ def window_workers(cfg):
     return int(shp["map_degree"]) + int(shp["reduce_degree"])
 
 
-#: glibc's mallopt parameters (malloc.h)
-_MALLOPT = {"trim_threshold": -1, "top_pad": -2, "mmap_threshold": -3}
-
-
-def set_host_allocator(cfg, environ=os.environ):
-    """The deployment's malloc thresholds (``host_allocator`` in the
-    configuration), set for this process as a launch script's ``MALLOC_*_``
-    variables would be: the batches this pipeline hands from thread to thread
-    (6-26 MB, hundreds a second) then come from the heap whatever was freed
-    first, instead of being mapped and unmapped one by one in some runs and
-    not in others.  The program sets no allocator policy of its own.  Left
-    alone, and False returned, where the environment sets a ``MALLOC_*``
-    variable itself or the C library has no ``mallopt``."""
-    if any(k.startswith("MALLOC_") for k in environ):
-        return False
-    mallopt = getattr(ctypes.CDLL(None), "mallopt", None)
-    if mallopt is None:
-        return False
-    return all(mallopt(_MALLOPT[k], int(cfg["host_allocator"][k]))
-               for k in _MALLOPT)
-
-
 def build(cfg, source_fn, sink_fn, trace_dir=None, name="q7_highest_bid"):
-    set_host_allocator(cfg)
     shp = cfg["shapes"]
     bid = int(shp["bid_type"])
     price_range = tuple(int(v) for v in shp["price_range"])

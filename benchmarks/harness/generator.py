@@ -15,7 +15,19 @@ Two loops, chosen by the mix file's ``loop``:
 ``open``    event *i* is due at ``i / rate`` seconds and carries that due time
             as its event time.  A chunk is pushed when its last event is due,
             never earlier, and the schedule does not slow when the system
-            does: lateness (actual push - due) is logged per chunk.
+            does: lateness (actual push - due) is logged per chunk.  A
+            chunk that is late is pushed all the same, after the window's
+            close too (``handed_over`` counts those pushed before it,
+            ``pushed`` all of them); only a generator more than
+            ``GIVE_UP_S`` behind when the stream should have ended stops.
+
+A stream may carry its own event times: where the reference module's
+``columns()`` yields a ``ts`` column, it is each event's offset (microseconds,
+any sign) from its chunk's base -- the generator's clock at the chunk's
+creation in the closed loop, the due time of the chunk's first event in the
+open loop -- and the generator adds the base to it instead of stamping its
+own.  Late and out-of-order events are such a stream's to define; the
+schedule (when a chunk is pushed) is the same either way.
 
 Imports numpy and the standard library only.
 """
@@ -39,6 +51,9 @@ def build_templates(stream, cfg, seed, dtype, chunk):
     gives the columns of events ``start .. start+n-1`` and
     ``stream.period_events(cfg)`` the number of events after which the stream
     repeats (ids apart).  The set holds ``lcm(chunk, period) / chunk`` chunks.
+    Returns ``(templates, id_shift, own_ts)``; ``own_ts`` says that the stream
+    carries its own event times (a ``ts`` column, held in the templates as
+    offsets from the chunk's base).
     """
     period = stream.period_events(cfg)
     set_events = math.lcm(chunk, period)
@@ -46,14 +61,15 @@ def build_templates(stream, cfg, seed, dtype, chunk):
     if n_templates * chunk * dtype.itemsize > 2 << 30:
         raise ValueError(
             f"template set of {n_templates} x {chunk} events needs over 2 GiB")
-    templates = []
+    templates, own_ts = [], False
     for j in range(n_templates):
         cols = stream.columns(cfg, seed, j * chunk, chunk)
+        own_ts = own_ts or "ts" in cols
         t = np.zeros(chunk, dtype=dtype)
         for name, col in cols.items():
             t[name] = col
         templates.append(t)
-    return templates, stream.id_shift(cfg, set_events)
+    return templates, stream.id_shift(cfg, set_events), own_ts
 
 
 def due_offsets_us(chunk, rate):
@@ -76,9 +92,12 @@ class ChunkLog:
     (a stream processor's input does not end when a measurement does), so the
     log holds the window's chunks first and the tail's after them."""
 
-    def __init__(self, chunk, off_us):
+    def __init__(self, chunk, off_us, ts_max_us=None):
         self.chunk = chunk
-        self.off_us = off_us          # per-event offset inside a chunk
+        self.off_us = off_us          # per-event due offset inside a chunk
+        # a stream with its own event times: the latest offset from the
+        # chunk's base in each template chunk (None: the generator stamps)
+        self.ts_max_us = ts_max_us
         self.base_us = []             # event time of each chunk's first event
         self.late_us = []             # open loop: actual push - due
         self.busy_ns = 0              # building + stamping, window only
@@ -87,21 +106,38 @@ class ChunkLog:
         self.t_window_end_ns = None   # the window's last push returned
         self.window_chunks = 0        # chunks that belong to the window
         self.handed_over = 0          # ... and were pushed before it closed
+        self.pushed = 0               # ... and were pushed at all, late or not
 
     @property
     def n_chunks(self):
         return len(self.base_us)
 
+    @property
+    def own_ts(self):
+        """Whether the stream carries its own event times."""
+        return self.ts_max_us is not None
+
     def window_last_event_us(self):
-        """Event time of the window's last event."""
-        if not self.window_chunks:
+        """The latest event time of the window's events."""
+        n = min(self.window_chunks, len(self.base_us))
+        if not n:
             return -1
-        return int(self.base_us[self.window_chunks - 1]) + int(self.off_us[-1])
+        if not self.own_ts:
+            return int(self.base_us[n - 1]) + int(self.off_us[-1])
+        base = np.asarray(self.base_us[:n], dtype=np.int64)
+        return int((base + self.ts_max_us[np.arange(n)
+                                          % len(self.ts_max_us)]).max())
 
     def for_oracle(self):
+        """What the reference needs to recompute every event's time: chunk
+        ``j`` has the base ``base_us[j]``; its ``k``-th event is due at
+        ``base_us[j] + off_us[k]``, which is also its event time unless the
+        stream carries its own (``own_ts``): then that is the base plus the
+        ``ts`` the reference's own ``columns()`` gives the event."""
         return {"chunk": self.chunk,
                 "base_us": np.asarray(self.base_us, dtype=np.int64),
-                "off_us": self.off_us}
+                "off_us": self.off_us,
+                "own_ts": self.own_ts}
 
 
 class Generator:
@@ -109,8 +145,10 @@ class Generator:
 
     ``mix`` is the parsed traffic file; ``chunk`` and ``rate`` come from the
     cell; ``seconds`` is the window and ``tail_seconds`` how long the stream
-    runs on after it.  ``clock_ns`` and ``sleep`` can be replaced in tests.
-    ``annotate`` wraps the push in a profiler annotation in traced runs.
+    runs on after it.  ``own_ts`` says that the templates' ``ts`` column holds
+    the stream's own event times as offsets from the chunk's base.
+    ``clock_ns`` and ``sleep`` can be replaced in tests.  ``annotate`` wraps
+    the push in a profiler annotation in traced runs.
     """
 
     #: an open loop that has fallen this far behind its schedule stops
@@ -120,7 +158,7 @@ class Generator:
 
     def __init__(self, templates, id_shift, mix, chunk, rate, seconds,
                  tail_seconds=None, clock_ns=time.monotonic_ns,
-                 sleep=time.sleep, annotate=None):
+                 sleep=time.sleep, annotate=None, own_ts=False):
         self.templates = templates
         self.id_shift = int(id_shift)
         self.loop = mix["loop"]
@@ -136,7 +174,10 @@ class Generator:
         self.clock_ns = clock_ns
         self.sleep = sleep
         self.annotate = annotate
-        self.log = ChunkLog(chunk, due_offsets_us(chunk, self.rate))
+        self.log = ChunkLog(
+            chunk, due_offsets_us(chunk, self.rate),
+            np.asarray([int(t["ts"].max()) for t in templates],
+                       dtype=np.int64) if own_ts else None)
         self._pool = []
         self.on_start = None          # called with t0_ns as the window opens
         self.on_window_end = None     # called as the window's last push returned
@@ -168,7 +209,9 @@ class Generator:
         cycle = j // n_t
         if cycle and self.id_shift:
             b["id"] += cycle * self.id_shift
-        if self.rate:
+        if self.log.own_ts:
+            np.add(t["ts"], base_us, out=b["ts"])
+        elif self.rate:
             np.add(self.log.off_us, base_us, out=b["ts"])
         else:
             b["ts"] = base_us
@@ -199,7 +242,7 @@ class Generator:
             now = self.clock_ns()
             if now >= end_ns and log.t_window_end_ns is None:
                 log.t_window_end_ns = now
-                log.window_chunks = log.handed_over = j
+                log.window_chunks = log.handed_over = log.pushed = j
                 if self.on_window_end is not None:
                     self.on_window_end()
             if now >= stop_ns:
@@ -241,6 +284,7 @@ class Generator:
             log.late_us.append((now - due_ns) / 1e3)
             self._push(shipper, b)
             if j < n_window:
+                log.pushed += 1
                 log.busy_ns += t_built - t
                 t_pushed = self.clock_ns()
                 log.blocked_ns += t_pushed - now

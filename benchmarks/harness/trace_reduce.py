@@ -12,10 +12,14 @@ Read with ``jax.profiler.ProfileData`` and nothing else.  What is taken:
 * per-executable device time is the sum of the durations on ``XLA Modules``,
   by the executable's name as the trace gives it;
 * idle gaps are the stretches between a chip's busy intervals, the longest
-  first, each attributed to the benchmark's own host annotation (names that
-  start with ``bench.``) that overlaps it most, or to ``unattributed``.
+  first, each named by what the host was doing in it: the program's own
+  phase (a host annotation whose name starts with ``wf.``) that covers most
+  of it where one overlaps it, else the benchmark's own annotation (names
+  that start with ``bench.``) that covers most of it, else ``unattributed``.
+  The generator is inside its push for nearly the whole of a closed loop, so
+  its annotation says nothing where the program says something.
 
-``benchmarks/tests/test_trace_reduce.py`` checks all of it on the small trace
+``benchmarks/tests/test_trace_reduce.py`` checks all of it on the small traces
 recorded beside this file.
 """
 
@@ -28,7 +32,8 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-ANNOTATION_PREFIX = "bench."
+#: host annotations that name an idle gap, the preferred kind first
+ANNOTATION_PREFIXES = ("wf.", "bench.")
 
 
 def find_xplane(trace_dir):
@@ -80,7 +85,7 @@ def reduce_planes(planes, top=10):
             for name, start, end in events:
                 starts.append(start)
                 ends.append(end)
-                if name.startswith(ANNOTATION_PREFIX) \
+                if name.startswith(ANNOTATION_PREFIXES) \
                         and not DEVICE_PLANE.match(pname):
                     annotations.append((name, start, end))
     if not starts:
@@ -108,15 +113,18 @@ def reduce_planes(planes, top=10):
     gaps.sort(key=lambda g: g[0] - g[1])
     idle_gaps = []
     for g_start, g_end in gaps[:top]:
-        best, best_ov = "unattributed", 0.0
         by_name = {}
         for name, a_start, a_end in annotations:
             ov = _overlap(g_start, g_end, a_start, a_end)
             if ov > 0:
                 by_name[name] = by_name.get(name, 0.0) + ov
-        for name, ov in by_name.items():
-            if ov > best_ov:
-                best, best_ov = name, ov
+        best = "unattributed"
+        for prefix in ANNOTATION_PREFIXES:
+            of_kind = {n: ov for n, ov in by_name.items()
+                       if n.startswith(prefix)}
+            if of_kind:
+                best = max(of_kind, key=of_kind.get)
+                break
         idle_gaps.append([best, (g_end - g_start) / 1e9])
     n_dev = len(devices)
     return {

@@ -13,9 +13,11 @@ holds no cell's, configuration's or metric's name.
 One process per run: load, warm up, measure ``--seconds``, drain, check,
 print.  The last line of standard output is one JSON object with the keys
 ``correct, attempted, failed, metrics, device`` (and ``breakdown`` in a traced
-run on a chip).  Without a TPU the run exits non-zero, unless
-``JAX_PLATFORMS=cpu`` is set on purpose: then it rehearses at a tiny size,
-says ``cpu`` in ``device`` and reports no trace-derived metric.
+run on a chip), then ``check``: each number compared beside its limit, which
+are also the last lines of standard error.  Without a TPU the run exits
+non-zero, unless ``JAX_PLATFORMS=cpu`` is set on purpose: then it rehearses
+at a tiny size, says ``cpu`` in ``device`` and reports no trace-derived
+metric.
 """
 
 from __future__ import annotations
@@ -178,7 +180,8 @@ def measure(args, say):
 
     import jax
     import numpy as np
-    from harness import check, device_assert, generator, peaks, trace_reduce
+    from harness import (check, device_assert, generator, host_allocator,
+                         peaks, trace_reduce)
     from harness.compile_counter import CompileCounter
 
     devs = jax.devices()
@@ -217,7 +220,7 @@ def measure(args, say):
     config = importlib.import_module(f"configs.{cell['config']}")
     oracle = importlib.import_module(f"configs.{cell['config']}_oracle")
     chunk, rate = int(cell["chunk"]), args.rate or cell.get("rate")
-    templates, id_shift = generator.build_templates(
+    templates, id_shift, own_ts = generator.build_templates(
         oracle, cfg, args.seed, config.record_dtype(cfg), chunk)
     mark("templates")
 
@@ -226,17 +229,26 @@ def measure(args, say):
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir, exist_ok=True)
 
+    say(f"host allocator: {host_allocator.apply(cfg)}")
     # -- warm-up pass: the cell's own pipeline under its own traffic for the
     #    cell's warm-up seconds, then the coalescing ladder on the devices its
     #    executors own
     warm_gen = generator.Generator(templates, id_shift, mix, chunk, rate,
                                    float(cell["warmup"]["seconds"]),
-                                   tail_seconds=0.0)
+                                   tail_seconds=0.0, own_ts=own_ts)
     warm_pipe = config.build(cfg, warm_gen, SinkRecorder(), name="warmup")
     warm_pipe.run_and_wait_end()
     n_workers = config.window_workers(cfg)
-    warm_devices, _ = device_assert.assert_device_path(
-        window_cores(warm_pipe._df), cfg["expected_core"], n_workers, platform)
+
+    def device_path(pipe):
+        return device_assert.assert_device_path(
+            window_cores(pipe._df), cfg["expected_core"], n_workers, platform)
+
+    try:
+        warm_devices, _ = device_path(warm_pipe)
+    except device_assert.DevicePathError as e:
+        say(f"device path (warm-up pass): {e}")
+        return EXIT_DEVICE_PATH, None, None
     mark("warm-up pass")
     n_ladder = resident.prewarm_regular_ladder(devices=sorted(
         warm_devices, key=lambda d: d.id))
@@ -246,7 +258,7 @@ def measure(args, say):
 
     # -- the measured pipeline
     gen = generator.Generator(templates, id_shift, mix, chunk, rate,
-                              args.seconds, annotate=annotate)
+                              args.seconds, annotate=annotate, own_ts=own_ts)
     sink = SinkRecorder(annotate=annotate)
     node_dir = os.path.join(out_dir, "nodes") if traced else None
     pipe = config.build(cfg, gen, sink, trace_dir=node_dir)
@@ -283,16 +295,16 @@ def measure(args, say):
     # -- the clock has stopped: device path, metrics, correctness
     log = gen.log
     try:
-        _devices, dispatches = device_assert.assert_device_path(
-            window_cores(pipe._df), cfg["expected_core"], n_workers, platform)
+        _devices, dispatches = device_path(pipe)
     except device_assert.DevicePathError as e:
         say(f"device path: {e}")
         return EXIT_DEVICE_PATH, None, None
     if res_stats.get("dispatches", 0) <= 0:
         say("device path: no resident dispatch recorded in the window")
         return EXIT_DEVICE_PATH, None, None
-    say(f"window cores {n_workers} x {cfg['expected_core']}, {dispatches} "
-        f"dispatches on {sorted(str(d) for d in _devices)}")
+    say(f"window cores "
+        f"{device_assert.describe(cfg['expected_core'], n_workers)}, "
+        f"{dispatches} dispatches on {sorted(str(d) for d in _devices)}")
 
     t0 = log.t0_ns
     rows = (np.concatenate(sink.rows) if sink.rows
@@ -305,8 +317,8 @@ def measure(args, say):
     t_chk = time.perf_counter()
     want = oracle.expected(cfg, args.seed, log.for_oracle())
     numbers, (rows_g, rows_w, missing_w) = check.compare(got, want)
-    correct, lines = check.verdict(numbers)
-    for line in lines:
+    correct, check_lines = check.verdict(numbers)
+    for line in check_lines:
         say(line)
     say(f"checked {len(want['key'])} reference results against "
         f"{len(got['key'])} delivered in {time.perf_counter() - t_chk:.2f} s")
@@ -323,7 +335,10 @@ def measure(args, say):
     t_last_due_ns = int(arrival_due.max() * 1e3) if len(arrival_due) \
         else gen_span_ns
     attempted = log.window_chunks * chunk
-    failed = (log.window_chunks - log.handed_over) * chunk \
+    # an event handed over late is late, not failed: its wait is in the
+    # latency, which counts from its due time.  Failed are the events the
+    # generator gave up on and those whose results never arrived.
+    failed = (log.window_chunks - log.pushed) * chunk \
         + oracle.events_of_missing(cfg, int(np.count_nonzero(missing_w)))
 
     trace = None
@@ -364,6 +379,10 @@ def measure(args, say):
         f"results at {t_last_due_ns / 1e9:.3f} s; then {log.n_chunks - log.window_chunks} "
         f"tail chunks, {len(rows)} results in all, graph joined at "
         f"{(t_done_ns - t0) / 1e9:.3f} s")
+    if log.pushed != log.handed_over or log.pushed != log.window_chunks:
+        say(f"generator: {log.pushed - log.handed_over} of the window's "
+            f"{log.window_chunks} chunks handed over after its close, "
+            f"{log.window_chunks - log.pushed} never (given up)")
     say(f"generator: busy {obs['gen']['busy_s']:.3f} s, in push "
         f"{obs['gen']['blocked_s']:.3f} s"
         + (f", lateness p50/p95/max {np.percentile(log.late_us, 50) / 1e3:.3f}"
@@ -420,8 +439,11 @@ def measure(args, say):
         for name, (sec, n) in sorted(trace["executables"].items(),
                                      key=lambda kv: -kv[1][0])[:12]:
             say(f"executable {name}: {sec:.6f} s in {n} launches")
+    # every number compared beside its limit, last in the line
+    result["check"] = check.beside_limits(numbers)
     detail = {"cfg": cfg, "oracle": oracle, "seed": args.seed, "got": got,
-              "want": want, "log": log.for_oracle(), "numbers": numbers}
+              "want": want, "log": log.for_oracle(), "numbers": numbers,
+              "check_lines": check_lines}
     return 0, result, detail
 
 
@@ -429,9 +451,11 @@ def main(argv=None):
     def say(text):
         print(text, flush=True)
 
-    rc, result, _detail = measure(parse_args(argv), say)
+    rc, result, detail = measure(parse_args(argv), say)
     if result is not None:
         print(json.dumps(result), flush=True)
+        # ... and as the last lines of standard error
+        print("\n".join(detail["check_lines"]), file=sys.stderr, flush=True)
     return rc
 
 

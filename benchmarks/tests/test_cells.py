@@ -32,16 +32,16 @@ def _run(cell, trace, seed=2**31 + 5, seconds=2):
          str(trace)], cwd=ROOT, env=env, capture_output=True, text=True,
         timeout=300)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    return proc.stdout.strip().splitlines()
+    return proc.stdout.strip().splitlines(), proc.stderr.strip().splitlines()
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("cell", _cells())
 def test_cell_runs_and_its_last_line_meets_the_contract(cell, trace):
-    lines = _run(cell, trace)
+    lines, errors = _run(cell, trace)
     result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}        # no breakdown without a chip
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "check"]  # no breakdown without a chip
     assert result["correct"] is True
     assert result["attempted"] > 0 and result["failed"] == 0
     dev = result["device"]
@@ -62,6 +62,10 @@ def test_cell_runs_and_its_last_line_meets_the_contract(cell, trace):
     # every number compared stands beside its limit
     checks = [ln for ln in lines if ln.startswith("check ")]
     assert len(checks) >= 5 and all("(limit 0)" in ln for ln in checks)
+    # ... in the result's line too, last there, and last on standard error
+    assert len(result["check"]) == len(checks)
+    assert all(v == {"value": 0, "limit": 0} for v in result["check"].values())
+    assert errors[-len(checks):] == checks
 
 
 def test_no_accelerator_and_no_rehearsal_exits_non_zero():

@@ -76,7 +76,25 @@ def test_open_loop_counts_what_the_window_did_not_hand_over():
     assert gen.log.window_chunks == 19
     assert 12 <= gen.log.handed_over <= 14            # 200 ms / 15 ms a push
     assert gen.log.n_chunks == 19                     # the rest came late
+    assert gen.log.pushed == 19                       # late, and not failed
     assert np.asarray(gen.log.late_us)[-1] > 80_000
+
+
+def test_open_loop_gives_up_far_behind_and_says_what_it_never_pushed():
+    chunk, rate = 100, 10_000
+    clock = FakeClock()
+    ship = Shipper(clock, cost_ns=0, stall={3: 11_000_000_000})
+    gen = generator.Generator(_templates(chunk), 2 * chunk, {"loop": "open"},
+                              chunk, rate, seconds=0.2, tail_seconds=0.0,
+                              clock_ns=clock.clock_ns, sleep=clock.sleep)
+    ends = []
+    gen.on_window_end = lambda: ends.append(clock.now)
+    gen(ship)
+    log = gen.log
+    assert log.window_chunks == 19
+    assert log.pushed == log.handed_over == log.n_chunks == 3
+    assert len(ends) == 1
+    assert log.window_last_event_us() == 2 * 10_000 + 9_900
 
 
 def test_closed_loop_window_then_tail():
@@ -92,7 +110,7 @@ def test_closed_loop_window_then_tail():
     gen(ship)
     log = gen.log
     assert len(ends) == 1
-    assert log.window_chunks == log.handed_over == 25
+    assert log.window_chunks == log.handed_over == log.pushed == 25
     assert 29 <= log.n_chunks <= 31
     assert log.t_window_end_ns - log.t0_ns >= 100_000_000
     for j, (t_push, b) in enumerate(ship.pushed):

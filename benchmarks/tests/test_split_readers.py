@@ -12,7 +12,8 @@ from conftest import ROOT, load
 NEW = ("launch_host_ms", "launch_device_ms", "launch_ready_at_poll_pct",
        "launch_service_max_ms", "ship_padding_pct", "node_self_max_pct",
        "node_blocked_max_pct", "source_self_pct", "idle_ship_starved_pct")
-MIXES = {"sat": ("throughput_eps", ["pipe_cb.sat", "ysb_kf.sat"]),
+MIXES = {"sat": ("throughput_eps", ["pipe_cb.sat", "ysb_kf.sat",
+                                    "sum_cb.sat"]),
          "paced": ("latency_p50_ms", ["pipe_cb.paced"])}
 
 
@@ -38,11 +39,14 @@ def test_new_entry_has_its_file_reader_unit_and_moves(metric, mix):
     assert hasattr(reader(spec["reader"]), "read") and spec["what"]
 
 
-def test_new_entries_stand_at_the_end_in_one_block():
+def test_new_entries_stand_in_one_unbroken_block_in_their_order():
+    """Wherever the block stands: later PRs append their own entries after
+    it, and none may come between."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         names = [x["name"] for x in json.load(f)["per_layer"]]
     new = [f"{m}.{mix}" for mix in ("sat", "paced") for m in NEW]
-    assert names[-len(new):] == new
+    at = names.index(new[0])
+    assert names[at:at + len(new)] == new
 
 
 # -- observations made by hand ------------------------------------------------

@@ -97,3 +97,76 @@ def test_unknown_device_kind_is_an_error():
     assert peaks.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         peaks.peaks_for("TPU v9 imaginary")
+
+
+# -- who an idle gap is named after -------------------------------------------
+
+def _planes(host_events):
+    """One chip busy 0-10, 110-120, 200-210, 300-310 and 400-401 (ns), so the
+    gaps are 10-110, 120-200, 210-300 and 310-400; the host as given."""
+    busy = [(0, 10), (110, 120), (200, 210), (300, 310), (400, 401)]
+    return {"/device:TPU:0": {"XLA Ops": [("%op = x", float(s), float(e))
+                                          for s, e in busy]},
+            "/host:CPU": {"python": [(n, float(s), float(e))
+                                     for n, s, e in host_events]}}
+
+
+def test_a_gap_is_named_by_the_programs_phase_where_one_overlaps_it():
+    got = trace_reduce.reduce_planes(_planes([
+        # the generator is inside its push all along, as in a closed loop
+        ("bench.gen_blocked_in_push", 0, 400),
+        # gap 10-110: two phases of the program, the second covers more
+        ("wf.launch_take", 10, 30), ("wf.device_put", 30, 100),
+        # gap 120-200: one phase covers 5 ns of it, the push all 80: the
+        # program's phase names it all the same
+        ("wf.harvest_wait", 150, 155),
+        # gap 210-300: two events of one phase add up against a longer one
+        ("wf.ship_idle", 210, 240), ("wf.ship_idle", 260, 290),
+        ("wf.native_bookkeeping", 240, 260), ("wf.dispatch", 292, 299),
+        # gap 310-400: nothing of the program: the benchmark's own
+        ("bench.sink_consume", 330, 340),
+        ("PjitFunction(step)", 310, 400)]))["idle_gaps"]
+    assert got[:4] == [["wf.device_put", 100e-9], ["wf.ship_idle", 90e-9],
+                       ["bench.gen_blocked_in_push", 90e-9],
+                       ["wf.harvest_wait", 80e-9]]
+
+
+def test_a_gap_nothing_overlaps_belongs_to_nobody():
+    got = trace_reduce.reduce_planes(_planes(
+        [("wf.dispatch", 0, 5), ("bench.sink_consume", 401, 500)]))
+    # the trailing stretch, after the last operation, is the sink's; the
+    # four gaps between operations are nobody's
+    assert sorted(got["idle_gaps"]) == sorted(
+        [["bench.sink_consume", 99e-9], ["unattributed", 100e-9],
+         ["unattributed", 80e-9], ["unattributed", 90e-9],
+         ["unattributed", 90e-9]])
+
+
+def test_the_recorded_ship_phases_name_the_gaps_of_their_trace():
+    """On the trace that holds the program's own ``wf.`` annotations
+    (``record_wf_trace.py``), against a plain loop over its events."""
+    path = os.path.join(BENCH, "harness", "testdata", "wf_trace.xplane.pb")
+    planes = trace_reduce.load(path)
+    gaps = trace_reduce.reduce_planes(planes)["idle_gaps"]
+    assert all(name.startswith("wf.") for name, _ in gaps)
+    ops = sorted((s, e) for _, s, e in planes["/device:TPU:0"]["XLA Ops"])
+    host = [ev for pname, lines in planes.items() if "device" not in pname
+            for events in lines.values() for ev in events
+            if ev[0].startswith("wf.")]
+    # the longest gap, found again the slow way; the stretch before the
+    # first operation and the one after the last count as gaps
+    every = [ev for lines in planes.values() for events in lines.values()
+             for ev in events]
+    t_lo, t_hi = min(s for _, s, _ in every), max(e for _, _, e in every)
+    edge, longest = t_lo, (0.0, 0.0)
+    for s, e in ops + [(t_hi, t_hi)]:
+        if s - edge > longest[1] - longest[0]:
+            longest = (edge, s)
+        edge = max(edge, e)
+    assert round((longest[1] - longest[0])) == round(gaps[0][1] * 1e9)
+    cover = {}
+    for name, s, e in host:
+        ov = min(e, longest[1]) - max(s, longest[0])
+        if ov > 0:
+            cover[name] = cover.get(name, 0.0) + ov
+    assert gaps[0][0] == max(cover, key=cover.get) == "wf.ship_idle"
