@@ -176,3 +176,29 @@ def highest_bid_windows(rows, win_len, value="price", ts="ts", rid="id",
                 + tuple(int(c["best"][f]) for f in carry)
                 + (int(c["best"][ts]), c["count"], c["last"]))
             for w, c in out.items()}
+
+
+def hot_items_windows(rows, win_len, slide_len, key="auction", ts="ts"):
+    """Plain reference of NEXMark Q5 "hot items" over a stream of bids
+    (dicts or structured rows): per sliding event-time window ``w`` =
+    ``[w*slide_len, w*slide_len + win_len)`` the ``key`` with the most bids,
+    ties to the lowest key, as ``{w: (key, its bids, all bids of the window,
+    the window's last bid's ts)}``.  A (key, window) pair without a bid
+    counts nowhere and a window without a bid gives no entry.  A loop over
+    bids into a dictionary ``(window, key) -> count``, the twin of
+    ``benchmarks/configs/q5_hot_items_oracle.py``."""
+    counts, total, last = {}, {}, {}
+    for r in rows:
+        t, k = int(r[ts]), int(r[key])
+        w = max((t - win_len) // slide_len + 1, 0)
+        while w * slide_len <= t:
+            counts[(w, k)] = counts.get((w, k), 0) + 1
+            total[w] = total.get(w, 0) + 1
+            last[w] = max(last.get(w, -1), t)
+            w += 1
+    best = {}
+    for (w, k), n in counts.items():
+        cur = best.get(w)
+        if cur is None or n > cur[1] or (n == cur[1] and k < cur[0]):
+            best[w] = (k, n)
+    return {w: best[w] + (total[w], last[w]) for w in best}

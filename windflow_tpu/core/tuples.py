@@ -65,6 +65,18 @@ def batch_from_columns(schema: Schema, key, id, ts, **payload) -> np.ndarray:
     return out
 
 
+def progress_row(dtype, wid: int, ts: int) -> np.ndarray:
+    """The row a stream-time stage sends after a fire: a marker (folded
+    nowhere) that promises every later row is at ``ts`` or past it, so a
+    window stage downstream closes on it what its own rows would close only
+    one slide later.  Key 0, the id of the last window fired."""
+    row = np.zeros(1, dtype=dtype)
+    row["id"] = wid
+    row["ts"] = ts
+    row[MARKER_FIELD] = True
+    return row
+
+
 def concat(batches) -> np.ndarray:
     batches = [b for b in batches if b is not None and len(b)]
     if not batches:

@@ -36,10 +36,12 @@ import threading
 import numpy as np
 
 from .slots import segments as _segments
-from .tuples import MARKER_FIELD, Schema
-from .windows import PatternConfig, Role, WindowSpec, WinType
+from .tuples import MARKER_FIELD, Schema, progress_row
+from .windows import (PatternConfig, Role, WindowSpec, WinType,
+                      check_stream_fire, run_stream_clock)
 from ..ops.functions import MultiReducer, Reducer
 from ..ops.monoid import NP_UFUNCS, identity as monoid_identity
+from ..utils import profile
 
 _NEG_INF = np.int64(-(2 ** 62))
 
@@ -64,15 +66,18 @@ def vec_core_supported(spec: WindowSpec, winfunc) -> bool:
             and -(-spec.win_len // spec.slide_len) <= 64)
 
 
-def make_vec_core(spec: WindowSpec, winfunc, **kw):
+def make_vec_core(spec: WindowSpec, winfunc, fire_on: str = "key", **kw):
     """The vectorised core for `spec` (vec_core_supported must hold):
     tumbling always vectorises; sliding defers to the first chunk's key
-    cardinality (LazySlidingCore)."""
+    cardinality (LazySlidingCore).  ``fire_on="stream"`` (time-based
+    windows that close on the stage's time, quiet keys retired) is one
+    core for both, :class:`VecStreamCore`: its key space is the one that
+    does not stay small."""
+    if fire_on == "stream":
+        return VecStreamCore(spec, winfunc, **kw)
     if spec.is_tumbling:
         return VecIncTumblingCore(spec, winfunc, **kw)
     return LazySlidingCore(spec, winfunc, **kw)
-
-
 
 
 class VecIncTumblingCore:
@@ -675,6 +680,229 @@ class VecIncSlidingCore(VecIncTumblingCore):
         self._nfired[slots] = self._ncreated[slots]
         self._seen[:self._n] = False
         return out
+
+
+class VecStreamCore:
+    """Time-based tumbling or sliding windows over a monoid reducer that
+    close on the STAGE's time — the highest ``ts`` taken in on any key —
+    and forget a key once its last open window has fired.
+
+    The per-key cores above fire a key's window when that key's next row
+    arrives (win_seq.hpp's triggerer); on a stream whose keys go quiet that
+    row never comes.  Here window ``w`` is ``[w*S, w*S + L)`` for every key
+    (``check_stream_fire``), so the stage keeps ONE count of fired windows:
+    a row at or past the end of window ``w`` fires ``w`` for every key that
+    holds rows in it, before the row itself is folded.  Defined for an
+    in-order stream; a row that arrives for a window already fired is not
+    folded into it (late, as the per-key cores drop a key's late rows).  An
+    (key, window) pair without a row gives no result.
+
+    State: ``W = ceil(L/S)`` accumulator lanes a live key (lane ``w % W``;
+    the open windows are ``[fired, fired + W)``, so lanes never collide) and
+    the rows each lane holds.  Off a window boundary a chunk costs its fold;
+    at one, O(live keys): the fire, then the retiring of every key whose
+    lanes are all empty — its slot is compacted away (``SlotMap.retain``),
+    and a key seen again starts as a new key.  So the state is bounded by the
+    keys of the open windows.  After a fire the core sends a
+    :func:`progress_row`.  Marker rows in the input move the clock and fold
+    nothing.
+    """
+
+    fire_on = "stream"
+
+    def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
+                 role: Role = Role.SEQ, map_indexes=(0, 1),
+                 result_ts_slide: int = None):
+        assert vec_core_supported(spec, winfunc)
+        check_stream_fire(spec, config, role)
+        self.spec = spec
+        self.winfunc = winfunc
+        self.is_nic = False
+        self.result_schema = Schema(**winfunc.result_fields)
+        self._result_dtype = self.result_schema.dtype()
+        self._L = int(spec.win_len)
+        self._S = int(spec.slide_len)
+        self._W = -(-self._L // self._S)
+        self._ts_slide = int(result_ts_slide if result_ts_slide is not None
+                             else spec.slide_len)
+        parts = winfunc.parts if isinstance(winfunc, MultiReducer) else [winfunc]
+        self._parts = [(p.out_field, p.field, None if p.op == "count"
+                        else NP_UFUNCS[p.op], p.dtype,
+                        p.dtype.type(monoid_identity(p.op, p.dtype)))
+                       for p in parts]
+        from .slots import SlotMap
+        self._slotmap = SlotMap(on_register=self._grow_for)
+        self._cap = 0
+        self._rows = np.zeros((0, self._W), dtype=np.int64)   # rows a lane
+        self._acc = {of: np.zeros((0, self._W), dtype=dt)
+                     for of, _f, _u, dt, _i in self._parts}
+        #: the windows the stage's clock has fired, and the end of the next
+        self._fired = 0
+        self._next_end = self._L
+        #: what the node reports (docs/OBSERVABILITY.md)
+        self.keys_live_peak = 0
+        self.keys_retired = 0
+        self.stream_fires = 0
+        self.stream_fire_rows = 0
+
+    @property
+    def keys_live(self) -> int:
+        return self._slotmap.n
+
+    def use_incremental(self):
+        return self  # inherently incremental
+
+    # ------------------------------------------------------------- key slots
+
+    def _grow_for(self, new_keys: np.ndarray):
+        """SlotMap registration hook: room for the new slots (their lanes
+        are empty: a retired slot's were reset when it went)."""
+        need = self._slotmap.n
+        if need > self._cap:
+            cap = max(self._cap * 2, need, 1024)
+            rows = np.zeros((cap, self._W), dtype=np.int64)
+            rows[:self._cap] = self._rows
+            self._rows = rows
+            for (of, _f, _u, dt, ident) in self._parts:
+                b = np.full((cap, self._W), ident, dtype=dt)
+                b[:self._cap] = self._acc[of]
+                self._acc[of] = b
+            self._cap = cap
+        self.keys_live_peak = max(self.keys_live_peak, need)
+
+    # ------------------------------------------------------------- processing
+
+    def process(self, batch: np.ndarray) -> np.ndarray:
+        outs = run_stream_clock(self, batch, self._fold) if len(batch) else ()
+        if not outs:
+            return np.zeros(0, dtype=self._result_dtype)
+        return outs[0] if len(outs) == 1 else np.concatenate(outs)
+
+    def _fold(self, rows: np.ndarray, ts: np.ndarray):
+        """Fold rows into the lanes of the open windows they lie in:
+        one sort by key groups the rows, one ``reduceat`` a statistic folds
+        each group, and a group's partial goes to the lane of every window
+        its rows lie in."""
+        L, S, W = self._L, self._S, self._W
+        hi = ts // S
+        # a window under `fired` has gone: the row is late for it
+        lo = np.maximum((ts - L) // S + 1, self._fired)
+        if (hi < lo).any():                 # late for every window of theirs
+            live = np.flatnonzero(hi >= lo)
+            rows, hi, lo = rows[live], hi[live], lo[live]
+        if not len(rows):
+            return
+        keys = np.ascontiguousarray(rows["key"], dtype=np.int64)
+        # the windows a row lies in, as one small number: between two
+        # boundaries the rows take few such ranges (one, where the window
+        # is a whole number of slides), so mostly the keys alone are sorted
+        span = (lo - self._fired) * W + (hi - self._fired)
+        one_span = span[0] == span[-1] and not (span != span[0]).any()
+        order = (np.argsort(keys, kind="stable") if one_span
+                 else np.lexsort((span, keys)))
+        keys, span = keys[order], span[order]
+        cut = np.diff(keys) != 0
+        if not one_span:
+            cut |= np.diff(span) != 0
+        bnd = np.concatenate(([0], np.flatnonzero(cut) + 1))
+        seg_slot = self._slotmap.lookup(keys[bnd])
+        seg_span = span[bnd]
+        seg_rows = np.diff(np.concatenate((bnd, [len(keys)])))
+        seg_vals = [None if ufunc is None else ufunc.reduceat(
+            rows[field].astype(dt, copy=False)[order], bnd)
+            for (_of, field, ufunc, dt, _ident) in self._parts]
+        for code in ([int(span[0])] if one_span
+                     else np.unique(seg_span).tolist()):
+            # the groups of one range hold each key once: a plain indexed
+            # update cannot meet a slot twice
+            sel = slice(None) if one_span else np.flatnonzero(
+                seg_span == code)
+            slots = seg_slot[sel]
+            for w in range(self._fired + code // W,
+                           self._fired + code % W + 1):
+                lane = w % W
+                self._rows[slots, lane] += seg_rows[sel]
+                for (of, _f, ufunc, _dt, _i), vals in zip(self._parts,
+                                                          seg_vals):
+                    if ufunc is not None:   # counts are the lanes' rows
+                        acc = self._acc[of]
+                        acc[slots, lane] = ufunc(acc[slots, lane], vals[sel])
+
+    def _take_window(self, w: int):
+        """Result rows of window ``w`` for the keys that hold rows in it
+        (their lanes emptied), or None."""
+        lane = w % self._W
+        n = self._slotmap.n
+        live = np.flatnonzero(self._rows[:n, lane])
+        if not len(live):
+            return None
+        out = np.zeros(len(live), dtype=self._result_dtype)
+        out["key"] = self._slotmap.keys[live]
+        out["id"] = w
+        out["ts"] = w * self._ts_slide + self._L - 1
+        for (of, _f, ufunc, dt, ident) in self._parts:
+            if ufunc is None:
+                out[of] = self._rows[live, lane].astype(dt)
+            else:
+                out[of] = self._acc[of][live, lane]
+                self._acc[of][live, lane] = ident
+        self._rows[live, lane] = 0
+        return out
+
+    def _fire(self, now: int) -> list:
+        """Fire every window that ends at or before ``now``, oldest first,
+        send the progress row, retire the keys left without rows."""
+        with profile.span("stream_fire"):
+            upto = (now - self._L) // self._S + 1
+            outs = []
+            # only the open windows can hold rows
+            for w in range(self._fired, min(upto, self._fired + self._W)):
+                out = self._take_window(w)
+                if out is not None:
+                    outs.append(out)
+            self._fired = upto
+            self._next_end = upto * self._S + self._L
+            n_rows = sum(len(o) for o in outs)
+            self.stream_fires += 1
+            self.stream_fire_rows += n_rows
+            profile.add("stream_fires")
+            profile.add("stream_fire_rows", n_rows)
+            outs.append(progress_row(self._result_dtype, upto - 1,
+                                     self._next_end - self._S))
+        self._retire()
+        return outs
+
+    def _retire(self):
+        """Forget the keys whose lanes are all empty: compact the slots."""
+        n = self._slotmap.n
+        with profile.span("key_retire"):
+            keep = np.flatnonzero(self._rows[:n].any(axis=1))
+            if len(keep) == n:
+                return
+            m = len(keep)
+            self._rows[:m] = self._rows[keep]
+            self._rows[m:n] = 0
+            for (of, _f, ufunc, _dt, ident) in self._parts:
+                if ufunc is not None:
+                    self._acc[of][:m] = self._acc[of][keep]
+                    self._acc[of][m:n] = ident
+            self._slotmap.retain(keep)
+            self.keys_retired += n - m
+            profile.add("keys_retired", n - m)
+
+    # -------------------------------------------------------------------- EOS
+
+    def flush(self) -> np.ndarray:
+        """EOS: every open window that holds rows fires, oldest first."""
+        outs = [o for o in (self._take_window(w) for w in
+                            range(self._fired, self._fired + self._W))
+                if o is not None]
+        self._fired += self._W
+        self._next_end = self._fired * self._S + self._L
+        self._retire()
+        if not outs:
+            return np.zeros(0, dtype=self._result_dtype)
+        return outs[0] if len(outs) == 1 else np.concatenate(outs)
 
 
 #: derived crossover cache, keyed by window shape — measured on THIS host

@@ -29,6 +29,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .tuples import MARKER_FIELD
+
 
 class WinType(enum.Enum):
     CB = "count"  # count-based: windows defined over tuple ids
@@ -186,3 +188,46 @@ class WindowSpec:
     def win_end(self, lwid):
         """Exclusive end position of window `lwid`."""
         return np.asarray(lwid, dtype=np.int64) * self.slide_len + self.win_len
+
+
+def check_stream_fire(spec: WindowSpec, config: PatternConfig, role: Role):
+    """``fire_on="stream"`` is defined for time-based windows of a plain
+    sequential worker (a Win_Seq, a Key_Farm's workers): every key's window
+    ``w`` is ``[w*slide, w*slide + win)``, so one clock closes it for all.
+    Raises ``ValueError`` otherwise."""
+    if spec.win_type is not WinType.TB:
+        raise ValueError("fire_on='stream' needs time-based windows: a "
+                         "count-based window has no time to close on")
+    if role is not Role.SEQ or (config is not None and (
+            config.n_outer != 1 or config.n_inner != 1)):
+        raise ValueError("fire_on='stream' needs a plain sequential window "
+                         "worker (Win_Seq, Key_Farm), not a nested or "
+                         f"staged one (role {role}, config {config})")
+
+
+def run_stream_clock(core, batch: np.ndarray, fold) -> list:
+    """One chunk through a stream-time core (``fire_on="stream"``): the
+    stage's clock runs row by row.  The real rows before the first row at or
+    past the end of the next window to fire (``core._next_end``) go to
+    ``fold(rows, ts)``; then ``core._fire(ts of that row)`` fires what the
+    clock has passed, and the rest of the chunk is looked at again.  Marker
+    rows move the clock and are folded nowhere.  Returns the result batches
+    ``fold`` and ``_fire`` returned, in order."""
+    ts = np.ascontiguousarray(batch["ts"], dtype=np.int64)
+    real = ~batch[MARKER_FIELD]
+    outs = []
+    lo, n = 0, len(batch)
+    while lo < n:
+        hit = ts[lo:] >= core._next_end
+        cut = lo + int(np.argmax(hit)) if hit.any() else n
+        if cut > lo:
+            rows, at = batch[lo:cut], ts[lo:cut]
+            if not real[lo:cut].all():
+                keep = np.flatnonzero(real[lo:cut])
+                rows, at = rows[keep], at[keep]
+            if len(rows):
+                outs.extend(fold(rows, at) or ())
+        if cut < n:
+            outs.extend(core._fire(int(ts[cut])))
+        lo = cut
+    return outs

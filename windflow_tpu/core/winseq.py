@@ -31,9 +31,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from .tuples import MARKER_FIELD, Schema
-from .windows import PatternConfig, Role, WindowSpec, WinType
+from .tuples import MARKER_FIELD, Schema, progress_row
+from .windows import (PatternConfig, Role, WindowSpec, WinType,
+                      check_stream_fire, run_stream_clock)
 from ..ops.functions import WindowFunction, WindowUpdate
+from ..utils import profile
 
 _NEG_INF = np.int64(-(2 ** 62))
 
@@ -66,8 +68,21 @@ class WinSeqCore:
 
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
-                 result_ts_slide: int = None):
+                 result_ts_slide: int = None, fire_on: str = "key"):
         self.spec = spec
+        if fire_on not in ("key", "stream"):
+            raise ValueError(f"fire_on is 'key' or 'stream', not {fire_on!r}")
+        #: ``key``: a key's window fires when that key's next row arrives
+        #: (win_seq.hpp's triggerer).  ``stream``: on the stage's time, the
+        #: highest position taken in on any key, and a key without an open
+        #: window is forgotten (``run_stream_clock``, ``_fire``)
+        self.fire_on = fire_on
+        if fire_on == "stream":
+            check_stream_fire(spec, config, role)
+            self._fired = 0                 # windows the stage has fired
+            self._next_end = int(spec.win_len)
+            self.keys_live_peak = self.keys_retired = 0
+            self.stream_fires = self.stream_fire_rows = 0
         # TB result ts uses the *global* slide of the logical window, which
         # differs from spec.slide_len inside a farm worker (private slide =
         # slide*pardegree). The reference quirkily uses the private slide
@@ -111,6 +126,12 @@ class WinSeqCore:
                 self.config.initial_id(key, self.role),
                 emit0,
             )
+            if self.fire_on == "stream":
+                # a new key, or one seen again after it was retired: the
+                # windows the stage has fired are behind it
+                st.next_lwid = st.n_fired = self._fired
+                self.keys_live_peak = max(self.keys_live_peak,
+                                          len(self._keys) + 1)
             self._keys[key] = st
         return st
 
@@ -176,6 +197,19 @@ class WinSeqCore:
             self._in_dtype = batch.dtype
         if len(batch) == 0:
             return np.zeros(0, dtype=self._result_dtype)
+        if self.fire_on == "stream":
+            # between two fires no key's own row can fire anything: every
+            # window that ends at or before the clock has fired for all
+            outs = run_stream_clock(
+                self, batch, lambda rows, _ts: self._process_keys(rows))
+        else:
+            outs = self._process_keys(batch)
+        if not outs:
+            return np.zeros(0, dtype=self._result_dtype)
+        return outs[0] if len(outs) == 1 else np.concatenate(outs)
+
+    def _process_keys(self, batch: np.ndarray) -> list:
+        """Group a chunk by key and run each group; the result batches."""
         outs = []
         keys = batch["key"]
         if keys[0] == keys[-1] and not np.any(keys != keys[0]):
@@ -191,9 +225,49 @@ class WinSeqCore:
                 r = self._process_key(int(keys[grp[0]]), batch[grp])
                 if r is not None:
                     outs.append(r)
-        if not outs:
-            return np.zeros(0, dtype=self._result_dtype)
-        return outs[0] if len(outs) == 1 else np.concatenate(outs)
+        return outs
+
+    # --------------------------------------------------- stream-time firing
+
+    @property
+    def keys_live(self) -> int:
+        return len(self._keys)
+
+    def _fire(self, now: int) -> list:
+        """Fire, for every key, the open windows that end at or before
+        ``now`` (window order over the whole output), send the progress
+        row, retire the keys with no window left open."""
+        with profile.span("stream_fire"):
+            first = self._fired
+            upto = int(self.spec.fired_before(now))
+            outs = []
+            for key, st in self._keys.items():
+                to = min(upto, st.next_lwid)
+                if to > st.n_fired:
+                    lwids = np.arange(st.n_fired, to, dtype=np.int64)
+                    st.n_fired = to
+                    outs.append(self._emit_windows(key, st, lwids, eos=False))
+            self._fired = upto
+            self._next_end = int(self.spec.win_end(upto))
+            if len(outs) > 1 and upto - first > 1:
+                # several windows fired at once: window order, not key order
+                out = np.concatenate(outs)
+                outs = [out[np.argsort(out["id"], kind="stable")]]
+            n_rows = sum(len(o) for o in outs)
+            self.stream_fires += 1
+            self.stream_fire_rows += n_rows
+            profile.add("stream_fires")
+            profile.add("stream_fire_rows", n_rows)
+            outs.append(progress_row(self._result_dtype, upto - 1,
+                                     self._next_end - self.spec.slide_len))
+        with profile.span("key_retire"):
+            gone = [k for k, st in self._keys.items()
+                    if st.n_fired >= st.next_lwid]
+            for k in gone:
+                del self._keys[k]
+            self.keys_retired += len(gone)
+            profile.add("keys_retired", len(gone))
+        return outs
 
     def _process_key(self, key: int, rows: np.ndarray):
         spec = self.spec

@@ -494,6 +494,12 @@ class _SinkNode(Node):
         self.vectorized = vectorized
 
     def svc(self, batch, channel=0):
+        # marker rows are the graph's own (EOS replay, a stream-time
+        # stage's progress rows): never the user's
+        if batch[MARKER_FIELD].any():
+            batch = select_rows(batch, ~batch[MARKER_FIELD])
+            if not len(batch):
+                return
         args = (self.ctx,) if self.rich else ()
         if self.vectorized:
             self.fn(batch, *args)

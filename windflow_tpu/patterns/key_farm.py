@@ -21,12 +21,16 @@ class KeyFarm(_Pattern):
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, name="key_farm", incremental=None,
                  result_fields=None, routing=None,
-                 config: PatternConfig = None, role: Role = Role.SEQ):
+                 config: PatternConfig = None, role: Role = Role.SEQ,
+                 fire_on: str = "key"):
         super().__init__(name, pardegree, routing or default_routing)
         self._seq_template = WinSeq(
             winfunc, win_len, slide_len, win_type, name=f"{name}_kf",
             incremental=incremental, result_fields=result_fields,
-            config=config, role=role)
+            config=config, role=role, fire_on=fire_on)
+        #: ``"stream"``: each worker closes its windows on its own clock
+        #: (WinSeq), and the workers' results merge on their progress rows
+        self.fire_on = fire_on
 
     @property
     def result_schema(self):
@@ -37,6 +41,15 @@ class KeyFarm(_Pattern):
         return StandardEmitter(self.parallelism, self.routing,
                                name=f"{self.name}.emitter")
 
+    def collector(self):
+        if self.fire_on == "stream" and self.parallelism > 1:
+            # a blind merge would hand a window stage behind the farm one
+            # worker's window w+1 before another's w
+            from ..runtime.ordering import ProgressMerge
+            return ProgressMerge(self.parallelism,
+                                 name=f"{self.name}.collector")
+        return super().collector()
+
     def _make_core(self, worker, i=0):
         """Core-factory hook: TPU farms override to build device cores
         (worker index `i` drives per-worker device placement)."""
@@ -45,5 +58,7 @@ class KeyFarm(_Pattern):
     def _make_replica(self, i):
         node = WinSeqNode(self._make_core(self._seq_template, i),
                           f"{self.name}.{i}")
+        if getattr(self, "burst_rows", None):    # a TPU farm's launch sizes
+            node.burst_rows = self.burst_rows
         node.ctx = RuntimeContext(self.parallelism, i, self.name)
         return node

@@ -741,6 +741,18 @@ class NativeResidentCore:
         if len(batch) and self._field_offsets(batch) is None:
             return self._fall_back().process(batch)
         self._process_rows(batch)
+        if (len(batch) and batch[MARKER_FIELD][-1]
+                and not self._recovery_mode):
+            # a progress row closes the batch (core/tuples.progress_row):
+            # the stage before says nothing more comes for the windows it
+            # fired, and nothing may come at all for a slide.  The windows
+            # the row closed here are cut into a launch now and their
+            # results leave with this call, not with the next batch's
+            profile.add("progress_seen")
+            for h in self._hs:
+                if self._lib.wf_core_fired_pending(h):
+                    self._lib.wf_core_force_flush(h)
+            return self._harvest(self._drain_entries())
         if self._overlap:
             drained = self._drain_out_q()
             if self._ship_exc is not None:

@@ -4,10 +4,14 @@
 
 from __future__ import annotations
 
+import numpy as np
+
+from ..core.tuples import MARKER_FIELD
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..core.winseq import WinSeqCore
 from ..ops.functions import WindowFunction, WindowUpdate, as_window_function, as_window_update
 from ..runtime.node import Node, RuntimeContext
+from ..utils import profile
 from .basic import _Pattern
 
 
@@ -25,6 +29,11 @@ class WinSeqNode(Node):
     #: device cores via their own snapshot hooks (ring archive handle +
     #: host bookkeeping) — and supervised restart replays the journal
     recoverable = True
+
+    #: a stream-time core's results of one fire leave in pieces of at most
+    #: this many rows (``_emit_fires``); a pattern that knows its stage's
+    #: ``flush_rows`` sets a sixteenth of it, which is this default's too
+    burst_rows = 1 << 16
 
     def __init__(self, core: WinSeqCore, name="win_seq"):
         super().__init__(name)
@@ -78,15 +87,53 @@ class WinSeqNode(Node):
             if pb is not None:
                 self._emit_each(pb(batch), triggering=True)
                 return
+        st = self.stats
+        if st is not None and len(batch) and batch[MARKER_FIELD][-1]:
+            st.bump("progress_seen")
         out = self.core.process(batch)
         if len(out):
             # triggering vs non-triggering split (win_seq.hpp:479-501)
-            if self.stats is not None:
-                self.stats.bump("windows_fired", len(out))
-                self.stats.bump("triggering_batches")
+            if st is not None:
+                st.bump("windows_fired", len(out))
+                st.bump("triggering_batches")
+            self._emit_results(out)
+        elif st is not None:
+            st.bump("non_triggering_batches")
+
+    def _emit_results(self, out):
+        if getattr(self.core, "fire_on", "key") == "stream":
+            self._emit_fires(out)
+        else:
             self.emit(out)
-        elif self.stats is not None:
-            self.stats.bump("non_triggering_batches")
+
+    def _emit_fires(self, out):
+        """A stream-time core's output: every fire's results, each closed
+        by its progress row.  One fire releases every live key's window at
+        once, so it leaves in pieces of at most ``burst_rows`` rows — the
+        nodes behind work on the first while this one cuts the next, instead
+        of one 10^6-row batch stalling each in turn — and a progress row is
+        always the last row of its piece."""
+        ends = np.flatnonzero(out[MARKER_FIELD]) + 1
+        if not len(ends) or ends[-1] != len(out):
+            ends = np.append(ends, len(out))        # the end-of-stream flush
+        lo = pieces = 0
+        for hi in ends.tolist():
+            while lo < hi:
+                step = min(hi, lo + self.burst_rows)
+                self.emit(out[lo:step])
+                lo = step
+                pieces += 1
+        n_progress = int(out[MARKER_FIELD].sum())
+        profile.add("progress_sent", n_progress)
+        profile.add("burst_batches", pieces)
+        st = self.stats
+        if st is not None:
+            st.bump("progress_sent", n_progress)
+            st.bump("burst_batches", pieces)
+            core = self.core
+            for name in ("keys_live", "keys_live_peak", "keys_retired",
+                         "stream_fires", "stream_fire_rows"):
+                st.counters[name] = int(getattr(core, name))
 
     def _emit_each(self, outs, triggering=False):
         fired = 0
@@ -112,7 +159,7 @@ class WinSeqNode(Node):
         if len(out):
             if self.stats is not None:
                 self.stats.bump("windows_fired", len(out))
-            self.emit(out)
+            self._emit_results(out)
 
 
 def window_cores(df) -> list:
@@ -140,10 +187,22 @@ class WinSeq(_Pattern):
                  win_type: WinType = WinType.CB, name="win_seq",
                  incremental: bool = None, result_fields=None,
                  config: PatternConfig = None, role: Role = Role.SEQ,
-                 map_indexes=(0, 1), result_ts_slide: int = None):
+                 map_indexes=(0, 1), result_ts_slide: int = None,
+                 fire_on: str = "key"):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self.result_ts_slide = result_ts_slide
+        #: ``"key"``: a key's window closes on that key's next row (the
+        #: reference's triggerer).  ``"stream"`` (time-based windows): on
+        #: the stage's time, the highest ``ts`` taken in on any key; quiet
+        #: keys are retired and a progress row follows every fire
+        #: (core/vecinc.VecStreamCore, core/winseq.py)
+        if fire_on not in ("key", "stream"):
+            raise ValueError(f"fire_on is 'key' or 'stream', not {fire_on!r}")
+        if fire_on == "stream":
+            from ..core.windows import check_stream_fire
+            check_stream_fire(self.spec, config, role)
+        self.fire_on = fire_on
         # resolve the function flavour (meta_utils.hpp signature deduction
         # becomes an explicit `incremental` switch)
         if incremental is True:
@@ -171,12 +230,14 @@ class WinSeq(_Pattern):
         if (vec_core_supported(self.spec, self.winfunc)
                 and not os.environ.get("WF_NO_VECCORE")):
             return make_vec_core(
-                self.spec, self.winfunc, config=self.config, role=self.role,
+                self.spec, self.winfunc, fire_on=self.fire_on,
+                config=self.config, role=self.role,
                 map_indexes=self.map_indexes,
                 result_ts_slide=self.result_ts_slide)
         core = WinSeqCore(self.spec, self.winfunc, config=self.config,
                           role=self.role, map_indexes=self.map_indexes,
-                          result_ts_slide=self.result_ts_slide)
+                          result_ts_slide=self.result_ts_slide,
+                          fire_on=self.fire_on)
         if self.incremental:
             core.use_incremental()
         return core

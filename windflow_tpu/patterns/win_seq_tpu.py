@@ -1019,6 +1019,26 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
                                            else [winfunc]), on_mesh)
 
 
+def stream_fire_plan(plan: CorePlan, fire_on: str) -> CorePlan:
+    """``plan`` itself if its core honours ``fire_on``; a ``ValueError`` for
+    ``fire_on="stream"`` on a device core: stream-time firing and the
+    retiring of quiet keys live in the host cores (core/vecinc.py,
+    core/winseq.py) until the resident cores learn them."""
+    if fire_on == "stream" and plan.core != "host":
+        raise ValueError(
+            "fire_on='stream' runs on the host window cores only (a "
+            "count, or min/max over the time field): this function is "
+            f"planned onto the {plan.core!r} core, which fires a key's "
+            "window on that key's next row")
+    return plan
+
+
+def _stream_burst_rows(batch_len, flush_rows) -> int:
+    """The pieces a stream-time stage's fire leaves in (WinSeqNode
+    ``burst_rows``), from the launch sizes the stage was given."""
+    return max(int(batch_len), int(flush_rows) // 16)
+
+
 def _native_core_fields():
     """The payload columns the native resident core stages
     (``wf_max_fields``), or None when the library is unavailable — also
@@ -1046,20 +1066,23 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                   device=None, depth=None, use_pallas=False,
                   compute_dtype=None, use_resident=None,
                   flush_rows=1 << 20, shards=1, worker_index=0, mesh=None,
-                  max_delay_ms=None):
+                  max_delay_ms=None, fire_on="key"):
     """Build the window core :func:`plan_core` names.  With ``mesh`` the
     resident ring is sharded ``P('kf', None)`` across the mesh devices (one
     dispatch serves every key group over ICI); ``max_delay_ms`` is a timer
-    on that core, whichever it is."""
-    plan = plan_core(spec, winfunc, use_pallas=use_pallas,
-                     use_resident=use_resident, mesh=mesh, shards=shards,
-                     native=_native_core_fields())
+    on that core, whichever it is; ``fire_on="stream"`` is the host cores'
+    (:func:`stream_fire_plan` refuses the others)."""
+    plan = stream_fire_plan(
+        plan_core(spec, winfunc, use_pallas=use_pallas,
+                  use_resident=use_resident, mesh=mesh, shards=shards,
+                  native=_native_core_fields()), fire_on)
     if plan.core == "host":
         from .win_seq import WinSeq
         return WinSeq(winfunc, spec.win_len, spec.slide_len,
                       spec.win_type, config=config, role=role,
                       map_indexes=map_indexes,
-                      result_ts_slide=result_ts_slide).make_core()
+                      result_ts_slide=result_ts_slide,
+                      fire_on=fire_on).make_core()
     kw = dict(batch_len=batch_len, config=config, role=role,
               map_indexes=map_indexes, result_ts_slide=result_ts_slide,
               compute_dtype=compute_dtype)
@@ -1098,9 +1121,10 @@ class WinSeqTPU(_Pattern):
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
                  depth=None, use_pallas=False, compute_dtype=None,
                  use_resident=None, flush_rows=1 << 20, shards=1,
-                 mesh=None, max_delay_ms=None):
+                 mesh=None, max_delay_ms=None, fire_on="key"):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
+        self._burst_rows = _stream_burst_rows(batch_len, flush_rows)
         self._kw = dict(batch_len=batch_len, config=config, role=role,
                         map_indexes=map_indexes,
                         result_ts_slide=result_ts_slide, device=device,
@@ -1108,7 +1132,7 @@ class WinSeqTPU(_Pattern):
                         compute_dtype=compute_dtype,
                         use_resident=use_resident, flush_rows=flush_rows,
                         shards=shards, mesh=mesh,
-                        max_delay_ms=max_delay_ms)
+                        max_delay_ms=max_delay_ms, fire_on=fire_on)
         self.winfunc = winfunc
 
     def make_core(self):
@@ -1120,6 +1144,7 @@ class WinSeqTPU(_Pattern):
 
     def _make_replica(self, i):
         node = WinSeqNode(self.make_core(), f"{self.name}.{i}")
+        node.burst_rows = self._burst_rows
         node.ctx = RuntimeContext(1, 0, self.name)
         return node
 
@@ -1158,16 +1183,18 @@ class KeyFarmTPU(_DeviceCoreFactory, KeyFarm):
                  pardegree=2, batch_len=512, name="key_farm_tpu",
                  routing=None, config=None, role=Role.SEQ, device=None,
                  depth=None, use_pallas=False, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None):
+                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
+                 fire_on="key"):
         self._raw_fn = winfunc
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
                             use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows,
-                            max_delay_ms=max_delay_ms)
+                            max_delay_ms=max_delay_ms, fire_on=fire_on)
+        self.burst_rows = _stream_burst_rows(batch_len, flush_rows)
         super().__init__(_host_standin(winfunc), win_len, slide_len, win_type,
                          pardegree=pardegree, name=name, routing=routing,
-                         config=config, role=role)
+                         config=config, role=role, fire_on=fire_on)
 
 
 class PaneFarmTPU(PaneFarm):

@@ -25,7 +25,8 @@ import enum
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD, select_rows, take_rows
+from ..core.tuples import (MARKER_FIELD, progress_row, select_rows,
+                           take_rows)
 from .node import Node
 
 _NEG_INF = -(2 ** 62)
@@ -401,3 +402,90 @@ class OrderingNode(Node):
     def eosnotify(self):
         for out in self.core.flush():
             self.emit(out)
+
+
+class ProgressMerge(Node):
+    """Merge of the result streams of stream-time window workers
+    (``fire_on="stream"``), released on the slowest channel's progress.
+
+    Each channel delivers results in nondecreasing ``ts`` and, after every
+    fire, a progress row (core/tuples.progress_row) that promises its later
+    rows are at that ``ts`` or past it.  Rows are held per channel, whole
+    batches, no per-key work, and released once every live channel's
+    progress has passed them; then ONE progress row at that minimum goes
+    downstream.  So a window stage behind the farm sees every worker's
+    results of window ``w`` before anything of ``w+1`` and closes on the
+    progress row, not on the next window's first result.  A channel at EOS
+    no longer holds the others back."""
+
+    yields_fresh = True         # batches handed on as they came, or gathers
+    quarantine_exempt = True    # framework shell: errors here fail fast
+
+    def __init__(self, n_channels: int, name="progress_merge"):
+        super().__init__(name)
+        self._held = [[] for _ in range(n_channels)]
+        self._progress = np.full(n_channels, _NEG_INF, dtype=np.int64)
+        self._eos = np.zeros(n_channels, dtype=bool)
+        self._wids = np.zeros(n_channels, dtype=np.int64)  # ... its window
+        self._sent = _NEG_INF       # progress already sent downstream
+        self._dtype = None
+
+    def svc(self, batch, channel=0):
+        mk = np.flatnonzero(batch[MARKER_FIELD])
+        if not len(mk):
+            self._held[channel].append(batch)
+            return
+        self._dtype = batch.dtype
+        if self.stats is not None:
+            self.stats.bump("progress_seen", len(mk))
+        last = int(mk[-1])
+        if last:
+            rows = batch[:last]
+            self._held[channel].append(
+                rows if len(mk) == 1 else select_rows(
+                    rows, ~rows[MARKER_FIELD]))
+        if int(batch["ts"][last]) > self._progress[channel]:
+            self._progress[channel] = batch["ts"][last]
+            self._wids[channel] = batch["id"][last]
+        if last + 1 < len(batch):
+            self._held[channel].append(batch[last + 1:])
+        self._release()
+
+    def on_channel_eos(self, channel: int):
+        self._eos[channel] = True
+        self._release()
+
+    def _release(self):
+        live = np.flatnonzero(~self._eos)
+        slowest = (live[np.argmin(self._progress[live])] if len(live)
+                   else None)
+        upto = 2 ** 62 if slowest is None else int(self._progress[slowest])
+        if upto <= self._sent:
+            return
+        out = []
+        for held in self._held:
+            while held:
+                rows = held[0]
+                # a channel's rows come in ts order: whole batches go, one
+                # that straddles the progress is cut
+                cut = (len(rows) if rows["ts"][-1] < upto else int(
+                    np.searchsorted(rows["ts"], upto, side="left")))
+                if cut:
+                    out.append(rows[:cut])
+                if cut < len(rows):
+                    held[0] = rows[cut:]
+                    break
+                held.pop(0)
+        if len({int(r["ts"][0]) for r in out}
+               | {int(r["ts"][-1]) for r in out}) > 1:
+            # more than one window went at once: back into ts order
+            rows = np.concatenate(out)
+            out = [take_rows(rows, np.argsort(rows["ts"], kind="stable"))]
+        for rows in out:
+            self.emit(rows)
+        self._sent = upto
+        if slowest is not None:
+            self.emit(progress_row(self._dtype, int(self._wids[slowest]),
+                                   upto))
+            if self.stats is not None:
+                self.stats.bump("progress_sent")
