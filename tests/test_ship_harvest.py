@@ -396,6 +396,8 @@ def test_harvest_field_names_what_made_the_thread_harvest():
     by_launch = {}
     for phase, _t0, _t1, launch, _shard, _cause, extra in profile.records():
         if phase == "harvest_wait":
-            assert set(extra) == {"ready", "harvest"}
+            # (and, since ISSUE 32, which call took it out of _out_q)
+            assert set(extra) == {"ready", "harvest", "handed", "out_q_ms"}
+            assert extra["handed"] == "svc"
             by_launch[launch] = extra["harvest"]
     assert len(by_launch) == 7

@@ -266,6 +266,13 @@ class NativeResidentCore:
         self._overlap = bool(overlap) and os.environ.get(
             "WF_NO_OVERLAP", "") in ("", "0")
         self._ship_exc = None
+        #: wakes the node thread that drives this core (``set_waker``), so
+        #: what a ship thread hands over between two chunks is emitted by
+        #: ``collect`` then and not with the next chunk
+        self._waker = None
+        #: launches ``collect`` took, and their result rows
+        self.result_wakes = 0
+        self.result_wake_rows = 0
         #: launches allowed to pile up in the C++ queue before process()
         #: throttles — restores the backpressure the synchronous ship loop
         #: provided (each queued Launch holds a staged K*R block)
@@ -365,6 +372,7 @@ class NativeResidentCore:
         """Serve one poke or drain on a ship thread.  Returns what the
         thread waits on next (_wait_target)."""
         kind, ev = tok
+        got, failed = (), False
         try:
             while self._ship_launch(shard, force=(kind == "drain")):
                 pass
@@ -373,22 +381,48 @@ class NativeResidentCore:
             for item in got:
                 self._out_q.put(item)
         except BaseException as e:  # surfaced on the node thread
-            self._ship_exc = e
-            return None
-        finally:
-            if ev is not None:
-                ev.set()
-        return self._wait_target(shard)
+            self._ship_exc, failed = e, True
+        if ev is not None:
+            ev.set()
+        if (got or failed) and kind != "drain":
+            # (under a drain the node thread itself stands waiting)
+            self._wake_node()
+        return None if failed else self._wait_target(shard)
 
     def _ship_waited(self, shard, harvested, exc):
         """Hand over what a ship thread's own wait harvested, or the
         failure it met, as under a token."""
         if exc is not None:
             self._ship_exc = exc
+            self._wake_node()
             return None
         for item in harvested:
             self._out_q.put(item)
+        self._wake_node()
         return self._wait_target(shard)
+
+    def _wake_node(self):
+        """After a hand-over or a failure, on a ship thread: an idle node
+        thread comes for it now (``collect``), a busy one with its next
+        ``process``."""
+        wake = self._waker
+        if wake is not None:
+            wake()
+
+    def set_waker(self, wake) -> bool:
+        """Take ``wake``, a callable for any thread that gets the thread
+        driving this core to call ``collect`` if it is idle (the node's
+        own ``Node._wake``; it must not pin that node).  Refused, with
+        False, where no ship thread hands results over, or where launches
+        and emissions must not follow the clock: the synchronous path,
+        recovery mode, a ``max_delay_ms`` core (it keeps its timer), the
+        Python delegate."""
+        if (not self._overlap or self._recovery_mode
+                or self.max_delay_s is not None
+                or self._delegate is not None):
+            return False
+        self._waker = wake
+        return True
 
     def _wait_target(self, shard):
         """The shard's executor, if its ship thread should now wait on its
@@ -408,14 +442,47 @@ class NativeResidentCore:
         exc, self._ship_exc = self._ship_exc, None
         raise exc
 
-    def _drain_out_q(self):
+    def _drain_out_q(self, handed="svc"):
+        """Everything the ship threads have handed over; `handed` says
+        which call of the node thread takes it (``wake``: ``collect``;
+        ``svc``: any other) on each launch's ``harvest_wait`` record."""
         items = []
         while True:
             try:
                 items.append(self._out_q.get_nowait())
             except _queue.Empty:
                 break
+        if items and profile.ENABLED:
+            now = time.perf_counter_ns()
+            for meta, _res in items:
+                profile.amend("harvest_wait", meta[6][0],
+                              since_end=("out_q_ms", now), handed=handed)
         return items
+
+    def _take_handed(self, handed="svc"):
+        """Drain `_out_q` on the node thread: a ship-thread failure is
+        raised here, once; what an earlier raise salvaged goes first."""
+        drained = self._drain_out_q(handed)
+        if self._ship_exc is not None:
+            self._raise_ship_exc(drained)
+        out, self._salvaged = self._salvaged + drained, []
+        return out
+
+    def collect(self) -> np.ndarray:
+        """The results the ship threads handed over since the node thread
+        last took any, for that thread between two ``process`` calls
+        (``WinSeqNode.on_wake``).  ``process`` keeps its own drain:
+        whichever comes first takes the items, the other finds nothing."""
+        if not self._overlap or self._delegate is not None:
+            return np.zeros(0, dtype=self._result_dtype)
+        taken = self._take_handed("wake")
+        out = self._harvest(taken)
+        if taken:
+            self.result_wakes += len(taken)
+            self.result_wake_rows += len(out)
+            profile.add("result_wakes", len(taken))
+            profile.add("result_wake_rows", len(out))
+        return out
 
     # ------------------------------------------------------------- delegate
 
@@ -487,6 +554,7 @@ class NativeResidentCore:
         if self._recovery_mode:
             return
         self._recovery_mode = True
+        self._waker = None
         if self._overlap:
             # ship threads drain into ONE completion-ordered queue, so a
             # multi-shard core's emission interleaving is wall-clock —
@@ -505,11 +573,7 @@ class NativeResidentCore:
                 q.put(("drain", ev))
             for ev in evs:
                 ev.wait()
-            drained = self._drain_out_q()
-            if self._ship_exc is not None:
-                self._raise_ship_exc(drained)
-            out, self._salvaged = self._salvaged + drained, []
-            return out
+            return self._take_handed()
         harvested = []
         for t in range(self.shards):
             while self._ship_launch(t, force=True):
@@ -754,11 +818,7 @@ class NativeResidentCore:
                     self._lib.wf_core_force_flush(h)
             return self._harvest(self._drain_entries())
         if self._overlap:
-            drained = self._drain_out_q()
-            if self._ship_exc is not None:
-                self._raise_ship_exc(drained)
-            out, self._salvaged = self._salvaged + drained, []
-            return self._harvest(out)
+            return self._harvest(self._take_handed())
         harvested = []
         for t in range(self.shards):
             while self._ship_launch(t):

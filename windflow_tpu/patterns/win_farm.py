@@ -253,6 +253,7 @@ class _OrderedWorkerNode(WinSeqNode):
                                if k != "ordering"})
 
     def svc_init(self):
+        super().svc_init()
         if self.n_input_channels != self.ordering.n_channels:
             raise RuntimeError(
                 f"{self.name}: wired with {self.n_input_channels} input "

@@ -38,6 +38,29 @@ class WinSeqNode(Node):
     def __init__(self, core: WinSeqCore, name="win_seq"):
         super().__init__(name)
         self.core = core
+        #: whether the core took this node's waker (``svc_init``)
+        self._woken = False
+
+    def svc_init(self):
+        # a core whose ship threads hand results over between two chunks
+        # (the native core, where it says so) has them wake this node, so
+        # a finished result leaves now and not with the next input
+        take = getattr(self.core, "set_waker", None)
+        self._woken = bool(take is not None and self._wake is not None
+                           and take(self._wake))
+
+    def on_wake(self):
+        if not self._woken:
+            return
+        core = self.core
+        out = core.collect()
+        if len(out):
+            st = self.stats
+            if st is not None:
+                st.bump("windows_fired", len(out))
+                st.counters["result_wakes"] = core.result_wakes
+                st.counters["result_wake_rows"] = core.result_wake_rows
+            self._emit_results(out)
 
     def checkpoint_prepare(self):
         """Device cores buffer fired windows in an async launch queue;

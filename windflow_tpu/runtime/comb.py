@@ -138,6 +138,8 @@ class Comb(Node):
             s.n_input_channels = 1
         for s in self.stages:
             s.stats = self.stats
+            # a fused stage's other threads wake the thread that runs it
+            s._wake = self._wake
             # the engine stamps the observability registry on the Comb's
             # context; fused stages keep their own ctx (their replica
             # index differs), so the handle is forwarded explicitly
@@ -149,6 +151,11 @@ class Comb(Node):
 
     def on_channel_eos(self, channel: int):
         self.stages[0].on_channel_eos(channel)
+
+    def on_wake(self):
+        # whichever stage asked: what it emits flows on as from its svc
+        for s in self.stages:
+            s.on_wake()
 
     def eosnotify(self):
         # cascade: flushing stage i may emit into stage i+1 (synchronously),

@@ -19,6 +19,7 @@ from __future__ import annotations
 import os
 import queue
 import threading
+import weakref
 from time import monotonic as _monotonic
 from time import perf_counter_ns as _pc_ns
 from time import sleep as _sleep
@@ -28,6 +29,11 @@ from .overload import DeadLetter, OverloadError, OverloadPolicy
 from ..recovery.epoch import EpochMarker, Tagged, is_ctrl_payload
 
 _EOS = object()
+#: the wake token (``Inbox.wake``): tells an idle node that something it
+#: alone may emit is ready (a window core's harvested launch).  Not a
+#: source's item: it never counts towards a node's live channels, is never
+#: shed, journaled or stamped by the tracer, and carries no batch.
+_WAKE = object()
 
 
 class _Cancelled(BaseException):
@@ -67,11 +73,27 @@ class Inbox:
         #: concurrent put, a fine trade for a telemetry-only value.
         self.hwm = 0
         self._track = False
+        #: whether ``wake`` may queue a token: set by the receive loop that
+        #: serves them, cleared while one is queued and when that loop ends
+        self._wake_armed = False
 
     def register_source(self) -> int:
         slot = self.n_sources
         self.n_sources += 1
         return slot
+
+    def wake(self):
+        """Queue one wake token if the inbox is empty and none is queued.
+        For any thread; never blocks and never raises.  A non-empty inbox
+        gets none: its node's next ``svc`` is already on its way.  (Two
+        threads may each queue one; the second wake finds nothing.)"""
+        if not self._wake_armed or not self._q.empty():
+            return
+        self._wake_armed = False
+        try:
+            self._q.put_nowait((-1, _WAKE))
+        except queue.Full:
+            self._wake_armed = True
 
     def _blocking(self, op):
         while True:
@@ -145,7 +167,11 @@ class Inbox:
                 victim = self._q.get_nowait()
             except queue.Empty:
                 continue    # consumer drained it meanwhile; retry the put
-            if victim[1] is _EOS or is_ctrl_payload(victim[1]):
+            if victim[1] is _WAKE:
+                # not an item of the stream: dropped uncounted (the queue
+                # is full, so the node's next svc is as good as a wake)
+                self._wake_armed = True
+            elif victim[1] is _EOS or is_ctrl_payload(victim[1]):
                 # EOS and epoch-marker control frames survive eviction
                 # (a shed marker would stall downstream barrier
                 # alignment the way a shed EOS would corrupt the
@@ -213,6 +239,7 @@ class NativeInbox:
         self.owner = None    # see Inbox
         self.hwm = 0         # see Inbox: observed-dataflow occupancy mark
         self._track = False
+        self._wake_armed = False    # see Inbox
 
     def __del__(self):
         h = getattr(self, "_h", None)
@@ -226,6 +253,19 @@ class NativeInbox:
         slot = self.n_sources
         self.n_sources += 1
         return slot
+
+    def wake(self):
+        """See ``Inbox.wake``.  The side table is empty exactly when nothing
+        is queued and nothing is being handed over."""
+        if (not self._wake_armed or self._items
+                or not getattr(self._lib, "wf_has_overload_queue", False)):
+            return
+        self._wake_armed = False
+        slot = self._slot_for(_WAKE)
+        if self._lib.wf_queue_try_push(self._h, -1, slot) != 0:
+            # full, or closed by a failed graph: nothing to wake
+            self._items.pop(slot, None)
+            self._wake_armed = True
 
     def _slot_for(self, item) -> int:
         with self._seq_lock:
@@ -314,7 +354,9 @@ class NativeInbox:
             if rc2 == 1:
                 continue    # consumer drained it meanwhile; retry the push
             victim = self._items.pop(vslot.value)
-            if victim is _EOS or is_ctrl_payload(victim):
+            if victim is _WAKE:
+                self._wake_armed = True     # see Inbox._put_shed_oldest
+            elif victim is _EOS or is_ctrl_payload(victim):
                 # control frames survive eviction (see Inbox)
                 self._push(vsrc.value, victim)
                 _sleep(0.001)   # see Inbox._put_shed_oldest: no hot spin
@@ -354,6 +396,19 @@ def _make_inbox(capacity: int, failed: threading.Event,
             # back to the Python queue
             return NativeInbox(capacity, failed, lib=lib, policy=policy)
     return Inbox(capacity, failed, policy)
+
+
+def _waker(inbox):
+    """``inbox.wake`` for a holder that may outlive the graph: through a weak
+    reference, so a window core's ship thread pins neither the inbox nor
+    what still lies in it."""
+    ref = weakref.ref(inbox)
+
+    def wake():
+        target = ref()
+        if target is not None:
+            target.wake()
+    return wake
 
 
 def _timed_get(inbox, stats):
@@ -722,9 +777,14 @@ class Dataflow:
                 events.emit("node_start", dataflow=self.name,
                             node=node.name,
                             source=isinstance(node, SourceNode))
-            node.svc_init()
             supervised = (node._recov is not None
                           and not isinstance(node, SourceNode))
+            if not supervised and not isinstance(node, SourceNode):
+                # the seed loop below serves wake tokens.  Not the
+                # supervised one: under recovery= a node's emission grouping
+                # stays a function of its input alone
+                node._wake = _waker(self._inboxes[id(node)])
+            node.svc_init()
             if isinstance(node, SourceNode):
                 if node._recov is not None:
                     # sequence-tag emissions + epoch-marker injection
@@ -747,6 +807,7 @@ class Dataflow:
                 live = inbox.n_sources
                 stats = node.stats
                 budget = self._error_budget_of(node)
+                inbox._wake_armed = True
                 while live > 0:
                     src, item = (inbox.get() if stats is None
                                  else _timed_get(inbox, stats))
@@ -763,6 +824,25 @@ class Dataflow:
                             events.emit("eos", dataflow=self.name,
                                         node=node.name, channel=src,
                                         live=live)
+                        if live > 0:
+                            # this frame kept the inbox from looking idle
+                            # and ran no svc: a wake withheld for it is
+                            # served here
+                            node.on_wake()
+                        continue
+                    if item is _WAKE:
+                        # re-armed before the call: what lands during it is
+                        # taken by it or gets a token of its own
+                        inbox._wake_armed = True
+                        if tracer is not None:
+                            tracer.set_current(None)    # as for an EOS
+                        if stats is None:
+                            node.on_wake()
+                        else:
+                            # the wake's time is service, not idle
+                            t0 = _pc_ns()
+                            node.on_wake()
+                            stats.record_svc(0, _pc_ns() - t0)
                         continue
                     ctx = None
                     if tracer is not None:
@@ -811,6 +891,9 @@ class Dataflow:
                     if ctx is not None:
                         tracer.record_hop(ctx, node._hop_id, span, parent,
                                           q_ns, dt, len(item))
+                # a wake from here on is dropped: eosnotify flushes what
+                # it would have announced
+                inbox._wake_armed = False
             if tracer is not None:
                 # EOS flushes are not attributable to any sampled batch:
                 # clear the thread-local so the last traced batch's span
@@ -928,6 +1011,8 @@ class Dataflow:
         repeat the original hold-or-process decisions, and the restored
         ``chan_epoch`` only knows the commit-time (possibly later)
         level."""
+        if item is _WAKE:
+            return False    # nobody arms a supervised node's inbox
         if item is _EOS:
             if lvl is None:
                 lvl = rec.chan_epoch.get(src, 0)

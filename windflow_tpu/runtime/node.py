@@ -132,6 +132,13 @@ class Node:
     #: ``_complete_barriers``), never on the per-item path.
     _ctl_seal_hook = None
     _ctl_epoch_hook = None
+    #: ``wake()`` of this node's own inbox (runtime/engine.py ``Inbox.wake``),
+    #: set by the engine before ``svc_init`` on a node whose receive loop
+    #: serves wake tokens (not a source, not under ``recovery=``).  A node
+    #: that has another thread prepare what only it may emit hands this to
+    #: that thread and emits in ``on_wake``; safe to call from any thread,
+    #: at any time, the graph's end included.
+    _wake = None
 
     def __init__(self, name: str = None):
         self.name = name or type(self).__name__
@@ -152,6 +159,12 @@ class Node:
 
     def on_channel_eos(self, channel: int):
         """Called when one input channel reaches EOS (eosnotify(id))."""
+
+    def on_wake(self):
+        """Called in the node's thread, between two ``svc`` calls, after
+        ``_wake()`` found the node idle — and wherever else the engine
+        cannot tell that a wake was not withheld, so with nothing to do as
+        often as not.  May emit."""
 
     def eosnotify(self):
         """Called once after ALL input channels reached EOS; flush here."""

@@ -98,6 +98,10 @@ _mu = threading.Lock()
 #: benchmark run (a few hundred launches and chunks a second for a minute)
 _RING = deque(maxlen=1 << 17)
 
+#: how far back ``amend`` looks: a record is amended milliseconds after it
+#: is written, a few dozen spans later
+_AMEND_DEPTH = 1 << 12
+
 #: one id sequence for launches and for the bookkeeping calls that cause
 #: them, so an id orders what it names and a cause is always smaller than
 #: the launches it fed (next() on a count is atomic under the GIL)
@@ -212,6 +216,24 @@ def add(name: str, value: float = 1.0):
     if ENABLED:
         with _mu:
             _val[name] += value
+
+
+def amend(phase: str, launch: int, since_end=None, **fields):
+    """Add `fields` to the newest record of `phase` that names `launch` and
+    carries extra fields — what became of the span's result after it
+    closed.  ``since_end=(name, t_ns)`` adds under `name` the milliseconds
+    from the record's end to `t_ns`.  Nothing happens if the ring holds no
+    such record among its newest ``_AMEND_DEPTH``."""
+    if not ENABLED:
+        return
+    with _mu:
+        for rec in itertools.islice(reversed(_RING), _AMEND_DEPTH):
+            if rec[3] == launch and rec[0] == phase and rec[6] is not None:
+                if since_end is not None:
+                    name, t_ns = since_end
+                    fields[name] = round((t_ns - rec[2]) / 1e6, 4)
+                rec[6].update(fields)
+                return
 
 
 def report() -> dict:
