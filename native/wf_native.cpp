@@ -19,11 +19,16 @@
 // Build: `make -C native` -> libwfnative.so (loaded by windflow_tpu/native).
 
 #include <algorithm>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
 #include <deque>
 #include <functional>
+#if defined(__GLIBC__)
+#include <malloc.h>
+#endif
+#include <memory>
 #include <mutex>
 #include <thread>
 #include <unordered_map>
@@ -91,6 +96,26 @@ static inline void copy_narrow(u8 *dst, const i64 *src, i64 cnt, int wire) {
         std::memcpy(dst, src, (size_t)cnt * 8);
 }
 
+static inline i64 fdiv(i64 a, i64 b) {  // floor division, b > 0
+    i64 q = a / b;
+    return (a % b != 0 && a < 0) ? q - 1 : q;
+}
+
+// A stream-time stage's held-back rows of one key (wf_core_set_stream): the
+// rows at or past the core's release bound, which the watermark has not
+// passed yet.  Records are flat, `1 + n_cols` int64 each: the position, then
+// the archived columns.  `ord` is the run that arrived in order (appended at
+// its back, released from its front); a row that arrives behind `ord`'s
+// newest goes into the bucket of its time slot instead (`lbk[j]` holds the
+// positions of slot `lb0 + j`, unsorted), so taking it in is O(1) and a
+// release sorts only the few slots the watermark has passed.
+struct Hold {
+    std::vector<i64> ord;
+    size_t ord_start = 0;              // records released from ord's front
+    std::deque<std::vector<i64>> lbk;
+    i64 lb0 = 0;
+};
+
 struct KeyState {
     // live archive: SoA ordered by pos, purge advances `start`
     // (core/archive.py's KeyArchive, reference stream_archive.hpp)
@@ -123,6 +148,8 @@ struct KeyState {
     // winning row has not been read back yet (wf_core_arg_gather pops it);
     // purge() keeps the archive from the oldest of them on
     std::deque<i64> held;
+    // stream-time cores only: the rows the watermark has not passed yet
+    std::unique_ptr<Hold> hold;
 
     inline void note_vals(int nf, const i64 *vs) {
         if (!pend_any) {
@@ -219,6 +246,10 @@ struct Launch {
     i64 Rb = 0;
     int trigger = 0;   // what cut the launch (FlushTrigger); a merged
                        // launch keeps its first part's
+    // stream-time cores: this launch ends a fire, and the last window that
+    // fire closed (the progress row that follows its results)
+    int has_progress = 0;
+    i64 progress_wid = 0;
 };
 
 // what cut a launch: a row or window count reached (flush_rows, batch_len),
@@ -257,6 +288,32 @@ struct Core {
     int nat_wire[kMaxFields] = {0};
     bool hopping;
 
+    // Stream-time stage (wf_core_set_stream; time-based sliding or tumbling
+    // windows of a plain sequential worker): window w is [w*slide,
+    // w*slide + win) for every key and every integer w, and closes when the
+    // watermark -- `clock`, the highest ts taken in (rows and markers), less
+    // `holdback` -- reaches its end.  Rows wait in their key's Hold until
+    // the watermark has passed them and reach archive and ring in position
+    // order, so a window's rows are a range of both exactly when it may fire.
+    int stream_mode = 0;
+    i64 holdback = 0;
+    i64 clock = NEG_INF;
+    bool clock_set = false;
+    i64 fired = 0;        // windows below it are closed
+    i64 next_end = 0;     // end of window `fired`: the next fire
+    i64 rel = NEG_INF;    // release bound: rows below it are archived
+    i64 slot_w = 1;       // width of a Hold's late-row time slot
+    i64 lag_max = 0;      // a release is made once the watermark leads
+                          // `rel` by more (bounds the slots a Hold keeps)
+    bool need_rebase = false;    // a row was inserted behind shipped ones
+    int progress_pending = 0;    // a fire no launch carries yet
+    i64 progress_wid = 0;
+    std::vector<std::vector<i64>> spare_slots;   // emptied slot buffers
+    std::vector<std::pair<i64, const i64 *>> late_scratch;
+    // what the stage counts (wf_core_stream_stats)
+    i64 n_ooo = 0, n_late = 0, n_behind = 0, n_held = 0, n_held_peak = 0,
+        n_fires = 0, n_merges = 0, merge_ns = 0, fire_ns = 0;
+
     std::unordered_map<i64, int> rowmap;
     std::vector<int> direct;          // fast dense map for small keys
     std::vector<KeyState> keys;       // dense by ring row
@@ -273,6 +330,7 @@ struct Core {
     std::deque<Launch> queue;
     std::mutex qmu;  // producer (process/eos on the node thread) vs
                      // consumer (wf_launch_peek/take on a ship thread)
+    i64 fast_rows = 0;      // rows the bulk path took (wf_core_fast_rows)
     i64 launches_made = 0;  // produced-launch counter; only the producer
                             // thread reads/writes it (queue.size() is
                             // not safe to read unlocked)
@@ -403,7 +461,11 @@ struct Core {
     // and never as a rebase — where the ring is full or a key is new it
     // leaves everything pending for the natural trigger.
     void flush(int trigger = NATURAL) {
-        if (hkey.empty() && pend_rows == 0) return;
+        // (a fire that closed nothing still owes its progress row, which
+        // rides on a launch so that it leaves after the earlier results)
+        if (hkey.empty() && pend_rows == 0
+            && !(progress_pending && !keys.empty()))
+            return;
         const bool early = trigger == EARLY;
         if (early && (hkey.empty() || arg_mode || nat_rb == 0)) return;
         const i64 K = (i64)keys.size();
@@ -416,8 +478,16 @@ struct Core {
         // (tiny or latency-bound streams) keep the minimal ring.
         if (cap == 0 && pend_rows >= flush_rows)
             room_mult = kCoalesceLadderMax + 2;
-        bool rebase = (cap == 0) || (KP < KPb);
+        bool rebase = (cap == 0) || (KP < KPb) || need_rebase;
         if (early && rebase) return;
+        need_rebase = false;
+        // a stream-time stage cuts a launch at every fire, whatever rows it
+        // holds then: one rectangle width for those and for the launches
+        // flush_rows cuts (two of its shares a key), so the stage meets one
+        // step shape a ring and not one a row count
+        if (stream_mode)
+            rb_floor = bucket(2 * std::max<i64>(
+                flush_rows / std::max<i64>(K, 1), 64));
         i64 maxpend = 0;
         for (auto &st : keys)
             maxpend = std::max(maxpend, st.appended - st.launched);
@@ -637,6 +707,11 @@ struct Core {
             L.habs = wlo;
             L.Rb = std::max(bucket(Rr), rb_floor);
             rb_floor = std::min(L.Rb, bucket(std::max<i64>(flush_rows, 1)));
+        } else if (stream_mode) {
+            L.Rb = std::max(bucket(Rr), rb_floor);
+            L.has_progress = progress_pending;
+            L.progress_wid = progress_wid;
+            progress_pending = 0;
         } else if (early) {
             L.Rb = std::max(bucket(Rr), nat_rb);
             if (L.regular) L.cmax = std::max(L.cmax, nat_cmax);
@@ -819,15 +894,299 @@ struct Core {
         return consumed;
     }
 
+
+    // ------------------------------------------------- stream-time stage
+    static inline i64 now_ns() {
+        return (i64)std::chrono::duration_cast<std::chrono::nanoseconds>(
+            std::chrono::steady_clock::now().time_since_epoch()).count();
+    }
+
+    // one released record onto its key's archive (position order kept by
+    // the caller)
+    inline void archive_row(KeyState &st, const i64 *rec) {
+        st.pos.push_back(rec[0]);
+        st.ts.push_back(rec[0]);
+        st.val.push_back(rec[1]);
+        const int nc = n_cols();
+        for (int f = 1; f < nc; ++f)
+            st.xval[(size_t)(f - 1)].push_back(rec[1 + f]);
+        st.note_vals(n_fields, rec + 1);
+        st.appended++;
+        pend_rows++;
+    }
+
+    inline void hold_row(KeyState &st, const i64 *rec) {
+        if (!st.hold) st.hold.reset(new Hold());
+        Hold &h = *st.hold;
+        const size_t stride = (size_t)(1 + n_cols());
+        const size_t on = h.ord.size();
+        if (on == h.ord_start * stride || rec[0] >= h.ord[on - stride]) {
+            h.ord.insert(h.ord.end(), rec, rec + stride);
+        } else {
+            // behind the in-order run: into its time slot
+            const i64 slot = fdiv(rec[0], slot_w);
+            if (h.lbk.empty()) h.lb0 = slot;
+            while (slot < h.lb0) {
+                h.lbk.emplace_front(take_slot());
+                --h.lb0;
+            }
+            while (slot - h.lb0 >= (i64)h.lbk.size())
+                h.lbk.emplace_back(take_slot());
+            std::vector<i64> &b = h.lbk[(size_t)(slot - h.lb0)];
+            b.insert(b.end(), rec, rec + stride);
+        }
+        if (++n_held > n_held_peak) n_held_peak = n_held;
+    }
+
+    std::vector<i64> take_slot() {
+        if (spare_slots.empty()) return std::vector<i64>();
+        std::vector<i64> v = std::move(spare_slots.back());
+        spare_slots.pop_back();
+        return v;
+    }
+
+    // move the held rows of one key that lie below `bound` onto its
+    // archive: the front of the in-order run merged with the late slots the
+    // bound has passed, those sorted first
+    void merge_key(KeyState &st, i64 bound) {
+        Hold &h = *st.hold;
+        const size_t stride = (size_t)(1 + n_cols());
+        const i64 *o = h.ord.data();
+        size_t a = h.ord_start, hi = h.ord.size() / stride, lo = a;
+        while (lo < hi) {
+            const size_t mid = lo + (hi - lo) / 2;
+            if (o[mid * stride] < bound) lo = mid + 1;
+            else hi = mid;
+        }
+        const size_t b = lo;
+        auto &late = late_scratch;
+        late.clear();
+        i64 full = 0;
+        if (!h.lbk.empty()) {
+            const i64 bslot = fdiv(bound, slot_w);
+            full = std::min<i64>(std::max<i64>(bslot - h.lb0, 0),
+                                 (i64)h.lbk.size());
+            for (i64 j = 0; j < full; ++j) {
+                const std::vector<i64> &v = h.lbk[(size_t)j];
+                for (size_t r = 0; r < v.size(); r += stride)
+                    late.emplace_back(v[r], v.data() + r);
+            }
+            if (full < (i64)h.lbk.size() && h.lb0 + full == bslot) {
+                // the slot the bound lies in: only what is below it
+                for (size_t r = 0; r < h.lbk[(size_t)full].size();
+                     r += stride) {
+                    const i64 *rec = h.lbk[(size_t)full].data() + r;
+                    if (rec[0] < bound) late.emplace_back(rec[0], rec);
+                }
+            }
+        }
+        if (a == b && late.empty()) return;
+        std::stable_sort(late.begin(), late.end(),
+                         [](const std::pair<i64, const i64 *> &x,
+                            const std::pair<i64, const i64 *> &y) {
+                             return x.first < y.first;
+                         });
+        const size_t total = (b - a) + late.size();
+        size_t li = 0;
+        for (size_t i = a; i < b; ++i) {
+            const i64 *rec = o + i * stride;
+            while (li < late.size() && late[li].first < rec[0])
+                archive_row(st, late[li++].second);
+            archive_row(st, rec);
+        }
+        while (li < late.size()) archive_row(st, late[li++].second);
+        n_held -= (i64)total;
+        // the in-order run: its front is gone
+        h.ord_start = b;
+        if (b * stride == h.ord.size()) {
+            h.ord.clear();
+            h.ord_start = 0;
+        } else if (b > 4096 && b * stride > h.ord.size() / 2) {
+            h.ord.erase(h.ord.begin(), h.ord.begin() + (ptrdiff_t)(b * stride));
+            h.ord_start = 0;
+        }
+        // the late slots: the passed ones go, the bound's own is compacted
+        if (full < (i64)h.lbk.size() && !late.empty()) {
+            std::vector<i64> &v = h.lbk[(size_t)full];
+            size_t w = 0;
+            for (size_t r = 0; r < v.size(); r += stride) {
+                if (v[r] >= bound) {
+                    if (w != r)
+                        std::memmove(v.data() + w, v.data() + r, stride * 8);
+                    w += stride;
+                }
+            }
+            v.resize(w);
+        }
+        for (i64 j = 0; j < full; ++j) {
+            h.lbk.front().clear();
+            spare_slots.push_back(std::move(h.lbk.front()));
+            h.lbk.pop_front();
+        }
+        h.lb0 += full;
+    }
+
+    // the watermark has reached `bound`: every held row below it goes to its
+    // archive (and, at the next flush, to the ring)
+    void release(i64 bound) {
+        if (bound <= rel) return;
+        i64 t0 = now_ns();
+        for (size_t r = 0; r < keys.size(); ++r) {
+            if (keys[r].hold) merge_key(keys[r], bound);
+            if (pend_rows >= flush_rows) {   // (a launch's cut is not the
+                merge_ns += now_ns() - t0;   // merge's time)
+                flush();
+                t0 = now_ns();
+            }
+        }
+        rel = bound;
+        merge_ns += now_ns() - t0;
+        ++n_merges;
+    }
+
+    // one window of one key, if it holds a row
+    void emit_stream_window(KeyState &st, i64 key, i64 w) {
+        const i64 *p = st.pos.data() + st.start;
+        const size_t n = st.live();
+        const i64 s = w * slide, e = s + win;
+        const size_t lo = std::lower_bound(p, p + n, s) - p;
+        const size_t hi = std::lower_bound(p, p + n, e) - p;
+        if (hi == lo) return;
+        wrow.push_back(st.row);
+        wlo.push_back((st.appended - (i64)n) + (i64)lo);
+        wlen.push_back((i64)(hi - lo));
+        hkey.push_back(key);
+        hid.push_back(w);
+        hts.push_back(w * result_ts_slide + win - 1);
+        hpm.push_back(p[hi - 1]);
+        hpmn.push_back(p[lo]);
+        if ((i64)hkey.size() >= batch_len) flush();
+    }
+
+    // windows [fired, upto) of every key, window by window, over what the
+    // archives hold
+    void fire_windows(i64 upto, bool eos = false) {
+        i64 pmin = INT64_MAX, pmax = INT64_MIN;
+        for (auto &st : keys) {
+            if (st.neutral || st.live() == 0) continue;
+            pmin = std::min(pmin, st.pos[st.start]);
+            pmax = std::max(pmax, st.pos.back());
+        }
+        if (pmin <= pmax) {
+            const i64 w_lo = std::max(fired, fdiv(pmin - win, slide) + 1);
+            const i64 w_hi = std::min(upto, fdiv(pmax, slide) + 1);
+            for (i64 w = w_lo; w < w_hi; ++w)
+                for (size_t r = 0; r < keys.size(); ++r)
+                    if (!keys[r].neutral)
+                        emit_stream_window(keys[r], rowkey[r], w);
+        }
+        if (!eos && upto > fired) {
+            fired = upto;
+            next_end = upto * slide + win;
+            // nothing below the last closed window's start is read again
+            for (auto &st : keys)
+                st.purge_pos = std::max(st.purge_pos, (upto - 1) * slide);
+        }
+    }
+
+    // the watermark reached the end of window `fired`
+    void fire_upto(i64 wm) {
+        release(wm);
+        const i64 t0 = now_ns();
+        fire_windows(fdiv(wm - win, slide) + 1);
+        progress_wid = fired - 1;
+        progress_pending = 1;
+        ++n_fires;
+        flush();
+        fire_ns += now_ns() - t0;
+    }
+
+    inline void tick(i64 t) {
+        clock = t;
+        if (!clock_set) {
+            // the stage starts at window 0, unless its first watermark
+            // lies before time 0: then at the first window that has not
+            // closed (core/windows.run_stream_clock)
+            clock_set = true;
+            fired = t - holdback < 0 ? fdiv(t - holdback - win, slide) + 1
+                                     : 0;
+            next_end = fired * slide + win;
+            rel = fired * slide;
+        }
+        const i64 wm = t - holdback;
+        if (wm >= next_end) fire_upto(wm);
+        else if (wm - rel > lag_max) release(wm);
+    }
+
+    // a row behind the release bound: late if every window of its is
+    // closed; else it is inserted where it belongs and the next flush
+    // re-ships the live rows (a rebase), so archive and ring stay ordered.
+    // Only a hold-back below the stream's disorder brings rows here:
+    // correct, and slow.  (No fired window is pending between two fires,
+    // so no descriptor's row coordinates move.)
+    void behind(KeyState &st, const i64 *rec) {
+        if (fdiv(rec[0], slide) < fired) {
+            ++n_late;
+            return;
+        }
+        ++n_behind;
+        const size_t at = (size_t)(std::upper_bound(
+            st.pos.begin() + (ptrdiff_t)st.start, st.pos.end(), rec[0])
+            - st.pos.begin());
+        st.pos.insert(st.pos.begin() + (ptrdiff_t)at, rec[0]);
+        st.ts.insert(st.ts.begin() + (ptrdiff_t)at, rec[0]);
+        st.val.insert(st.val.begin() + (ptrdiff_t)at, rec[1]);
+        for (int f = 1; f < n_cols(); ++f)
+            st.xval[(size_t)(f - 1)].insert(
+                st.xval[(size_t)(f - 1)].begin() + (ptrdiff_t)at, rec[1 + f]);
+        st.appended++;
+        pend_rows++;
+        need_rebase = true;
+    }
+
+    i64 process_stream(const u8 *base, i64 n, i64 itemsize, i64 o_key,
+                       i64 o_ts, i64 o_marker, i64 o_val,
+                       const i64 *o_xval) {
+        const i64 q0 = launches_made;
+        const int nc = n_cols();
+        if (nc > 1 && o_xval == nullptr) return -1;
+        i64 rec[1 + kMaxFields + kMaxCarry];
+        for (i64 i = 0; i < n; ++i) {
+            const u8 *rp = base + i * itemsize;
+            std::memcpy(&rec[0], rp + o_ts, 8);
+            if (rec[0] > clock) tick(rec[0]);
+            if (rp[o_marker]) continue;   // moves the clock, folds nowhere
+            i64 key;
+            std::memcpy(&key, rp + o_key, 8);
+            std::memcpy(&rec[1], rp + o_val, 8);
+            for (int f = 1; f < nc; ++f)
+                std::memcpy(&rec[1 + f], rp + o_xval[f - 1], 8);
+            KeyState &st = state(key);
+            if (st.neutral) st.neutral = false;
+            if (rec[0] < st.last_pos) ++n_ooo;
+            else st.last_pos = rec[0];
+            if (rec[0] >= rel) hold_row(st, rec);
+            else behind(st, rec);
+        }
+        // what the watermark has passed by the end of the chunk goes on
+        if (clock_set) release(clock - holdback);
+        if (pend_rows >= flush_rows || need_rebase) flush();
+        return launches_made - q0;
+    }
+
     i64 process(const u8 *base, i64 n, i64 itemsize, i64 o_key, i64 o_id,
                 i64 o_ts, i64 o_marker, i64 o_val,
                 i64 shard_mod = 1, i64 shard_id = 0,
                 const u8 *shard_of = nullptr,
                 const i64 *o_xval = nullptr) {
+        if (stream_mode)
+            return process_stream(base, n, itemsize, o_key, o_ts, o_marker,
+                                  o_val, o_xval);
         const i64 q0 = launches_made;
         if (shard_of == nullptr && shard_mod == 1) {
             const i64 fdone = process_fast(base, n, itemsize, o_key, o_id,
                                            o_ts, o_marker, o_val);
+            fast_rows += fdone;
             if (fdone >= n) return launches_made - q0;
             base += fdone * itemsize;
             n -= fdone;
@@ -910,6 +1269,13 @@ struct Core {
 
     i64 eos() {
         const i64 q0 = launches_made;
+        if (stream_mode) {
+            // every held row goes on, every window that holds a row fires
+            release(INT64_MAX);
+            fire_windows(INT64_MAX, true);
+            flush(EOS);
+            return launches_made - q0;
+        }
         for (size_t r = 0; r < keys.size(); ++r) {
             KeyState &st = keys[r];
             if (st.neutral) continue;   // key migrated away at a rescale
@@ -1084,6 +1450,9 @@ i64 wf_core_process(void *h, const void *base, i64 n, i64 itemsize,
     return ((Core *)h)->process((const u8 *)base, n, itemsize, o_key, o_id,
                                 o_ts, o_marker, o_val);
 }
+
+// rows the key-periodic bulk path (process_fast) has taken so far
+i64 wf_core_fast_rows(void *h) { return ((Core *)h)->fast_rows; }
 
 // single source of truth for the staging bound (Python guards read it)
 i64 wf_max_fields(void) { return kMaxFields; }
@@ -1323,7 +1692,15 @@ void wf_core_release(void *h) {
         for (auto &xv : st.xval) std::vector<i64>().swap(xv);
         st.start = 0;
         st.held.clear();
+        st.hold.reset();
     }
+    std::vector<std::vector<i64>>().swap(c->spare_slots);
+#if defined(__GLIBC__)
+    // (freed to the arena of the thread that grew them is not freed to the
+    // system: a worker's arena keeps what its stream peaked at, and the next
+    // pipeline's workers grow their own beside it)
+    malloc_trim(0);
+#endif
 }
 
 // --------------------------------------------------------------- renumber
@@ -1633,6 +2010,10 @@ static bool try_merge(Launch &A, Launch &B, i64 slide, i64 max_cells,
     cat64(A.hpmin, B.hpmin);
     cat64(A.habs, B.habs);
     A.Rb = std::max(A.Rb, B.Rb);
+    if (B.has_progress) {   // the later fire's progress covers the earlier
+        A.has_progress = 1;
+        A.progress_wid = B.progress_wid;
+    }
     A.blk = std::move(nblks[0]);
     for (int f = 1; f < n_fields; ++f)
         A.xblk[(size_t)(f - 1)] = std::move(nblks[(size_t)f]);
@@ -1713,6 +2094,49 @@ int wf_launch_peek(void *h, i64 *K, i64 *R, i64 *B, int *wire, int *rebase,
     Launch &L = c->queue.front();
     *K = L.K; *R = L.R; *B = L.B; *wire = L.wire; *rebase = L.rebase;
     *KP = L.KP; *cap = L.cap;
+    return 1;
+}
+
+// Stream-time firing (fire_on="stream" with a hold-back, core/windows.py):
+// once, right after wf_core_new / set_fields, before any process call.
+// Time-based sliding or tumbling windows of a plain sequential worker, no
+// arg-extremum; returns 0 where the core cannot (the caller refuses).
+int wf_core_set_stream(void *h, i64 holdback) {
+    Core *c = (Core *)h;
+    if (c->kind != TB || c->hopping || c->arg_mode || c->role != SEQ
+        || c->n_outer != 1 || c->n_inner != 1 || holdback < 0
+        || !c->keys.empty())
+        return 0;
+    c->stream_mode = 1;
+    // (a throughput stream by its nature: the full coalescing ladder's ring
+    // room from the first flush, as after a row-triggered one)
+    c->room_mult = kCoalesceLadderMax + 2;
+    c->holdback = holdback;
+    c->lag_max = holdback + c->win;
+    c->slot_w = std::max<i64>((holdback + c->win) >> 8, 1);
+    return 1;
+}
+
+// what a stream-time core counts, cumulative (node thread): rows taken in
+// behind their key's newest, rows dropped as late, rows inserted behind
+// shipped ones, rows held now and at their peak, fires, releases and the
+// nanoseconds inside them, nanoseconds closing windows, the keys met
+// (native_core.py _STREAM_STATS names them in this order)
+void wf_core_stream_stats(void *h, i64 *out) {
+    Core *c = (Core *)h;
+    out[0] = c->n_ooo; out[1] = c->n_late; out[2] = c->n_behind;
+    out[3] = c->n_held; out[4] = c->n_held_peak; out[5] = c->n_fires;
+    out[6] = c->n_merges; out[7] = c->merge_ns; out[8] = c->fire_ns;
+    out[9] = (i64)c->keys.size();
+}
+
+// whether the front launch ends a fire, and the last window it closed (call
+// between peek and take)
+int wf_launch_peek_progress(void *h, i64 *wid) {
+    Core *c = (Core *)h;
+    std::lock_guard<std::mutex> lk(c->qmu);
+    if (c->queue.empty() || !c->queue.front().has_progress) return 0;
+    *wid = c->queue.front().progress_wid;
     return 1;
 }
 

@@ -202,3 +202,40 @@ def hot_items_windows(rows, win_len, slide_len, key="auction", ts="ts"):
         if cur is None or n > cur[1] or (n == cur[1] and k < cur[0]):
             best[w] = (k, n)
     return {w: best[w] + (total[w], last[w]) for w in best}
+
+
+def watermark_windows(rows, win_len, slide_len, holdback, value="value",
+                      ts="ts", key="key", marker="marker"):
+    """Plain reference of a stream-time window stage with a hold-back
+    (``fire_on="stream"``, ``holdback=``) over rows in ARRIVAL order: the
+    clock is the highest ``ts`` taken in (marker rows too), the watermark
+    the clock less ``holdback``, and window ``w`` = ``[w*slide_len,
+    w*slide_len + win_len)``, for every integer ``w``, is closed once the
+    watermark has reached its end.  A row is summed into every window of its
+    key that is not closed, whatever came before it, and is late if all of
+    its windows are.  A stage whose first watermark lies before time 0
+    starts at the first window that watermark has not closed, else at
+    window 0.  Returns
+    ``({(key, w): sum}, [arrival index of every late row])``: a loop over
+    rows into a dictionary."""
+    sums, late = {}, []
+    clock = closed = None
+    for i, r in enumerate(rows):
+        t = int(r[ts])
+        if clock is None or t > clock:
+            clock = t
+            upto = (clock - holdback - win_len) // slide_len + 1
+            if closed is None:
+                closed = upto if clock - holdback < 0 else 0
+            closed = max(closed, upto)
+        if marker in r.dtype.names and r[marker]:
+            continue
+        first = max((t - win_len) // slide_len + 1, closed)
+        last = t // slide_len
+        if last < first:
+            late.append(i)
+            continue
+        for w in range(first, last + 1):
+            k = (int(r[key]), w)
+            sums[k] = sums.get(k, 0) + int(r[value])
+    return sums, late

@@ -282,3 +282,57 @@ def test_max_delay_ms_is_a_timer_on_the_planned_core(fn, opts, no_native,
     assert built(make_core_for(CB, fn(), **opts)) == want
     if want.core in ("native", "resident_py"):
         assert core.max_delay_s == 0.025
+
+
+# -- fire_on="stream": the plan goes through stream_fire_plan, which keeps it
+#    on the host cores and on the native resident core, or names the reason
+SLIDING = WindowSpec(10, 5, WinType.TB)
+HOPPING = WindowSpec(5, 10, WinType.TB)
+
+#: (id, spec, window function, make_core_for's options, the built plan or
+#: the refusal's text)
+STREAM_CASES = [
+    ("sum", SLIDING, isum, {}, CorePlan("native", "regular", False)),
+    ("sum-tumbling", TB, isum, dict(holdback=30),
+     CorePlan("native", "regular", False)),
+    ("ysb", SLIDING, ysb, dict(holdback=7),
+     CorePlan("native", "regular", False)),
+    ("two-fields", SLIDING, two_fields, {},
+     CorePlan("native", "multi", False)),
+    ("count", SLIDING, lambda: Reducer("count"), dict(holdback=7), HOST),
+    ("count+max-ts", SLIDING, count_max_ts, {}, HOST),
+    ("sum-cb", CB, isum, {}, "needs time-based windows"),
+    ("sum-hopping", HOPPING, isum, {}, "hopping windows stay on the host"),
+    ("sum-shards", SLIDING, isum, dict(shards=2), "one shard on one device"),
+    ("sum-mesh", SLIDING, isum, dict(mesh=True), "one shard on one device"),
+    ("sum-max-delay", SLIDING, isum, dict(max_delay_ms=5), "wall clock"),
+    ("arg", SLIDING, arg, {}, "arg-extremum family"),
+    ("sum-pallas", SLIDING, isum, dict(use_pallas=True),
+     "planned onto the 'restage' core"),
+    ("sum-no-native", SLIDING, isum, dict(native=None),
+     "planned onto the 'resident_py' core"),
+    ("five-fields", SLIDING, lambda: MultiReducer(
+        *[Reducer("sum", f, out_field="s" + f, **R) for f in "abcde"]), {},
+     "planned onto the 'resident_py' core"),
+    ("negative-holdback", SLIDING, isum, dict(holdback=-1), "span of time"),
+]
+
+
+@pytest.mark.parametrize("spec,fn,opts,want", [c[1:] for c in STREAM_CASES],
+                         ids=[c[0] for c in STREAM_CASES])
+def test_stream_fire_plan_table(spec, fn, opts, want, mesh, monkeypatch):
+    opts = dict(opts)
+    if opts.get("mesh"):
+        opts["mesh"] = mesh
+    if "native" in opts and opts.pop("native") is None:
+        monkeypatch.setenv("WF_NO_NATIVE_CORE", "1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        if isinstance(want, str):
+            with pytest.raises(ValueError, match=want):
+                make_core_for(spec, fn(), fire_on="stream", **opts)
+            return
+        core = make_core_for(spec, fn(), fire_on="stream", **opts)
+    assert built(core) == want
+    assert core.fire_on == "stream"
+    assert core.holdback == opts.get("holdback", 0)

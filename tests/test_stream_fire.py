@@ -242,10 +242,20 @@ def test_a_marker_row_moves_the_clock_and_folds_nothing(kind):
      "plain sequential"),
     # a *TPU pattern learns its core when the graph is built, before any
     # thread starts (make_core_for, plan_core's caller)
-    (lambda: WinSeqTPU(Reducer("sum"), 10, 5, WinType.TB,
-                       fire_on="stream").make_core(), "host window cores"),
-    (lambda: KeyFarmTPU(Reducer("sum"), 10, 5, WinType.TB,
-                        fire_on="stream").replicas(), "host window cores"),
+    # (a sum over time-based windows runs on the native resident core since
+    # PR 33, tests/test_late_events.py; what that core cannot keep refuses)
+    (lambda: WinSeqTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
+                       shards=2).make_core(), "one shard on one device"),
+    (lambda: KeyFarmTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
+                        use_pallas=True).replicas(), "host window cores"),
+    (lambda: WinSeqTPU(Reducer("sum"), 5, 10, WinType.TB,
+                       fire_on="stream").make_core(), "hopping windows"),
+    (lambda: WinSeqTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
+                       max_delay_ms=5).make_core(), "wall clock"),
+    (lambda: WinSeq(Reducer("sum"), 10, 5, WinType.TB, holdback=3),
+     "belongs to fire_on='stream'"),
+    (lambda: WinSeq(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
+                    holdback=-1), "span of time"),
     (lambda: WinSeqTPU(Reducer("count"), 10, 5, WinType.CB,
                        fire_on="stream").make_core(), "time-based"),
     (lambda: WinSeq(Reducer("sum"), 10, 5, WinType.TB, fire_on="watermark"),

@@ -268,13 +268,30 @@ class _WindowMixin:
         return self
 
 
+class _StreamTimeMixin:
+    """Win_Seq and Key_Farm (and their TPU forms) over time-based windows."""
+
+    def withStreamTime(self, holdback: int = 0):
+        """Close windows on the stage's time and not on each key's next row
+        (``fire_on="stream"``): a window closes once the stage's watermark
+        -- the highest ``ts`` it has taken in, less ``holdback`` in the
+        stream's ``ts`` units -- reaches its end.  A row is counted in every
+        window of its key still open, whatever came before it, so with a
+        hold-back at least the stream's disorder no row is dropped as late.
+        Without this call a key's window closes on that key's next row and
+        a row behind its key's newest is dropped, as in the reference."""
+        self._kw["fire_on"] = "stream"
+        self._kw["holdback"] = int(holdback)
+        return self
+
+
 class _WinParMixin:
     def withParallelism(self, n: int):
         self._kw["pardegree"] = int(n)
         return self
 
 
-class WinSeq_Builder(_Builder, _WindowMixin):
+class WinSeq_Builder(_Builder, _WindowMixin, _StreamTimeMixin):
     """builders.hpp:579."""
     _pattern_cls = WinSeq
 
@@ -327,7 +344,8 @@ class WinFarm_Builder(_NestingMixin, _Builder, _WindowMixin, _WinParMixin):
         return self
 
 
-class KeyFarm_Builder(_NestingMixin, _Builder, _WindowMixin, _WinParMixin):
+class KeyFarm_Builder(_NestingMixin, _Builder, _WindowMixin, _WinParMixin,
+                      _StreamTimeMixin):
     """builders.hpp:1193."""
     _pattern_cls = KeyFarm
     _nested_cls = KeyFarmOf
@@ -440,6 +458,13 @@ class _TPUMixin:
                           "its own tiling — argument ignored", stacklevel=2)
         return self
 
+    def withFlushRows(self, rows: int):
+        """Rows a resident-path worker ships in one launch at most (a launch
+        is also cut by ``withBatch``'s windows, and by every fire of a
+        stream-time stage)."""
+        self._kw["flush_rows"] = int(rows)
+        return self
+
     def withScratchpad(self, size: int):
         warnings.warn("withScratchpad applies to raw CUDA functors; the "
                       "JAX window-function contract passes columns instead "
@@ -482,7 +507,8 @@ class WinFarmTPU_Builder(_Builder, _WindowMixin, _WinParMixin, _TPUMixin):
         return self
 
 
-class KeyFarmTPU_Builder(_Builder, _WindowMixin, _WinParMixin, _TPUMixin):
+class KeyFarmTPU_Builder(_Builder, _WindowMixin, _WinParMixin, _TPUMixin,
+                         _StreamTimeMixin):
     """builders.hpp:1366 (KeyFarmGPU_Builder)."""
     _pattern_cls = KeyFarmTPU
 

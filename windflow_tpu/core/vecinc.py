@@ -692,14 +692,18 @@ class VecStreamCore:
     row never comes.  Here window ``w`` is ``[w*S, w*S + L)`` for every key
     (``check_stream_fire``), so the stage keeps ONE count of fired windows:
     a row at or past the end of window ``w`` fires ``w`` for every key that
-    holds rows in it, before the row itself is folded.  Defined for an
-    in-order stream; a row that arrives for a window already fired is not
-    folded into it (late, as the per-key cores drop a key's late rows).  An
+    holds rows in it, before the row itself is folded.  With a ``holdback``
+    the windows close on the watermark, the clock less the hold-back.  A row
+    is folded into every window of its key that has not fired, whatever
+    rows came before it; a row that arrives for a window already fired is
+    not folded into it, and one that finds all its windows fired is late:
+    dropped and counted (``late_rows``).  So on a stream whose disorder the
+    hold-back covers the results are those of the same rows in order.  An
     (key, window) pair without a row gives no result.
 
-    State: ``W = ceil(L/S)`` accumulator lanes a live key (lane ``w % W``;
-    the open windows are ``[fired, fired + W)``, so lanes never collide) and
-    the rows each lane holds.  Off a window boundary a chunk costs its fold;
+    State: ``W = ceil((L + holdback)/S)`` accumulator lanes a live key (lane
+    ``w % W``; the open windows are ``[fired, fired + W)``, so lanes never
+    collide) and the rows each lane holds.  Off a window boundary a chunk costs its fold;
     at one, O(live keys): the fire, then the retiring of every key whose
     lanes are all empty — its slot is compacted away (``SlotMap.retain``),
     and a key seen again starts as a new key.  So the state is bounded by the
@@ -712,17 +716,22 @@ class VecStreamCore:
 
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
-                 result_ts_slide: int = None):
+                 result_ts_slide: int = None, holdback: int = 0):
         assert vec_core_supported(spec, winfunc)
-        check_stream_fire(spec, config, role)
+        check_stream_fire(spec, config, role, holdback)
         self.spec = spec
+        #: the watermark is the clock less this (``run_stream_clock``)
+        self.holdback = int(holdback)
+        self._clock_started = False
         self.winfunc = winfunc
         self.is_nic = False
         self.result_schema = Schema(**winfunc.result_fields)
         self._result_dtype = self.result_schema.dtype()
         self._L = int(spec.win_len)
         self._S = int(spec.slide_len)
-        self._W = -(-self._L // self._S)
+        # the windows open at once: a row's last window lies under the
+        # clock, the first open one over the watermark
+        self._W = -(-(self._L + self.holdback) // self._S)
         self._ts_slide = int(result_ts_slide if result_ts_slide is not None
                              else spec.slide_len)
         parts = winfunc.parts if isinstance(winfunc, MultiReducer) else [winfunc]
@@ -744,6 +753,7 @@ class VecStreamCore:
         self.keys_retired = 0
         self.stream_fires = 0
         self.stream_fire_rows = 0
+        self.late_rows = 0
 
     @property
     def keys_live(self) -> int:
@@ -789,6 +799,8 @@ class VecStreamCore:
         lo = np.maximum((ts - L) // S + 1, self._fired)
         if (hi < lo).any():                 # late for every window of theirs
             live = np.flatnonzero(hi >= lo)
+            self.late_rows += len(rows) - len(live)
+            profile.add("late_rows", len(rows) - len(live))
             rows, hi, lo = rows[live], hi[live], lo[live]
         if not len(rows):
             return

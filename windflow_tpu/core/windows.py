@@ -190,11 +190,15 @@ class WindowSpec:
         return np.asarray(lwid, dtype=np.int64) * self.slide_len + self.win_len
 
 
-def check_stream_fire(spec: WindowSpec, config: PatternConfig, role: Role):
+def check_stream_fire(spec: WindowSpec, config: PatternConfig, role: Role,
+                      holdback: int = 0):
     """``fire_on="stream"`` is defined for time-based windows of a plain
     sequential worker (a Win_Seq, a Key_Farm's workers): every key's window
     ``w`` is ``[w*slide, w*slide + win)``, so one clock closes it for all.
-    Raises ``ValueError`` otherwise."""
+    ``holdback`` (>= 0, in the unit of ``ts``) keeps the stage's watermark
+    that far behind its clock.  Raises ``ValueError`` otherwise."""
+    if int(holdback) < 0:
+        raise ValueError(f"holdback is a span of time >= 0, not {holdback}")
     if spec.win_type is not WinType.TB:
         raise ValueError("fire_on='stream' needs time-based windows: a "
                          "count-based window has no time to close on")
@@ -205,20 +209,47 @@ def check_stream_fire(spec: WindowSpec, config: PatternConfig, role: Role):
                          f"staged one (role {role}, config {config})")
 
 
+def check_fire_on(fire_on: str, spec: WindowSpec, config: PatternConfig,
+                  role: Role, holdback: int = 0):
+    """The ``fire_on=`` / ``holdback=`` arguments of a window stage, checked
+    once for the patterns and the cores: ``"key"`` (no hold-back: a key's own
+    next row closes its window) or ``"stream"`` (:func:`check_stream_fire`)."""
+    if fire_on not in ("key", "stream"):
+        raise ValueError(f"fire_on is 'key' or 'stream', not {fire_on!r}")
+    if fire_on == "stream":
+        check_stream_fire(spec, config, role, holdback)
+    elif holdback:
+        raise ValueError("holdback= belongs to fire_on='stream': a key's "
+                         "own next row closes its window otherwise")
+
+
 def run_stream_clock(core, batch: np.ndarray, fold) -> list:
     """One chunk through a stream-time core (``fire_on="stream"``): the
-    stage's clock runs row by row.  The real rows before the first row at or
-    past the end of the next window to fire (``core._next_end``) go to
-    ``fold(rows, ts)``; then ``core._fire(ts of that row)`` fires what the
-    clock has passed, and the rest of the chunk is looked at again.  Marker
-    rows move the clock and are folded nowhere.  Returns the result batches
-    ``fold`` and ``_fire`` returned, in order."""
+    stage's clock -- the highest ``ts`` taken in -- runs row by row, and its
+    watermark is the clock less ``core.holdback``.  The real rows before the
+    first row that takes the watermark to the end of the next window to
+    fire (``core._next_end``) go to ``fold(rows, ts)``; then
+    ``core._fire(watermark)`` fires what the watermark has passed, and the
+    rest of the chunk is looked at again.  Marker rows move the clock and
+    are folded nowhere.  A stage starts at window 0, as the per-key cores
+    do, unless its first watermark lies before time 0: then window ``w`` is
+    ``[w*S, w*S + L)`` for every integer ``w`` from the first one that
+    watermark has not closed, so that no row at or past it loses a window.
+    Returns the result batches ``fold`` and ``_fire`` returned, in order."""
     ts = np.ascontiguousarray(batch["ts"], dtype=np.int64)
     real = ~batch[MARKER_FIELD]
+    hold = core.holdback
     outs = []
     lo, n = 0, len(batch)
+    if n and not core._clock_started:
+        core._clock_started = True
+        spec = core.spec
+        if int(ts[0]) - hold < 0:
+            first = (int(ts[0]) - hold - spec.win_len) // spec.slide_len + 1
+            core._fired = first
+            core._next_end = first * spec.slide_len + spec.win_len
     while lo < n:
-        hit = ts[lo:] >= core._next_end
+        hit = ts[lo:] >= core._next_end + hold
         cut = lo + int(np.argmax(hit)) if hit.any() else n
         if cut > lo:
             rows, at = batch[lo:cut], ts[lo:cut]
@@ -228,6 +259,6 @@ def run_stream_clock(core, batch: np.ndarray, fold) -> list:
             if len(rows):
                 outs.extend(fold(rows, at) or ())
         if cut < n:
-            outs.extend(core._fire(int(ts[cut])))
+            outs.extend(core._fire(int(ts[cut]) - hold))
         lo = cut
     return outs

@@ -33,7 +33,7 @@ import numpy as np
 
 from .tuples import MARKER_FIELD, Schema, progress_row
 from .windows import (PatternConfig, Role, WindowSpec, WinType,
-                      check_stream_fire, run_stream_clock)
+                      check_fire_on, run_stream_clock)
 from ..ops.functions import WindowFunction, WindowUpdate
 from ..utils import profile
 
@@ -68,17 +68,21 @@ class WinSeqCore:
 
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
-                 result_ts_slide: int = None, fire_on: str = "key"):
+                 result_ts_slide: int = None, fire_on: str = "key",
+                 holdback: int = 0):
         self.spec = spec
-        if fire_on not in ("key", "stream"):
-            raise ValueError(f"fire_on is 'key' or 'stream', not {fire_on!r}")
+        check_fire_on(fire_on, spec, config, role, holdback)
         #: ``key``: a key's window fires when that key's next row arrives
         #: (win_seq.hpp's triggerer).  ``stream``: on the stage's time, the
-        #: highest position taken in on any key, and a key without an open
-        #: window is forgotten (``run_stream_clock``, ``_fire``)
+        #: highest position taken in on any key, less ``holdback``; a row is
+        #: folded into every window of its key that has not fired, whatever
+        #: came before it, and a key without an open window is forgotten
+        #: (``run_stream_clock``, ``_fold_stream``, ``_fire``)
         self.fire_on = fire_on
         if fire_on == "stream":
-            check_stream_fire(spec, config, role)
+            self.holdback = int(holdback)
+            self._clock_started = False
+            self.late_rows = 0
             self._fired = 0                 # windows the stage has fired
             self._next_end = int(spec.win_len)
             self.keys_live_peak = self.keys_retired = 0
@@ -239,14 +243,18 @@ class WinSeqCore:
         row, retire the keys with no window left open."""
         with profile.span("stream_fire"):
             first = self._fired
-            upto = int(self.spec.fired_before(now))
+            upto = max(first, (now - self.spec.win_len)
+                       // self.spec.slide_len + 1)
             outs = []
             for key, st in self._keys.items():
                 to = min(upto, st.next_lwid)
                 if to > st.n_fired:
-                    lwids = np.arange(st.n_fired, to, dtype=np.int64)
+                    lwids = self._holding_rows(
+                        st, np.arange(st.n_fired, to, dtype=np.int64))
                     st.n_fired = to
-                    outs.append(self._emit_windows(key, st, lwids, eos=False))
+                    if len(lwids):
+                        outs.append(
+                            self._emit_windows(key, st, lwids, eos=False))
             self._fired = upto
             self._next_end = int(self.spec.win_end(upto))
             if len(outs) > 1 and upto - first > 1:
@@ -269,7 +277,74 @@ class WinSeqCore:
             profile.add("keys_retired", len(gone))
         return outs
 
+    def _holding_rows(self, st: _KeyState, lwids: np.ndarray) -> np.ndarray:
+        """Those of a key's windows ``lwids`` that hold a row (stream-time
+        stages give no result for the others; an incremental window's
+        accumulator without one is dropped here)."""
+        if self.is_nic:
+            p = st.archive.positions
+            has = (np.searchsorted(p, self.spec.win_end(lwids), side="left")
+                   > np.searchsorted(p, self.spec.win_start(lwids),
+                                     side="left"))
+        else:
+            has = np.fromiter((int(lw) in st.inc_last_ts for lw in lwids),
+                              dtype=bool, count=len(lwids))
+            for lw in lwids[~has]:
+                st.inc_accs.pop(int(lw), None)
+        return lwids[has]
+
+    def _fold_stream(self, key: int, rows: np.ndarray):
+        """A key's rows of one chunk on a stream-time stage, in any order:
+        into the archive at their place (NIC) or into the accumulator of
+        every window of theirs that has not fired (INC).  A row whose
+        windows have all fired is late: dropped and counted.  Nothing fires
+        here: the stage's watermark does that (``_fire``)."""
+        spec = self.spec
+        pos = rows["ts"].astype(np.int64)
+        keep = pos // spec.slide_len >= self._fired
+        if spec.is_hopping:
+            in_win = pos % spec.slide_len < spec.win_len   # not in a gap
+            n_late = int(np.count_nonzero(in_win & ~keep))
+            keep &= in_win
+        else:
+            n_late = len(rows) - int(np.count_nonzero(keep))
+        if n_late:
+            self.late_rows += n_late
+            profile.add("late_rows", n_late)
+        if not keep.all():
+            rows, pos = rows[keep], pos[keep]
+        if not len(rows):
+            return None
+        if len(rows) > 1 and (np.diff(pos) < 0).any():
+            order = np.argsort(pos, kind="stable")
+            rows, pos = rows[order], pos[order]
+        st = self._state(key)
+        st.rcv_counter += len(rows)
+        st.last_pos = max(st.last_pos, int(pos[-1]))
+        if self.is_nic:
+            arch = st.archive
+            if len(arch) and pos[0] < arch.positions[-1]:
+                arch.insert_sorted(rows)
+            else:
+                arch.append(rows)
+        new_next = max(st.next_lwid, int(pos[-1]) // spec.slide_len + 1)
+        created = range(st.next_lwid, new_next)
+        st.next_lwid = new_next
+        if not self.is_nic:
+            for lw in created:
+                st.inc_accs[lw] = self.winfunc.init(key, st.first_gwid + lw)
+            for lw, acc in st.inc_accs.items():
+                lo = np.searchsorted(pos, spec.win_start(lw), side="left")
+                hi = np.searchsorted(pos, spec.win_end(lw), side="left")
+                if hi > lo:
+                    self.winfunc.update_many(key, st.first_gwid + lw,
+                                             rows[lo:hi], acc)
+                    st.inc_last_ts[lw] = int(rows["ts"][hi - 1])
+        return None
+
     def _process_key(self, key: int, rows: np.ndarray):
+        if self.fire_on == "stream":
+            return self._fold_stream(key, rows)
         spec = self.spec
         st = self._state(key)
         pos = rows[self.pos_field].astype(np.int64)
@@ -447,7 +522,14 @@ class WinSeqCore:
                 continue
             lwids = np.arange(st.n_fired, st.next_lwid, dtype=np.int64)
             st.n_fired = st.next_lwid
-            r = self._emit_windows(key, st, lwids, eos=True)
+            stream = self.fire_on == "stream"
+            if stream:
+                lwids = self._holding_rows(st, lwids)
+                if not len(lwids):
+                    continue
+            # (a stream-time window may end before its key's last row: it
+            # is evaluated over its own range, not to the archive's end)
+            r = self._emit_windows(key, st, lwids, eos=not stream)
             if r is not None:  # device cores enqueue instead of returning
                 outs.append(r)
         if not outs:

@@ -143,6 +143,14 @@ def _bind(lib):
     lib.wf_launch_peek_arg.argtypes = [ctypes.c_void_p, p_i64, p_i64]
     lib.wf_launch_peek_cut.restype = ctypes.c_int
     lib.wf_launch_peek_cut.argtypes = [ctypes.c_void_p, p_int, p_i64]
+    lib.wf_core_fast_rows.restype = i64
+    lib.wf_core_fast_rows.argtypes = [ctypes.c_void_p]
+    lib.wf_core_set_stream.restype = ctypes.c_int
+    lib.wf_core_set_stream.argtypes = [ctypes.c_void_p, i64]
+    lib.wf_core_stream_stats.restype = None
+    lib.wf_core_stream_stats.argtypes = [ctypes.c_void_p, p_i64]
+    lib.wf_launch_peek_progress.restype = ctypes.c_int
+    lib.wf_launch_peek_progress.argtypes = [ctypes.c_void_p, p_i64]
     lib.wf_core_fired_pending.restype = i64
     lib.wf_core_fired_pending.argtypes = [ctypes.c_void_p]
     lib.wf_core_flush_early.restype = i64

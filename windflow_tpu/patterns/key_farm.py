@@ -22,12 +22,12 @@ class KeyFarm(_Pattern):
                  pardegree=2, name="key_farm", incremental=None,
                  result_fields=None, routing=None,
                  config: PatternConfig = None, role: Role = Role.SEQ,
-                 fire_on: str = "key"):
+                 fire_on: str = "key", holdback: int = 0):
         super().__init__(name, pardegree, routing or default_routing)
         self._seq_template = WinSeq(
             winfunc, win_len, slide_len, win_type, name=f"{name}_kf",
             incremental=incremental, result_fields=result_fields,
-            config=config, role=role, fire_on=fire_on)
+            config=config, role=role, fire_on=fire_on, holdback=holdback)
         #: ``"stream"``: each worker closes its windows on its own clock
         #: (WinSeq), and the workers' results merge on their progress rows
         self.fire_on = fire_on

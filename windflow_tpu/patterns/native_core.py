@@ -20,7 +20,7 @@ import weakref
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD, Schema
+from ..core.tuples import MARKER_FIELD, Schema, progress_row
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..ops.functions import NO_ARG_ID, ArgReducer, Reducer
 from ..utils import profile
@@ -40,6 +40,12 @@ _DISPATCH_WINDOW = 8
 _EARLY_SHARE = 0.5
 #: FlushTrigger (wf_native.cpp): what cut a launch, on its launch record
 _TRIGGERS = ("natural", "early", "forced", "eos")
+
+#: what wf_core_stream_stats writes, in its order (cumulative but for
+#: ``rows_held`` and ``keys``, which are levels)
+_STREAM_STATS = ("rows_out_of_order", "late_rows", "rows_reinserted",
+                 "rows_held", "rows_held_peak", "watermark_fires",
+                 "merges", "merge_ns", "fire_ns", "keys")
 
 _U64 = (1 << 64) - 1
 
@@ -118,13 +124,15 @@ class NativeResidentCore:
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
                  depth: int = 8, compute_dtype=None, shards: int = 1,
                  overlap: bool = True, worker_index: int = 0,
-                 max_delay_ms=None, mesh=None):
+                 max_delay_ms=None, mesh=None, fire_on: str = "key",
+                 holdback: int = 0):
         from ..native import load
         from ..ops.functions import MultiReducer
         from ..ops.resident import make_executor
         from .win_seq_tpu import (_ARGEXT_PLACEMENT, _arg_parts,
                                   _argext_misplaced, _executor_family,
-                                  _native_refusal, acc_dtypes_by_field,
+                                  _native_refusal, _native_stream_refusal,
+                                  acc_dtypes_by_field,
                                   resolve_worker_device, split_pos_max)
         self._lib = load()
         if self._lib is None:
@@ -173,6 +181,27 @@ class NativeResidentCore:
         self._ship_fields = tuple(dict.fromkeys(
             p.field for p in self._dev_parts))
         family = _executor_family("native", self._dev_parts)
+        #: ``"stream"``: windows close on the stage's watermark -- the
+        #: highest ``ts`` taken in less ``holdback`` -- and rows reach
+        #: archive and ring once it has passed them (wf_core_set_stream)
+        self.fire_on = fire_on
+        self.holdback = int(holdback)
+        if fire_on == "stream":
+            refusal = _native_stream_refusal(
+                spec, config, role, family, mesh is not None, shards,
+                max_delay_ms, holdback)
+            if refusal is not None:
+                raise ValueError(refusal)
+            #: what the node reports (docs/OBSERVABILITY.md), cumulative
+            self.rows_out_of_order = self.late_rows = 0
+            self.rows_reinserted = self.rows_held_peak = 0
+            self.watermark_fires = 0
+            self.keys_live = self.keys_live_peak = 0
+            self._stream_seen = dict.fromkeys(_STREAM_STATS, 0)
+            self._stream_buf = (ctypes.c_longlong * len(_STREAM_STATS))()
+            #: launch id -> the last window its fire closed (ship thread
+            #: writes, node thread pops at harvest)
+            self._progress = {}
         #: beyond ``regular`` the C++ core stages per-field rectangles
         self._multi = family != "regular"
         self.spec = spec
@@ -245,7 +274,8 @@ class NativeResidentCore:
                                           False))
         #: control-plane keyed migration (control/rescale.py) — an
         #: instance attr, not a class attr: it follows the loaded library
-        self.keyed_migratable = self.has_state_abi
+        #: (a stream-time core's held-back rows are not in the state ABI)
+        self.keyed_migratable = self.has_state_abi and fire_on != "stream"
         #: dataflow metrics sink, mirrored by Supervisor.attach_all (the
         #: core itself has no dataflow reference)
         self._obs_metrics = None
@@ -327,6 +357,12 @@ class NativeResidentCore:
                     raise TypeError(
                         f"native core archives {got} carried columns, the "
                         f"arg-extremum needs {self._carry_cols}")
+        if self.fire_on == "stream":
+            for h in self._hs:
+                if not self._lib.wf_core_set_stream(h, self.holdback):
+                    raise ValueError(
+                        "the native core refused fire_on='stream' for "
+                        f"{self.spec} with holdback {self.holdback}")
         self._harr = (ctypes.c_void_p * self.shards)(*self._hs)
 
     def _start_ship_threads(self):
@@ -493,6 +529,10 @@ class NativeResidentCore:
                 "the arg-extremum runs on the native resident core only: "
                 f"fields {self._ship_fields + self._carry_cols} must be "
                 "int64 columns of the stream")
+        if self.fire_on == "stream":
+            raise TypeError(
+                "fire_on='stream' runs on the native resident core only: "
+                f"fields {self._ship_fields} must be int64 columns")
         from .win_seq_tpu import ResidentWinSeqCore
         self._delegate = ResidentWinSeqCore(self.spec, self.reducer,
                                             **self._args)
@@ -629,6 +669,11 @@ class NativeResidentCore:
             return {"kind": "native_delegate",
                     "inner": self._delegate.state_snapshot()}
         self._require_state_abi("epoch snapshots")
+        if self.fire_on == "stream":
+            from ..runtime.node import SnapshotUnsupported
+            raise SnapshotUnsupported(
+                "a stream-time native core's held-back rows are not in the "
+                "native state ABI yet")
         if self.max_delay_s is not None:
             # wall-clock flushes make replay launch boundaries (and so
             # emission seqs) nondeterministic — same decline as the
@@ -857,7 +902,9 @@ class NativeResidentCore:
                 for h in self._hs:
                     self._lib.wf_core_force_flush(h)
                 self._last_flush_t = now
-        if (b is not None and not launched and self.max_delay_s is None
+        if self.fire_on == "stream":
+            self._sync_stream_stats()
+        elif (b is not None and not launched and self.max_delay_s is None
                 and not self._recovery_mode):
             self._flush_early()
         if self._overlap:
@@ -877,6 +924,31 @@ class NativeResidentCore:
                     if beats % 20 == 0:
                         for q in self._ship_qs:
                             q.put(("ship", None))
+
+    def _sync_stream_stats(self):
+        """What the C++ stream-time core (one shard: ``_native_stream_refusal``)
+        counted since the last call, onto this core's cumulative attributes
+        and ``utils/profile`` (the two spans are timed inside the C++ call,
+        so they carry no annotation and no ring record)."""
+        buf = self._stream_buf
+        self._lib.wf_core_stream_stats(self._hs[0], buf)
+        tot = dict(zip(_STREAM_STATS, buf))
+        new = {name: tot[name] - self._stream_seen[name] for name in tot}
+        self._stream_seen = tot
+        for name in ("rows_out_of_order", "late_rows", "rows_reinserted",
+                     "watermark_fires"):
+            setattr(self, name, tot[name])
+            if new[name]:
+                profile.add(name, new[name])
+        self.rows_held_peak = tot["rows_held_peak"]
+        self.keys_live = tot["keys"]
+        self.keys_live_peak = max(self.keys_live_peak, tot["keys"])
+        if new["merges"]:
+            profile.record("reorder_merge", new["merge_ns"] / 1e9,
+                           new["merges"])
+        if new["watermark_fires"]:
+            profile.record("watermark_fire", new["fire_ns"] / 1e9,
+                           new["watermark_fires"])
 
     def _flush_early(self):
         """Device-following flush: `flush_rows` and `batch_len` are the
@@ -912,6 +984,8 @@ class NativeResidentCore:
         this tail)."""
         for h in self._hs:
             self._lib.wf_core_eos(h)
+        if self.fire_on == "stream":
+            self._sync_stream_stats()
         return self._drain_entries()
 
     def flush(self) -> np.ndarray:
@@ -996,6 +1070,10 @@ class NativeResidentCore:
         lib.wf_launch_peek_cut(handle, ctypes.byref(trigger),
                                ctypes.byref(rb))
         KPp, Rb = KP.value, max(_bucket(max(R, 1)), rb.value)
+        if self.fire_on == "stream":
+            wid = ctypes.c_longlong()
+            if lib.wf_launch_peek_progress(handle, ctypes.byref(wid)):
+                self._progress[tag[0]] = wid.value
         habs = shifts = None
         if self._arg is not None:
             # each window's absolute first row, and the rows' slide when
@@ -1192,4 +1270,11 @@ class NativeResidentCore:
                     res[part.out_field] = finalize_window_values(
                         part, hpm if part.op == "max" else hpmn, hlen)
             outs.append(res)
+            if self.fire_on == "stream" and tag[0] in self._progress:
+                # the launch ended a fire: what follows is at or past the
+                # end of the last window it closed
+                wid = self._progress.pop(tag[0])
+                outs.append(progress_row(
+                    self._result_dtype, wid,
+                    wid * self.result_ts_slide + self.spec.win_len))
         return outs[0] if len(outs) == 1 else np.concatenate(outs)

@@ -7,7 +7,8 @@ from __future__ import annotations
 import numpy as np
 
 from ..core.tuples import MARKER_FIELD
-from ..core.windows import PatternConfig, Role, WindowSpec, WinType
+from ..core.windows import (PatternConfig, Role, WindowSpec, WinType,
+                            check_fire_on)
 from ..core.winseq import WinSeqCore
 from ..ops.functions import WindowFunction, WindowUpdate, as_window_function, as_window_update
 from ..runtime.node import Node, RuntimeContext
@@ -153,10 +154,20 @@ class WinSeqNode(Node):
         if st is not None:
             st.bump("progress_sent", n_progress)
             st.bump("burst_batches", pieces)
-            core = self.core
-            for name in ("keys_live", "keys_live_peak", "keys_retired",
-                         "stream_fires", "stream_fire_rows"):
-                st.counters[name] = int(getattr(core, name))
+            self._stream_counters(st)
+
+    def _stream_counters(self, st):
+        """A stream-time core's own counts onto the node's (the cores report
+        what they have: a host core holds no row back, the native core
+        retires no key)."""
+        core = self.core
+        for name in ("keys_live", "keys_live_peak", "keys_retired",
+                     "stream_fires", "stream_fire_rows", "late_rows",
+                     "rows_out_of_order", "rows_reinserted",
+                     "rows_held_peak", "watermark_fires"):
+            value = getattr(core, name, None)
+            if value is not None:
+                st.counters[name] = int(value)
 
     def _emit_each(self, outs, triggering=False):
         fired = 0
@@ -211,7 +222,7 @@ class WinSeq(_Pattern):
                  incremental: bool = None, result_fields=None,
                  config: PatternConfig = None, role: Role = Role.SEQ,
                  map_indexes=(0, 1), result_ts_slide: int = None,
-                 fire_on: str = "key"):
+                 fire_on: str = "key", holdback: int = 0):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self.result_ts_slide = result_ts_slide
@@ -219,13 +230,12 @@ class WinSeq(_Pattern):
         #: reference's triggerer).  ``"stream"`` (time-based windows): on
         #: the stage's time, the highest ``ts`` taken in on any key; quiet
         #: keys are retired and a progress row follows every fire
-        #: (core/vecinc.VecStreamCore, core/winseq.py)
-        if fire_on not in ("key", "stream"):
-            raise ValueError(f"fire_on is 'key' or 'stream', not {fire_on!r}")
-        if fire_on == "stream":
-            from ..core.windows import check_stream_fire
-            check_stream_fire(self.spec, config, role)
+        #: (core/vecinc.VecStreamCore, core/winseq.py).  ``holdback`` (in
+        #: the unit of ``ts``) keeps the stage's watermark that far behind
+        #: its clock, so rows up to that much out of order are all counted
+        check_fire_on(fire_on, self.spec, config, role, holdback)
         self.fire_on = fire_on
+        self.holdback = int(holdback)
         # resolve the function flavour (meta_utils.hpp signature deduction
         # becomes an explicit `incremental` switch)
         if incremental is True:
@@ -250,17 +260,19 @@ class WinSeq(_Pattern):
         # (debugging / differential runs).
         import os
         from ..core.vecinc import make_vec_core, vec_core_supported
+        stream = ({"holdback": self.holdback}
+                  if self.fire_on == "stream" else {})
         if (vec_core_supported(self.spec, self.winfunc)
                 and not os.environ.get("WF_NO_VECCORE")):
             return make_vec_core(
                 self.spec, self.winfunc, fire_on=self.fire_on,
                 config=self.config, role=self.role,
                 map_indexes=self.map_indexes,
-                result_ts_slide=self.result_ts_slide)
+                result_ts_slide=self.result_ts_slide, **stream)
         core = WinSeqCore(self.spec, self.winfunc, config=self.config,
                           role=self.role, map_indexes=self.map_indexes,
                           result_ts_slide=self.result_ts_slide,
-                          fire_on=self.fire_on)
+                          fire_on=self.fire_on, **stream)
         if self.incremental:
             core.use_incremental()
         return core

@@ -1019,18 +1019,54 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
                                            else [winfunc]), on_mesh)
 
 
-def stream_fire_plan(plan: CorePlan, fire_on: str) -> CorePlan:
-    """``plan`` itself if its core honours ``fire_on``; a ``ValueError`` for
-    ``fire_on="stream"`` on a device core: stream-time firing and the
-    retiring of quiet keys live in the host cores (core/vecinc.py,
-    core/winseq.py) until the resident cores learn them."""
-    if fire_on == "stream" and plan.core != "host":
-        raise ValueError(
-            "fire_on='stream' runs on the host window cores only (a "
-            "count, or min/max over the time field): this function is "
-            f"planned onto the {plan.core!r} core, which fires a key's "
-            "window on that key's next row")
-    return plan
+def _native_stream_refusal(spec, config, role, family, on_mesh, shards,
+                           max_delay_ms=None, holdback=0):
+    """Why the native resident core cannot close these windows on the
+    stage's watermark (``fire_on="stream"``), or None when it can: the C++
+    core holds rows back per key and releases them in order behind ONE
+    clock, for time-based sliding or tumbling windows of a plain sequential
+    worker whose stats the ``regular`` and ``multi`` families evaluate."""
+    from ..core.windows import check_stream_fire
+    check_stream_fire(spec, config, role, holdback)
+    if spec.is_hopping:
+        return ("fire_on='stream' on the device needs sliding or tumbling "
+                "windows: hopping windows stay on the host window cores")
+    if family == "argext":
+        return ("fire_on='stream' on the device: the arg-extremum family "
+                "reads its winning row back from an archive that follows "
+                "arrival, which a held-back row would reorder")
+    if on_mesh or int(shards) != 1:
+        return ("fire_on='stream' on the device runs one shard on one "
+                "device: a mesh or key shards would each keep a clock")
+    if max_delay_ms is not None:
+        return ("fire_on='stream' on the device follows the stream's time; "
+                "max_delay_ms follows the wall clock")
+    return None
+
+
+def stream_fire_plan(plan: CorePlan, fire_on: str, spec=None, config=None,
+                     role=Role.SEQ, shards=1, max_delay_ms=None,
+                     holdback=0) -> CorePlan:
+    """``plan`` itself if its core honours ``fire_on``.  ``"stream"`` runs
+    on the host window cores and on the native resident core
+    (:func:`_native_stream_refusal` says where that one cannot); a
+    ``ValueError`` names the reason for every other core: the Python
+    resident core and the restaging core fire a key's window on that key's
+    next row."""
+    if fire_on != "stream" or plan.core == "host":
+        return plan
+    if plan.core == "native":
+        why = _native_stream_refusal(
+            spec, config, role, plan.family, plan.mesh, shards, max_delay_ms,
+            holdback)
+        if why is None:
+            return plan
+        raise ValueError(why)
+    raise ValueError(
+        "fire_on='stream' runs on the host window cores (a count, or "
+        "min/max over the time field) and on the native resident core: "
+        f"this function is planned onto the {plan.core!r} core, which "
+        "fires a key's window on that key's next row")
 
 
 def _stream_burst_rows(batch_len, flush_rows) -> int:
@@ -1066,23 +1102,25 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                   device=None, depth=None, use_pallas=False,
                   compute_dtype=None, use_resident=None,
                   flush_rows=1 << 20, shards=1, worker_index=0, mesh=None,
-                  max_delay_ms=None, fire_on="key"):
+                  max_delay_ms=None, fire_on="key", holdback=0):
     """Build the window core :func:`plan_core` names.  With ``mesh`` the
     resident ring is sharded ``P('kf', None)`` across the mesh devices (one
     dispatch serves every key group over ICI); ``max_delay_ms`` is a timer
-    on that core, whichever it is; ``fire_on="stream"`` is the host cores'
+    on that core, whichever it is; ``fire_on="stream"`` with its
+    ``holdback`` is the host cores' and the native resident core's
     (:func:`stream_fire_plan` refuses the others)."""
     plan = stream_fire_plan(
         plan_core(spec, winfunc, use_pallas=use_pallas,
                   use_resident=use_resident, mesh=mesh, shards=shards,
-                  native=_native_core_fields()), fire_on)
+                  native=_native_core_fields()), fire_on, spec, config, role,
+        shards, max_delay_ms, holdback)
     if plan.core == "host":
         from .win_seq import WinSeq
         return WinSeq(winfunc, spec.win_len, spec.slide_len,
                       spec.win_type, config=config, role=role,
                       map_indexes=map_indexes,
                       result_ts_slide=result_ts_slide,
-                      fire_on=fire_on).make_core()
+                      fire_on=fire_on, holdback=holdback).make_core()
     kw = dict(batch_len=batch_len, config=config, role=role,
               map_indexes=map_indexes, result_ts_slide=result_ts_slide,
               compute_dtype=compute_dtype)
@@ -1096,7 +1134,8 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
               worker_index=worker_index, mesh=mesh, max_delay_ms=max_delay_ms)
     if plan.core == "native":
         from .native_core import NativeResidentCore
-        return NativeResidentCore(spec, winfunc, shards=shards, **kw)
+        return NativeResidentCore(spec, winfunc, shards=shards,
+                                  fire_on=fire_on, holdback=holdback, **kw)
     return ResidentWinSeqCore(spec, winfunc, **kw)
 
 
@@ -1121,7 +1160,7 @@ class WinSeqTPU(_Pattern):
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
                  depth=None, use_pallas=False, compute_dtype=None,
                  use_resident=None, flush_rows=1 << 20, shards=1,
-                 mesh=None, max_delay_ms=None, fire_on="key"):
+                 mesh=None, max_delay_ms=None, fire_on="key", holdback=0):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self._burst_rows = _stream_burst_rows(batch_len, flush_rows)
@@ -1132,7 +1171,8 @@ class WinSeqTPU(_Pattern):
                         compute_dtype=compute_dtype,
                         use_resident=use_resident, flush_rows=flush_rows,
                         shards=shards, mesh=mesh,
-                        max_delay_ms=max_delay_ms, fire_on=fire_on)
+                        max_delay_ms=max_delay_ms, fire_on=fire_on,
+                        holdback=holdback)
         self.winfunc = winfunc
 
     def make_core(self):
@@ -1184,17 +1224,19 @@ class KeyFarmTPU(_DeviceCoreFactory, KeyFarm):
                  routing=None, config=None, role=Role.SEQ, device=None,
                  depth=None, use_pallas=False, compute_dtype=None,
                  use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
-                 fire_on="key"):
+                 fire_on="key", holdback=0):
         self._raw_fn = winfunc
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
                             use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows,
-                            max_delay_ms=max_delay_ms, fire_on=fire_on)
+                            max_delay_ms=max_delay_ms, fire_on=fire_on,
+                            holdback=holdback)
         self.burst_rows = _stream_burst_rows(batch_len, flush_rows)
         super().__init__(_host_standin(winfunc), win_len, slide_len, win_type,
                          pardegree=pardegree, name=name, routing=routing,
-                         config=config, role=role, fire_on=fire_on)
+                         config=config, role=role, fire_on=fire_on,
+                         holdback=holdback)
 
 
 class PaneFarmTPU(PaneFarm):

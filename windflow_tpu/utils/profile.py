@@ -218,6 +218,15 @@ def add(name: str, value: float = 1.0):
             _val[name] += value
 
 
+def record(name: str, seconds: float, calls: int = 1):
+    """Account time measured elsewhere (inside a native call) to the span
+    `name`: the accumulators only -- no annotation, no ring record."""
+    if ENABLED:
+        with _mu:
+            _acc[name] += seconds
+            _cnt[name] += calls
+
+
 def amend(phase: str, launch: int, since_end=None, **fields):
     """Add `fields` to the newest record of `phase` that names `launch` and
     carries extra fields — what became of the span's result after it
