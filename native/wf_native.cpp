@@ -30,8 +30,10 @@
 #endif
 #include <memory>
 #include <mutex>
+#include <new>
 #include <thread>
 #include <unordered_map>
+#include <utility>
 #include <vector>
 
 using i64 = long long;
@@ -116,13 +118,35 @@ struct Hold {
     i64 lb0 = 0;
 };
 
+// An archive column.  Its allocator default-initialises, so a resize() that
+// grows the column leaves the new rows unwritten instead of zero-filling
+// them: the bulk path (process_fast) writes every one of them in the pass
+// that follows, or rolls them back.  Every other use means what it meant on
+// a std::vector<i64> (assign and insert take their value, erase and reserve
+// touch no new row).
+template <class T>
+struct NoInitAlloc : std::allocator<T> {
+    template <class U> struct rebind { using other = NoInitAlloc<U>; };
+    NoInitAlloc() = default;
+    template <class U> NoInitAlloc(const NoInitAlloc<U> &) {}
+    template <class U> void construct(U *p) { ::new ((void *)p) U; }
+    template <class U, class... A> void construct(U *p, A &&...a) {
+        ::new ((void *)p) U(std::forward<A>(a)...);
+    }
+};
+using Col = std::vector<i64, NoInitAlloc<i64>>;
+
 struct KeyState {
     // live archive: SoA ordered by pos, purge advances `start`
-    // (core/archive.py's KeyArchive, reference stream_archive.hpp)
-    std::vector<i64> pos, ts, val;
+    // (core/archive.py's KeyArchive, reference stream_archive.hpp).  `ts`
+    // holds rows only on a core that can be asked for an arbitrary row's
+    // own timestamp later (Core::keep_ts: a count-based arg-extremum);
+    // everywhere else a time-based row's `pos` is its ts and a count-based
+    // window's result ts is carried in `tail_ts`
+    Col pos, ts, val;
     // extra payload columns (fields 1..F-1 of a multi-field core);
     // empty on the default single-field cores so per-key memory stays flat
-    std::vector<std::vector<i64>> xval;
+    std::vector<Col> xval;
     size_t start = 0;
     i64 appended = 0;      // rows ever archived (absolute row domain)
     i64 launched = 0;      // rows already shipped to the device ring
@@ -131,6 +155,10 @@ struct KeyState {
     i64 initial_id = 0, first_gwid = 0;
     i64 next_lwid = 0, n_fired = 0, emit_counter = 0;
     i64 marker_pos = NEG_INF, marker_ts = 0;
+    // the newest archived row (count-based cores): every window still to
+    // fire ends above it, so it is the last row of each of them that the
+    // next row closes -- what a CB result's ts is read from (emit_windows)
+    i64 tail_pos = NEG_INF, tail_ts = 0;
     i64 purge_pos = NEG_INF;  // purge deferred to flush (rebase invariant)
     // per-field value range of UNSHIPPED rows, tracked at append time so
     // flush()'s wire-dtype choice needs no re-scan of the pending rows
@@ -180,7 +208,7 @@ struct KeyState {
 
     size_t live() const { return pos.size() - start; }
 
-    void purge() {
+    void purge(bool keep_ts) {
         if (purge_pos <= NEG_INF) return;
         const i64 *p = pos.data() + start;
         size_t cut = std::lower_bound(p, p + live(), purge_pos) - p;
@@ -197,7 +225,7 @@ struct KeyState {
         // amortised compaction (archive.py:purge_below)
         if (start > 4096 && start > live()) {
             pos.erase(pos.begin(), pos.begin() + start);
-            ts.erase(ts.begin(), ts.begin() + start);
+            if (keep_ts) ts.erase(ts.begin(), ts.begin() + start);
             val.erase(val.begin(), val.begin() + start);
             for (auto &xv : xval)
                 xv.erase(xv.begin(), xv.begin() + start);
@@ -280,6 +308,13 @@ struct Core {
     int arg_mode = 0, n_carry = 0, arg_field = 0;
     i64 window_rows = 0, cap_floor = 0, rb_floor = 0, kp_lo = 8;
     int n_cols() const { return n_fields + n_carry; }
+    // whether the archives hold a `ts` column: only a count-based
+    // arg-extremum's result carries the ts of an arbitrary archived row
+    // (wf_core_arg_gather); set with arg_mode, before any row
+    bool keep_ts = false;
+    // int64 columns a row takes in its key's archive: pos, the shipped
+    // fields, the carried ones, ts where it is kept
+    int archive_cols() const { return 1 + n_cols() + (keep_ts ? 1 : 0); }
     // the shape of the last natural launch: rectangle width, windows a key
     // (regular launches) and wire dtypes.  An early flush pads itself up to
     // it, so a launch cut short of flush_rows runs the step executable the
@@ -378,7 +413,21 @@ struct Core {
         return st;
     }
 
-    void emit_windows(KeyState &st, i64 key, i64 w_from, i64 w_to, bool eos) {
+    // Where a count-based window's result ts is read from: the ts of its
+    // last row, the newest row below its end.  A window fires on the first
+    // row at or past its end, so that is the newest row archived before the
+    // trigger (`tail_*`; the newest row of all at eos()) -- or, on the bulk
+    // path, which fires a block's windows after copying all of it, a row of
+    // that block: the key's `m` rows there hold positions q0 .. q0+m-1 and
+    // their ts stand `stride` bytes apart in the input chunk from `ts0`.
+    struct LastRow {
+        i64 tail_pos, tail_ts;
+        i64 q0 = 0, m = 0, stride = 0;
+        const u8 *ts0 = nullptr;
+    };
+
+    void emit_windows(KeyState &st, i64 key, i64 w_from, i64 w_to, bool eos,
+                      const LastRow &last) {
         const i64 stride = n_outer * n_inner;
         const i64 *p = st.pos.data() + st.start;
         const size_t n = st.live();
@@ -393,9 +442,15 @@ struct Core {
             if (kind == TB) {
                 out_ts = gwid * result_ts_slide + win - 1;
             } else {
-                size_t idx = std::lower_bound(p, p + n, e_abs) - p;
-                if (idx > 0 && p[idx - 1] >= s_abs)
-                    out_ts = st.ts[st.start + idx - 1];
+                // 0 when the last row below the end lies below the start
+                // too (an empty window)
+                i64 lp = last.tail_pos, lt = last.tail_ts;
+                if (last.m > 0 && e_abs > last.q0) {
+                    const i64 r = std::min(e_abs - 1 - last.q0, last.m - 1);
+                    lp = last.q0 + r;
+                    std::memcpy(&lt, last.ts0 + r * last.stride, 8);
+                }
+                if (lp >= s_abs) out_ts = lt;
             }
             // marker rows overwrite the result ts of windows they fall
             // below — CB only: TB keeps the closed form above
@@ -448,7 +503,7 @@ struct Core {
         const size_t rows = (size_t)(cap_floor / 2);
         for (auto &st : keys) {
             st.pos.reserve(rows);
-            st.ts.reserve(rows);
+            if (keep_ts) st.ts.reserve(rows);
             st.val.reserve(rows);
             for (auto &xv : st.xval) xv.reserve(rows);
         }
@@ -727,7 +782,7 @@ struct Core {
             queue.push_back(std::move(L));
         }
         ++launches_made;
-        for (auto &st : keys) st.purge();
+        for (auto &st : keys) st.purge(keep_ts);
         pend_rows = 0;
         wrow.clear(); wlo.clear(); wlen.clear();
         hkey = {}; hid = {}; hts = {}; hpm = {}; hpmn = {};
@@ -749,7 +804,8 @@ struct Core {
         // bench hot loop and stays specialized; multi-field streams (none
         // of which are key-periodic in the tracked workloads) take the
         // general loop
-        if (kind != CB || hopping || n < 2 || n_cols() > 1) return 0;
+        if (kind != CB || hopping || n < 2 || n_cols() > 1 || arg_mode)
+            return 0;
         i64 key0;
         std::memcpy(&key0, base + o_key, 8);
         i64 P = -1;
@@ -798,7 +854,7 @@ struct Core {
         if (batch_len < (i64)1 << 40)
             block = std::min(block, batch_len * slide);
         block = std::max(block, P);
-        std::vector<i64 *> pw((size_t)P), tw((size_t)P), vw((size_t)P);
+        std::vector<i64 *> pw((size_t)P), vw((size_t)P);
         std::vector<i64> mcnt((size_t)P), save_next((size_t)P);
         std::vector<size_t> save_sz((size_t)P);
         i64 consumed = 0;
@@ -813,11 +869,10 @@ struct Core {
                 KeyState &st = *sts[(size_t)k];
                 save_sz[(size_t)k] = st.pos.size();
                 save_next[(size_t)k] = nextpos[(size_t)k];
+                // (grown unwritten: Col's allocator fills nothing in)
                 st.pos.resize(st.pos.size() + (size_t)m);
-                st.ts.resize(st.ts.size() + (size_t)m);
                 st.val.resize(st.val.size() + (size_t)m);
                 pw[(size_t)k] = st.pos.data() + save_sz[(size_t)k];
-                tw[(size_t)k] = st.ts.data() + save_sz[(size_t)k];
                 vw[(size_t)k] = st.val.data() + save_sz[(size_t)k];
             }
             // fused verify + copy: one sequential pass over the block
@@ -826,17 +881,23 @@ struct Core {
             i64 bmin = INT64_MAX, bmax = INT64_MIN;
             i64 done = 0;
             for (; done < take; ++done) {
-                i64 k, id, t, v;
+                i64 k, id, v;
                 std::memcpy(&k, rp + o_key, 8);
                 std::memcpy(&id, rp + o_id, 8);
                 if (k != key_of[(size_t)idx] || id != nextpos[(size_t)idx]
                     || rp[o_marker])
                     break;
-                std::memcpy(&t, rp + o_ts, 8);
                 std::memcpy(&v, rp + o_val, 8);
                 if (v < bmin) bmin = v;
                 if (v > bmax) bmax = v;
-                *tw[(size_t)idx]++ = t;
+                // the columns were grown unwritten, so nothing has pulled
+                // their lines into the cache (the zero-fill used to): ask
+                // for the line after next as a column enters a new one, or
+                // each of the 2P write streams waits on its own misses
+                if ((((uintptr_t)vw[(size_t)idx]) & 63) == 0)
+                    __builtin_prefetch(vw[(size_t)idx] + 16, 1);
+                if ((((uintptr_t)pw[(size_t)idx]) & 63) == 0)
+                    __builtin_prefetch(pw[(size_t)idx] + 16, 1);
                 *vw[(size_t)idx]++ = v;
                 *pw[(size_t)idx]++ = nextpos[(size_t)idx]++;
                 rp += itemsize;
@@ -848,7 +909,6 @@ struct Core {
                 for (i64 k = 0; k < P; ++k) {
                     KeyState &st = *sts[(size_t)k];
                     st.pos.resize(save_sz[(size_t)k]);
-                    st.ts.resize(save_sz[(size_t)k]);
                     st.val.resize(save_sz[(size_t)k]);
                     nextpos[(size_t)k] = save_next[(size_t)k];
                 }
@@ -870,9 +930,18 @@ struct Core {
                 st.note_range0(bmin, bmax);
             }
             for (i64 k = 0; k < P; ++k) {
-                if (mcnt[(size_t)k] == 0) continue;
+                const i64 m = mcnt[(size_t)k];
+                if (m == 0) continue;
                 KeyState &st = *sts[(size_t)k];
                 const i64 endpos = st.last_pos;
+                // the key's rows of this block, as the input chunk holds
+                // them: the ts column is read there and not archived
+                const LastRow last{
+                    st.tail_pos, st.tail_ts, save_next[(size_t)k], m,
+                    P * itemsize,
+                    base + (consumed + (k - idx0 + P) % P) * itemsize + o_ts};
+                st.tail_pos = endpos;
+                std::memcpy(&st.tail_ts, last.ts0 + (m - 1) * last.stride, 8);
                 if (endpos >= st.next_create) {
                     st.next_lwid = (endpos - st.initial_id) / slide + 1;
                     st.next_create = st.next_lwid * slide + st.initial_id;
@@ -883,7 +952,8 @@ struct Core {
                     const i64 from = st.n_fired;
                     st.n_fired = to;
                     st.fire_pos = to * slide + win + st.initial_id;
-                    emit_windows(st, key_of[(size_t)k], from, to, false);
+                    emit_windows(st, key_of[(size_t)k], from, to, false,
+                                 last);
                     if ((i64)hkey.size() >= batch_len) flush();
                 }
             }
@@ -905,7 +975,6 @@ struct Core {
     // the caller)
     inline void archive_row(KeyState &st, const i64 *rec) {
         st.pos.push_back(rec[0]);
-        st.ts.push_back(rec[0]);
         st.val.push_back(rec[1]);
         const int nc = n_cols();
         for (int f = 1; f < nc; ++f)
@@ -1134,7 +1203,6 @@ struct Core {
             st.pos.begin() + (ptrdiff_t)st.start, st.pos.end(), rec[0])
             - st.pos.begin());
         st.pos.insert(st.pos.begin() + (ptrdiff_t)at, rec[0]);
-        st.ts.insert(st.ts.begin() + (ptrdiff_t)at, rec[0]);
         st.val.insert(st.val.begin() + (ptrdiff_t)at, rec[1]);
         for (int f = 1; f < n_cols(); ++f)
             st.xval[(size_t)(f - 1)].insert(
@@ -1222,6 +1290,9 @@ struct Core {
             const bool mk = rp[o_marker] != 0;
             KeyState &st = state(key);
             if (st.neutral) st.neutral = false;
+            // the newest row archived before this one: the last row of the
+            // windows this one closes
+            const LastRow last{st.tail_pos, st.tail_ts};
             const i64 pos = (kind == CB) ? id : tsv;
             if (pos < st.last_pos) continue;       // out-of-order drop
             st.last_pos = pos;
@@ -1233,8 +1304,10 @@ struct Core {
                 if (hopping && ((pos - st.initial_id) % slide) >= win)
                     continue;                      // hopping gap
                 st.pos.push_back(pos);
-                st.ts.push_back(tsv);
+                if (keep_ts) st.ts.push_back(tsv);
                 st.val.push_back(val);
+                st.tail_pos = pos;
+                st.tail_ts = tsv;
                 i64 vrow[kMaxFields];
                 vrow[0] = val;
                 for (int f = 1; f < n_cols(); ++f) {
@@ -1257,7 +1330,7 @@ struct Core {
                 const i64 from = st.n_fired;
                 st.n_fired = to;
                 st.fire_pos = to * slide + win + st.initial_id;
-                emit_windows(st, key, from, to, false);
+                emit_windows(st, key, from, to, false, last);
                 if ((i64)hkey.size() >= batch_len) flush();
             }
             // rows-only flush: giant windows accumulate rows long before
@@ -1282,7 +1355,8 @@ struct Core {
             if (st.n_fired < st.next_lwid) {
                 const i64 from = st.n_fired;
                 st.n_fired = st.next_lwid;
-                emit_windows(st, rowkey[r], from, st.next_lwid, true);
+                emit_windows(st, rowkey[r], from, st.next_lwid, true,
+                             LastRow{st.tail_pos, st.tail_ts});
             }
         }
         flush(EOS);
@@ -1454,6 +1528,13 @@ i64 wf_core_process(void *h, const void *base, i64 n, i64 itemsize,
 // rows the key-periodic bulk path (process_fast) has taken so far
 i64 wf_core_fast_rows(void *h) { return ((Core *)h)->fast_rows; }
 
+// bytes one row takes in its key's archive (8 a column: pos, the shipped
+// fields, the carried ones, ts on a count-based arg-extremum alone); fixed
+// once the core is configured
+i64 wf_core_archive_row_bytes(void *h) {
+    return 8 * (i64)((Core *)h)->archive_cols();
+}
+
 // single source of truth for the staging bound (Python guards read it)
 i64 wf_max_fields(void) { return kMaxFields; }
 
@@ -1481,12 +1562,13 @@ i64 wf_core_set_fields(void *h, i64 n_fields, const int *max_wires) {
 // call, after wf_core_set_fields.  The core then archives `n_carry` more
 // int64 columns per row (offsets follow the shipped fields' in the _f
 // entry point; never shipped) and keeps every fired window's rows until
-// wf_core_arg_gather has read its winner.  `arg_field` is the index among
-// the shipped fields of the one the extremum runs over; `window_rows` the
-// rows one key's window is declared to hold on this core (0: not declared,
-// the ring grows as the stream shows).  Returns the carry count accepted (a
-// short return, or -1 for an arg_field that is no shipped field, is a
-// refusal).
+// wf_core_arg_gather has read its winner; a count-based core also archives
+// every row's own ts from then on (keep_ts), which the winner's result may
+// carry.  `arg_field` is the index among the shipped fields of the one the
+// extremum runs over; `window_rows` the rows one key's window is declared
+// to hold on this core (0: not declared, the ring grows as the stream
+// shows).  Returns the carry count accepted (a short return, or -1 for an
+// arg_field that is no shipped field, is a refusal).
 i64 wf_core_set_arg(void *h, i64 n_carry, i64 arg_field, i64 window_rows) {
     Core *c = (Core *)h;
     if (arg_field < 0 || arg_field >= c->n_fields) return -1;
@@ -1494,6 +1576,7 @@ i64 wf_core_set_arg(void *h, i64 n_carry, i64 arg_field, i64 window_rows) {
     int nc = (int)(n_carry < 0 ? 0 : n_carry);
     if (nc > kMaxCarry) nc = kMaxCarry;
     c->arg_mode = 1;
+    c->keep_ts = c->kind == CB;
     c->n_carry = nc;
     c->arg_field = (int)arg_field;
     c->kp_lo = 1;
@@ -1531,17 +1614,17 @@ i64 wf_core_arg_gather(void *h, i64 B, const i64 *hkey, const i64 *habs,
         if (nties[i] > 1) {
             ++tied;
             if (c->n_carry > 0) {
-                const std::vector<i64> &vals =
+                const Col &vals =
                     c->arg_field == 0
                         ? st.val : st.xval[(size_t)(c->arg_field - 1)];
-                const std::vector<i64> &ids =
-                    st.xval[(size_t)(c->n_fields - 1)];
+                const Col &ids = st.xval[(size_t)(c->n_fields - 1)];
                 const size_t lo = st.start + (size_t)j0;
                 for (size_t q = lo; q < lo + (size_t)hlen[i]; ++q)
                     if (vals[q] == ext[i] && ids[q] < ids[j]) j = q;
             }
         }
-        out_ts[i] = st.ts[j];
+        // (a time-based row's position is its ts)
+        out_ts[i] = c->keep_ts ? st.ts[j] : st.pos[j];
         for (int k = 0; k < c->n_carry; ++k)
             out_cols[k * B + i] =
                 st.xval[(size_t)(c->n_fields - 1 + k)][j];
@@ -1686,10 +1769,10 @@ i64 wf_core_eos(void *h) { return ((Core *)h)->eos(); }
 void wf_core_release(void *h) {
     Core *c = (Core *)h;
     for (auto &st : c->keys) {
-        std::vector<i64>().swap(st.pos);
-        std::vector<i64>().swap(st.ts);
-        std::vector<i64>().swap(st.val);
-        for (auto &xv : st.xval) std::vector<i64>().swap(xv);
+        Col().swap(st.pos);
+        if (c->keep_ts) Col().swap(st.ts);
+        Col().swap(st.val);
+        for (auto &xv : st.xval) Col().swap(xv);
         st.start = 0;
         st.held.clear();
         st.hold.reset();
@@ -2392,8 +2475,15 @@ i64 wf_keyscan_ordered(const i64 *slots, const i64 *pos, i64 n,
 //
 // kStateAbiVersion stamps every blob and is exposed via wf_abi_version();
 // tests compare it against the source constant to catch a stale .so.
+// Version 2: a key's record carries its newest archived row's position and
+// ts (tail_pos, tail_ts: what a count-based window's result ts is read
+// from) and a `ts` array only where the core keeps the column
+// (Core::keep_ts); the config echo names the archive's column count.  A
+// version-1 blob (a `ts` array in every record, no tail) is refused with
+// -4 like any other version: a durable checkpoint an older library wrote
+// does not restore into this one.
 
-static const i64 kStateAbiVersion = 1;
+static const i64 kStateAbiVersion = 2;
 static const i64 kStateMagicCore = 0x57464E5354415445LL;  // "WFNSTATE"
 static const i64 kStateMagicKey = 0x57464E534B455931LL;   // "WFNSKEY1"
 
@@ -2456,7 +2546,7 @@ inline int find_row(Core *c, i64 key) {
 }
 
 inline i64 key_rec_i64s(const Core *c, const KeyState &st) {
-    return 11 + (i64)st.live() * (2 + c->n_cols());
+    return 13 + (i64)st.live() * c->archive_cols();
 }
 
 void export_key(const Core *c, const KeyState &st, i64 key, StateWr &w) {
@@ -2471,9 +2561,11 @@ void export_key(const Core *c, const KeyState &st, i64 key, StateWr &w) {
     w.put(st.emit_counter);
     w.put(st.marker_pos);
     w.put(st.marker_ts);
+    w.put(st.tail_pos);
+    w.put(st.tail_ts);
     w.put(L);
     w.put_arr(st.pos.data() + st.start, (size_t)L);
-    w.put_arr(st.ts.data() + st.start, (size_t)L);
+    if (c->keep_ts) w.put_arr(st.ts.data() + st.start, (size_t)L);
     w.put_arr(st.val.data() + st.start, (size_t)L);
     for (int f = 1; f < c->n_cols(); ++f)
         w.put_arr(st.xval[(size_t)(f - 1)].data() + st.start, (size_t)L);
@@ -2486,6 +2578,7 @@ bool import_key(Core *c, StateRd &r) {
     const i64 next_lwid = r.get(), n_fired = r.get();
     const i64 emit_counter = r.get(), marker_pos = r.get();
     const i64 marker_ts = r.get();
+    const i64 tail_pos = r.get(), tail_ts = r.get();
     const i64 L = r.get();
     if (!r.ok || L < 0 || appended < L) return false;
     KeyState &st = c->state(key);
@@ -2493,10 +2586,12 @@ bool import_key(Core *c, StateRd &r) {
                          && st.last_pos <= NEG_INF))
         return false;   // live state on the importing side: refuse
     st.pos.assign((size_t)L, 0);
-    st.ts.assign((size_t)L, 0);
     st.val.assign((size_t)L, 0);
     if (!r.get_arr(st.pos.data(), (size_t)L)) return false;
-    if (!r.get_arr(st.ts.data(), (size_t)L)) return false;
+    if (c->keep_ts) {
+        st.ts.assign((size_t)L, 0);
+        if (!r.get_arr(st.ts.data(), (size_t)L)) return false;
+    }
     if (!r.get_arr(st.val.data(), (size_t)L)) return false;
     for (int f = 1; f < c->n_cols(); ++f) {
         auto &xv = st.xval[(size_t)(f - 1)];
@@ -2513,6 +2608,8 @@ bool import_key(Core *c, StateRd &r) {
     st.emit_counter = emit_counter;
     st.marker_pos = marker_pos;
     st.marker_ts = marker_ts;
+    st.tail_pos = tail_pos;
+    st.tail_ts = tail_ts;
     st.purge_pos = NEG_INF;
     st.pend_any = false;
     st.neutral = false;
@@ -2526,8 +2623,8 @@ bool import_key(Core *c, StateRd &r) {
 
 }  // namespace
 
-// Whole-core blob: header (magic, abi, win, slide, kind, role, n_fields,
-// room_mult, launches_made, n_keys) + one record per non-neutral key.
+// Whole-core blob: header (magic, abi, win, slide, kind, role, archive
+// columns, room_mult, launches_made, n_keys) + one record per non-neutral key.
 // Size/export return -1 when the core is not drained.
 i64 wf_core_state_size(void *h) {
     Core *c = (Core *)h;
@@ -2548,7 +2645,7 @@ i64 wf_core_state_export(void *h, void *buf, i64 cap) {
     w.put(c->slide);
     w.put((i64)c->kind);
     w.put((i64)c->role);
-    w.put((i64)c->n_cols());
+    w.put((i64)c->archive_cols());
     w.put(c->room_mult);
     w.put(c->launches_made);
     i64 nk = 0;
@@ -2580,7 +2677,7 @@ i64 wf_core_state_import(void *h, const void *buf, i64 nbytes) {
     if (r.get() != kStateAbiVersion) return -4;
     if (r.get() != c->win || r.get() != c->slide
         || r.get() != (i64)c->kind || r.get() != (i64)c->role
-        || r.get() != (i64)c->n_cols())
+        || r.get() != (i64)c->archive_cols())
         return -5;
     c->room_mult = r.get();
     c->launches_made = r.get();
@@ -2632,7 +2729,7 @@ i64 wf_core_key_export(void *h, i64 key, void *buf, i64 cap) {
     StateWr w{(u8 *)buf, (const u8 *)buf + cap};
     w.put(kStateMagicKey);
     w.put(kStateAbiVersion);
-    w.put((i64)c->n_cols());
+    w.put((i64)c->archive_cols());
     export_key(c, c->keys[(size_t)row], key, w);
     if (!w.ok) return -1;
     return (i64)(w.p - (u8 *)buf);
@@ -2650,7 +2747,7 @@ i64 wf_core_key_neutralize(void *h, i64 key) {
     if (row < 0) return -2;
     KeyState &st = c->keys[(size_t)row];
     st.pos.clear();
-    st.ts.clear();
+    if (c->keep_ts) st.ts.clear();
     st.val.clear();
     for (auto &xv : st.xval) xv.clear();
     st.start = 0;
@@ -2660,6 +2757,8 @@ i64 wf_core_key_neutralize(void *h, i64 key) {
     st.emit_counter = (c->role == MAP) ? c->map_idx0 : 0;
     st.marker_pos = NEG_INF;
     st.marker_ts = 0;
+    st.tail_pos = NEG_INF;
+    st.tail_ts = 0;
     st.purge_pos = NEG_INF;
     st.held.clear();
     st.pend_any = false;
@@ -2675,7 +2774,7 @@ i64 wf_core_key_import(void *h, const void *buf, i64 nbytes) {
     StateRd r{(const u8 *)buf, (const u8 *)buf + nbytes};
     if (r.get() != kStateMagicKey) return -3;
     if (r.get() != kStateAbiVersion) return -4;
-    if (r.get() != (i64)c->n_cols()) return -5;
+    if (r.get() != (i64)c->archive_cols()) return -5;
     if (!r.ok || !import_key(c, r)) return -6;
     // the imported rows are in no ring: force a rebase at the next flush
     c->KP = 0;

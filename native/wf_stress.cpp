@@ -20,16 +20,21 @@
 //                     import / neutralize, and the refusal codes) while
 //                     a background thread hammers an unrelated queue —
 //                     any accidental shared global between the
-//                     subsystems becomes a TSan report.
+//                     subsystems becomes a TSan report.  Each case
+//                     starts with the bulk path (bulk_case): archive
+//                     columns grown unwritten, a block rolled back, the
+//                     windows' ts read from the input chunk.
 //
 //   ./wf_stress_tsan --seed 1 --n 4
 
 #include <atomic>
+#include <cstddef>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
 #include <thread>
+#include <utility>
 #include <vector>
 
 using i64 = int64_t;
@@ -56,6 +61,7 @@ i64 wf_core_process(void *h, const void *base, i64 n, i64 itemsize,
                     i64 o_key, i64 o_id, i64 o_ts, i64 o_marker,
                     i64 o_val);
 i64 wf_core_force_flush(void *h);
+i64 wf_core_fast_rows(void *h);
 int wf_launch_peek(void *h, i64 *K, i64 *R, i64 *B, int *wire, int *rebase,
                    i64 *KP, i64 *cap);
 void wf_launch_take(void *h, void *blk, i64 *offs, int32_t *wrows,
@@ -247,9 +253,11 @@ static void *new_core() {
                        (i64)1 << 20, 64, 2);
 }
 
-static void drain_launches(void *h) {
-    // consume every queued launch (the ship thread's role): export
-    // refuses while c->queue is non-empty
+// consume every queued launch (the ship thread's role): export refuses
+// while c->queue is non-empty.  `each(B, hkey, hid, hts)` sees every
+// launch's result headers
+template <class F>
+static void drain_launches(void *h, F each) {
     i64 K, R, B, KP, cap;
     int wire, rebase;
     while (wf_launch_peek(h, &K, &R, &B, &wire, &rebase, &KP, &cap) == 1) {
@@ -262,7 +270,12 @@ static void drain_launches(void *h) {
                        w4.data() + nb, w4.data() + 2 * nb, h8.data(),
                        h8.data() + nb, h8.data() + 2 * nb,
                        h8.data() + 3 * nb);
+        each(B, h8.data(), h8.data() + nb, h8.data() + 2 * nb);
     }
+}
+
+static void drain_launches(void *h) {
+    drain_launches(h, [](i64, const i64 *, const i64 *, const i64 *) {});
 }
 
 static void feed(void *h, i64 n_keys, i64 rows_per_key, i64 id0) {
@@ -283,7 +296,53 @@ static void feed(void *h, i64 n_keys, i64 rows_per_key, i64 id0) {
     drain_launches(h);
 }
 
+// The bulk path (key-periodic chunks) grows its archive columns unwritten
+// and rolls a block back on a pattern break; a window's result ts is read
+// from the input chunk, no ts column is archived.  Two chunks, the second
+// with two neighbours swapped mid-block: every full window's ts must be
+// its last row's (id 8w + 7 of its key).
+static void bulk_case(u64 seed, int tid) {
+    Rng r(seed + 7919 * (u64)(tid + 1));
+    const i64 P = r.range(2, 9), per = 40;
+    void *h = wf_core_new(8, 8, 0, 0, 0, 1, 8, 0, 1, 8, 0, 1, 8, 4, 64, 2);
+    i64 n_windows = 0;
+    for (int chunk = 0; chunk < 2; ++chunk) {
+        std::vector<Row> rows;
+        for (i64 i = chunk * per; i < (chunk + 1) * per; ++i)
+            for (i64 k = 0; k < P; ++k)
+                rows.push_back(Row{k, i, i * 7 + k, 0, (i * 31 + k) % 100});
+        if (chunk == 1)
+            std::swap(rows[(size_t)(P * 9)], rows[(size_t)(P * 9 + 1)]);
+        CHECK(wf_core_process(h, rows.data(), (i64)rows.size(),
+                              (i64)sizeof(Row), offsetof(Row, key),
+                              offsetof(Row, id), offsetof(Row, ts),
+                              offsetof(Row, marker),
+                              offsetof(Row, value)) >= 0, "bulk process");
+        wf_core_force_flush(h);
+        drain_launches(h, [&](i64 B, const i64 *hkey, const i64 *hid,
+                              const i64 *hts) {
+            for (i64 i = 0; i < B; ++i)
+                CHECK(hts[i] == (hid[i] * 8 + 7) * 7 + hkey[i],
+                      "window ts: key %lld id %lld ts %lld",
+                      (long long)hkey[i], (long long)hid[i],
+                      (long long)hts[i]);
+            n_windows += B;
+        });
+    }
+    // the first chunk whole and the second up to the broken block went
+    // the bulk way, the rest through the general loop
+    CHECK(wf_core_fast_rows(h) >= P * per
+              && wf_core_fast_rows(h) < 2 * P * per,
+          "fast_rows=%lld", (long long)wf_core_fast_rows(h));
+    // (the last window waits for a row past its end)
+    CHECK(n_windows == P * (2 * per / 8 - 1), "bulk windows=%lld",
+          (long long)n_windows);
+    wf_core_free(h);
+}
+
 static void state_abi_case(u64 seed, int tid) {
+    bulk_case(seed, tid);
+
     Rng r(seed + 31337 * (u64)(tid + 1));
     const i64 n_keys = r.range(2, 9);
     void *a = new_core();

@@ -319,6 +319,67 @@ def test_a_declared_window_sizes_the_ring_up_front(declared, monkeypatch):
         ArgReducer("max", "price", window_rows=0)
 
 
+@pytest.mark.parametrize("wt", [WinType.TB, WinType.CB], ids=["tb", "cb"])
+def test_the_winning_rows_own_ts_is_read_back(wt):
+    """The ts the result carries is the winning row's own.  On a time-based
+    window that is the row's position, and the native archive keeps no ts
+    column; on a count-based one it is anything the row says (here: in no
+    order at all), the one case the archive keeps the column for."""
+    n, win = 4000, 250
+    rows = _bids(n, 21, span=16 * win, prices=(100, 400), plant=9)
+    if wt is WinType.CB:
+        rows["ts"] = np.random.default_rng(3).integers(0, 10**6, n)
+
+    def fn():
+        return MultiReducer(
+            ArgReducer("max", "price", id_out="bid", carry=(("ts", "when"),),
+                       value_range=(0, 1000)),
+            Reducer("count", out_field="count"))
+
+    core = make_core_for(WindowSpec(win, win, wt), fn(), batch_len=1,
+                         flush_rows=256)
+    # position, price, tie-break id -- and ts on the count-based core alone
+    assert core.archive_row_bytes == (32 if wt is WinType.CB else 24)
+    outs = []
+    for i in range(0, n, 700):
+        outs.append(core.process(rows[i:i + 700]))
+        _wait_harvests(core)
+    got = np.concatenate(outs + [core.flush()])
+    host = WinSeq(fn(), win, win, wt).make_core()
+    assert np.array_equal(
+        got, np.concatenate([host.process(rows), host.flush()]))
+    pos = rows["ts"] if wt is WinType.TB else rows["id"]
+    full = got[got["count"] > 0]
+    assert len(full) >= 15
+    for r in full:
+        w = rows[(pos >= r["id"] * win) & (pos < (r["id"] + 1) * win)]
+        _v, bid, _a, when = _loop(w, "max")
+        assert (int(r["bid"]), int(r["when"])) == (bid, when)
+
+
+@pytest.mark.parametrize("kind,n_fields,n_carry,arg,want", [
+    ("cb", 1, 0, False, 16), ("cb", 1, 0, True, 24), ("tb", 1, 0, True, 16),
+    ("cb", 2, 0, False, 24), ("cb", 1, 2, True, 40), ("tb", 2, 3, True, 48)])
+def test_archive_row_bytes_by_the_cores_own_arguments(kind, n_fields, n_carry,
+                                                      arg, want):
+    """8 bytes a column: the position, each shipped field, each carried
+    column, and ts where the core is a count-based arg-extremum -- which it
+    knows from how it was configured, before its first row."""
+    import ctypes
+    from windflow_tpu import native
+    lib = native.load()
+    h = lib.wf_core_new(8, 8, 0 if kind == "cb" else 1, 0, 0, 1, 8, 0, 1, 8,
+                        0, 1, 8, 64, 256, 2)
+    try:
+        wires = (ctypes.c_int * n_fields)(*[2] * n_fields)
+        assert lib.wf_core_set_fields(h, n_fields, wires) == n_fields
+        if arg:
+            assert lib.wf_core_set_arg(h, n_carry, 0, 0) == n_carry
+        assert lib.wf_core_archive_row_bytes(h) == want
+    finally:
+        lib.wf_core_free(h)
+
+
 # -- refusals ---------------------------------------------------------------
 
 def test_a_path_that_cannot_run_it_refuses_loudly(monkeypatch):

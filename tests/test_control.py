@@ -339,11 +339,15 @@ def test_threshold_driven_rescale_differential():
     assert per_key(got) == per_key(oracle)
 
 
-def test_native_keyfarm_threshold_rescale_matches_oracle():
+@pytest.mark.parametrize("own_ts", [False, True], ids=["ts_is_id", "own_ts"])
+def test_native_keyfarm_threshold_rescale_matches_oracle(own_ts):
     """ISSUE 17 acceptance: a threshold-driven Rescale on a Key_Farm of
     native C++ cores migrates per-key wf_core state at the epoch
     barrier — per-key result sequences identical to the fixed-width
-    oracle (order, drops, dups checked per key)."""
+    oracle (order, drops, dups checked per key).  `own_ts`: every row
+    carries a timestamp of its own and the results' ts are compared too —
+    a migrated key's first window after the barrier takes its ts from a
+    row archived on the old owner, which the key's blob carries."""
     from windflow_tpu.native import enabled
     lib = enabled()
     if lib is None or not getattr(lib, "wf_has_state_abi", False):
@@ -353,8 +357,14 @@ def test_native_keyfarm_threshold_rescale_matches_oracle():
 
     def build(out, **kw):
         pipe = MultiPipe("job", capacity=4, **kw)
-        pipe.add_source(Source(batches=lambda i: keyed_batches(),
-                               name="src"))
+
+        def batches(_i):
+            for b in keyed_batches():
+                if own_ts:
+                    b["ts"] = b["id"] * 7 + b["key"] * 3 + 11
+                yield b
+
+        pipe.add_source(Source(batches=batches, name="src"))
         pipe.add(KeyFarmTPU(Reducer("sum", "value"), 8, 4, pardegree=2,
                             batch_len=64, name="kf"))
 
@@ -362,7 +372,7 @@ def test_native_keyfarm_threshold_rescale_matches_oracle():
             if r is not None:
                 time.sleep(0.0002)    # slow sink: inbox depth drives the rule
                 out.append((int(r["key"]), int(r["id"]),
-                            int(r["value"])))
+                            (int(r["ts"]), int(r["value"]))))
         pipe.add_sink(Sink(sink, name="sink"))
         return pipe
 
@@ -390,6 +400,10 @@ def test_native_keyfarm_threshold_rescale_matches_oracle():
     hist = [h for fc in pipe.controller.farms for h in fc.history]
     assert hist, "threshold rule never fired"
     assert per_key(got) == per_key(oracle)
+    if own_ts:
+        # a full window's ts is its last row's: id 4w + 7 of its key
+        assert all(t == (4 * i + 7) * 7 + k * 3 + 11
+                   for k, i, (t, _v) in oracle[:200])
 
 
 def test_crash_after_rescale_restores_migrated_placement():

@@ -384,6 +384,152 @@ def test_native_periodic_fast_path_cross_chunk_gap():
     assert_equal_results(host, run_core(nat, batches))
 
 
+def _ts_of(keys, ids):
+    """A row's own timestamp, apart from its id and from any other key's."""
+    return np.asarray(ids) * 7 + np.asarray(keys) * 3 + 11
+
+
+def _periodic(n_keys, lo, m, rng):
+    keys = np.tile(np.arange(n_keys), m)
+    ids = np.repeat(np.arange(lo, lo + m), n_keys)
+    return batch_from_columns(
+        SCHEMA, key=keys, id=ids, ts=_ts_of(keys, ids),
+        value=rng.integers(-50, 100, size=m * n_keys).astype(np.int64))
+
+
+def _swap_pair(b, at):
+    """Two neighbours of different keys change places: the key period
+    breaks at row `at`, each key's own order survives."""
+    b = b.copy()
+    b[[at, at + 1]] = b[[at + 1, at]]
+    return b
+
+
+def _ts_case(case):
+    """(spec, core arguments, batches, how much of the stream the bulk path
+    of a one-shard core must take: "all", "some" or "none")."""
+    from windflow_tpu.core.tuples import MARKER_FIELD
+    rng = np.random.default_rng(len(case))
+    if case in ("bulk_blocks", "bulk_break"):
+        # 6 keys, blocks of 32 rows: a block ends inside a key period and
+        # inside a chunk, a chunk ends inside a block
+        bs = [_periodic(6, lo, min(97, 600 - lo), rng)
+              for lo in range(0, 600, 97)]
+        if case == "bulk_break":
+            bs[2] = _swap_pair(bs[2], 301)
+        return (WindowSpec(16, 4, WinType.CB),
+                dict(batch_len=8, flush_rows=500), bs,
+                "all" if case == "bulk_blocks" else "some")
+    if case in ("bulk_gaps", "general_gaps"):
+        # ids jump between chunks: one row closes several windows, the
+        # first of them on the chunk before's last row, the rest empty
+        bs = [_periodic(4, lo, 21, rng) for lo in (0, 50, 200)]
+        if case == "general_gaps":
+            bs = [_swap_pair(b, 0) for b in bs]
+        return (WindowSpec(8, 4, WinType.CB),
+                dict(batch_len=32, flush_rows=64), bs,
+                "all" if case == "bulk_gaps" else "none")
+    assert case == "markers_eos"
+    # a marker row a key closes windows on the newest archived row (it is
+    # archived itself nowhere); more rows; windows left open at the end
+    mk = batch_from_columns(SCHEMA, key=np.arange(3), id=[30, 31, 37],
+                            ts=[4000, 4001, 4002], value=np.zeros(3))
+    mk[MARKER_FIELD] = True
+    bs = [_periodic(3, 0, 21, rng), mk, _periodic(3, 48, 23, rng)]
+    return (WindowSpec(8, 4, WinType.CB),
+            dict(batch_len=16, flush_rows=48), bs, "all")
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("case", ["bulk_blocks", "bulk_break", "bulk_gaps",
+                                  "general_gaps", "markers_eos"])
+def test_native_cb_result_ts_is_the_host_cores(case, shards):
+    """A count-based window's result ts is its last row's, and the native
+    archive keeps no ts column to read it from: the bulk path reads it in
+    the input chunk (or carries it over from the block before), the general
+    loop carries the newest archived row's.  Bit-identical to the host core
+    on every path, with a ts that is no function of the id alone."""
+    spec, kw, batches, bulk = _ts_case(case)
+    host = run_core(WinSeqCore(spec, Reducer("sum")), batches)
+    assert len(np.unique(host["ts"])) > 12
+    nat = make_native(spec, Reducer("sum"), shards=shards, **kw)
+    assert_equal_results(host, run_core(nat, batches))
+    rows = sum(int((~b["marker"]).sum()) for b in batches)
+    if shards > 1 or bulk == "none":
+        assert nat.fast_rows == 0
+    elif bulk == "all":
+        assert nat.fast_rows == rows
+    else:
+        assert 0 < nat.fast_rows < rows
+
+
+@pytest.mark.parametrize("kind,fields,want", [
+    ("cb_sum", 1, 16), ("tb_sum", 1, 16), ("tb_multi", 2, 24),
+    ("cb_multi", 3, 32)])
+def test_archive_row_bytes_counts_the_columns(kind, fields, want):
+    """8 bytes a column: the position and each shipped field -- no ts (the
+    arg-extremum cores, which carry columns and on count-based windows keep
+    ts, are tests/test_argext.py's)."""
+    from windflow_tpu.ops.functions import MultiReducer
+    sch = Schema(a=np.int64, b=np.int64, c=np.int64)
+    wt = WinType.CB if kind.startswith("cb") else WinType.TB
+    fn = (Reducer("sum", "a") if fields == 1 else MultiReducer(*[
+        Reducer("sum", f, "s" + f, value_range=(0, 100))
+        for f in "abc"[:fields]]))
+    core = make_native(WindowSpec(8, 4, wt), fn)
+    assert core.archive_row_bytes == want
+    n = 40
+    b = batch_from_columns(sch, key=np.zeros(n), id=np.arange(n),
+                           ts=np.arange(n) * 2, a=np.arange(n) % 7,
+                           b=np.arange(n) % 5, c=np.arange(n) % 3)
+    host = WinSeqCore(WindowSpec(8, 4, wt), fn)
+    want_rows = np.concatenate([host.process(b), host.flush()])
+    got = np.concatenate([core.process(b), core.flush()])
+    assert np.array_equal(np.sort(got, order="id"),
+                          np.sort(want_rows, order="id"))
+
+
+@pytest.mark.parametrize("periodic", [True, False],
+                         ids=["periodic", "shuffled"])
+def test_the_window_workers_log_says_what_its_archive_took(periodic,
+                                                           tmp_path):
+    """``archive_row_bytes`` and ``fast_rows`` in the window worker's node
+    log: 16 bytes a row for a single-field sum, and every row of a
+    key-periodic stream through the bulk path (none of a shuffled one)."""
+    import json
+    from windflow_tpu.api import MultiPipe
+    from windflow_tpu.patterns.basic import Sink, Source
+    from windflow_tpu.patterns.win_seq_tpu import WinSeqTPU
+    rng = np.random.default_rng(2)
+    chunks = [_periodic(8, lo, 64, rng) for lo in range(0, 512, 64)]
+    if not periodic:
+        chunks = [_swap_pair(c, 0) for c in chunks]
+    got = []
+
+    def source(shipper):
+        for c in chunks:
+            shipper.push_batch(c.copy())
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        pipe = (MultiPipe("job", trace_dir=str(tmp_path))
+                .add_source(Source(source, SCHEMA, name="src"))
+                .add(WinSeqTPU(Reducer("sum"), 16, 4, WinType.CB,
+                               batch_len=64, flush_rows=256, name="win"))
+                .add_sink(Sink(lambda r: got.append(r.copy())
+                               if r is not None else None, vectorized=True)))
+        pipe.run_and_wait_end(timeout=120)
+    host = run_core(WinSeqCore(WindowSpec(16, 4, WinType.CB),
+                               Reducer("sum")), chunks)
+    assert_equal_results(host, np.sort(np.concatenate(got),
+                                       order=["key", "id"]))
+    logs = [json.loads(p.read_text()) for p in tmp_path.glob("*.log")]
+    (win,) = [n for n in logs if "archive_row_bytes" in n]
+    assert "win" in win["node"] and win["archive_row_bytes"] == 16
+    assert win["fast_rows"] == (win["rcv_tuples"] if periodic else 0)
+    assert win["rcv_tuples"] == 8 * 512
+
+
 def test_native_rebase_reships_wide_values_on_wide_wire():
     """A ring rebase re-ships ALL live rows; the wire dtype must cover the
     re-shipped (previously shipped) values, not just the pending ones —
@@ -1137,6 +1283,96 @@ def test_native_state_roundtrip_byte_identical(shards):
     out_b.extend(r.flush_batches())
 
     assert [x.tobytes() for x in out_a] == [x.tobytes() for x in out_b]
+
+
+def _cut_stream(wt):
+    """Three keys, ids running on over three chunks; the cut falls after the
+    second, inside open windows: the first window to fire after it (ids 4-11
+    of a key, closed by id 12) has its last row before it."""
+    rng = np.random.default_rng(5)
+    bs = [_periodic(3, lo, m, rng) for lo, m in ((0, 7), (7, 5), (12, 19))]
+    spec = (WindowSpec(8, 4, wt) if wt is WinType.CB
+            else WindowSpec(56, 28, wt))
+    return spec, bs, 2
+
+
+@pytest.mark.parametrize("per_key", [False, True], ids=["core", "key"])
+@pytest.mark.parametrize("wt", [WinType.CB, WinType.TB], ids=["cb", "tb"])
+def test_native_state_carries_the_result_ts(wt, per_key):
+    """Exported mid-window and imported into a fresh core -- whole-core blob
+    or key by key (export, neutralize, import) -- the stream goes on to the
+    results of a core that never stopped, ts included: the newest archived
+    row's position and ts travel in the blob, the ts column does not
+    exist."""
+    spec, batches, cut = _cut_stream(wt)
+
+    def fresh():
+        return make_native(spec, Reducer("sum", "value"), batch_len=32,
+                           flush_rows=64)
+
+    def run(core, bs):
+        return [o for b in bs for o in core.process_batches(b)]
+
+    whole = fresh()
+    want = run(whole, batches) + whole.flush_batches()
+    a = fresh()
+    got = run(a, batches[:cut]) + a.checkpoint_drain_batches()
+    b = fresh()
+    if per_key:
+        keys = a.keyed_state_keys()
+        assert list(keys) == [0, 1, 2]
+        b.keyed_state_import(a.keyed_state_export(keys))
+        assert len(a.keyed_state_keys()) == 0
+        assert a.flush_batches() == []    # a neutralized key fires no more
+    else:
+        b.state_restore(a.state_snapshot())
+    got += run(b, batches[cut:]) + b.flush_batches()
+    want, got = (np.sort(np.concatenate(x), order=["key", "id"])
+                 for x in (want, got))
+    assert_equal_results(want, got)
+    if wt is WinType.CB:
+        # window 1 of key 0: rows 4-11, the last of them before the cut
+        w1 = got[(got["key"] == 0) & (got["id"] == 1)]
+        assert int(w1["ts"][0]) == int(_ts_of(0, 11))
+
+
+def test_state_abi_is_version_2():
+    """Version 2: a key's record carries tail_pos / tail_ts and a ts array
+    only on a count-based arg-extremum core."""
+    assert _abi_source_constant() == 2
+    assert int(native.load().wf_abi_version()) == 2
+
+
+@pytest.mark.parametrize("per_key", [False, True], ids=["core", "key"])
+def test_a_version_1_blob_is_refused(per_key):
+    """An older library's blob (its ts array in every record, no tail) is
+    refused by its version stamp, code -4, and leaves the core as it was."""
+    spec, batches, cut = _cut_stream(WinType.CB)
+    a = make_native(spec, Reducer("sum", "value"), batch_len=32,
+                    flush_rows=64)
+    for b in batches[:cut]:
+        a.process_batches(b)
+    a.checkpoint_drain_batches()
+
+    def stamped_1(blob):
+        words = np.frombuffer(blob, dtype=np.int64).copy()
+        assert words[1] == 2
+        words[1] = 1
+        return words.tobytes()
+
+    fresh = make_native(spec, Reducer("sum", "value"), batch_len=32,
+                        flush_rows=64)
+    with pytest.raises(RuntimeError, match=r"code -4"):
+        if per_key:
+            frag = a.keyed_state_export([0])
+            frag["blobs"] = {k: stamped_1(v)
+                             for k, v in frag["blobs"].items()}
+            fresh.keyed_state_import(frag)
+        else:
+            snap = a.state_snapshot().resolve()
+            snap["blobs"] = tuple(stamped_1(v) for v in snap["blobs"])
+            fresh.state_restore(snap)
+    assert len(fresh.keyed_state_keys()) == 0
 
 
 def test_native_state_export_requires_drain():

@@ -42,7 +42,7 @@ def build(trace_dir=None):
 def test_trace_files_written(tmp_path):
     d = str(tmp_path / "log")
     build(trace_dir=d).run_and_wait_end()
-    files = sorted(os.listdir(d))
+    files = sorted(f for f in os.listdir(d) if f.endswith(".log"))
     assert len(files) == 3  # source, win_seq, sink
     logs = {f: json.load(open(os.path.join(d, f))) for f in files}
     win = next(v for v in logs.values() if "windows_fired" in v)
@@ -64,7 +64,7 @@ def test_env_var_enables_tracing(tmp_path, monkeypatch):
     d = str(tmp_path / "envlog")
     monkeypatch.setenv("WF_LOG_DIR", d)
     build().run_and_wait_end()
-    assert len(os.listdir(d)) == 3
+    assert len([f for f in os.listdir(d) if f.endswith(".log")]) == 3
 
 
 def test_snapshot_carries_robustness_counters():

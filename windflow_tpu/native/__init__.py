@@ -145,6 +145,8 @@ def _bind(lib):
     lib.wf_launch_peek_cut.argtypes = [ctypes.c_void_p, p_int, p_i64]
     lib.wf_core_fast_rows.restype = i64
     lib.wf_core_fast_rows.argtypes = [ctypes.c_void_p]
+    lib.wf_core_archive_row_bytes.restype = i64
+    lib.wf_core_archive_row_bytes.argtypes = [ctypes.c_void_p]
     lib.wf_core_set_stream.restype = ctypes.c_int
     lib.wf_core_set_stream.argtypes = [ctypes.c_void_p, i64]
     lib.wf_core_stream_stats.restype = None

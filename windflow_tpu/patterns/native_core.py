@@ -163,7 +163,9 @@ class NativeResidentCore:
         self._arg = args[0] if args else None
         #: columns the C++ archive keeps beside the shipped ones and never
         #: ships: the tie-break id first, then what the result carries
-        #: (``ts`` is archived anyway)
+        #: (``ts`` needs none: a time-based row's position is its ts, and a
+        #: count-based arg-extremum core archives the column,
+        #: wf_core_arg_gather)
         self._carry_cols = ()
         if self._arg is not None:
             if _argext_misplaced(mesh, shards):
@@ -364,6 +366,17 @@ class NativeResidentCore:
                         "the native core refused fire_on='stream' for "
                         f"{self.spec} with holdback {self.holdback}")
         self._harr = (ctypes.c_void_p * self.shards)(*self._hs)
+        #: bytes a row takes in its key's C++ archive: 8 a column -- the
+        #: position, each shipped field, each carried column, and ``ts`` on
+        #: a count-based arg-extremum alone (docs/OBSERVABILITY.md)
+        self.archive_row_bytes = int(
+            self._lib.wf_core_archive_row_bytes(self._hs[0]))
+
+    @property
+    def fast_rows(self) -> int:
+        """Rows the C++ bulk path took (``Core::process_fast``: key-periodic
+        in-order chunks on a one-shard count-based sum)."""
+        return sum(int(self._lib.wf_core_fast_rows(h)) for h in self._hs)
 
     def _start_ship_threads(self):
         # one ship thread per shard: each owns its executor, so the

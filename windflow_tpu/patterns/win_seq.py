@@ -160,12 +160,15 @@ class WinSeqNode(Node):
         """A stream-time core's own counts onto the node's (the cores report
         what they have: a host core holds no row back, the native core
         retires no key)."""
-        core = self.core
-        for name in ("keys_live", "keys_live_peak", "keys_retired",
-                     "stream_fires", "stream_fire_rows", "late_rows",
-                     "rows_out_of_order", "rows_reinserted",
-                     "rows_held_peak", "watermark_fires"):
-            value = getattr(core, name, None)
+        self._core_counters(st, (
+            "keys_live", "keys_live_peak", "keys_retired", "stream_fires",
+            "stream_fire_rows", "late_rows", "rows_out_of_order",
+            "rows_reinserted", "rows_held_peak", "watermark_fires"))
+
+    def _core_counters(self, st, names):
+        """Copies those of the core's own counts it has onto the node's."""
+        for name in names:
+            value = getattr(self.core, name, None)
             if value is not None:
                 st.counters[name] = int(value)
 
@@ -184,6 +187,11 @@ class WinSeqNode(Node):
                 self.stats.bump("non_triggering_batches")
 
     def eosnotify(self):
+        if self.stats is not None:
+            # what a native core says of its archives, now that every row
+            # is in: the bytes one took there, the rows its bulk path took
+            self._core_counters(self.stats,
+                                ("archive_row_bytes", "fast_rows"))
         if self._recov is not None:
             fb = getattr(self.core, "flush_batches", None)
             if fb is not None:
