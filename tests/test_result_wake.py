@@ -731,7 +731,7 @@ def test_counters_and_record_fields_read_what_the_wake_took(tmp_path):
     s.run()
     recs = [r for r in profile.records() if r[0] == "harvest_wait"]
     assert len(recs) == 7
-    for _phase, _t0, t1, _launch, _shard, _cause, extra in recs:
+    for _phase, _t0, t1, _launch, _shard, _cause, extra, _cpu in recs:
         assert set(extra) == {"ready", "harvest", "handed", "out_q_ms"}
         assert 0 <= extra["out_q_ms"] < 1e3 * LONG
     assert handed() == ["wake"] * 6 + ["svc"]

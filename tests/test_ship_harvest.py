@@ -394,7 +394,8 @@ def test_harvest_field_names_what_made_the_thread_harvest():
     counters = profile.counters()
     assert counters["harvest_waited"] == 6 and counters["launches"] == 7
     by_launch = {}
-    for phase, _t0, _t1, launch, _shard, _cause, extra in profile.records():
+    for phase, _t0, _t1, launch, _shard, _cause, extra, _cpu in \
+            profile.records():
         if phase == "harvest_wait":
             # (and, since ISSUE 32, which call took it out of _out_q)
             assert set(extra) == {"ready", "harvest", "handed", "out_q_ms"}

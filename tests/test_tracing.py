@@ -6,23 +6,12 @@ import json
 import os
 
 import numpy as np
-import pytest
 
 from windflow_tpu import (MultiPipe, Reducer, Schema, Sink_Builder,
                           Source_Builder, WinSeq_Builder,
                           batch_from_columns)
-from windflow_tpu.utils import profile
 
 SCHEMA = Schema(value=np.int64)
-
-
-@pytest.fixture(autouse=True)
-def _no_stale_launch_records():
-    """Another test file's ship thread can end a span after that file's own
-    reset (its core is collected later, in this process): the ring then holds
-    a record and a traced dataflow here would write ``launches.jsonl``."""
-    profile.reset()
-    yield
 
 
 def batches(n=100):

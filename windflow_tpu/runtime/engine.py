@@ -415,10 +415,24 @@ def _timed_get(inbox, stats):
     """``inbox.get()`` booked as the node's idle time: waiting for input
     is neither service nor blocked (utils/tracing.py, the three-way
     split).  Both receive loops take it when the node has stats."""
-    t0 = _pc_ns()
+    stats.cpu_turn()
+    t0, c0 = stats.clocks()
     got = inbox.get()
-    stats.idle_ns += _pc_ns() - t0
+    t1, c1 = stats.clocks()
+    stats.record_idle(t1 - t0, c1 - c0)
     return got
+
+
+def _timed_svc(node, stats, item, src) -> int:
+    """``node.svc`` on the wall clock, for a node with stats (booked there)
+    or a traced batch; returns the time.  An svc that raises books
+    nothing."""
+    t0 = _pc_ns()
+    node.svc(item, src)
+    dt = _pc_ns() - t0
+    if stats is not None:
+        stats.record_svc(len(item), dt)
+    return dt
 
 
 class Dataflow:
@@ -636,6 +650,9 @@ class Dataflow:
         self._inboxes: dict[int, Inbox] = {}
         self._edges: list[tuple[Node, Node]] = []
         self._threads: list[threading.Thread] = []
+        #: the clock at run(): the launch records wait() writes are this
+        #: run's own, not an earlier graph's of the same process
+        self._run_ns = None
         self._errors: list[BaseException] = []
         self._failed = threading.Event()
         #: quarantined poison batches (DeadLetter records, arrival order);
@@ -785,6 +802,10 @@ class Dataflow:
                 # stays a function of its input alone
                 node._wake = _waker(self._inboxes[id(node)])
             node.svc_init()
+            # the run on the thread's CPU clock, read once at each end: what
+            # of it was not a wait is the node's own (NodeStats.snapshot)
+            if node.stats is not None:
+                node.stats.run_begins()
             if isinstance(node, SourceNode):
                 if node._recov is not None:
                     # sequence-tag emissions + epoch-marker injection
@@ -864,11 +885,7 @@ class Dataflow:
                         # the next error fails fast exactly like default
                         try:
                             if timed:
-                                t0 = _pc_ns()
-                                node.svc(item, src)
-                                dt = _pc_ns() - t0
-                                if stats is not None:
-                                    stats.record_svc(len(item), dt)
+                                dt = _timed_svc(node, stats, item, src)
                             else:
                                 node.svc(item, src)
                         except OverloadError:
@@ -881,11 +898,7 @@ class Dataflow:
                             self._quarantine(node, item, src, e)
                             continue    # no span: the batch died here
                     elif timed:
-                        t0 = _pc_ns()
-                        node.svc(item, src)
-                        dt = _pc_ns() - t0
-                        if stats is not None:
-                            stats.record_svc(len(item), dt)
+                        dt = _timed_svc(node, stats, item, src)
                     else:
                         node.svc(item, src)
                     if ctx is not None:
@@ -894,6 +907,8 @@ class Dataflow:
                 # a wake from here on is dropped: eosnotify flushes what
                 # it would have announced
                 inbox._wake_armed = False
+            if node.stats is not None:
+                node.stats.run_ends()
             if tracer is not None:
                 # EOS flushes are not attributable to any sampled batch:
                 # clear the thread-local so the last traced batch's span
@@ -1109,11 +1124,7 @@ class Dataflow:
         if rec.budget > 0:
             try:
                 if timed:
-                    t0 = _pc_ns()
-                    node.svc(payload, src)
-                    dt = _pc_ns() - t0
-                    if stats is not None:
-                        stats.record_svc(len(payload), dt)
+                    dt = _timed_svc(node, stats, payload, src)
                 else:
                     node.svc(payload, src)
             except OverloadError:
@@ -1131,11 +1142,7 @@ class Dataflow:
                     self._quarantine(node, payload, src, e)
                 return      # no span: the batch died here
         elif timed:
-            t0 = _pc_ns()
-            node.svc(payload, src)
-            dt = _pc_ns() - t0
-            if stats is not None:
-                stats.record_svc(len(payload), dt)
+            dt = _timed_svc(node, stats, payload, src)
         else:
             node.svc(payload, src)
         if ctx is not None:
@@ -1325,6 +1332,7 @@ class Dataflow:
                     self._blackbox = BlackBox(
                         self.trace_dir, self.name, events=self.events,
                         tracer=self.tracer, shipper=self.federation)
+        self._run_ns = _pc_ns()
         for node in self.nodes:
             t = threading.Thread(target=self._run_node, args=(node,),
                                  name=f"{self.name}/{node.name}", daemon=True)
@@ -1380,11 +1388,13 @@ class Dataflow:
             if self.tracer is not None:
                 self.tracer.close()     # flush buffered spans to disk
             if self.trace_dir:
-                # the ship path's launch records (utils/profile.py's ring:
-                # empty, and no file, unless profiling was on)
+                # the ship path's launch records (utils/profile.py's ring)
+                # that began since run(): none, and no file, unless
+                # profiling was on meanwhile
                 from ..utils import profile
                 profile.write_records(
-                    os.path.join(self.trace_dir, "launches.jsonl"))
+                    os.path.join(self.trace_dir, "launches.jsonl"),
+                    since_ns=self._run_ns)
             if self.events is not None and not self._stop_logged:
                 self._stop_logged = True
                 self.events.emit("dataflow_stop", dataflow=self.name,

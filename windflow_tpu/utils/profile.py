@@ -9,11 +9,15 @@ When enabled a span is recorded three ways from ONE pair of clock reads:
 the accumulators above; a ``jax.profiler.TraceAnnotation("wf.<phase>",
 launch=, shard=)`` held open for the span's life, so the phase lies on the
 host plane of the profiler's own trace, on the device events' clock; and a
-record ``(phase, t0_ns, t1_ns, launch, shard, cause, extra)`` in a bounded
-ring (``records()``, ``write_records()`` — the engine writes it to
-``<trace_dir>/launches.jsonl``).  ``launch`` is the id ``next_id()`` gave
-the launch when the ship thread took it, ``cause`` the id of the
-``_process_rows`` call that last fed its core.
+record ``(phase, t0_ns, t1_ns, launch, shard, cause, extra, cpu_ns)`` in a
+bounded ring (``records()``, ``write_records()`` — the engine writes its
+own run's to ``<trace_dir>/launches.jsonl``).  ``cpu_ns`` is the span on
+the thread's CPU clock, read at the same two instants: the rest of the span
+the thread was not running (it waited, for the interpreter lock or anything
+else); where that clock is dear every ``tracing.cpu_every()``-th span of a
+phase has it, the others ``None`` and no field in the file.  ``launch`` is
+the id ``next_id()`` gave the launch when the ship thread took it, ``cause``
+the id of the ``_process_rows`` call that last fed its core.
 
 Enablement is *not* frozen at import: ``WF_PROFILE`` is re-read lazily at
 every ``span`` entry (spans bracket ms-scale ship phases, so the environ
@@ -35,6 +39,8 @@ import os
 import threading
 import time
 from collections import defaultdict, deque
+
+from .tracing import cpu_every
 
 _FORCED: bool | None = None   # enable()/disable() override; None = env
 
@@ -94,8 +100,9 @@ _val: dict[str, float] = defaultdict(float)
 _mu = threading.Lock()
 
 #: the spans of the run, oldest dropped first: (phase, t0_ns, t1_ns,
-#: launch, shard, cause, extra) on the perf_counter_ns clock.  Sized for a
-#: benchmark run (a few hundred launches and chunks a second for a minute)
+#: launch, shard, cause, extra, cpu_ns) on the perf_counter_ns clock.
+#: Sized for a benchmark run (a few hundred launches and chunks a second
+#: for a minute)
 _RING = deque(maxlen=1 << 17)
 
 #: how far back ``amend`` looks: a record is amended milliseconds after it
@@ -154,11 +161,12 @@ def _annotation(name, launch, shard):
 class span:
     """``with span("device_put", launch=7, shard=0): ...`` — one ship
     phase: wall time per phase, and with profiling on a ``wf.`` annotation
-    and a ring record (module docstring).  ``extra`` may be set inside the
-    block (a dict) and rides on the record."""
+    and a ring record with the span's CPU time (module docstring).
+    ``extra`` may be set inside the block (a dict) and rides on the
+    record."""
 
     __slots__ = ("name", "launch", "shard", "cause", "extra", "t0", "t1",
-                 "_acc_on", "_ann")
+                 "_c0", "_acc_on", "_ann")
 
     def __init__(self, name: str, launch: int = None, shard: int = None,
                  cause: int = None):
@@ -177,6 +185,10 @@ class span:
         if self._acc_on:
             self._ann = _annotation(self.name, self.launch, self.shard)
             self.t0 = time.perf_counter_ns()
+            # every cpu_every()-th span of a phase, counted as they close
+            self._c0 = (time.thread_time_ns()
+                        if _cnt.get(self.name, 0) % cpu_every() == 0
+                        else None)
         else:
             self.t0 = (time.perf_counter_ns()
                        if _RECORDER is not None else None)
@@ -187,12 +199,14 @@ class span:
         if t0 is not None:
             t1 = self.t1 = time.perf_counter_ns()
             if self._acc_on:
+                cpu = (None if self._c0 is None
+                       else time.thread_time_ns() - self._c0)
                 self._ann.__exit__(*exc)
                 with _mu:
                     _acc[self.name] += (t1 - t0) / 1e9
                     _cnt[self.name] += 1
                     _RING.append((self.name, t0, t1, self.launch,
-                                  self.shard, self.cause, self.extra))
+                                  self.shard, self.cause, self.extra, cpu))
             rec = _RECORDER
             if rec is not None:
                 if self.launch is None:
@@ -267,17 +281,23 @@ def records() -> list:
         return list(_RING)
 
 
-def write_records(path: str) -> int:
+def write_records(path: str, since_ns: int = None) -> int:
     """Write the ring as one JSON object per span; returns how many.
-    Nothing recorded, nothing written."""
+    With ``since_ns`` only the spans that began at or after it (a graph
+    writes its own run's, not what an earlier one left in the ring).
+    Nothing to write, nothing written."""
     recs = records()
+    if since_ns is not None:
+        recs = [r for r in recs if r[1] >= since_ns]
     if not recs:
         return 0
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(path, "w") as f:
-        for phase, t0, t1, launch, shard, cause, extra in recs:
+        for phase, t0, t1, launch, shard, cause, extra, cpu in recs:
             line = {"phase": phase, "t0_ns": t0, "t1_ns": t1,
                     "launch": launch, "shard": shard, "cause": cause}
+            if cpu is not None:
+                line["cpu_ns"] = cpu
             if extra:
                 line.update(extra)
             f.write(json.dumps(line) + "\n")
