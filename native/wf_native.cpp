@@ -2461,6 +2461,231 @@ i64 wf_keyscan_ordered(const i64 *slots, const i64 *pos, i64 n,
     return ok;
 }
 
+// ---------------------------------------------------------------- stream fold
+// The fold of a stream-time host core (core/vecinc.py VecStreamCore): the
+// rows of a chunk between two window boundaries go into the lanes of their
+// key's open windows in ONE pass, where numpy sorted the rows, cut them
+// into groups, searched two sorted levels for every group's slot and
+// updated the lanes by fancy index (some forty calls a chunk over 100 MB of
+// state, a cost per distinct key: PERF.md §5-6, PR 38).  All state stays
+// the caller's: numpy arrays handed in by address, so the core is plain
+// data and the rest of it (fire, retire, flush, a deep-copy snapshot) works
+// on the same arrays.
+//
+// The index is an open-addressing table of `cap` (a power of two) pairs
+// {key, slot}; slot -1 is an empty cell.  Slot numbers come out as the
+// numpy fold's: it looked up its SORTED group keys, so the unseen keys of
+// one fold are numbered in ascending key order — and a fire's results leave
+// in slot order.  Hence: an unseen key takes the next slot as it is met and
+// its rows are folded there like any other's; when the stretch is through,
+// the slots it added are put into their keys' order (a few thousand lanes
+// moved, the rows not looked at again).
+
+namespace {
+
+inline size_t sfold_home(i64 key, int shift) {
+    return (size_t)(((unsigned long long)key * 0x9E3779B97F4A7C15ULL)
+                    >> shift);
+}
+
+inline i64 sfold_load_i64(const char *p) {
+    i64 v;
+    std::memcpy(&v, p, 8);
+    return v;
+}
+
+// an integer field of 1, 2, 4 or 8 bytes as int64; `kind` is its size,
+// negative where unsigned (what ndarray.astype(int64) gives)
+inline i64 sfold_load(const char *p, i64 kind) {
+    switch (kind) {
+    case 8: case -8: return sfold_load_i64(p);
+    case 4: { int32_t v; std::memcpy(&v, p, 4); return v; }
+    case -4: { uint32_t v; std::memcpy(&v, p, 4); return v; }
+    case 2: { int16_t v; std::memcpy(&v, p, 2); return v; }
+    case -2: { uint16_t v; std::memcpy(&v, p, 2); return v; }
+    case 1: return *(const signed char *)p;
+    default: return *(const unsigned char *)p;
+    }
+}
+
+// The windows [lo, hi] a row at `ts` lies in that have not fired (none
+// where hi < lo: the row is late), and lo's lane.  The divisions are redone
+// only when `ts` leaves the stretch on which they are constant (between two
+// boundaries: nearly never).
+struct SfoldRange {
+    i64 L, S, W, fired;
+    i64 from = 0, to = 0, lo = 0, hi = 0, lane = 0;
+    inline void at(i64 ts) {
+        if (ts >= from && ts < to) return;
+        hi = fdiv(ts, S);
+        const i64 first = fdiv(ts - L, S) + 1;
+        from = std::max(hi * S, (first - 1) * S + L);
+        to = std::min((hi + 1) * S, first * S + L);
+        lo = std::max(first, fired);
+        lane = lo % W;
+        if (lane < 0) lane += W;
+    }
+};
+
+const int kSfoldMaxParts = 8;
+
+// The lanes: rows a lane, and the accumulators of the parts that keep one
+// (a count is its lane's rows).
+struct Sfold {
+    i64 W, m = 0;
+    i64 *lane_rows;
+    i64 op[kSfoldMaxParts], off[kSfoldMaxParts], kind[kSfoldMaxParts];
+    i64 *acc[kSfoldMaxParts];
+
+    // desc, per part: op (0 count, 1 sum, 2 min, 3 max), the field's
+    // offset in a row, its kind (sfold_load)
+    Sfold(i64 W_, i64 *lane_rows_, i64 n_parts, const i64 *desc,
+          i64 *const *accs) : W(W_), lane_rows(lane_rows_) {
+        for (i64 p = 0; p < n_parts; ++p) {
+            if (!desc[3 * p]) continue;
+            op[m] = desc[3 * p];
+            off[m] = desc[3 * p + 1];
+            kind[m] = desc[3 * p + 2];
+            acc[m++] = accs[p];
+        }
+    }
+
+    inline void row(i64 slot, const char *rec, const SfoldRange &r) const {
+        i64 v[kSfoldMaxParts];
+        for (i64 q = 0; q < m; ++q) v[q] = sfold_load(rec + off[q], kind[q]);
+        i64 lane = r.lane;
+        const i64 at = slot * W;
+        for (i64 w = r.lo; w <= r.hi; ++w) {
+            lane_rows[at + lane] += 1;
+            for (i64 q = 0; q < m; ++q) {
+                i64 &a = acc[q][at + lane];
+                switch (op[q]) {
+                case 1: a = (i64)((unsigned long long)a
+                                  + (unsigned long long)v[q]); break;
+                case 2: a = std::min(a, v[q]); break;
+                default: a = std::max(a, v[q]);
+                }
+            }
+            if (++lane == W) lane = 0;
+        }
+    }
+
+    // Slots [n0, n1), numbered as their keys were met, into the order of
+    // their keys: keys, lanes and the index's cells.
+    void sort_slots(i64 *tab, int shift, size_t mask, i64 *slot_keys,
+                    i64 n0, i64 n1) const {
+        const i64 k = n1 - n0;
+        std::vector<std::pair<i64, i64>> by_key((size_t)k);  // key, old slot
+        for (i64 j = 0; j < k; ++j)
+            by_key[(size_t)j] = {slot_keys[n0 + j], n0 + j};
+        std::sort(by_key.begin(), by_key.end());
+        std::vector<i64> moved((size_t)(k * W));
+        auto permute = [&](i64 *lanes) {
+            for (i64 j = 0; j < k; ++j)
+                std::memcpy(&moved[(size_t)(j * W)],
+                            lanes + by_key[(size_t)j].second * W,
+                            (size_t)W * 8);
+            std::memcpy(lanes + n0 * W, moved.data(), (size_t)(k * W) * 8);
+        };
+        permute(lane_rows);
+        for (i64 q = 0; q < m; ++q) permute(acc[q]);
+        for (i64 j = 0; j < k; ++j) {
+            const i64 key = by_key[(size_t)j].first;
+            slot_keys[n0 + j] = key;
+            size_t at = sfold_home(key, shift);
+            while (tab[2 * at] != key || tab[2 * at + 1] < n0)
+                at = (at + 1) & mask;
+            tab[2 * at + 1] = n0 + j;
+        }
+    }
+};
+
+}  // namespace
+
+i64 wf_sfold_max_parts(void) { return kSfoldMaxParts; }
+
+// (Re)build the index over the keys of slots 0..n-1; `cap` >= 2n.
+void wf_sfold_index(i64 *tab, i64 cap, const i64 *keys, i64 n) {
+    std::memset(tab, 0xff, (size_t)cap * 16);
+    const int shift = 64 - __builtin_ctzll((unsigned long long)cap);
+    const size_t mask = (size_t)cap - 1;
+    const i64 ahead = 16;
+    for (i64 s = 0; s < n; ++s) {
+        if (s + ahead < n)
+            __builtin_prefetch(tab + 2 * sfold_home(keys[s + ahead], shift),
+                               1);
+        size_t at = sfold_home(keys[s], shift);
+        while (tab[2 * at + 1] != -1) at = (at + 1) & mask;
+        tab[2 * at] = keys[s];
+        tab[2 * at + 1] = s;
+    }
+}
+
+// Fold rows [lo, n) of a structured chunk (`base`, `stride` bytes a row; a
+// key and a ts of 8 bytes, a marker of 1) up to the first row, marker or
+// not, whose ts has reached `bound`: returns its index, or n.  geom = {L,
+// S, W, fired}.  Marker rows are skipped; a row all of whose windows have
+// fired is dropped and counted.  `slot_keys`, `lane_rows` (W lanes a slot,
+// zero where a slot is new) and each `acc[p]` (the part's identity there)
+// have room for `slot_cap` slots, `tab` has `cap` cells.  io: [0] the live
+// slots, in and out; [1] the late rows, out; [2] the live slots when this
+// stretch began, in; [3] out, 1 where the call stopped short, at the row it
+// returns, because that row's key is unseen and there is no slot left for
+// it or the index would pass half full: the caller makes room (the index
+// rebuilt) and calls again from that row with io[2] as it was -- the
+// stretch's new slots are put into key order when its last call ends.
+i64 wf_sfold(const char *base, i64 stride, i64 lo, i64 n, i64 off_key,
+             i64 off_ts, i64 off_marker, i64 bound, const i64 *geom,
+             i64 *tab, i64 cap, i64 *slot_keys, i64 slot_cap,
+             i64 *lane_rows, i64 n_parts, const i64 *desc, i64 *const *acc,
+             i64 *io) {
+    SfoldRange range{geom[0], geom[1], geom[2], geom[3]};
+    const Sfold fold(geom[2], lane_rows, n_parts, desc, acc);
+    const int shift = 64 - __builtin_ctzll((unsigned long long)cap);
+    const size_t mask = (size_t)cap - 1;
+    const i64 room = std::min(slot_cap, cap / 2), ahead = 8;
+    i64 n1 = io[0], late = 0, cut = n;
+    io[3] = 0;
+    for (i64 i = lo; i < n; ++i) {
+        const char *rec = base + i * stride;
+        if (i + ahead < n)
+            __builtin_prefetch(tab + 2 * sfold_home(sfold_load_i64(
+                rec + ahead * stride + off_key), shift));
+        const i64 ts = sfold_load_i64(rec + off_ts);
+        if (ts >= bound) {
+            cut = i;
+            break;
+        }
+        if (rec[off_marker]) continue;
+        range.at(ts);
+        if (range.hi < range.lo) {
+            ++late;
+            continue;
+        }
+        const i64 key = sfold_load_i64(rec + off_key);
+        size_t at = sfold_home(key, shift);
+        while (tab[2 * at + 1] != -1 && tab[2 * at] != key)
+            at = (at + 1) & mask;
+        if (tab[2 * at + 1] == -1) {        // unseen: the next slot
+            if (n1 >= room) {
+                cut = i;
+                io[3] = 1;
+                break;
+            }
+            tab[2 * at] = key;
+            tab[2 * at + 1] = n1;
+            slot_keys[n1++] = key;
+        }
+        fold.row(tab[2 * at + 1], rec, range);
+    }
+    io[0] = n1;
+    io[1] = late;
+    if (!io[3] && n1 - io[2] > 1
+        && !std::is_sorted(slot_keys + io[2], slot_keys + n1))
+        fold.sort_slots(tab, shift, mask, slot_keys, io[2], n1);
+    return cut;
+}
+
 // ---------------------------------------------------------------- state ABI
 // Exactly-once checkpoint / keyed-migration support (docs/ROBUSTNESS.md
 // "Native state ABI").  Blobs are flat little-endian i64 streams: a tagged

@@ -23,16 +23,24 @@
 //                     subsystems becomes a TSan report.  Each case
 //                     starts with the bulk path (bulk_case): archive
 //                     columns grown unwritten, a block rolled back, the
-//                     windows' ts read from the input chunk.
+//                     windows' ts read from the input chunk.  Then the
+//                     stream fold (sfold_case): wf_sfold over arrays the
+//                     caller owns, as VecStreamCore does — the lanes and
+//                     the index grown and the index rebuilt between
+//                     calls, slots retired and their keys seen again —
+//                     against a std::map.
 //
 //   ./wf_stress_tsan --seed 1 --n 4
 
+#include <algorithm>
+#include <array>
 #include <atomic>
 #include <cstddef>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <map>
 #include <thread>
 #include <utility>
 #include <vector>
@@ -76,6 +84,12 @@ i64 wf_core_key_state_size(void *h, i64 key);
 i64 wf_core_key_export(void *h, i64 key, void *buf, i64 cap);
 i64 wf_core_key_import(void *h, const void *buf, i64 nbytes);
 i64 wf_core_key_neutralize(void *h, i64 key);
+void wf_sfold_index(i64 *tab, i64 cap, const i64 *keys, i64 n);
+i64 wf_sfold(const char *base, i64 stride, i64 lo, i64 n, i64 off_key,
+             i64 off_ts, i64 off_marker, i64 bound, const i64 *geom,
+             i64 *tab, i64 cap, i64 *slot_keys, i64 slot_cap,
+             i64 *lane_rows, i64 n_parts, const i64 *desc, i64 *const *acc,
+             i64 *io);
 }
 
 #if defined(__SANITIZE_THREAD__)
@@ -340,8 +354,151 @@ static void bulk_case(u64 seed, int tid) {
     wf_core_free(h);
 }
 
+// The stream fold over caller-owned arrays (core/vecinc.py's part played
+// here): every array sized exactly as the call is told and no larger, so a
+// write past it is ASan's, and with room for a few new keys only, so most
+// stretches stop short and go on after the arrays have grown and the index
+// was rebuilt; chunks cut at window boundaries with markers and late rows
+// among them; at every boundary the oldest window's lane is taken, slots
+// left empty are retired and the index is rebuilt.  Counts, sums and maxima
+// per (key, window) against a std::map; the slots a stretch adds come out
+// in key order.
+static void sfold_case(u64 seed, int tid) {
+    Rng r(seed + 104729 * (u64)(tid + 1));
+    const i64 S = r.range(3, 9), W = r.range(1, 4), L = S * W;
+    const i64 desc[9] = {0, 0, 0, 1, (i64)offsetof(Row, value), 8,
+                         3, (i64)offsetof(Row, ts), 8};
+    const i64 kMin = -((i64)1 << 62);
+    std::vector<i64> tab, slot_keys, lane_rows, sum, mx;
+    i64 n_slots = 0, fired = 0, clock = 0, next_key = 0, late_seen = 0,
+        stops = 0;
+    std::map<std::pair<i64, i64>, std::array<i64, 3>> want;  // key, window
+    auto reserve = [&](i64 need) {
+        slot_keys.resize((size_t)need);
+        lane_rows.resize((size_t)(need * W), 0);
+        sum.resize((size_t)(need * W), 0);
+        mx.resize((size_t)(need * W), kMin);
+        i64 cap = 2;
+        while (cap < 2 * need) cap *= 2;
+        if ((i64)tab.size() != 2 * cap) {       // grown (or shrunk): rebuilt
+            tab.assign((size_t)(2 * cap), 0);
+            wf_sfold_index(tab.data(), cap, slot_keys.data(), n_slots);
+        }
+    };
+    for (int chunk = 0; chunk < 40; ++chunk) {
+        std::vector<Row> rows;
+        const i64 n = r.range(1, 400);
+        for (i64 i = 0; i < n; ++i) {
+            clock += r.range(0, 3) == 0;
+            const bool hot = r.range(0, 3) == 0;
+            const i64 key = hot ? 7 : 4 * (next_key - r.range(0, 30)) + 1;
+            next_key += r.range(0, 4) == 0;
+            const i64 ts = clock - (r.range(0, 5) ? 0 : r.range(0, 2 * L));
+            rows.push_back(Row{key, i, ts, (u8)(r.range(0, 9) == 0),
+                               r.range(-9, 100)});
+        }
+        i64 lo = 0;
+        while (lo < n) {
+            const i64 geom[4] = {L, S, W, fired};
+            const i64 bound = fired * S + L, n0 = n_slots;
+            i64 io[4] = {n_slots, 0, n0, 1}, cut = lo, late_got = 0;
+            while (io[3]) {             // a stretch, in as many calls
+                reserve(io[0] + r.range(1, 6));
+                i64 *acc[3] = {nullptr, sum.data(), mx.data()};
+                cut = wf_sfold(
+                    (const char *)rows.data(), (i64)sizeof(Row), cut, n,
+                    offsetof(Row, key), offsetof(Row, ts),
+                    offsetof(Row, marker), bound, geom, tab.data(),
+                    (i64)tab.size() / 2, slot_keys.data(),
+                    (i64)slot_keys.size(), lane_rows.data(), 3, desc, acc,
+                    io);
+                n_slots = io[0];
+                late_got += io[1];
+                stops += io[3];
+            }
+            CHECK(cut >= lo && cut <= n, "cut=%lld", (long long)cut);
+            for (i64 s = n0 + 1; s < n_slots; ++s)
+                CHECK(slot_keys[(size_t)s - 1] < slot_keys[(size_t)s],
+                      "new slots out of key order at %lld", (long long)s);
+            i64 late = 0;
+            for (i64 i = lo; i < cut; ++i) {
+                const Row &x = rows[(size_t)i];
+                CHECK(x.ts < bound, "row past the bound folded");
+                if (x.marker) continue;
+                const i64 hi = x.ts >= 0 ? x.ts / S : -((-x.ts + S - 1) / S);
+                i64 first = x.ts - L >= 0 ? (x.ts - L) / S + 1
+                                          : -((L - x.ts + S - 1) / S) + 1;
+                first = std::max(first, fired);
+                late += hi < first;
+                for (i64 w = first; w <= hi; ++w) {
+                    auto it = want.find({x.key, w});
+                    if (it == want.end())
+                        it = want.insert({{x.key, w}, {0, 0, kMin}}).first;
+                    it->second[0] += 1;
+                    it->second[1] += x.value;
+                    it->second[2] = std::max(it->second[2], x.ts);
+                }
+            }
+            CHECK(late_got == late, "late %lld != %lld", (long long)late_got,
+                  (long long)late);
+            late_seen += late;
+            lo = cut;
+            if (cut == n) break;
+            CHECK(rows[(size_t)cut].ts >= bound, "cut before the bound");
+            // the boundary: window `fired` leaves, empty slots are retired
+            const i64 lane = ((fired % W) + W) % W;
+            i64 kept = 0;
+            for (i64 s = 0; s < n_slots; ++s) {
+                const size_t at = (size_t)(s * W + lane);
+                auto it = want.find({slot_keys[(size_t)s], fired});
+                if (lane_rows[at]) {
+                    CHECK(it != want.end()
+                              && it->second[0] == lane_rows[at]
+                              && it->second[1] == sum[at]
+                              && it->second[2] == mx[at],
+                          "key %lld window %lld",
+                          (long long)slot_keys[(size_t)s], (long long)fired);
+                    want.erase(it);
+                } else {
+                    CHECK(it == want.end(), "a window lost its rows");
+                }
+                lane_rows[at] = 0;
+                sum[at] = 0;
+                mx[at] = kMin;
+                bool live = false;
+                for (i64 l = 0; l < W; ++l)
+                    live |= lane_rows[(size_t)(s * W + l)] != 0;
+                if (!live) continue;
+                for (i64 l = 0; l < W; ++l) {
+                    lane_rows[(size_t)(kept * W + l)] =
+                        lane_rows[(size_t)(s * W + l)];
+                    sum[(size_t)(kept * W + l)] = sum[(size_t)(s * W + l)];
+                    mx[(size_t)(kept * W + l)] = mx[(size_t)(s * W + l)];
+                }
+                slot_keys[(size_t)kept++] = slot_keys[(size_t)s];
+            }
+            for (i64 s = kept; s < n_slots; ++s)
+                for (i64 l = 0; l < W; ++l) {
+                    lane_rows[(size_t)(s * W + l)] = 0;
+                    sum[(size_t)(s * W + l)] = 0;
+                    mx[(size_t)(s * W + l)] = kMin;
+                }
+            n_slots = kept;
+            ++fired;
+            tab.clear();        // rebuilt by the next reserve()
+        }
+    }
+    for (const auto &kv : want)
+        CHECK(kv.first.second >= fired, "window %lld of key %lld never left",
+              (long long)kv.first.second, (long long)kv.first.first);
+    CHECK(fired > 3 && late_seen > 0 && stops > 3,
+          "fired=%lld late=%lld stops=%lld", (long long)fired,
+          (long long)late_seen, (long long)stops);
+}
+
 static void state_abi_case(u64 seed, int tid) {
     bulk_case(seed, tid);
+    sfold_case(seed, tid);
 
     Rng r(seed + 31337 * (u64)(tid + 1));
     const i64 n_keys = r.range(2, 9);

@@ -42,6 +42,11 @@ NODE_OPTIONAL_FIELDS = {
     "filter_rows_out": (int,),
     "split_batches": (int,),
     "single_dest_batches": (int,),
+    # a stream-time host window worker (core/vecinc.VecStreamCore): the
+    # chunks its native fold took, ÷ (non_triggering_batches +
+    # triggering_batches) its engagement; the most keys it held at once
+    "fold_native_batches": (int,),
+    "keys_live_peak": (int,),
     # span-tracing latency fields (obs/trace.py; only on traced graphs)
     "q_p50_us": (int, float),
     "q_p95_us": (int, float),

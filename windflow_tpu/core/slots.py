@@ -122,6 +122,15 @@ class SlotMap:
         self._new_keys = np.zeros(0, dtype=np.int64)
         self._new_slots = np.zeros(0, dtype=np.int64)
 
+    def reindex(self):
+        """Rebuild the sorted view from ``keys[:n]``, for an owner that
+        numbered slots behind its back (``VecStreamCore``'s native fold
+        keeps a hash index of its own and leaves this view empty)."""
+        order = np.argsort(self.keys[:self.n], kind="stable")
+        self.state_restore({"n": self.n, "keys": self.keys,
+                            "sorted_keys": self.keys[:self.n][order],
+                            "sorted_slots": order})
+
     def _find(self, keys: np.ndarray):
         """``(slots, found)`` over both levels; ``slots`` is meaningful
         where ``found``."""

@@ -31,6 +31,7 @@ bookkeeping is O(rows log rows) regardless of key cardinality.
 
 from __future__ import annotations
 
+import ctypes
 import threading
 
 import numpy as np
@@ -38,7 +39,8 @@ import numpy as np
 from .slots import segments as _segments
 from .tuples import MARKER_FIELD, Schema, progress_row
 from .windows import (PatternConfig, Role, WindowSpec, WinType,
-                      check_stream_fire, run_stream_clock)
+                      check_stream_fire, run_stream_clock, start_stream_clock)
+from .. import native
 from ..ops.functions import MultiReducer, Reducer
 from ..ops.monoid import NP_UFUNCS, identity as monoid_identity
 from ..utils import profile
@@ -710,6 +712,19 @@ class VecStreamCore:
     keys of the open windows.  After a fire the core sends a
     :func:`progress_row`.  Marker rows in the input move the clock and fold
     nothing.
+
+    The fold has two forms of one algorithm, chosen from what the core can
+    observe at its first chunk (:meth:`_native_plan`): where the native
+    library is loaded and every part is a count or an int64 sum / min / max
+    over an integer field, one C++ call a stretch between two window
+    boundaries (``wf_sfold``: a hash index of key -> slot, each row straight
+    into its lanes, no interpreter lock held; ``fold_native_batches`` counts
+    the chunks it took); anywhere else :meth:`_fold`, numpy over the rows
+    sorted by key and ``SlotMap``'s sorted view.  Both number a stretch's
+    unseen keys in ascending key order, so the slots, and with them the
+    order of a fire's rows, are the same.  All state is numpy arrays the
+    core owns, the hash index (``_tab``) too: a deep copy is a snapshot (it
+    leaves the index out; the copy rebuilds it from the keys).
     """
 
     fire_on = "stream"
@@ -754,6 +769,18 @@ class VecStreamCore:
         self.stream_fires = 0
         self.stream_fire_rows = 0
         self.late_rows = 0
+        #: the chunks the native fold took (÷ the node's batches: its share)
+        self.fold_native_batches = 0
+        #: None until the first chunk decides; then whether the key index is
+        #: the hash table (``_tab``: cells of {key, slot}) or the SlotMap's
+        self._native = None
+        self._plan = (None, None)                # a chunk dtype, its plan
+        self._tab = np.zeros((0, 2), dtype=np.int64)
+
+    def __getstate__(self):
+        """A copy carries no hash index: the next chunk rebuilds it from
+        the keys (:meth:`_reserve`)."""
+        return {**self.__dict__, "_tab": self._tab[:0]}
 
     @property
     def keys_live(self) -> int:
@@ -765,9 +792,13 @@ class VecStreamCore:
     # ------------------------------------------------------------- key slots
 
     def _grow_for(self, new_keys: np.ndarray):
-        """SlotMap registration hook: room for the new slots (their lanes
-        are empty: a retired slot's were reset when it went)."""
-        need = self._slotmap.n
+        """SlotMap registration hook: room for the new slots."""
+        self._grow_to(self._slotmap.n)
+        self.keys_live_peak = max(self.keys_live_peak, self._slotmap.n)
+
+    def _grow_to(self, need: int):
+        """Lanes for ``need`` slots (a new slot's are empty: a retired
+        slot's were reset when it went)."""
         if need > self._cap:
             cap = max(self._cap * 2, need, 1024)
             rows = np.zeros((cap, self._W), dtype=np.int64)
@@ -778,15 +809,115 @@ class VecStreamCore:
                 b[:self._cap] = self._acc[of]
                 self._acc[of] = b
             self._cap = cap
-        self.keys_live_peak = max(self.keys_live_peak, need)
+
+    def _reserve(self, need: int):
+        """Room for ``need`` slots before a native fold writes: lanes, the
+        slot -> key column, and an index under half full (rebuilt from the
+        keys when it has to grow)."""
+        self._grow_to(need)
+        sm = self._slotmap
+        if len(sm.keys) < self._cap:
+            grown = np.empty(self._cap, dtype=np.int64)
+            grown[:sm.n] = sm.keys[:sm.n]
+            sm.keys = grown
+        if 2 * need > len(self._tab):
+            self._reindex(need)
+
+    def _reindex(self, need: int = 0):
+        """The hash index anew from the live slots' keys (O(live keys)),
+        with room for ``need`` slots at least."""
+        need = max(need, self._slotmap.n, 1)
+        if 2 * need > len(self._tab):
+            self._tab = np.empty((1 << (2 * need - 1).bit_length(), 2),
+                                 dtype=np.int64)
+        native.enabled().wf_sfold_index(
+            self._tab.ctypes.data, len(self._tab),
+            self._slotmap.keys.ctypes.data, self._slotmap.n)
 
     # ------------------------------------------------------------- processing
 
     def process(self, batch: np.ndarray) -> np.ndarray:
-        outs = run_stream_clock(self, batch, self._fold) if len(batch) else ()
+        if not len(batch):
+            return np.zeros(0, dtype=self._result_dtype)
+        plan = self._native_plan(batch.dtype)
+        if self._native is None:
+            self._native = plan is not None
+        elif self._native and plan is None:     # the stream changed its record
+            self._slotmap.reindex()
+            self._native = False
+        outs = (self._process_native(batch, plan) if self._native
+                else run_stream_clock(self, batch, self._fold))
         if not outs:
             return np.zeros(0, dtype=self._result_dtype)
         return outs[0] if len(outs) == 1 else np.concatenate(outs)
+
+    #: a part's ufunc (None: a count) as ``wf_sfold`` numbers the operations
+    _NATIVE_OPS = {None: 0, np.add: 1, np.minimum: 2, np.maximum: 3}
+
+    def _native_plan(self, dtype):
+        """What ``wf_sfold`` needs to read a chunk of this ``dtype`` --
+        the offsets of key, ts and marker and one {op, offset, kind} a part
+        -- or None where it cannot run: no native library, a part that is
+        not a count or an int64 sum / min / max over an integer field."""
+        if self._plan[0] is dtype or self._plan[0] == dtype:
+            return self._plan[1]
+        lib = native.enabled()
+        desc = []
+        for (_of, name, ufunc, acc_dtype, _ident) in self._parts:
+            op = self._NATIVE_OPS.get(ufunc)
+            if op == 0:
+                desc += [0, 0, 0]
+                continue
+            field, offset = dtype.fields[name][:2]
+            if (op is None or acc_dtype != np.int64
+                    or field.kind not in "iu" or field.shape):
+                break
+            desc += [op, offset,
+                     field.itemsize * (-1 if field.kind == "u" else 1)]
+        plan = None
+        if (lib is not None and len(desc) == 3 * len(self._parts)
+                and len(self._parts) <= lib.wf_sfold_max_parts()
+                and all(dtype.fields[f][0] == np.int64 for f in ("key", "ts"))
+                and dtype.fields[MARKER_FIELD][0].itemsize == 1):
+            plan = ([dtype.fields[f][1] for f in ("key", "ts", MARKER_FIELD)],
+                    np.array(desc, dtype=np.int64))
+        self._plan = (dtype, plan)
+        return plan
+
+    def _process_native(self, batch: np.ndarray, plan) -> list:
+        """One chunk through ``wf_sfold``: what :func:`run_stream_clock`
+        does with :meth:`_fold`, the stretch up to the next boundary a call
+        (it finds the boundary, skips the markers, counts the late rows)."""
+        offs, desc = plan
+        lib, sm = native.enabled(), self._slotmap
+        start_stream_clock(self, int(batch["ts"][0]))
+        self.fold_native_batches += 1
+        n, lo, outs = len(batch), 0, []
+        io = np.zeros(4, dtype=np.int64)
+        while lo < n:
+            # a stretch: one call, or more where the keys outgrow their room
+            io[0] = io[2] = sm.n
+            io[3] = 1
+            geom = np.array([self._L, self._S, self._W, self._fired],
+                            dtype=np.int64)
+            while io[3]:
+                self._reserve(sm.n + 1)
+                acc = (ctypes.c_void_p * len(self._parts))(*(
+                    self._acc[of].ctypes.data for of, *_ in self._parts))
+                lo = lib.wf_sfold(
+                    batch.ctypes.data, batch.strides[0], lo, n, *offs,
+                    self._next_end + self.holdback, geom.ctypes.data,
+                    self._tab.ctypes.data, len(self._tab),
+                    sm.keys.ctypes.data, self._cap, self._rows.ctypes.data,
+                    len(self._parts), desc.ctypes.data, acc, io.ctypes.data)
+                sm.n = int(io[0])
+                if io[1]:
+                    self.late_rows += int(io[1])
+                    profile.add("late_rows", int(io[1]))
+            self.keys_live_peak = max(self.keys_live_peak, sm.n)
+            if lo < n:
+                outs.extend(self._fire(int(batch["ts"][lo]) - self.holdback))
+        return outs
 
     def _fold(self, rows: np.ndarray, ts: np.ndarray):
         """Fold rows into the lanes of the open windows they lie in:
@@ -898,7 +1029,12 @@ class VecStreamCore:
                 if ufunc is not None:
                     self._acc[of][:m] = self._acc[of][keep]
                     self._acc[of][m:n] = ident
-            self._slotmap.retain(keep)
+            if self._native:
+                self._slotmap.keys[:m] = self._slotmap.keys[keep]
+                self._slotmap.n = m
+                self._reindex()
+            else:
+                self._slotmap.retain(keep)
             self.keys_retired += n - m
             profile.add("keys_retired", n - m)
 

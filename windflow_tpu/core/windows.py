@@ -223,6 +223,20 @@ def check_fire_on(fire_on: str, spec: WindowSpec, config: PatternConfig,
                          "own next row closes its window otherwise")
 
 
+def start_stream_clock(core, ts0: int):
+    """The first row a stream-time core takes in, at ``ts0``: the stage
+    starts at window 0 unless its first watermark lies before time 0
+    (:func:`run_stream_clock`)."""
+    if core._clock_started:
+        return
+    core._clock_started = True
+    spec = core.spec
+    if ts0 - core.holdback < 0:
+        first = (ts0 - core.holdback - spec.win_len) // spec.slide_len + 1
+        core._fired = first
+        core._next_end = first * spec.slide_len + spec.win_len
+
+
 def run_stream_clock(core, batch: np.ndarray, fold) -> list:
     """One chunk through a stream-time core (``fire_on="stream"``): the
     stage's clock -- the highest ``ts`` taken in -- runs row by row, and its
@@ -241,13 +255,8 @@ def run_stream_clock(core, batch: np.ndarray, fold) -> list:
     hold = core.holdback
     outs = []
     lo, n = 0, len(batch)
-    if n and not core._clock_started:
-        core._clock_started = True
-        spec = core.spec
-        if int(ts[0]) - hold < 0:
-            first = (int(ts[0]) - hold - spec.win_len) // spec.slide_len + 1
-            core._fired = first
-            core._next_end = first * spec.slide_len + spec.win_len
+    if n:
+        start_stream_clock(core, int(ts[0]))
     while lo < n:
         hit = ts[lo:] >= core._next_end + hold
         cut = lo + int(np.argmax(hit)) if hit.any() else n

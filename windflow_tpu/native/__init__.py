@@ -177,6 +177,18 @@ def _bind(lib):
     lib.wf_launch_coalesce.argtypes = [ctypes.c_void_p, i64, i64, i64]
     lib.wf_launch_take_regular.argtypes = [ctypes.c_void_p, p_i32,
                                            p_i32, p_i32, p_i32]
+    # the stream-time host core's fold (core/vecinc.VecStreamCore): every
+    # array is the caller's, passed as an address
+    lib.wf_sfold_max_parts.restype = i64
+    lib.wf_sfold_max_parts.argtypes = []
+    lib.wf_sfold_index.restype = None
+    lib.wf_sfold_index.argtypes = [ctypes.c_void_p, i64, ctypes.c_void_p,
+                                   i64]
+    lib.wf_sfold.restype = i64
+    lib.wf_sfold.argtypes = ([ctypes.c_void_p] + [i64] * 7
+                             + [ctypes.c_void_p] * 2 + [i64]
+                             + [ctypes.c_void_p, i64, ctypes.c_void_p, i64]
+                             + [ctypes.c_void_p] * 3)
     lib.wf_queue_new.restype = ctypes.c_void_p
     lib.wf_queue_new.argtypes = [i64]
     lib.wf_queue_free.argtypes = [ctypes.c_void_p]

@@ -188,10 +188,11 @@ class WinSeqNode(Node):
 
     def eosnotify(self):
         if self.stats is not None:
-            # what a native core says of its archives, now that every row
-            # is in: the bytes one took there, the rows its bulk path took
-            self._core_counters(self.stats,
-                                ("archive_row_bytes", "fast_rows"))
+            # what a core says of its paths, now that every row is in: the
+            # bytes one took in a native core's archive, the rows its bulk
+            # path took, the chunks a stream-time host core folded natively
+            self._core_counters(self.stats, (
+                "archive_row_bytes", "fast_rows", "fold_native_batches"))
         if self._recov is not None:
             fb = getattr(self.core, "flush_batches", None)
             if fb is not None:
