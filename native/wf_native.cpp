@@ -282,8 +282,9 @@ struct Launch {
 
 // what cut a launch: a row or window count reached (flush_rows, batch_len),
 // the device-following flush below them (wf_core_flush_early), the caller
-// (the max-delay timer, a checkpoint barrier), the end of the stream
-enum FlushTrigger { NATURAL = 0, EARLY = 1, FORCED = 2, EOS = 3 };
+// (the max-delay timer, a progress row), the end of the stream, a
+// checkpoint barrier's drain (wf_core_barrier_flush)
+enum FlushTrigger { NATURAL = 0, EARLY = 1, FORCED = 2, EOS = 3, BARRIER = 4 };
 
 struct Core {
     i64 win, slide;
@@ -514,7 +515,12 @@ struct Core {
     // idle.  It is made only where it costs no shape and no ring of its own:
     // after a natural launch has set both, padded to that launch's shape,
     // and never as a rebase — where the ring is full or a key is new it
-    // leaves everything pending for the natural trigger.
+    // leaves everything pending for the natural trigger.  A BARRIER flush
+    // (wf_core_barrier_flush: a checkpoint's drain, once an epoch) ships
+    // whatever is pending like a FORCED one, rebase included, but padded to
+    // the last natural launch's shape where there is one: the rows since
+    // the last launch are any number below flush_rows, and each bucket of
+    // them would else be a step executable of its own.
     void flush(int trigger = NATURAL) {
         // (a fire that closed nothing still owes its progress row, which
         // rides on a launch so that it leaves after the earlier results)
@@ -523,6 +529,9 @@ struct Core {
             return;
         const bool early = trigger == EARLY;
         if (early && (hkey.empty() || arg_mode || nat_rb == 0)) return;
+        // takes the last natural launch's width and wire dtypes
+        const bool padded = early || (trigger == BARRIER && nat_rb != 0
+                                      && !arg_mode && !stream_mode);
         const i64 K = (i64)keys.size();
         const i64 KPb = bucket(std::max<i64>(K, 1), kp_lo);
         // a row-triggered FIRST flush marks a throughput stream: provision
@@ -549,7 +558,7 @@ struct Core {
         Launch L;
         if (!rebase) {
             const i64 Rb = std::max(bucket(std::max<i64>(maxpend, 1)),
-                                    early ? nat_rb : rb_floor);
+                                    padded ? nat_rb : rb_floor);
             if (arg_mode) {
                 // an arg-extremum ring is never re-shipped.  It is kept at
                 // least twice as wide as the live rows plus one rectangle
@@ -678,7 +687,7 @@ struct Core {
                      || (vmin[f] >= INT32_MIN && vmax[f] <= INT32_MAX))
                 w = 2;
             else w = 3;   // int64 wire (64-bit accumulate dtype)
-            L.xwire[f] = early ? std::max(w, nat_wire[f]) : w;
+            L.xwire[f] = padded ? std::max(w, nat_wire[f]) : w;
         }
         L.wire = L.xwire[0];
         const i64 Rr = std::max<i64>(R, 1);
@@ -767,7 +776,7 @@ struct Core {
             L.has_progress = progress_pending;
             L.progress_wid = progress_wid;
             progress_pending = 0;
-        } else if (early) {
+        } else if (padded) {
             L.Rb = std::max(bucket(Rr), nat_rb);
             if (L.regular) L.cmax = std::max(L.cmax, nat_cmax);
         } else if (trigger == NATURAL) {
@@ -1836,6 +1845,16 @@ i64 wf_core_force_flush(void *h) {
     Core *c = (Core *)h;
     const i64 q0 = c->launches_made;
     c->flush(FORCED);
+    return c->launches_made - q0;
+}
+
+// a checkpoint barrier's drain (NativeResidentCore.checkpoint_drain_batches):
+// ship what is pending, as wf_core_force_flush does, padded to the last
+// natural launch's shape where there is one (Core::flush)
+i64 wf_core_barrier_flush(void *h) {
+    Core *c = (Core *)h;
+    const i64 q0 = c->launches_made;
+    c->flush(BARRIER);
     return c->launches_made - q0;
 }
 

@@ -47,6 +47,17 @@ NODE_OPTIONAL_FIELDS = {
     # triggering_batches) its engagement; the most keys it held at once
     "fold_native_batches": (int,),
     "keys_live_peak": (int,),
+    # a supervised node under recovery= (recovery/epoch.NodeRecovery
+    # .counters, copied onto its NodeStats as it ends)
+    "epochs_committed": (int,),
+    "checkpoints_skipped": (int,),
+    "ckpt_bytes": (int,),
+    "ckpt_bytes_peak": (int,),
+    "journal_peak": (int,),
+    "node_restarts": (int,),
+    "replayed_batches": (int,),
+    "restore_ms": (int, float),
+    "dedup_dropped_batches": (int,),
     # span-tracing latency fields (obs/trace.py; only on traced graphs)
     "q_p50_us": (int, float),
     "q_p95_us": (int, float),

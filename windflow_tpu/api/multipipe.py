@@ -370,6 +370,12 @@ class MultiPipe:
         else:
             df.run_and_wait_end(timeout=timeout)
 
+    def recovery_report(self) -> dict:
+        """What the recovery layer did at each supervised node
+        (engine.Dataflow.recovery_report): empty before ``run()`` and
+        without ``recovery=``."""
+        return self._df.recovery_report() if self._df is not None else {}
+
     @property
     def dead_letters(self):
         """Quarantined poison batches (engine DeadLetter records) — only

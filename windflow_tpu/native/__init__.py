@@ -97,6 +97,8 @@ def _bind(lib):
     lib.wf_core_eos.argtypes = [ctypes.c_void_p]
     lib.wf_core_force_flush.restype = i64
     lib.wf_core_force_flush.argtypes = [ctypes.c_void_p]
+    lib.wf_core_barrier_flush.restype = i64
+    lib.wf_core_barrier_flush.argtypes = [ctypes.c_void_p]
     lib.wf_renum_new.restype = ctypes.c_void_p
     lib.wf_renum_new.argtypes = []
     lib.wf_renum_free.argtypes = [ctypes.c_void_p]

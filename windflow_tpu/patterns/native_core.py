@@ -14,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import os
 import queue as _queue
+import sys
 import threading
 import time
 import weakref
@@ -39,7 +40,7 @@ _DISPATCH_WINDOW = 8
 #: after the one before
 _EARLY_SHARE = 0.5
 #: FlushTrigger (wf_native.cpp): what cut a launch, on its launch record
-_TRIGGERS = ("natural", "early", "forced", "eos")
+_TRIGGERS = ("natural", "early", "forced", "eos", "barrier")
 
 #: what wf_core_stream_stats writes, in its order (cumulative but for
 #: ``rows_held`` and ``keys``, which are levels)
@@ -68,16 +69,27 @@ class NativeStateSnapshot:
     the byte copy must happen at the barrier (wf_core_state_export runs on
     the node thread, under the drained cut) — so resolve(), on the
     supervisor's writer thread, only packages the already-captured bytes
-    into the pickle-ready dict."""
+    into the pickle-ready dict.
+
+    A blob is the bytes as the export wrote them: ``bytes``, or a view of
+    one of its core's export buffers (``_export_buffer``), which the core
+    writes again only once this handle and every other holder of the view
+    are gone.  ``resolve()`` copies views out, so what is pickled never
+    shares a buffer."""
 
     __slots__ = ("blobs", "abi")
 
     def __init__(self, blobs, abi: int):
-        self.blobs = tuple(blobs)   # one bytes blob per key shard
+        self.blobs = tuple(blobs)   # one blob per key shard
         self.abi = int(abi)
 
+    @property
+    def nbytes(self) -> int:
+        return sum(len(b) for b in self.blobs)
+
     def resolve(self) -> dict:
-        return {"kind": "native", "abi": self.abi, "blobs": self.blobs}
+        return {"kind": "native", "abi": self.abi,
+                "blobs": tuple(bytes(b) for b in self.blobs)}
 
 
 def _ship_loop(core_ref, ship_q, shard, shard_id):
@@ -286,6 +298,8 @@ class NativeResidentCore:
         #: early flush — so a replayed run's per-launch emission regroups
         #: exactly like the original's
         self._recovery_mode = False
+        #: buffers state_snapshot exports into (_export_buffer)
+        self._export_bufs = []
         self._delegate = None
         self._offsets = None
         self._salvaged = []  # results drained during a raise, returned to
@@ -658,18 +672,47 @@ class NativeResidentCore:
         return [self._harvest([e]) for e in self._eos_and_drain()]
 
     def checkpoint_drain_batches(self):
-        """Epoch-barrier drain (WinSeqNode.checkpoint_prepare): force-
-        flush pending rows/windows into launches — NOT eos, unfired
-        windows stay pending — and block out the in-flight results (they
-        pre-date the snapshot cut and would otherwise be lost on
-        restore).  Afterwards the C++ cores are drained, which is exactly
-        the precondition wf_core_state_export checks."""
+        """Epoch-barrier drain (WinSeqNode.checkpoint_prepare): flush
+        pending rows/windows into launches — NOT eos, unfired windows stay
+        pending; padded to the last natural launch's shape, so a barrier a
+        second meets no step executable of its own — and block out the
+        in-flight results (they pre-date the snapshot cut and would
+        otherwise be lost on restore).  Afterwards the C++ cores are
+        drained, which is exactly the precondition wf_core_state_export
+        checks."""
         if self._delegate is not None:
             return self._delegate.checkpoint_drain_batches()
         self._enter_recovery_mode()
         for h in self._hs:
-            self._lib.wf_core_force_flush(h)
+            self._lib.wf_core_barrier_flush(h)
         return [self._harvest([e]) for e in self._drain_entries()]
+
+    def _export_buffer(self, nbytes: int) -> np.ndarray:
+        """A uint8 buffer of at least ``nbytes`` that nobody holds any more:
+        one this core exported into before, whose snapshot (the views of it)
+        has been dropped by everyone -- the recovery record replaces it at
+        the next commit, the checkpoint writer once it is pickled -- else a
+        new one of twice the size.  A snapshot a second is up to a whole
+        window of archive columns; mapped afresh each time, its first touch
+        of every page cost more than the copy (0.8 s a snapshot of 0.1-0.24
+        GB on the benchmark's host, PERF.md PR 40), and twice with the copy
+        into ``bytes`` that used to follow.  In steady state two buffers a
+        shard alternate: the committed snapshot's and the one being
+        written."""
+        bufs, best = self._export_bufs, None
+        for i in range(len(bufs)):
+            # free: held by the list and by the call's argument alone
+            if (sys.getrefcount(bufs[i]) == 2 and len(bufs[i]) >= nbytes
+                    and (best is None or len(bufs[i]) < len(bufs[best]))):
+                best = i
+        if best is not None:
+            return bufs[best]
+        # outgrown buffers go as soon as nobody holds them
+        self._export_bufs = [bufs[i] for i in range(len(bufs))
+                             if sys.getrefcount(bufs[i]) > 2]
+        buf = np.empty(max(2 * nbytes, 1 << 16), dtype=np.uint8)
+        self._export_bufs.append(buf)
+        return buf
 
     def state_snapshot(self):
         """Export the drained C++ state (per-key archives + window/
@@ -704,21 +747,24 @@ class NativeResidentCore:
                 raise RuntimeError(
                     "native core not drained at the snapshot barrier "
                     "(checkpoint_prepare must flush + drain first)")
-            buf = np.empty(max(n, 1), dtype=np.uint8)
+            buf = self._export_buffer(n)
             got = int(lib.wf_core_state_export(h, buf.ctypes.data, n))
             if got != n:
                 raise RuntimeError(
                     f"native state export wrote {got} of {n} bytes")
-            blobs.append(buf[:n].tobytes())
-        nbytes = sum(len(b) for b in blobs)
+            blob = buf[:n]
+            blob.flags.writeable = False
+            blobs.append(blob)
+        snap = NativeStateSnapshot(blobs, abi=int(lib.wf_abi_version()))
         self._obs_count("native_state_exports")
-        self._obs_count("native_state_export_bytes", nbytes)
-        self._obs_hist("native_state_blob_bytes", nbytes)
-        return NativeStateSnapshot(blobs, abi=int(lib.wf_abi_version()))
+        self._obs_count("native_state_export_bytes", snap.nbytes)
+        self._obs_hist("native_state_blob_bytes", snap.nbytes)
+        return snap
 
     def state_restore(self, snap):
         if isinstance(snap, NativeStateSnapshot):
-            snap = snap.resolve()
+            # (the handle's own blobs: a restore copies nothing out)
+            snap = {"kind": "native", "abi": snap.abi, "blobs": snap.blobs}
         kind = snap.get("kind")
         if kind == "native_delegate":
             if self._delegate is None:

@@ -70,33 +70,38 @@ class WinSeqNode(Node):
         emission seq numbering independent of harvest timing (host
         cores: no-op)."""
         drain = getattr(self.core, "checkpoint_drain_batches", None)
-        return None if drain is None else drain()
+        if drain is None:
+            return None
+        with profile.span("checkpoint_drain"):
+            return drain()
 
     def state_snapshot(self):
-        snap_fn = getattr(self.core, "state_snapshot", None)
-        if snap_fn is not None:
-            return snap_fn()
-        import copy
-        try:
-            return {"core": copy.deepcopy(self.core)}
-        except Exception as e:
-            # a core holding native/device handles without its own
-            # snapshot hooks cannot deep-copy — decline loudly so the
-            # supervisor degrades to fail-like-seed for this node
-            from ..runtime.node import SnapshotUnsupported
-            raise SnapshotUnsupported(
-                f"{self.name}: core {type(self.core).__name__} is not "
-                f"deep-copyable ({type(e).__name__}: {e})") from e
+        with profile.span("state_export"):
+            snap_fn = getattr(self.core, "state_snapshot", None)
+            if snap_fn is not None:
+                return snap_fn()
+            import copy
+            try:
+                return {"core": copy.deepcopy(self.core)}
+            except Exception as e:
+                # a core holding native/device handles without its own
+                # snapshot hooks cannot deep-copy — decline loudly so the
+                # supervisor degrades to fail-like-seed for this node
+                from ..runtime.node import SnapshotUnsupported
+                raise SnapshotUnsupported(
+                    f"{self.name}: core {type(self.core).__name__} is not "
+                    f"deep-copyable ({type(e).__name__}: {e})") from e
 
     def state_restore(self, snap):
         # the native core's snapshot is a lazy handle object, not a
         # dict — anything that isn't the deep-copy form goes to the
         # core's own restore hook
-        if isinstance(snap, dict) and "core" in snap:
-            import copy
-            self.core = copy.deepcopy(snap["core"])
-        else:
-            self.core.state_restore(snap)
+        with profile.span("state_restore"):
+            if isinstance(snap, dict) and "core" in snap:
+                import copy
+                self.core = copy.deepcopy(snap["core"])
+            else:
+                self.core.state_restore(snap)
 
     def svc(self, batch, channel=0):
         if self._recov is not None:

@@ -75,6 +75,10 @@ class NodeRecovery:
         "requarantine_skip",
         # restart bookkeeping
         "snapshot", "restarts_used", "unrecoverable",
+        # what the layer did, for NodeStats and recovery_report()
+        "epochs_committed", "checkpoints_skipped", "ckpt_bytes",
+        "ckpt_bytes_peak", "journal_peak", "replayed_batches",
+        "restore_ms", "dedup_dropped", "t_raise",
     )
 
     def __init__(self, node_id: str, policy, supervisor, is_source: bool,
@@ -111,6 +115,21 @@ class NodeRecovery:
         self.snapshot = None          # (epoch, node_state, runner_state)
         self.restarts_used = 0
         self.unrecoverable = None     # reason string once set
+        #: barriers this node snapshotted at (the epoch-0 snapshot is not
+        #: one), and barriers a node that can snapshot passed without
+        self.epochs_committed = 0
+        self.checkpoints_skipped = 0
+        #: bytes of the snapshots that say their size (``nbytes``: the
+        #: native core's blobs), in all and the largest
+        self.ckpt_bytes = 0
+        self.ckpt_bytes_peak = 0
+        self.journal_peak = 0         # most inputs journaled at once
+        self.replayed_batches = 0     # journaled batches served again
+        #: a raise -> its journal replayed and the node back on its
+        #: inbox, back-off included, summed over the restarts
+        self.restore_ms = 0.0
+        self.dedup_dropped = 0        # batches dropped as a replayed prefix
+        self.t_raise = None           # monotonic time of the open raise
 
     # ------------------------------------------------------------- producer
 
@@ -201,6 +220,14 @@ class NodeRecovery:
             self.supervisor.note_overflow(self)
             return
         self.journal.append((src, self._journal_item(item), lvl))
+        if len(self.journal) > self.journal_peak:
+            self.journal_peak = len(self.journal)
+
+    def is_replayed(self, src: int, seq: int) -> bool:
+        """Whether ``seq`` on channel ``src`` lies in the prefix this
+        consumer has seen: a restarted producer's replay, which the caller
+        drops -- the drop that makes delivery exactly-once."""
+        return seq <= self.last_seen.get(src, -1)
 
     def _journal_item(self, item):
         if (self.copy_inputs and type(item) is Tagged
@@ -249,6 +276,14 @@ class NodeRecovery:
             "budget": self.budget,
             "epoch": epoch,
         }
+        if epoch > 0:
+            self.epochs_committed += 1
+            # (barrier alignment may jump epochs: a lagging channel's EOS)
+            self.checkpoints_skipped += max(epoch - self.epoch - 1, 0)
+        nbytes = int(getattr(node_state, "nbytes", 0))
+        self.ckpt_bytes += nbytes
+        if nbytes > self.ckpt_bytes_peak:
+            self.ckpt_bytes_peak = nbytes
         self.epoch = epoch
         self.snapshot = (epoch, node_state, runner_state)
         self.quarantined = 0
@@ -286,3 +321,19 @@ class NodeRecovery:
             self.journal = []
             self.journaling = False
             self.supervisor.note_unrecoverable(self, reason)
+
+    def counters(self) -> dict:
+        """What the layer did at this node, under the names its NodeStats
+        file and ``Dataflow.recovery_report()`` give them
+        (docs/OBSERVABILITY.md "Recovery")."""
+        return {
+            "epochs_committed": self.epochs_committed,
+            "checkpoints_skipped": self.checkpoints_skipped,
+            "ckpt_bytes": self.ckpt_bytes,
+            "ckpt_bytes_peak": self.ckpt_bytes_peak,
+            "journal_peak": self.journal_peak,
+            "node_restarts": self.restarts_used,
+            "replayed_batches": self.replayed_batches,
+            "restore_ms": round(self.restore_ms, 3),
+            "dedup_dropped_batches": self.dedup_dropped,
+        }

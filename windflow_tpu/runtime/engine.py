@@ -924,6 +924,9 @@ class Dataflow:
                 shed = getattr(self._inboxes[id(node)], "shed", 0)
                 if shed:
                     node.stats.record_shed(shed)
+                if supervised:
+                    # what the recovery layer did here (recovery/epoch.py)
+                    node.stats.counters.update(node._recov.counters())
                 if self.trace_dir:
                     node.stats.write(self.trace_dir)
             if events is not None:
@@ -1011,6 +1014,7 @@ class Dataflow:
                     # this node alone cannot fix the farm — fail the
                     # graph like the seed engine
                     raise
+                rec.t_raise = _monotonic()
                 if not self._supervisor.authorize_restart(node, rec, e):
                     raise
                 restoring = True
@@ -1049,8 +1053,8 @@ class Dataflow:
                             channel=src, live=rec.live)
             return True
         if type(item) is Tagged:
-            seq, payload = item.seq, item.payload
-            stale = seq <= rec.last_seen.get(src, -1)
+            payload = item.payload
+            stale = rec.is_replayed(src, item.seq)
         else:
             payload = item
             stale = False
@@ -1069,6 +1073,7 @@ class Dataflow:
                 rec.chan_epoch[src] = payload.epoch
             return True
         if stale:
+            rec.dedup_dropped += 1
             return False            # duplicate from a restarted producer
         if lvl is None:
             lvl = rec.chan_epoch.get(src, 0)
@@ -1223,12 +1228,15 @@ class Dataflow:
         if not rec.journaling:
             # non-snapshotable node: just track the epoch so held-back
             # items and marker forwarding stay aligned
+            if rec.unrecoverable is not None:
+                rec.checkpoints_skipped += 1
             rec.epoch = epoch
             return
         try:
             state = node.state_snapshot()
         except SnapshotUnsupported as e:
             rec.mark_unrecoverable(str(e) or type(e).__name__)
+            rec.checkpoints_skipped += 1
             rec.epoch = epoch
             return
         rec.commit(epoch, state)
@@ -1243,17 +1251,22 @@ class Dataflow:
                                     _monotonic() - t0)
 
     def _restore_and_replay(self, node: Node, rec, events):
+        from ..utils import profile
         t0 = _monotonic()
         node_state, todo = rec.restore()
         replayed = -1      # -1: state_restore itself not yet done
         try:
             node.state_restore(node_state)
             replayed = 0
-            for src, item, lvl in todo:
-                if self._dispatch_supervised(node, rec, events, src, item,
-                                             lvl=lvl):
-                    self._complete_barriers(node, rec, events)
-                replayed += 1
+            with profile.span("journal_replay"):
+                for src, item, lvl in todo:
+                    if self._dispatch_supervised(node, rec, events, src,
+                                                 item, lvl=lvl):
+                        self._complete_barriers(node, rec, events)
+                    replayed += 1
+                    if (type(item) is Tagged
+                            and type(item.payload) is not EpochMarker):
+                        rec.replayed_batches += 1
         except BaseException:
             # a fault re-hit mid-replay: the crashing item is already
             # back in the journal (dispatch appends before handling) —
@@ -1267,6 +1280,9 @@ class Dataflow:
         # a transient original fault may not re-raise on replay:
         # leftover skips must never swallow a future real quarantine
         rec.requarantine_skip = 0
+        if rec.t_raise is not None:
+            rec.restore_ms += (_monotonic() - rec.t_raise) * 1e3
+            rec.t_raise = None
         self._supervisor.note_restored(node, rec, len(todo),
                                        _monotonic() - t0)
 
@@ -1429,6 +1445,18 @@ class Dataflow:
     def cardinality(self) -> int:
         """Number of execution threads (multipipe.hpp:973)."""
         return len(self.nodes)
+
+    def recovery_report(self) -> dict[str, dict]:
+        """What the recovery layer did at each supervised node, by the
+        node's id (its NodeStats name): barriers snapshotted at and
+        skipped, snapshot bytes, the journal's peak, restarts, batches
+        replayed, time from a raise to the end of its replay, batches
+        dropped as a replayed prefix (``NodeRecovery.counters``).  Empty
+        without ``recovery=``.  Stable once wait() returned."""
+        return {node._recov.node_id: node._recov.counters()
+                for node in self.nodes
+                if node._recov is not None
+                and not isinstance(node, SourceNode)}
 
     def shed_counts(self) -> dict[str, int]:
         """Items shed per node (the node whose inbox dropped them), for
