@@ -42,6 +42,13 @@ NODE_OPTIONAL_FIELDS = {
     "filter_rows_out": (int,),
     "split_batches": (int,),
     "single_dest_batches": (int,),
+    # the Filter's hand-on (core/tuples.Selection): batches it handed on
+    # ungathered, ÷ its emitting batches; on the keyed emitter the batches
+    # and rows that came so, ÷ rcv_batches / rcv_tuples.  A selection's
+    # len() is its survivor count: what rcv_tuples and a hop's rows read
+    "filter_selections": (int,),
+    "selection_batches": (int,),
+    "selection_rows": (int,),
     # a stream-time host window worker (core/vecinc.VecStreamCore): the
     # chunks its native fold took, ÷ (non_triggering_batches +
     # triggering_batches) its engagement; the most keys it held at once

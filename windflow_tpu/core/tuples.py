@@ -102,6 +102,30 @@ def select_rows(batch: np.ndarray, mask) -> np.ndarray:
     return take_rows(batch, np.flatnonzero(mask))
 
 
+class Selection:
+    """Rows ``idx`` (ascending) of ``base``, not gathered yet: what a node
+    that only drops rows hands a consumer that will copy every survivor
+    anyway (a Filter in front of a splitting emitter), so a row is copied
+    once, not twice.  Only where the wiring proved that the consumer takes
+    one (``Node.takes_selection`` / ``emit_selection``, runtime/node.py);
+    ``len()`` is the survivor count, which is what row counters read.  It
+    only ever reads ``base`` — an emitted batch is immutable — and holds
+    it alive until it is dropped."""
+
+    __slots__ = ("base", "idx")
+
+    def __init__(self, base: np.ndarray, idx: np.ndarray):
+        self.base = base
+        self.idx = idx
+
+    def __len__(self):
+        return len(self.idx)
+
+    def materialize(self) -> np.ndarray:
+        """The survivors as the array ``select_rows`` would have made."""
+        return take_rows(self.base, self.idx)
+
+
 def schema_of(batch: np.ndarray) -> Schema:
     """Recover a Schema from a structured batch array."""
     skip = set(INFO_FIELDS) | {MARKER_FIELD}

@@ -21,8 +21,8 @@ import sys
 
 import numpy as np
 
-from ..core.tuples import (MARKER_FIELD, Schema, group_by_key, select_rows,
-                           take_rows)
+from ..core.tuples import (MARKER_FIELD, Schema, Selection, group_by_key,
+                           select_rows, take_rows)
 from ..runtime.emitters import Collector, StandardEmitter, default_routing
 from ..runtime.node import Node, RuntimeContext, SourceNode
 
@@ -275,7 +275,8 @@ class Map(_Pattern):
 class _FilterNode(Node):
     shed_safe = True   # stateless operator: shedding drops stream rows
     recoverable = True  # stateless: supervised restart needs no snapshot
-    #: the surviving-rows gather is a fresh allocation every time
+    #: the surviving-rows gather is a fresh allocation every time (a
+    #: selection goes only to a consumer that writes nowhere: it splits)
     yields_fresh = True
 
     def __init__(self, fn, name, rich, vectorized):
@@ -291,11 +292,16 @@ class _FilterNode(Node):
         else:
             mask = np.fromiter((bool(self.fn(row, *args)) for row in batch),
                                dtype=bool, count=len(batch))
-        out = select_rows(batch, mask)
+        # the one consumer splits, so it copies every survivor anyway: hand
+        # it the selection and skip the gather (node.py, emit_selection)
+        hand_on = self.emit_selection
+        out = (Selection(batch, np.flatnonzero(mask)) if hand_on
+               else select_rows(batch, mask))
         st = self.stats
         if st is not None:
             st.bump("filter_rows_in", len(batch))
             st.bump("filter_rows_out", len(out))
+            st.bump("filter_selections", int(hand_on and len(out) > 0))
         if len(out):
             self.emit(out)
 

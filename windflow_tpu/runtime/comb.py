@@ -52,6 +52,8 @@ class Comb(Node):
             b.input_fresh = bool(a.yields_fresh)
         #: the Comb hands downstream whatever its last stage emits
         self.yields_fresh = bool(self.stages[-1].yields_fresh)
+        #: ... and takes what its first stage takes (node.py, selections)
+        self.takes_selection = bool(self.stages[0].takes_selection)
         #: the Comb's inbox feeds its FIRST stage, so the overload
         #: contract of that stage governs the fused node (shed only if
         #: the head may shed, runtime/overload.py)
@@ -80,6 +82,15 @@ class Comb(Node):
             and not any(
                 hasattr(getattr(s, "core", None), "process_batches")
                 for s in self.stages[:-1]))
+
+    @property
+    def emit_selection(self):
+        """The last stage's: the Comb's output edges are its (node.py)."""
+        return self.stages[-1].emit_selection
+
+    @emit_selection.setter
+    def emit_selection(self, on):
+        self.stages[-1].emit_selection = on
 
     # -- recovery ----------------------------------------------------------
 

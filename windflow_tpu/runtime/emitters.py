@@ -13,7 +13,7 @@ import ctypes
 
 import numpy as np
 
-from ..core.tuples import MARKER_FIELD, select_rows, take_rows
+from ..core.tuples import MARKER_FIELD, Selection, select_rows, take_rows
 from .node import Node
 
 _NEG_INF = np.int64(-(2 ** 62))
@@ -275,11 +275,20 @@ class StandardEmitter(Node):
         self.n_active = n_dest
         self.routing = routing  # vectorised fn(keys, n) -> dest indices
         self._rr = 0
+        #: a keyed split copies every row into its destination's array, so
+        #: rows still to be gathered (core/tuples.Selection) serve it as
+        #: well as gathered ones (node.py, takes_selection)
+        self.takes_selection = routing is not None and n_dest > 1
 
     def svc(self, batch, channel=0):
         n = self.n_active
+        sel = type(batch) is Selection
+        st = self.stats
+        if st is not None:
+            st.bump("selection_batches", int(sel))
+            st.bump("selection_rows", len(batch) if sel else 0)
         if n == 1:
-            self.emit_to(0, batch)
+            self.emit_to(0, batch.materialize() if sel else batch)
             return
         if self.routing is None:
             # round-robin whole chunks: preserves per-key order only within a
@@ -289,20 +298,24 @@ class StandardEmitter(Node):
             return
         if len(batch) == 0:
             return
-        st = self.stats
-        dest = np.asarray(self.routing(batch["key"], n))
+        # a selection routes on its survivors' keys and is split straight
+        # out of its base: rows idx[...] of it, the one copy a row gets
+        base, idx = (batch.base, batch.idx) if sel else (batch, None)
+        keys = base["key"] if idx is None else base["key"].take(idx)
+        dest = np.asarray(self.routing(keys, n))
         if dest[0] == dest[-1] and not np.any(dest != dest[0]):
             if st is not None:
                 st.bump("single_dest_batches")
-            self.emit_to(int(dest[0]), batch)
+            self.emit_to(int(dest[0]), batch.materialize() if sel else batch)
             return
         if st is not None:
             st.bump("split_batches")
         # one owned array per destination: a consumer may write its batch
         for d in range(n):
-            idx = np.flatnonzero(dest == d)
-            if len(idx):
-                self.emit_to(d, take_rows(batch, idx))
+            rows = np.flatnonzero(dest == d)
+            if len(rows):
+                self.emit_to(d, take_rows(
+                    base, rows if idx is None else idx[rows]))
 
 
 class Collector(Node):

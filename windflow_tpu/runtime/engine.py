@@ -697,6 +697,22 @@ class Dataflow:
         src._outputs.append((inbox, slot))
         self._edges.append((src, dst))
 
+    def _hand_on_selections(self):
+        """Once the graph is whole: a node whose ONE output edge ends in a
+        node that takes selections may hand them on (node.py,
+        ``emit_selection``: a Filter in front of a splitting emitter then
+        skips its gather).  Only in the plain graph: journals, ``Tagged``
+        envelopes, shed items and dead letters hold arrays."""
+        if self.recovery is not None or self.overload is not None:
+            return
+        consumers = {}
+        for src, dst in self._edges:
+            consumers.setdefault(id(src), []).append(dst)
+        for node in self.nodes:
+            dsts = consumers.get(id(node), ())
+            node.emit_selection = (len(dsts) == 1
+                                   and bool(dsts[0].takes_selection))
+
     def on_epoch_sealed(self, fn):
         """Register ``fn(epoch)`` to fire each time the recovery
         supervisor seals a checkpoint epoch (every expected node's blob
@@ -1299,6 +1315,7 @@ class Dataflow:
             # unset default never touches the check package.
             from ..check import enforce
             enforce(self)
+        self._hand_on_selections()
         if self.recovery is not None and self._supervisor is None:
             from ..recovery.supervisor import Supervisor
             self._supervisor = Supervisor(self, self.recovery)

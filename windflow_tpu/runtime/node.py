@@ -71,6 +71,16 @@ class Node:
     yields_fresh = False
     #: the wiring layer proved this node's input batches are handed off
     input_fresh = False
+    #: selections (core/tuples.Selection), the same per-edge proof for a
+    #: copy that need not happen: a consumer that copies every row it is
+    #: given anyway (a splitting emitter) declares ``takes_selection``; the
+    #: engine sets ``emit_selection`` on a producer whose one output edge
+    #: ends in such a consumer, in a graph with no recovery and no overload
+    #: policy (``Dataflow._hand_on_selections``), and a producer that only
+    #: drops rows (Filter) then hands base + row index on instead of
+    #: gathering.  Both default to False: arrays cross every other edge.
+    takes_selection = False
+    emit_selection = False
     #: per-node poison-tuple allowance (runtime/overload.py): how many svc
     #: exceptions this node may quarantine to the dataflow's dead-letter
     #: queue before failing fast.  None = defer to the dataflow's
