@@ -1,0 +1,274 @@
+"""The ``spatial_wf`` deployment on the CPU at a small size
+(benchmarks/configs/spatial_wf.*): the configuration's own ``build`` -- a
+user's window function (the all-pairs skyline) staged to the device through
+``WinFarmTPU`` on the Python resident core -- against the plain reference,
+exact; the float16 control that has to read wrong; the step cache a
+function's pipelines share (``ops/resident._FN_STEP_CACHE``); launches of
+one, two and four windows; and the counters the function-bound launch keeps.
+"""
+
+import gc
+import json
+import os
+import sys
+import weakref
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+for _p in (ROOT, BENCH):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from configs import spatial_wf, spatial_wf_oracle  # noqa: E402
+from harness import check  # noqa: E402
+
+from windflow_tpu.api import MultiPipe  # noqa: E402
+from windflow_tpu.core.windows import WinType  # noqa: E402
+from windflow_tpu.ops import resident  # noqa: E402
+from windflow_tpu.patterns.basic import Sink, Source  # noqa: E402
+from windflow_tpu.patterns.win_seq import window_cores  # noqa: E402
+from windflow_tpu.patterns.win_seq_tpu import (  # noqa: E402
+    JaxWindowFunction, WinFarmTPU, plan_core)
+from windflow_tpu.utils import profile  # noqa: E402
+
+CHUNK, RATE, N_CHUNKS = 64, 100_000, 60    # 3,840 points, 200 a window
+
+
+@pytest.fixture(autouse=True)
+def _profile_state():
+    profile.disable()
+    profile.reset()
+    yield
+    profile.auto()
+    profile.reset()
+
+
+def _cfg(**shapes):
+    with open(os.path.join(BENCH, "configs", "spatial_wf.json")) as f:
+        cfg = json.load(f)
+    cfg["shapes"].update(win_us=2_000, slide_us=500, flush_rows=4096)
+    cfg["shapes"].update(shapes)
+    cfg["stream"]["template_events"] = 4096
+    return cfg
+
+
+def _log():
+    """The open loop's schedule: event i is due at i / RATE."""
+    return {"chunk": CHUNK,
+            "off_us": (np.arange(CHUNK, dtype=np.int64) * 1_000_000) // RATE,
+            "base_us": [(j * CHUNK * 1_000_000) // RATE
+                        for j in range(N_CHUNKS)]}
+
+
+def _source(cfg, seed, log):
+    period = spatial_wf_oracle.period_events(cfg)
+
+    def generate(shipper):
+        for j, base in enumerate(log["base_us"]):
+            start = j * CHUNK
+            batch = np.zeros(CHUNK, dtype=spatial_wf.record_dtype(cfg))
+            for name, col in spatial_wf_oracle.columns(
+                    cfg, seed, start % period, CHUNK).items():
+                batch[name] = col
+            batch["id"] += spatial_wf_oracle.id_shift(
+                cfg, start - start % period)
+            batch["ts"] = base + log["off_us"]
+            shipper.push_batch(batch)
+    return generate
+
+
+def _run(cfg, seed, build=spatial_wf.build):
+    """One pass of the pipeline: the sink's table, the log, the pipe."""
+    log, got = _log(), []
+    pipe = build(cfg, _source(cfg, seed, log),
+                 lambda r: got.append(r.copy())
+                 if r is not None and len(r) else None)
+    pipe.run_and_wait_end(timeout=300)
+    table = {k: np.asarray(v, dtype=np.int64) for k, v in
+             spatial_wf.result_table(np.concatenate(got)).items()}
+    return table, log, pipe
+
+
+def _skyline_fn(fn):
+    return JaxWindowFunction(fn, fields=("x", "y"),
+                             result_fields=dict(spatial_wf.RESULT_FIELDS),
+                             field_dtypes={"x": np.float32, "y": np.float32})
+
+
+def _build_with(winfunc):
+    """The configuration's pipeline around another window function."""
+    def build(cfg, source_fn, sink_fn):
+        shp = cfg["shapes"]
+        return (MultiPipe("sky_other")
+                .add_source(Source(source_fn, spatial_wf.SCHEMA, fresh=True))
+                .add(WinFarmTPU(winfunc, shp["win_us"], shp["slide_us"],
+                                WinType.TB, pardegree=2, batch_len=1,
+                                flush_rows=4096, use_resident=True))
+                .chain_sink(Sink(sink_fn, vectorized=True)))
+    return build
+
+
+def _numbers(table, want):
+    return check.compare(table, want)[0]
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 11, 424242])
+def test_pipeline_matches_the_reference_exactly(seed):
+    cfg = _cfg()
+    table, log, pipe = _run(cfg, seed)
+    cores = window_cores(pipe._df)
+    assert [type(c).__name__ for c in cores] == ["ResidentWinSeqCore"] * 2
+    assert all(c.executor.dispatches > 0 for c in cores)
+    want = spatial_wf_oracle.expected(cfg, seed, log)
+    brute = spatial_wf_oracle.brute_force(cfg, seed, log)
+    assert len(want["wid"]) > 70
+    assert all(np.array_equal(want[k], brute[k]) for k in brute)
+    assert set(_numbers(table, want).values()) == {0}
+    assert np.array_equal(table["wid"], want["wid"])     # in order at the sink
+
+
+def test_plan_core_chooses_the_python_resident_core():
+    fn = spatial_wf.window_function()
+    from windflow_tpu.core.windows import WindowSpec
+    plan = plan_core(WindowSpec(2_000, 500, WinType.TB), fn,
+                     use_resident=True, native=4)
+    assert (plan.core, plan.family, plan.mesh) == ("resident_py", "multi",
+                                                   False)
+
+
+def test_float16_control_reads_wrong():
+    cfg, seed = _cfg(), 5
+    log = _log()
+    want = spatial_wf_oracle.expected(cfg, seed, log)
+    control = spatial_wf_oracle.expected(cfg, seed, log,
+                                         acc_dtype=np.float16)
+    numbers = _numbers({k: v for k, v in control.items()
+                        if not k.startswith("_")}, want)
+    assert numbers["wrong.checksum"] > 0
+    assert not check.verdict(numbers)[0]
+    # ... and so do float16 rings under the program itself
+    with np.errstate(invalid="ignore"):
+        table, _log_, _pipe = _run(
+            cfg, seed, _build_with(spatial_wf.window_function(np.float16)))
+    assert _numbers(table, want)["wrong.checksum"] > 0
+
+
+def _builds():
+    return resident.stats_snapshot()["udf_step_builds"]
+
+
+def test_a_second_pipeline_with_the_same_function_builds_no_step():
+    cfg = _cfg()
+    first, _l, _p = _run(cfg, 9)
+    built = _builds()
+    assert built > 0
+    again, _l, _p = _run(cfg, 9)                # same function object
+    assert _builds() == built
+    assert all(np.array_equal(first[k], again[k]) for k in first)
+
+    def other(keys, gwids, cols, mask):         # a different function
+        return spatial_wf.skyline(keys, gwids, cols, mask)
+
+    third, _l, _p = _run(cfg, 9, _build_with(_skyline_fn(other)))
+    assert _builds() > built
+    assert all(np.array_equal(first[k], third[k]) for k in first)
+
+
+def test_the_step_cache_does_not_keep_a_function_alive():
+    def mine(keys, gwids, cols, mask):
+        return spatial_wf.skyline(keys, gwids, cols, mask)
+
+    fn = _skyline_fn(mine)
+    ex = resident.MultiFieldResidentExecutor(
+        ("x", "y"), jax_fn=fn, acc_dtypes={"x": np.float32,
+                                           "y": np.float32})
+    ex.reset(1, 64)
+    pts = np.asarray([[3, 1, 2, 2]], dtype=np.float32)
+    ex.launch("m", {"x": pts, "y": pts[:, ::-1].copy()},
+              np.zeros(1, dtype=np.int64), np.zeros(1, dtype=np.int64),
+              np.zeros(1, dtype=np.int64), np.asarray([4]),
+              wkeys=np.zeros(1, dtype=np.int64),
+              wgwids=np.zeros(1, dtype=np.int64))
+    (_meta, (size, checksum)), = ex.drain()
+    # (3,2) (1,2) (2,1) (2,3): (1,2) and (2,1) are the frontier
+    assert (int(size[0]), float(checksum[0])) == (2, 6.0)
+    assert mine in resident._FN_STEP_CACHE
+    dead = weakref.ref(mine)
+    del mine, fn, ex
+    gc.collect()
+    assert dead() is None
+    assert not any(f.__name__ == "mine" for f in resident._FN_STEP_CACHE)
+
+
+class _Unhashable:
+    """A callable the function-keyed cache cannot key."""
+    __hash__ = None
+
+    def __call__(self, keys, gwids, cols, mask):
+        return spatial_wf.skyline(keys, gwids, cols, mask)
+
+
+def test_a_function_that_cannot_be_keyed_keeps_its_steps_in_its_executor():
+    fn = _skyline_fn(_Unhashable())
+    ex = resident.MultiFieldResidentExecutor(
+        ("x", "y"), jax_fn=fn, acc_dtypes={"x": np.float32,
+                                           "y": np.float32})
+    ex.reset(1, 64)
+    one = np.ones((1, 2), dtype=np.float32)
+    z = np.zeros(1, dtype=np.int64)
+    for _ in range(2):
+        ex.launch("m", {"x": one, "y": one}, z, z, z, np.asarray([2]),
+                  wkeys=z, wgwids=z)
+    results = ex.drain()
+    assert len(ex._step_cache) == 1 and len(results) == 2
+    assert int(results[0][1][0][0]) == 2          # identical points both live
+
+
+@pytest.mark.parametrize("batch_len", [2, 4])
+def test_launches_of_several_windows_agree_with_one(batch_len):
+    one, log, _p = _run(_cfg(), 13)
+    many, _l, pipe = _run(_cfg(batch_len=batch_len), 13)
+    assert all(np.array_equal(one[k], many[k]) for k in one)
+    fewer = sum(c.executor.dispatches for c in window_cores(pipe._df))
+    assert fewer < sum(c.executor.dispatches
+                       for c in window_cores(_p._df))
+
+
+def test_the_function_bound_launch_counts_its_windows_rows_and_cells():
+    cfg, seed = _cfg(), 21
+    profile.enable()
+    table, log, pipe = _run(cfg, seed)
+    counters, spans = profile.counters(), profile.report()
+    want = spatial_wf_oracle.expected(cfg, seed, log)
+    ts = spatial_wf_oracle._event_times(log)
+    _i, lo, hi, _c = spatial_wf_oracle._windows(cfg, ts)
+    assert counters["udf_windows"] == len(want["wid"]) == len(table["wid"])
+    assert counters["udf_rows"] == int((hi - lo).sum())
+    # every window of a launch runs at the bucketed longest length
+    assert counters["udf_cells"] >= counters["udf_rows"]
+    assert counters["udf_cells"] % 256 == 0       # 200 points run as 256
+    dispatches = sum(c.executor.dispatches for c in window_cores(pipe._df))
+    assert spans["launch_take"][1] == spans["dispatch"][1] == dispatches
+    # a step of the built-in families counts none of it
+    assert "udf_windows" not in _reducer_counters()
+
+
+def _reducer_counters():
+    from windflow_tpu.core.tuples import Schema, batch_from_columns
+    from windflow_tpu.core.windows import WindowSpec
+    from windflow_tpu.ops.functions import MultiReducer, Reducer
+    from windflow_tpu.patterns.win_seq_tpu import ResidentWinSeqCore
+    profile.reset()
+    core = ResidentWinSeqCore(
+        WindowSpec(4, 2, WinType.CB),
+        MultiReducer(Reducer("sum", "a", "s", value_range=(0, 32)),
+                     Reducer("max", "a", "m", value_range=(0, 32))),
+        batch_len=4)
+    ids = np.arange(32)
+    core.process(batch_from_columns(Schema(a=np.int64), key=ids % 2,
+                                    id=ids // 2, ts=ids, a=ids))
+    core.flush()
+    return profile.counters()

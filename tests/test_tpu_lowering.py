@@ -143,18 +143,49 @@ def test_restaging_gather_compiles(v5e):
                                   b).compile()
 
 
-def test_skyline_step_compiles(v5e):
-    """apps/spatial.py's device skyline, the (B, pad, pad) dominance test,
-    on the multi-field resident step it runs on (use_resident=True)."""
+def _app_skyline():
     from windflow_tpu.apps.spatial import device_skyline
+    return device_skyline()
+
+
+def _cell_skyline():
+    """The window function of the benchmark's ``spatial_wf`` configuration
+    (benchmarks/configs/spatial_wf.py)."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from configs import spatial_wf
+    return spatial_wf.window_function()
+
+
+@pytest.mark.parametrize("make,kp,cap,rb,B,pad", [
+    (_app_skyline, 8, 8192, 2048, 256, 512),
+    # what the cell spatial_wf.paced meets: a pipeline's first launch, the
+    # steady launch of one window, and launches of two and four
+    (_cell_skyline, 8, 262144, 131072, 1, 131072),
+    (_cell_skyline, 8, 262144, 16384, 1, 131072),
+    (_cell_skyline, 8, 262144, 16384, 2, 131072),
+    (_cell_skyline, 8, 262144, 16384, 4, 131072)])
+def test_skyline_step_compiles(v5e, make, kp, cap, rb, B, pad):
+    """A device skyline, the (B, pad, pad) dominance test, on the multi-field
+    resident step it runs on (use_resident=True): XLA has to fuse compare
+    and reduce, so no buffer of a pair matrix's size exists, and the user's
+    function stands under its own name in the compiled step."""
     S = _one(v5e)
-    kp, cap, rb, B, pad = 8, 8192, 2048, 256, 512
-    key = (("x", "y"), (), None, cap, rb, B, kp, (F32, F32), (F32, F32), pad)
-    fn = resident._make_multi_step(key, device_skyline())
+    key = (("x", "y"), (), ("x", "y"), cap, rb, B, kp, (F32, F32),
+           (F32, F32), pad)
+    udf = make()             # held here: a step holds its function weakly
+    fn = resident._make_multi_step(key, udf)
     ring, blk = S((kp, cap), jnp.float32), S((kp, rb), jnp.float32)
     b = S((B,), jnp.int32)
-    fn.lower((ring, ring), (blk, blk), S((kp,), jnp.int32), b, b, b, b,
-             b).compile()
+    compiled = fn.lower((ring, ring), (blk, blk), S((kp,), jnp.int32), b, b,
+                        b, b, b).compile()
+    # ... not even at one bit a pair
+    assert compiled.memory_analysis().temp_size_in_bytes < B * pad * pad // 8
+    assert "wf_udf" in compiled.as_text()
 
 
 @pytest.mark.parametrize("B,pad,N", [(8, 8, 1024), (8192, 256, 1 << 20),

@@ -31,6 +31,7 @@ executor is a dumb, replayable launch queue, like the reference's per-worker
 from __future__ import annotations
 
 import threading
+import weakref
 from collections import deque
 
 import numpy as np
@@ -49,6 +50,12 @@ from .monoid import identity as _identity
 _STEP_CACHE = {}
 #: step-cache keys added by prewarm_regular_ladder (never seed ladders)
 _PREWARMED = set()
+#: steps bound to a user's window function (JaxWindowFunction.fn), a dict of
+#: them per function object: every executor given the same function finds
+#: them again (a warm-up pipeline's steps serve the pipeline after it), and
+#: they go when the function does -- the steps hold it weakly (_weak_fn)
+_FN_STEP_CACHE = weakref.WeakKeyDictionary()
+_FN_STEP_MU = threading.Lock()
 
 # -- launch diagnostics (always on: one lock round-trip per dispatch) -------
 # Every resident dispatch feeds these process-wide counters: dispatch count,
@@ -59,7 +66,9 @@ _PREWARMED = set()
 # launch service can be told from a slow host loop.
 
 _STATS_MU = threading.Lock()
-_STATS = {"dispatches": 0, "merges": 0, "svc_s_sum": 0.0, "svc_n": 0}
+_STATS = {"dispatches": 0, "merges": 0, "svc_s_sum": 0.0, "svc_n": 0,
+          # steps built around a user's window function (_make_multi_step)
+          "udf_step_builds": 0}
 
 
 def stats_add(name: str, value=1):
@@ -68,7 +77,8 @@ def stats_add(name: str, value=1):
 
 
 def stats_snapshot(reset: bool = False) -> dict:
-    """{"dispatches", "merges", "mean_launch_ms"} since the last reset."""
+    """{"dispatches", "merges", "udf_step_builds", "mean_launch_ms"} since
+    the last reset."""
     with _STATS_MU:
         snap = dict(_STATS)
         if reset:
@@ -610,6 +620,59 @@ class ResidentWindowExecutor:
         return ready
 
 
+def _weak_fn(fn):
+    """A call that gives `fn` back without keeping it alive: a step cached
+    under its function (_FN_STEP_CACHE) must not pin it.  A callable that
+    cannot be referenced weakly is held as it is (its steps then live in
+    its executor's own cache, _fn_step)."""
+    try:
+        return weakref.ref(fn)
+    except TypeError:
+        return lambda: fn
+
+
+def _fn_step(own_cache, key, jax_fn, make):
+    """The step of shape `key` bound to `jax_fn`, built by `make(key,
+    jax_fn)` at most once per function object and shape: cached under the
+    function the user passed, or in `own_cache` (the executor's) for a
+    callable that cannot be hashed or referenced weakly."""
+    with _FN_STEP_MU:
+        try:
+            cache = _FN_STEP_CACHE.setdefault(jax_fn.fn, {})
+        except TypeError:
+            cache = own_cache
+        fn = cache.get(key)
+        if fn is None:
+            fn = cache[key] = make(key, jax_fn)
+    return fn
+
+
+def _bind_udf(jax_fn):
+    """What a step keeps of a user's window function, counted as a build:
+    its fields and a weak handle on the function."""
+    stats_add("udf_step_builds")
+    return tuple(jax_fn.fields), _weak_fn(jax_fn.fn)
+
+
+def _eval_udf(udf, rings, fidx, cap, pad, wrows, wstarts, wlens, wkeys,
+              wgwids):
+    """The user's window function over (B, pad) gathers of its fields, as a
+    tuple of outputs; its operations read ``wf_udf`` in a device trace,
+    apart from the append and the gathers around them."""
+    fields, fn_ref = udf
+    idx = jnp.minimum(
+        wstarts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :],
+        cap - 1)
+    mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < wlens[:, None]
+    cols = {}
+    for f in fields:
+        vals = rings[fidx[f]][wrows[:, None], idx]
+        cols[f] = jnp.where(mask, vals, 0)
+    with jax.named_scope("wf_udf"):
+        res = fn_ref()(wkeys, wgwids, cols, mask)
+    return res if isinstance(res, tuple) else (res,)
+
+
 def _make_multi_step(key, jax_fn):
     """Fused multi-field append + eval: one ring per field, reducer stats
     evaluate over their field's ring, and an optional batched JAX window
@@ -620,6 +683,7 @@ def _make_multi_step(key, jax_fn):
     (fields, stats, _fnid, cap, Rb, Bb, KP, wires, accs, pad) = key
     acc_dts = tuple(np.dtype(a) for a in accs)
     fidx = {f: i for i, f in enumerate(fields)}
+    udf = None if jax_fn is None else _bind_udf(jax_fn)
 
     def step(rings, blks, offs, wrows, wstarts, wlens, wkeys, wgwids):
         rings = tuple(_ring_append(r, b, offs, dt)
@@ -628,17 +692,9 @@ def _make_multi_step(key, jax_fn):
         for op, f in stats:
             outs.append(_ring_eval(op, cap, pad, acc_dts[fidx[f]],
                                    rings[fidx[f]], wrows, wstarts, wlens))
-        if jax_fn is not None:
-            idx = jnp.minimum(
-                wstarts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :],
-                cap - 1)
-            mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < wlens[:, None]
-            cols = {}
-            for f in jax_fn.fields:
-                vals = rings[fidx[f]][wrows[:, None], idx]
-                cols[f] = jnp.where(mask, vals, 0)
-            res = jax_fn.fn(wkeys, wgwids, cols, mask)
-            outs.extend(res if isinstance(res, tuple) else (res,))
+        if udf is not None:
+            outs.extend(_eval_udf(udf, rings, fidx, cap, pad, wrows, wstarts,
+                                  wlens, wkeys, wgwids))
         return rings, tuple(outs)
 
     return _named_jit(step, "wf_step_multi")
@@ -685,7 +741,17 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         self._svc = deque(maxlen=32)
         self._svc_mean = 0.0
         self.dispatches = 0
-        self._step_cache = {}   # per-executor cache for fn-bound steps
+        #: the step key's function slot: the function's fields (the function
+        #: itself keys _FN_STEP_CACHE), None for a step that binds none
+        self._fn_slot = None if jax_fn is None else tuple(jax_fn.fields)
+        #: fn-bound steps of a callable that _FN_STEP_CACHE cannot key
+        self._step_cache = {}
+        #: the smallest bucket of a launch's window count.  A built-in stat
+        #: over a few padded windows costs nothing worth a shape; a user's
+        #: function is run over every window of the bucket whatever it holds
+        #: (one window padded to eight ran an all-pairs function eight
+        #: times: 102 ms a launch where 14 do, PERF.md PR 42)
+        self._batch_floor = 8 if jax_fn is None else 1
 
     #: the stats this executor's step evaluates
     _OPS = _REDUCE_OPS
@@ -749,23 +815,24 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
             raise ValueError("rectangle exceeds ring rows; reset() first")
         B = len(wstarts)
         Rb = _bucket(max(R, 1))
-        Bb = _bucket(max(B, 1))
+        Bb = _bucket(max(B, 1), lo=self._batch_floor)
         _check_ring_overflow(offs, Rb, self.cap)
         pad = (_bucket(int(wlens.max()) if B else 1)
                if (self.jax_fn is not None
                    or any(op != "sum" for op, _f in self.stats)) else 0)
         wires = tuple(blks[f].dtype.str for f in self.fields)
-        key = (self.fields, self.stats, None, self.cap, Rb, Bb,
+        key = (self.fields, self.stats, self._fn_slot, self.cap, Rb, Bb,
                self.KP, wires,
                tuple(self.acc_dtypes[f].str for f in self.fields), pad)
-        # fn-bound steps cache per executor (the jitted closure pins the
-        # fn; a process-wide cache keyed on fn identity would pin every
-        # instance + compiled executable forever); stat-only steps share
-        # the process-wide cache like the base class
-        cache = _STEP_CACHE if self.jax_fn is None else self._step_cache
-        fn = cache.get(key)
-        if fn is None:
-            fn = cache[key] = _make_multi_step(key, self.jax_fn)
+        # stat-only steps share the process-wide cache like the base class;
+        # a step bound to a user's function is cached under that function
+        if self.jax_fn is None:
+            fn = _STEP_CACHE.get(key)
+            if fn is None:
+                fn = _STEP_CACHE[key] = _make_multi_step(key, None)
+        else:
+            fn = _fn_step(self._step_cache, key, self.jax_fn,
+                          _make_multi_step)
         with profile.span("device_put", *tag):
             blkps = tuple(
                 (blks[f] if blks[f].shape == (self.KP, Rb)
@@ -782,11 +849,22 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
+        if self.jax_fn is not None:
+            self._count_udf(B, wlens, Bb * pad)
         with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:
                 o.copy_to_host_async()
         self._dispatched(meta, B, out, sp, tag)
+
+    @staticmethod
+    def _count_udf(B, wlens, cells):
+        """One launch of a user's window function: its windows, the rows
+        they hold, and the cells the library padded them to (`cells`: every
+        window of the bucketed batch at the bucketed longest length)."""
+        profile.add("udf_windows", B)
+        profile.add("udf_rows", int(np.sum(wlens, dtype=np.int64)))
+        profile.add("udf_cells", cells)
 
 
 # -- the arg-extremum family ---------------------------------------------------
@@ -974,6 +1052,7 @@ def _make_mesh_multi_step(key, jax_fn):
      axis) = key
     acc_dts = tuple(np.dtype(a) for a in accs)
     fidx = {f: i for i, f in enumerate(fields)}
+    udf = None if jax_fn is None else _bind_udf(jax_fn)
     from jax.sharding import PartitionSpec as P
 
     def local(rings, blks, offs, lrows, lstarts, llens, lkeys, lgwids):
@@ -986,17 +1065,9 @@ def _make_mesh_multi_step(key, jax_fn):
         for op, f in stats:
             outs.append(_ring_eval(op, cap, pad, acc_dts[fidx[f]],
                                    rings[fidx[f]], wrows, wstarts, wlens))
-        if jax_fn is not None:
-            idx = jnp.minimum(
-                wstarts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :],
-                cap - 1)
-            mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < wlens[:, None]
-            cols = {}
-            for f in jax_fn.fields:
-                vals = rings[fidx[f]][wrows[:, None], idx]
-                cols[f] = jnp.where(mask, vals, 0)
-            res = jax_fn.fn(lkeys[0], lgwids[0], cols, mask)
-            outs.extend(res if isinstance(res, tuple) else (res,))
+        if udf is not None:
+            outs.extend(_eval_udf(udf, rings, fidx, cap, pad, wrows, wstarts,
+                                  wlens, lkeys[0], lgwids[0]))
         outs = tuple(o[None, :] for o in outs)
         return rings, outs
 
@@ -1074,7 +1145,7 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
             c = int(m.sum())
             slots[m] = np.arange(c)
             maxc = max(maxc, c)
-        Bs = _bucket(max(maxc, 1))
+        Bs = _bucket(max(maxc, 1), lo=self._batch_floor)
         lrows = np.zeros((S, Bs), dtype=np.int32)
         lstarts = np.zeros((S, Bs), dtype=np.int32)
         llens = np.zeros((S, Bs), dtype=np.int32)
@@ -1095,14 +1166,17 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
                if (self.jax_fn is not None
                    or any(op != "sum" for op, _f in self.stats)) else 0)
         wires = tuple(blks[f].dtype.str for f in self.fields)
-        key = ("mesh-multi", self.fields, self.stats, None, self.cap, Rb,
-               Bs, self.KP, wires,
+        key = ("mesh-multi", self.fields, self.stats, self._fn_slot,
+               self.cap, Rb, Bs, self.KP, wires,
                tuple(self.acc_dtypes[f].str for f in self.fields), pad,
                self.mesh, self.axis)
-        cache = _STEP_CACHE if self.jax_fn is None else self._step_cache
-        fn = cache.get(key)
-        if fn is None:
-            fn = cache[key] = _make_mesh_multi_step(key, self.jax_fn)
+        if self.jax_fn is None:
+            fn = _STEP_CACHE.get(key)
+            if fn is None:
+                fn = _STEP_CACHE[key] = _make_mesh_multi_step(key, None)
+        else:
+            fn = _fn_step(self._step_cache, key, self.jax_fn,
+                          _make_mesh_multi_step)
         # shard-major physical scatter (MeshResidentExecutor.launch)
         rows = np.arange(K)
         prow = (rows % S) * rps + rows // S
@@ -1125,6 +1199,8 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
+        if self.jax_fn is not None:
+            self._count_udf(B, wlens, S * Bs * pad)
         with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:

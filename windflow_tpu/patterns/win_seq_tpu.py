@@ -31,6 +31,7 @@ from ..core.winseq import WinSeqCore
 from ..ops.device import DeviceWindowExecutor, builtin_batch_fn
 from ..ops.functions import ArgReducer, MultiReducer, Reducer
 from ..runtime.node import RuntimeContext
+from ..utils import profile
 from .basic import _Pattern
 from .key_farm import KeyFarm
 from .pane_farm import PaneFarm
@@ -560,8 +561,50 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
     def _flush_batch(self):
         if not self._wdesc and not self._pend_rows:
             return
-        from ..ops.resident import _bucket
         ex = self.executor
+        # the host's part of the launch before the transfer, under the name
+        # the native core's ship thread gives its own (OBSERVABILITY.md)
+        with profile.span("launch_take"):
+            blks, offs, wrows, wstarts, wlens = self._take_launch(ex)
+        if self.field is None:
+            # multi-field executor: ships every ring's rectangle + the
+            # (keys, gwids) header columns the JAX fn contract receives
+            if self._jax_fn is not None and self._wdesc:
+                wkeys = np.concatenate([
+                    np.full(len(lens), key, dtype=np.int64)
+                    for key, _a, lens, _g in self._wdesc])
+                wgwids = np.concatenate(
+                    [g for _k, _a, _l, g in self._wdesc]).astype(np.int64)
+            else:
+                wkeys = wgwids = np.zeros(0, dtype=np.int64)
+            ex.launch(self._hdr, blks, offs, wrows, wstarts, wlens,
+                      wkeys=wkeys, wgwids=wgwids)
+        else:
+            ex.launch(self._hdr, blks[self.field], offs, wrows,
+                      wstarts, wlens)
+        # --- advance cursors, apply deferred purges ---
+        for key in self._rowmap:
+            self._launched[key] = self._appended.get(key, 0)
+        for key, pos in self._purge_pos.items():
+            st = self._keys.get(key)
+            if st is not None:
+                st.archive.purge_below(pos)
+        self._pend_cols = {f: {} for f in self._ship_fields}
+        self._pend_rows = 0
+        self._wdesc, self._hdr, self._n_wins = [], [], 0
+        self._purge_pos = {}
+        if self.max_delay_s is not None:
+            # every flush (natural or forced) restarts the latency clock —
+            # otherwise a saturated stream would fragment launches at
+            # max_delay cadence despite fresh batch_len/flush_rows flushes
+            import time as _time
+            self._last_flush_t = _time.monotonic()
+
+    def _take_launch(self, ex):
+        """What the next launch ships: the per-field rectangles, the ring
+        write offsets and the fired windows in ring coordinates -- after a
+        rebase of the ring where it has no room for them."""
+        from ..ops.resident import _bucket
         rowmap = self._rowmap
         K = len(rowmap)
         # --- decide append vs rebase ---
@@ -638,39 +681,7 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
             wlens = np.concatenate([lens for _k, _a, lens, _g in self._wdesc])
         else:
             wrows = wstarts = wlens = np.zeros(0, dtype=np.int64)
-        if self.field is None:
-            # multi-field executor: ships every ring's rectangle + the
-            # (keys, gwids) header columns the JAX fn contract receives
-            if self._jax_fn is not None and self._wdesc:
-                wkeys = np.concatenate([
-                    np.full(len(lens), key, dtype=np.int64)
-                    for key, _a, lens, _g in self._wdesc])
-                wgwids = np.concatenate(
-                    [g for _k, _a, _l, g in self._wdesc]).astype(np.int64)
-            else:
-                wkeys = wgwids = np.zeros(0, dtype=np.int64)
-            ex.launch(self._hdr, blks, offs[:K], wrows, wstarts, wlens,
-                      wkeys=wkeys, wgwids=wgwids)
-        else:
-            ex.launch(self._hdr, blks[self.field], offs[:K], wrows,
-                      wstarts, wlens)
-        # --- advance cursors, apply deferred purges ---
-        for key in rowmap:
-            self._launched[key] = self._appended.get(key, 0)
-        for key, pos in self._purge_pos.items():
-            st = self._keys.get(key)
-            if st is not None:
-                st.archive.purge_below(pos)
-        self._pend_cols = {f: {} for f in self._ship_fields}
-        self._pend_rows = 0
-        self._wdesc, self._hdr, self._n_wins = [], [], 0
-        self._purge_pos = {}
-        if self.max_delay_s is not None:
-            # every flush (natural or forced) restarts the latency clock —
-            # otherwise a saturated stream would fragment launches at
-            # max_delay cadence despite fresh batch_len/flush_rows flushes
-            import time as _time
-            self._last_flush_t = _time.monotonic()
+        return blks, offs[:K], wrows, wstarts, wlens
 
     # ---------------------------------------------------------------- harvest
 
