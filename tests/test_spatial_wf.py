@@ -4,7 +4,9 @@ user's window function (the all-pairs skyline) staged to the device through
 ``WinFarmTPU`` on the Python resident core -- against the plain reference,
 exact; the float16 control that has to read wrong; the step cache a
 function's pipelines share (``ops/resident._FN_STEP_CACHE``); launches of
-one, two and four windows; and the counters the function-bound launch keeps.
+one, two and four windows; the counters the function-bound launch keeps; and
+the ladder a function-bound launch pads its windows to
+(``ops/device._bucket_fine``), beside the power of two a built-in stat keeps.
 """
 
 import gc
@@ -28,6 +30,7 @@ from harness import check  # noqa: E402
 from windflow_tpu.api import MultiPipe  # noqa: E402
 from windflow_tpu.core.windows import WinType  # noqa: E402
 from windflow_tpu.ops import resident  # noqa: E402
+from windflow_tpu.ops.device import _bucket, _bucket_fine  # noqa: E402
 from windflow_tpu.patterns.basic import Sink, Source  # noqa: E402
 from windflow_tpu.patterns.win_seq import window_cores  # noqa: E402
 from windflow_tpu.patterns.win_seq_tpu import (  # noqa: E402
@@ -55,11 +58,11 @@ def _cfg(**shapes):
     return cfg
 
 
-def _log():
-    """The open loop's schedule: event i is due at i / RATE."""
+def _log(rate=RATE):
+    """The open loop's schedule: event i is due at i / rate."""
     return {"chunk": CHUNK,
-            "off_us": (np.arange(CHUNK, dtype=np.int64) * 1_000_000) // RATE,
-            "base_us": [(j * CHUNK * 1_000_000) // RATE
+            "off_us": (np.arange(CHUNK, dtype=np.int64) * 1_000_000) // rate,
+            "base_us": [(j * CHUNK * 1_000_000) // rate
                         for j in range(N_CHUNKS)]}
 
 
@@ -80,9 +83,9 @@ def _source(cfg, seed, log):
     return generate
 
 
-def _run(cfg, seed, build=spatial_wf.build):
+def _run(cfg, seed, build=spatial_wf.build, rate=RATE):
     """One pass of the pipeline: the sink's table, the log, the pipe."""
-    log, got = _log(), []
+    log, got = _log(rate), []
     pipe = build(cfg, _source(cfg, seed, log),
                  lambda r: got.append(r.copy())
                  if r is not None and len(r) else None)
@@ -272,3 +275,122 @@ def _reducer_counters():
                                     id=ids // 2, ts=ids, a=ids))
     core.flush()
     return profile.counters()
+
+
+# -- the padded length of a window handed to a user's function ----------------
+
+def _fine(n):
+    return _bucket_fine(n), _bucket(n)
+
+
+@pytest.mark.parametrize("octave", range(3, 19))
+def test_the_ladder_of_a_function_bound_pad(octave):
+    """Every length of (2^(octave-1), 2^octave] up to 4,096; above, the
+    neighbours of each step and every 997th length between."""
+    lo, hi = (1 << (octave - 1)) + 1, 1 << octave
+    ns = range(lo, hi + 1)
+    if hi > 4096:
+        steps = range(lo - 1, hi + 1, hi // 16)
+        ns = sorted(set(ns[::997]) | {n for s in steps
+                                      for n in (s - 1, s, s + 1) if n in ns})
+    last = 0
+    for n in ns:
+        fine, power = _fine(n)
+        assert n <= fine <= power == hi
+        assert fine >= last                                   # monotone
+        last = fine
+        if n < 1024:
+            assert fine == power
+        else:
+            assert fine % 128 == 0 and 8 * fine <= 9 * n      # <= 12.5% over
+        if n >= 8192:
+            assert fine % 1024 == 0
+    if hi > 1024:
+        # eight steps a doubling: 2^k * (8 + j) / 8
+        assert sorted({_fine(n)[0] for n in ns}) == [
+            (hi // 2) * (8 + j) // 8 for j in range(1, 9)]
+
+
+@pytest.mark.parametrize("n, want", [
+    (0, 8), (1, 8), (200, 256), (1023, 1024), (1024, 1024), (1025, 1152),
+    (98_304, 98_304), (98_305, 106_496), (102_400, 106_496),
+    (102_401, 106_496), (106_497, 114_688), (131_073, 147_456)])
+def test_the_ladder_at_the_lengths_the_records_name(n, want):
+    assert _fine(n)[0] == want
+
+
+def _fn_pads(fn):
+    """The padded lengths of the steps cached under a user's function."""
+    return {key[-1] for key in resident._FN_STEP_CACHE.get(fn, {})}
+
+
+@pytest.mark.parametrize("points, pad", [(1151, 1152), (1152, 1152),
+                                         (1153, 1280)])
+def test_windows_on_under_and_over_a_ladder_step_match_the_reference(
+        points, pad):
+    """One point a microsecond, so a window of `points` us holds `points`
+    of them: just under, on and just over the step 1,152."""
+    cfg, seed = _cfg(win_us=points, slide_us=384), 31 + points
+    table, log, pipe = _run(cfg, seed, rate=1_000_000)
+    want = spatial_wf_oracle.expected(cfg, seed, log)
+    ts = spatial_wf_oracle._event_times(log)
+    _i, lo, hi, _c = spatial_wf_oracle._windows(cfg, ts)
+    assert int((hi - lo).max()) == points and len(want["wid"]) >= 8
+    assert set(_numbers(table, want).values()) == {0}
+    assert np.array_equal(table["wid"], want["wid"])
+    pads = _fn_pads(spatial_wf.skyline)
+    assert pad in pads and 2048 not in pads
+
+
+def _multi_executors(fn=None, stats=()):
+    """A single-device multi-field executor and its mesh twin."""
+    from windflow_tpu.parallel.mesh import make_mesh
+    kw = dict(stats=stats, jax_fn=fn,
+              acc_dtypes={"x": np.float32, "y": np.float32})
+    one = resident.MultiFieldResidentExecutor(("x", "y"), **kw)
+    mesh = resident.MeshMultiFieldResidentExecutor(
+        ("x", "y"), mesh=make_mesh(n_kf=2), **kw)
+    return one, mesh
+
+
+def _launch_one_window(ex, n):
+    """One key's `n` points appended and evaluated as one window."""
+    rng = np.random.default_rng(n)
+    x = rng.integers(0, 1 << 16, (1, n)).astype(np.float32)
+    y = rng.integers(0, 1 << 16, (1, n)).astype(np.float32)
+    z = np.zeros(1, dtype=np.int64)
+    ex.reset(1, 4096)
+    ex.launch("m", {"x": x, "y": y}, z, z, z, np.asarray([n]),
+              wkeys=z, wgwids=z)
+    (_meta, out), = ex.drain()
+    return [np.asarray(o).ravel()[:1] for o in out]
+
+
+def test_the_mesh_twin_pads_a_function_bound_launch_as_the_executor_does():
+    def mine(keys, gwids, cols, mask):
+        return spatial_wf.skyline(keys, gwids, cols, mask)
+
+    one, mesh = _multi_executors(_skyline_fn(mine))
+    outs = [_launch_one_window(ex, 1100) for ex in (one, mesh)]
+    keys = list(resident._FN_STEP_CACHE[mine])
+    assert len(keys) == 2 and keys[0][0] != "mesh-multi" == keys[1][0]
+    assert keys[0][-1] == keys[1][-3] == 1152          # `pad` in both keys
+    assert all(np.array_equal(a, b) for a, b in zip(*outs))
+    lens = np.asarray([1100, 7, 1153])
+    assert one._pad_for(lens) == mesh._pad_for(lens) == 1280
+
+
+def test_a_stat_only_launch_still_keys_its_step_on_the_power_of_two():
+    one, mesh = _multi_executors(stats=(("max", "x"), ("sum", "y")))
+    (mx, sm), (mx_m, sm_m) = (_launch_one_window(ex, 1100)
+                              for ex in (one, mesh))
+    assert mx == mx_m and sm == sm_m
+    # the parent's key, letter for letter: pad 2,048 last
+    key = (("x", "y"), (("max", "x"), ("sum", "y")), None, 4096, 2048, 8, 8,
+           ("<f4", "<f4"), ("<f4", "<f4"), 2048)
+    assert key in resident._STEP_CACHE
+    assert ("mesh-multi",) + key[:6] + (16,) + key[7:] + (
+        mesh.mesh, "kf") in resident._STEP_CACHE        # 2 shards x 8 rows
+    assert one._pad_for(np.asarray([1100])) == 2048
+    one.stats = (("sum", "x"),)                  # prefix sums gather nothing
+    assert one._pad_for(np.asarray([1100])) == 0

@@ -59,6 +59,24 @@ def _bucket(n: int, lo: int = 8) -> int:
     return b
 
 
+#: steps a doubling of `_bucket_fine`'s ladder
+_FINE_STEPS = 8
+
+
+def _bucket_fine(n: int) -> int:
+    """Next step >= n of a geometric ladder of `_FINE_STEPS` a doubling,
+    ``2^k * (8 + j) / 8``, from 1,024 up; the power of two under it.  The
+    padded length of a window handed to a user's function, whose cost goes
+    with every cell and, all pairs, with their square: at most an eighth
+    over `n` where `_bucket` is up to twice it, never over `_bucket(n)`, and
+    every step a multiple of 128 lanes."""
+    b = _bucket(n)
+    if b <= 1024:
+        return b
+    step = b // (2 * _FINE_STEPS)
+    return -(-n // step) * step
+
+
 @functools.lru_cache(maxsize=None)
 def builtin_batch_fn(op: str, field: str = "value"):
     """Batched window function for a built-in reduction, in JAX.  Cached so

@@ -42,7 +42,7 @@ from jax import lax
 
 from ..utils import profile
 from .backend import default_device
-from .device import _bucket
+from .device import _bucket, _bucket_fine
 from .monoid import identity as _identity
 
 #: process-wide compiled-step cache (executors are per-pattern-instance,
@@ -750,7 +750,8 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         #: over a few padded windows costs nothing worth a shape; a user's
         #: function is run over every window of the bucket whatever it holds
         #: (one window padded to eight ran an all-pairs function eight
-        #: times: 102 ms a launch where 14 do, PERF.md PR 42)
+        #: times: 102 ms a launch where 14 do, PERF.md PR 42).  The padded
+        #: window length hangs on the same thing: _pad_for
         self._batch_floor = 8 if jax_fn is None else 1
 
     #: the stats this executor's step evaluates
@@ -803,6 +804,22 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
                 return np.dtype(dt)
         return np.dtype(ladder[-1])
 
+    def _pad_for(self, wlens) -> int:
+        """The padded window length of a launch, part of its step's shape:
+        the longest window's bucket.  A built-in stat's is the power of two
+        (a padded cell is a masked lane of a cheap gather-reduce, and finer
+        buckets would multiply its shapes), 0 where every stat is a prefix
+        sum's; a user's function pays for every cell, an all-pairs one for
+        their square, so its windows go up `_bucket_fine`'s ladder (131,072
+        cells for a window of 102,400 ran 1.64 times the pair tests needed,
+        PERF.md PR 43)."""
+        longest = int(wlens.max()) if len(wlens) else 1
+        if self.jax_fn is not None:
+            return _bucket_fine(longest)
+        if any(op != "sum" for op, _f in self.stats):
+            return _bucket(longest)
+        return 0
+
     def launch(self, meta, blks: dict, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
                wkeys: np.ndarray = None, wgwids: np.ndarray = None,
@@ -817,9 +834,7 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         Rb = _bucket(max(R, 1))
         Bb = _bucket(max(B, 1), lo=self._batch_floor)
         _check_ring_overflow(offs, Rb, self.cap)
-        pad = (_bucket(int(wlens.max()) if B else 1)
-               if (self.jax_fn is not None
-                   or any(op != "sum" for op, _f in self.stats)) else 0)
+        pad = self._pad_for(wlens)
         wires = tuple(blks[f].dtype.str for f in self.fields)
         key = (self.fields, self.stats, self._fn_slot, self.cap, Rb, Bb,
                self.KP, wires,
@@ -861,7 +876,8 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
     def _count_udf(B, wlens, cells):
         """One launch of a user's window function: its windows, the rows
         they hold, and the cells the library padded them to (`cells`: every
-        window of the bucketed batch at the bucketed longest length)."""
+        window of the bucketed batch at the longest length's step of
+        `_bucket_fine`'s ladder)."""
         profile.add("udf_windows", B)
         profile.add("udf_rows", int(np.sum(wlens, dtype=np.int64)))
         profile.add("udf_cells", cells)
@@ -1162,9 +1178,7 @@ class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
                 lgwids[shard, slots] = wgwids
         Rb = _bucket(max(R, 1))
         _check_ring_overflow(offs, Rb, self.cap)
-        pad = (_bucket(int(wlens.max()) if B else 1)
-               if (self.jax_fn is not None
-                   or any(op != "sum" for op, _f in self.stats)) else 0)
+        pad = self._pad_for(wlens)
         wires = tuple(blks[f].dtype.str for f in self.fields)
         key = ("mesh-multi", self.fields, self.stats, self._fn_slot,
                self.cap, Rb, Bs, self.KP, wires,
