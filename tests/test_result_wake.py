@@ -388,12 +388,12 @@ class _Stream:
     sink notes what came and when."""
 
     def __init__(self, stage, chunks, between=None, fused_sink=False,
-                 slow=False, **pipe_kw):
+                 slow=False, schema=SCHEMA, **pipe_kw):
         self.chunks, self.between = chunks, between
         self.got, self.at = [], []
         self.ended = None
         self.pipe = MultiPipe("wake", **pipe_kw)
-        self.pipe.add_source(Source(batches=self._gen(), schema=SCHEMA))
+        self.pipe.add_source(Source(batches=self._gen(), schema=schema))
         self.pipe.add(stage)
         sink = Sink(self._sink, vectorized=True)
         (self.pipe.chain_sink if fused_sink else self.pipe.add_sink)(sink)
@@ -472,7 +472,15 @@ class _Stream:
         wait_for(served, LONG, "every node to have served its input")
         for core in self.cores:
             settle(core, LONG)
-            wait_for(core._out_q.empty, LONG, "an empty _out_q")
+        # a count, not a look at the queues: a harvest takes its launch out
+        # of flight before it puts the result into _out_q, so in between
+        # nothing is in flight and _out_q reads empty, and the next chunk's
+        # process() took what a wake should have.  A launch's record says
+        # ``handed`` once the node thread has taken its result
+        wait_for(lambda: len(handed()) == sum(ex.dispatches
+                                              for core in self.cores
+                                              for ex in core.executors),
+                 LONG, "every launch to be handed over and taken")
 
 
 def _cores_of(node):
@@ -644,7 +652,19 @@ def test_a_failure_is_raised_once_by_collect_and_loses_no_window():
         return fetch(sel, out)
 
     ex._fetch = fetch_failing_once
+    # no fetch returns or fails before process() has: a ship thread that
+    # got there first had process() itself raise the failure, or take the
+    # other shard's result, at its closing drain
+    back = threading.Event()
+    for ex in core.executors:
+        def gated(sel, out, fetch=ex._fetch):
+            assert back.wait(LONG)
+            return fetch(sel, out)
+
+        ex._fetch = gated
     outs = [core.process(chunk)]
+    assert len(outs[0]) == 0
+    back.set()
     # shard 0's wait failed, shard 1's handed its launch over: a wake each
     wait_for(lambda: core._ship_exc is not None
              and core._out_q.qsize() == 1 and len(woken) >= 2, LONG,

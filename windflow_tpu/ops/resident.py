@@ -300,6 +300,12 @@ class ResidentWindowExecutor:
     #: the newest dispatch's device result, replaced by the dispatching
     #: thread: the one value ring_idle() reads from any other thread
     _last_out = None
+    #: which call of its node's thread takes a harvested result on, written
+    #: as ``handed`` onto the launch's ``harvest_wait`` record: ``svc`` or
+    #: ``wake``, set by a core that harvests on that thread itself (the
+    #: Python resident core); None where another thread harvests and the
+    #: core amends the record at its hand-over (the native core)
+    handed = None
 
     def __init__(self, op, device=None, depth: int = 8,
                  acc_dtype=np.int32):
@@ -534,8 +540,8 @@ class ResidentWindowExecutor:
         (harvest_oldest: the native core's ship threads) that is the ring's
         own service — the device step, the copy to the host and the fetch;
         where launches are harvested only at a caller's poll (the
-        synchronous path, the Python resident core) it also holds the wait
-        for that poll.  Safe to read from any thread."""
+        synchronous path, a Python resident core that nothing wakes) it
+        also holds the wait for that poll.  Safe to read from any thread."""
         return self._svc_mean
 
     def _harvest_one(self, how: str, ready: bool = None):
@@ -551,6 +557,8 @@ class ResidentWindowExecutor:
             ready = self._is_ready(out)
         with profile.span("harvest_wait", *tag) as sp:
             sp.extra = {"ready": ready, "harvest": how}
+            if self.handed is not None:
+                sp.extra["handed"] = self.handed
             res = self._fetch(sel, out)
         # only now: a fetch that raised leaves its launch in flight, for
         # the next harvest to try again
@@ -603,6 +611,13 @@ class ResidentWindowExecutor:
         if isinstance(out, tuple):
             return all(o.is_ready() for o in out)
         return out.is_ready()
+
+    @staticmethod
+    def wait_ready(out):
+        """Block until one launch's device result `out` is ready (the wait
+        releases the interpreter lock).  For any thread: it reads nothing
+        of the executor."""
+        jax.block_until_ready(out)
 
     def drain(self):
         # EOS drain taper: issue async D2H copies for EVERY in-flight

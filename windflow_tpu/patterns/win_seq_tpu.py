@@ -21,6 +21,9 @@ covers the reference's "host-callable device functor" testing trick).
 
 from __future__ import annotations
 
+import queue
+import threading
+import weakref
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -382,6 +385,28 @@ def finalize_window_values(reducer: Reducer, vals: np.ndarray,
     return vals
 
 
+def _watch_loop(launches, wake):
+    """A :class:`ResidentWinSeqCore`'s watcher thread: waits on each
+    dispatched launch's device result in turn and wakes the node that
+    drives the core when it is there.  It holds the queue, the waker and
+    one launch's output arrays, and touches nothing else — harvest, fetch
+    and every counter stay on the node's thread, so the executors keep
+    their one-thread contract.  ``None`` ends it."""
+    while True:
+        item = launches.get()
+        if item is None:
+            return
+        wait, out = item
+        try:
+            wait(out)
+        except Exception:
+            # a device failure is not raised here: the node's own poll or
+            # fetch meets it, once, where it is raised without a watcher
+            pass
+        del item, wait, out     # (nothing of a launch held over the next get)
+        wake()
+
+
 class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
     """Window core whose archive lives in device HBM (ops/resident.py).
 
@@ -507,6 +532,77 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         self._hdr = []        # (key, ids, ts, lens) per fire
         self._n_wins = 0
         self._purge_pos = {}  # key -> purge threshold deferred to flush
+        # this core harvests on the thread that drives it, so it says
+        # itself which call took a launch (ops/resident ``handed``)
+        self.executor.handed = "svc"
+        #: wakes the node thread that drives this core (``set_waker``), so
+        #: a launch whose result lands between two chunks is taken by
+        #: ``collect`` then and not with the next chunk
+        self._waker = None
+        #: the watcher thread and what ends it (``_watch``)
+        self._watcher = None
+        self._watch_q = None
+        self._watch_stop = None
+        self._watch_name = f"wf-watch.{worker_index}"
+        #: launches ``collect`` took, and their result rows
+        self.result_wakes = 0
+        self.result_wake_rows = 0
+
+    # ------------------------------------------------------------ the waker
+
+    def set_waker(self, wake) -> bool:
+        """Take ``wake``, a callable for any thread that gets the thread
+        driving this core to call ``collect`` if it is idle (the node's
+        own ``Node._wake``; it must not pin that node): the contract of
+        ``NativeResidentCore.set_waker``.  Refused, with False, under
+        ``max_delay_ms``: that core keeps its timer.  Two more never get
+        here: the native core hands its Python delegate no waker, and the
+        engine gives a node under ``recovery=`` no ``_wake`` to hand on."""
+        # (a watcher of an earlier stream would call that stream's waker)
+        self._stop_watcher()
+        if self.max_delay_s is not None:
+            return False
+        self._waker = wake
+        return True
+
+    def _watch(self, out):
+        """Hand a dispatched launch's device result to the watcher
+        thread, which the first one starts."""
+        if self._watcher is None:
+            self._watch_q = q = queue.SimpleQueue()
+            # the thread holds neither the core nor its executor: a core
+            # that is dropped ends it, as the stream's end does
+            self._watch_stop = weakref.finalize(self, q.put, None)
+            self._watcher = threading.Thread(
+                target=_watch_loop, args=(q, self._waker), daemon=True,
+                name=self._watch_name)
+            self._watcher.start()
+        self._watch_q.put((self.executor.wait_ready, out))
+
+    def _stop_watcher(self):
+        th, self._watcher = self._watcher, None
+        if th is not None:
+            self._watch_stop()
+            th.join(timeout=10)
+
+    def collect(self) -> np.ndarray:
+        """The results of the launches that became ready since the node
+        thread last looked, for that thread between two ``process`` calls
+        (``WinSeqNode.on_wake``).  ``process`` keeps its own poll:
+        whichever comes first takes a launch, the other finds nothing."""
+        ex = self.executor
+        ex.handed = "wake"
+        try:
+            harvested = ex.poll()
+        finally:
+            ex.handed = "svc"
+        out = self._concat(self._build_results(harvested))
+        if harvested:
+            self.result_wakes += len(harvested)
+            self.result_wake_rows += len(out)
+            profile.add("result_wakes", len(harvested))
+            profile.add("result_wake_rows", len(out))
+        return out
 
     # ------------------------------------------------------------ bookkeeping
 
@@ -582,6 +678,8 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         else:
             ex.launch(self._hdr, blks[self.field], offs, wrows,
                       wstarts, wlens)
+        if self._waker is not None:
+            self._watch(ex._last_out)
         # --- advance cursors, apply deferred purges ---
         for key in self._rowmap:
             self._launched[key] = self._appended.get(key, 0)
@@ -723,21 +821,23 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                 self._flush_batch()
                 self._last_flush_t = now
 
-    def process(self, batch):
-        super().process(batch)  # fired windows are enqueued, not returned
-        self._maybe_delay_flush()
-        outs = self._build_results(self.executor.poll())
+    def _concat(self, outs):
         if not outs:
             return np.zeros(0, dtype=self._result_dtype)
         return np.concatenate(outs)
 
+    def process(self, batch):
+        super().process(batch)  # fired windows are enqueued, not returned
+        self._maybe_delay_flush()
+        return self._concat(self._build_results(self.executor.poll()))
+
     def flush(self):
         super().flush()          # enqueue EOS leftovers
         self._flush_batch()      # launch the partial batch
-        outs = self._build_results(self.executor.drain())
-        if not outs:
-            return np.zeros(0, dtype=self._result_dtype)
-        return np.concatenate(outs)
+        harvested = self.executor.drain()
+        # every launch is in: the watcher has nothing left to wait for
+        self._stop_watcher()
+        return self._concat(self._build_results(harvested))
 
     # -- recovery (docs/ROBUSTNESS.md): emission hooks come from
     # _AsyncLaunchRecovery ------------------------------------------------
