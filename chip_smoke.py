@@ -15,7 +15,9 @@ Legs (each run twice: cold wall with compiles, then warm):
   B  sum_test_tpu at bench.py's constants: WinSeqTPU, 16M tuples
   C  the step families A and B never compile: TB windows, max, a two-field
      MultiReducer, a JaxWindowFunction on the restaging executor
-  D  the Pallas kernel, B = 8192 and 32768
+  F  (x1, every host) leg B's stream on a ring placed on a mesh of ONE
+     chip: the mesh placement (ops/resident._OnMesh) on the device it is
+     written for
   E-G (>= 4 chips) KeyFarmTPU one ring per chip, a mesh-sharded ring,
      __graft_entry__'s mesh dry run
 
@@ -409,48 +411,6 @@ def legs_families(n_tuples=4_000_000, platform="tpu", counter=None, seed=11,
     return [tb_sum, cb_max, two_field, jax_fn]
 
 
-def legs_pallas(n_tuples=4_000_000, platform="tpu", counter=None, seed=13,
-                chunk=1 << 20, cases=(("sum", 8192), ("sum", 32768),
-                                      ("max", 8192))):
-    """Leg D: the Pallas window kernel through ``use_pallas=True``, CB
-    256/64, against the host WinSeq.  On a TPU the kernel is compiled by
-    Mosaic (a refusal raises: there is no gather fallback); the CPU backend
-    the tests name runs it through the Pallas interpreter.  Returns one
-    callable per case."""
-    from windflow_tpu.core.windows import WinType
-    from windflow_tpu.ops.device import _JIT_CACHE
-    from windflow_tpu.ops.functions import Reducer
-    from windflow_tpu.patterns.win_seq_tpu import DeviceWinSeqCore, WinSeqTPU
-
-    schema, batches = _sum_stream(n_tuples, chunk, seed)
-    oracles = {}
-
-    def case(op, batch_len):
-        if op not in oracles:
-            oracles[op] = _oracle(batches, schema, Reducer(op), WinType.CB)
-
-        def kernel_built(cores):
-            ex = cores[0].executor
-            if not ex.use_pallas:
-                raise AssertionError("executor dropped use_pallas")
-            keys = [k for k in _JIT_CACHE
-                    if k[:4] == ("pallas", op, "value", platform)]
-            if not keys:
-                raise AssertionError(
-                    f"no pallas {op} kernel was built for {platform}")
-            return {"pallas_buckets": sorted((k[4], k[5]) for k in keys)}
-
-        return _stage_leg(
-            f"D pallas {op} B={batch_len}", batches, schema,
-            lambda: WinSeqTPU(
-                Reducer(op, value_range=VAL_RANGE), WIN, SLIDE, WinType.CB,
-                batch_len=batch_len, use_pallas=True),
-            oracles[op], DeviceWinSeqCore, platform, counter,
-            resident=False, extra_check=kernel_built)
-
-    return [functools.partial(case, op, b) for op, b in cases]
-
-
 def legs_multichip(n_chips, platform="tpu", counter=None, pipe_tuples=None,
                    sum_tuples=None, **size):
     """Legs E-G, one process driving `n_chips` devices: leg A's pipeline on
@@ -532,8 +492,10 @@ def main():
     print(json.dumps({"native_build_s": rebuild_native()}), flush=True)
 
     common = dict(platform="tpu", counter=counter)
+    from windflow_tpu.parallel.mesh import make_mesh
     plan = [lambda: leg_pipe(**common), lambda: leg_sum(**common),
-            *legs_families(**common), *legs_pallas(**common)]
+            *legs_families(**common),
+            lambda: leg_sum(mesh=make_mesh(1, 1), **common)]
     if len(devs) >= 4:
         plan += legs_multichip(4, **common)
     failed = []

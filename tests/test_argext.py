@@ -386,8 +386,6 @@ def test_a_path_that_cannot_run_it_refuses_loudly(monkeypatch):
     spec = WindowSpec(8, 8, WinType.CB)
     red = ArgReducer("max", "price", value_range=PRICE)
     with pytest.raises(ValueError, match="cannot run on the device"):
-        make_core_for(spec, red, use_pallas=True)
-    with pytest.raises(ValueError, match="cannot run on the device"):
         make_core_for(spec, red, use_resident=False)
     with pytest.raises(ValueError, match="one shard"):
         make_core_for(spec, red, shards=2)

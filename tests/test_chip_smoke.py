@@ -34,6 +34,17 @@ def test_leg_sum_tiny():
     assert f["windows"] == 320 and f["dispatches"] > 0
 
 
+def test_leg_sum_on_a_mesh_of_one_tiny():
+    """Leg F as a one-chip host runs it: the mesh placement over one
+    device."""
+    from windflow_tpu.parallel.mesh import make_mesh
+    f = _check(cs.leg_sum(n_tuples=N, batch_len=128, flush_rows=1 << 12,
+                          mesh=make_mesh(1, 1), **TINY), "NativeResidentCore")
+    assert f["leg"] == "F mesh ring x1" and f["dispatches"] > 0
+    assert (f["ring_devices"], f["windows"]) == (1, 320)
+    assert f["ring_spec"] == "PartitionSpec('kf', None)"
+
+
 def test_legs_families_tiny():
     legs = [leg() for leg in cs.legs_families(
         n_tuples=N, batch_len=128, flush_rows=1 << 12, **TINY)]
@@ -42,15 +53,6 @@ def test_legs_families_tiny():
         _check(f, "NativeResidentCore")
     assert legs[2]["rings"] == 2
     assert _check(legs[3], "DeviceWinSeqCore")["launches"] > 0
-
-
-def test_legs_pallas_tiny():
-    """On the CPU backend the kernel runs through the Pallas interpreter;
-    the leg still demands the pallas executor and oracle-equal windows."""
-    for leg in cs.legs_pallas(n_tuples=N, cases=(("sum", 64), ("max", 256)),
-                              **TINY):
-        f = _check(leg(), "DeviceWinSeqCore")
-        assert f["pallas_buckets"] and f["launches"] > 0
 
 
 def test_legs_multichip_tiny():

@@ -111,81 +111,6 @@ def test_incremental_rejected_on_device():
         core.use_incremental()
 
 
-@pytest.mark.parametrize("op,pad,B", [("sum", 32, 64), ("max", 256, 200),
-                                      ("min", 512, 130), ("sum", 8, 8)])
-def test_pallas_windowed_reduce_interpret(op, pad, B):
-    """The pallas kernel (interpret mode on CPU) against numpy: windows
-    start at any offset inside a 128-lane row, lengths 0..pad, batch sizes
-    off the 128 grid (tests/test_tpu_lowering.py compiles the same kernel
-    for a v5e)."""
-    from windflow_tpu.ops.monoid import identity
-    from windflow_tpu.ops.pallas_kernels import (flat_slack,
-                                                 windowed_reduce_pallas)
-
-    rng = np.random.default_rng(0)
-    n = 3000
-    flat = rng.integers(-100, 100, size=n).astype(np.int32)
-    starts = rng.integers(0, n - pad, size=B).astype(np.int32)
-    lens = rng.integers(0, pad + 1, size=B).astype(np.int32)
-    padded = np.zeros(4096 + flat_slack(pad), dtype=np.int32)
-    padded = padded[:len(padded) // 128 * 128]
-    padded[:n] = flat
-    out = np.asarray(windowed_reduce_pallas(padded, starts, lens, pad, op,
-                                            interpret=True))
-    red = {"sum": np.sum, "max": np.max, "min": np.min}[op]
-    want = np.array([red(flat[s:s + l]) if l else identity(op, np.int32)
-                     for s, l in zip(starts, lens)], dtype=np.int32)
-    assert np.array_equal(out, want)
-
-
-def test_pallas_kernel_refuses_what_it_cannot_hold():
-    from windflow_tpu.ops.pallas_kernels import (MAX_FLAT_BYTES,
-                                                 windowed_reduce_pallas)
-    z = np.zeros(8, np.int32)
-    with pytest.raises(ValueError, match="multiple of 128"):
-        windowed_reduce_pallas(np.zeros(1000, np.int32), z, z, 8, "sum",
-                               interpret=True)
-    with pytest.raises(ValueError, match="32-bit"):
-        windowed_reduce_pallas(np.zeros(1024, np.int8), z, z, 8, "sum",
-                               interpret=True)
-    with pytest.raises(ValueError, match="exceeds"):
-        windowed_reduce_pallas(
-            np.zeros(MAX_FLAT_BYTES // 4 + 128, np.int32), z, z, 8, "sum",
-            interpret=True)
-
-
-def test_win_seq_tpu_pallas_matches():
-    stage = WinSeqTPU(Reducer("sum"), 12, 5, WinType.CB, batch_len=64,
-                      use_pallas=True)
-    got = run_windowed(stage, cb_stream_batches(2, 200))
-    assert got == ref(12, 5, WinType.CB, cb_stream_batches(2, 200))
-    assert stage.make_core().executor.use_pallas
-
-
-def test_pallas_compile_failure_raises(monkeypatch):
-    """use_pallas=True is an explicit request: a kernel the compiler
-    refuses raises out of the launch — nothing swaps in the XLA gather
-    path behind the caller's back."""
-    from windflow_tpu.ops import device, pallas_kernels
-
-    def refused(*a, **kw):
-        raise RuntimeError("Mosaic failed to compile TPU kernel")
-
-    monkeypatch.setattr(pallas_kernels, "windowed_reduce_pallas", refused)
-    monkeypatch.setattr(device, "_JIT_CACHE", {})
-    ex = device.DeviceWindowExecutor(
-        device.builtin_batch_fn("sum"), use_pallas=True, op="sum")
-    z = np.zeros(8, np.int64)
-    with pytest.raises(RuntimeError, match="Mosaic failed"):
-        ex.launch(None, {"value": np.arange(64)}, z, z + 4, z, z)
-    assert ex.use_pallas and ex.launches == 0
-    # the same launch without the request is served by the gather path
-    ok = device.DeviceWindowExecutor(device.builtin_batch_fn("sum"),
-                                     op="sum")
-    ok.launch(None, {"value": np.arange(64)}, z, z + 4, z, z)
-    assert ok.drain()[0][1]["value"].tolist() == [6] * 8
-
-
 @pytest.mark.parametrize("pardegree", [2, 3])
 def test_win_farm_tpu(pardegree):
     keys, n = 3, 140

@@ -677,42 +677,46 @@ def _S(shape, dt):
     return jax.ShapeDtypeStruct(shape, dt)
 
 
+def _reg_key(cap, rb):
+    return resident.StepKey("regular", resident._ANY_DEVICE, "sum", cap, rb,
+                            C, KP, I8, I32, slide=4)
+
+
 def _family(name):
     k = _S((KP,), jnp.int32)
     b = _S((B,), jnp.int32)
     ring, blk = _S((KP, CAP), jnp.int32), _S((KP, RB), jnp.int8)
     if name == "wf_step_regular":
-        fn = resident._make_regular_step(
-            ("reg", "sum", CAP, RB, KP, C, I8, I32, 4))
-        return fn, (ring, blk, k, k, k, k)
+        return resident._make_regular_step(_reg_key(CAP, RB)), \
+            (ring, blk, k, k, k, k)
+    one = resident._ANY_DEVICE
     if name == "wf_step_append_eval":
-        fn = resident._make_step((("max",), CAP, RB, B, KP, I8, I32, 16))
+        fn = resident._make_step(resident.StepKey(
+            "append_eval", one, ("max",), CAP, RB, B, KP, I8, I32, 16))
         return fn, (ring, blk, k, b, b, b)
+    multi = resident.StepKey(
+        "multi", one, (("sum", "a"), ("max", "b")), CAP, RB, B, KP, (I8, I8),
+        (I32, I32), 16, fields=("a", "b"))
     if name == "wf_step_multi":
-        key = (("a", "b"), (("sum", "a"), ("max", "b")), None, CAP, RB, B,
-               KP, (I8, I8), (I32, I32), 16)
-        fn = resident._make_multi_step(key, None)
+        fn = resident._make_multi_step(multi, None)
         return fn, ((ring, ring), (blk, blk), k, b, b, b, b, b)
     if name == "wf_step_argext":
-        key = ("argext", ("a", "b"), (("argmax", "a"), ("sum", "b")), CAP,
-               RB, B, KP, (I8, I8), (I32, I32), 0, 256)
-        fn = resident._make_argext_step(key)
+        fn = resident._make_argext_step(multi._replace(
+            family="argext", stats=(("argmax", "a"), ("sum", "b")), pad=0,
+            eb=256))
         return fn, ((ring, ring), (blk, blk), k, k, b, b, b)
     from jax.sharding import Mesh
-    mesh = Mesh(np.array(jax.devices()[:4]), ("kf",))
+    on = resident._OnMesh(Mesh(np.array(jax.devices()[:4]), ("kf",)), "kf")
     d = _S((4, B), jnp.int32)
     if name == "wf_step_regular_mesh":
-        fn = resident._make_mesh_regular_step(
-            ("mesh-reg", "sum", CAP, RB, KP, C, I8, I32, 4, mesh, "kf"))
+        fn = resident._make_regular_step(_reg_key(CAP, RB)._replace(place=on))
         return fn, (ring, blk, k, k, k, k)
     if name == "wf_step_append_eval_mesh":
-        fn = resident._make_mesh_step(
-            ("mesh", ("max",), CAP, RB, B, KP, I8, I32, 16, mesh, "kf"))
+        fn = resident._make_step(resident.StepKey(
+            "append_eval", on, ("max",), CAP, RB, B, KP, I8, I32, 16))
         return fn, (ring, blk, k, d, d, d)
     assert name == "wf_step_multi_mesh"
-    key = ("mesh-multi", ("a", "b"), (("sum", "a"), ("max", "b")), None,
-           CAP, RB, B, KP, (I8, I8), (I32, I32), 16, mesh, "kf")
-    fn = resident._make_mesh_multi_step(key, None)
+    fn = resident._make_multi_step(multi._replace(place=on), None)
     return fn, ((ring, ring), (blk, blk), k, d, d, d, d, d)
 
 
@@ -729,7 +733,7 @@ def test_step_executable_is_named_by_its_family(name):
 def test_prewarm_ladder_goes_through_the_named_factories():
     """The ladder warms what the window uses: its siblings come from the
     same factories, so they carry the family's name too."""
-    key = ("reg", "sum", 1024, 16, 8, 8, I8, I32, 4)
+    key = _reg_key(1024, 16)
     saved = dict(resident._STEP_CACHE)
     saved_warm = set(resident._PREWARMED)
     try:

@@ -720,16 +720,16 @@ def test_prewarm_regular_ladder_covers_merged_shapes():
     nat = make_native(spec, Reducer("sum"), batch_len=1 << 20,
                       flush_rows=256, overlap=False)
     run_core(nat, batches)
-    base = [k for k in R._STEP_CACHE if k[0] == "reg"]
+    base = [k for k in R._STEP_CACHE if k.family == "regular"]
     assert base, "no regular buckets compiled"
     n = R.prewarm_regular_ladder()
     assert n > 0
     for key in base:
-        _t, op, cap, Rb, KP, C, blk_dt, acc_dt, slide = key
         for m in (2, 4, 8, 16):
-            if Rb * m > cap or (KP // 2 + 1) * Rb * m > (1 << 24):
+            if (key.Rb * m > key.cap
+                    or (key.KP // 2 + 1) * key.Rb * m > (1 << 24)):
                 continue
-            sk = ("reg", op, cap, Rb * m, KP, C * m, blk_dt, acc_dt, slide)
+            sk = key._replace(Rb=key.Rb * m, Bb=key.Bb * m)
             assert sk in R._STEP_CACHE, f"ladder sibling missing: {sk}"
     # idempotent: a second call has nothing left to do
     assert R.prewarm_regular_ladder() == 0

@@ -70,7 +70,6 @@ CASES = [
     ("arg+siblings", CB, lambda: MultiReducer(
         arg(), ("count", None, "n"), Reducer("sum", "qty", **R)), {},
      NATIVE_ARGEXT),
-    ("arg-pallas", CB, arg, dict(use_pallas=True), "on the resident path"),
     ("arg-restage", CB, arg, dict(use_resident=False),
      "on the resident path"),
     ("arg-mesh", CB, arg, dict(mesh=True), "one shard on one device"),
@@ -88,15 +87,12 @@ CASES = [
      lambda: Reducer("max", "ts", out_field="hi", **R), {},
      CorePlan("native", "regular", False)),
     ("count+max-ts", TB, count_max_ts, {}, HOST),
-    ("count+max-ts-pallas", TB, count_max_ts, dict(use_pallas=True), HOST),
-    ("count-pallas", CB, lambda: Reducer("count"), dict(use_pallas=True),
+    ("count-restage", CB, lambda: Reducer("count"), dict(use_resident=False),
      RESTAGE),
     # -- MultiReducer: resident only
     ("count-only-forced", CB, lambda: MultiReducer(("count", None, "n")),
      dict(use_resident=True), "needs >=1 non-count stat"),
     ("multi-restage", CB, ysb, dict(use_resident=False),
-     "resident device path only"),
-    ("multi-pallas", CB, ysb, dict(use_pallas=True),
      "resident device path only"),
     ("multi-float-sum", CB, lambda: MultiReducer(
         Reducer("sum", "x", dtype=np.float32), ("count", None, "n")), {},
@@ -138,10 +134,8 @@ CASES = [
      CorePlan("resident_py", "multi", False)),
     ("jax-fn-mesh", CB, jax_fn, dict(mesh=True),
      CorePlan("resident_py", "multi", True)),
-    ("jax-fn-mesh-pallas", CB, jax_fn, dict(mesh=True, use_pallas=True),
-     "needs a resident-path Reducer"),
-    ("jax-fn-resident-pallas-no-native", CB, jax_fn,
-     dict(use_resident=True, use_pallas=True, native=None),
+    ("jax-fn-resident-no-native", CB, jax_fn,
+     dict(use_resident=True, native=None),
      CorePlan("resident_py", "multi", False)),
     # -- one Reducer
     ("sum", CB, isum, {}, CorePlan("native", "regular", False)),
@@ -149,7 +143,6 @@ CASES = [
      CorePlan("native", "regular", False)),
     ("sum-no-native", CB, isum, dict(native=None),
      CorePlan("resident_py", "regular", False)),
-    ("sum-pallas", CB, isum, dict(use_pallas=True), RESTAGE),
     ("sum-restage", CB, isum, dict(use_resident=False), RESTAGE),
     ("float-sum", CB, fsum, {}, RESTAGE),
     ("float-sum-forced", CB, fsum, dict(use_resident=True),
@@ -167,28 +160,26 @@ CASES = [
      "needs a resident-path Reducer"),
     ("float-sum-mesh", CB, fsum, dict(mesh=True),
      "requires the resident path"),
-    ("sum-mesh-pallas", CB, isum, dict(mesh=True, use_pallas=True),
-     "requires the resident path"),
     ("sum-mesh-restage", CB, isum, dict(mesh=True, use_resident=False),
      "requires the resident path"),
     ("float-sum-mesh-forced", CB, fsum, dict(mesh=True, use_resident=True),
      CorePlan("native", "regular", True)),
 ]
 
-_FAMILY = {"ResidentWindowExecutor": ("regular", False),
-           "MeshResidentExecutor": ("regular", True),
-           "MultiFieldResidentExecutor": ("multi", False),
-           "MeshMultiFieldResidentExecutor": ("multi", True),
-           "ArgExtResidentExecutor": ("argext", False)}
+_FAMILY = {"ResidentWindowExecutor": "regular",
+           "MultiFieldResidentExecutor": "multi",
+           "ArgExtResidentExecutor": "argext"}
 _CORE = {"NativeResidentCore": "native", "ResidentWinSeqCore": "resident_py",
          "DeviceWinSeqCore": "restage"}
 
 
 def built(core) -> CorePlan:
-    """The plan a built core embodies, read from its classes."""
+    """The plan a built core embodies, read from its classes and from
+    where its executor's rings live."""
     kind = _CORE.get(type(core).__name__, "host")
-    ex = type(getattr(core, "executor", None)).__name__
-    return CorePlan(kind, *_FAMILY.get(ex, (None, False)))
+    ex = getattr(core, "executor", None)
+    return CorePlan(kind, _FAMILY.get(type(ex).__name__),
+                    getattr(ex, "mesh", None) is not None)
 
 
 @pytest.fixture(scope="module")
@@ -241,8 +232,7 @@ def test_plan_does_not_depend_on_what_ran_before(monkeypatch):
     service and the environment change nothing — and a stage with a latency
     budget no launch could meet still gets the core its arguments name (it
     moved to the host core once a warm-up had taught the process a floor)."""
-    args = dict(use_pallas=False, use_resident=None, mesh=None, shards=1,
-                native=FIELDS)
+    args = dict(use_resident=None, mesh=None, shards=1, native=FIELDS)
     before = [plan_core(CB, isum(), **args), plan_core(TB, ysb(), **args)]
     tight = make_core_for(CB, isum(), max_delay_ms=1e-6)
     assert len(_launch_some(make_core_for(CB, isum(), batch_len=4,
@@ -307,7 +297,7 @@ STREAM_CASES = [
     ("sum-mesh", SLIDING, isum, dict(mesh=True), "one shard on one device"),
     ("sum-max-delay", SLIDING, isum, dict(max_delay_ms=5), "wall clock"),
     ("arg", SLIDING, arg, {}, "arg-extremum family"),
-    ("sum-pallas", SLIDING, isum, dict(use_pallas=True),
+    ("sum-restage", SLIDING, isum, dict(use_resident=False),
      "planned onto the 'restage' core"),
     ("sum-no-native", SLIDING, isum, dict(native=None),
      "planned onto the 'resident_py' core"),

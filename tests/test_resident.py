@@ -504,29 +504,6 @@ def test_host_free_tb_aggregate_routes_to_host_core():
     assert isinstance(cb, ResidentWinSeqCore)
 
 
-def test_host_free_routing_honors_pallas_request():
-    """use_pallas=True must keep the device path even for host-free
-    reducers (Pallas benchmarking stays reachable)."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        core = make_core_for(WindowSpec(10, 5, WinType.TB),
-                             Reducer("max", "ts", "hi"), use_pallas=True)
-    assert isinstance(core, DeviceWinSeqCore)
-
-
-def test_host_free_multireducer_ignores_pallas_flag():
-    """MultiReducer has no Pallas path, so use_pallas must not block its
-    host-free routing (it used to raise a misleading resident-only
-    error)."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        core = make_core_for(
-            WindowSpec(10, 5, WinType.TB),
-            MultiReducer(("count", None, "c"), ("max", "ts", "hi")),
-            use_pallas=True)
-    assert not isinstance(core, (DeviceWinSeqCore, ResidentWinSeqCore))
-
-
 def test_acc_dtype_warning_gated_on_value_range():
     """The int32-accumulate wrap warning must not fire
     when the Reducer's declared value_range plus the CB window length prove

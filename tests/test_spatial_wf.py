@@ -321,7 +321,7 @@ def test_the_ladder_at_the_lengths_the_records_name(n, want):
 
 def _fn_pads(fn):
     """The padded lengths of the steps cached under a user's function."""
-    return {key[-1] for key in resident._FN_STEP_CACHE.get(fn, {})}
+    return {key.pad for key in resident._FN_STEP_CACHE.get(fn, {})}
 
 
 @pytest.mark.parametrize("points, pad", [(1151, 1152), (1152, 1152),
@@ -343,14 +343,11 @@ def test_windows_on_under_and_over_a_ladder_step_match_the_reference(
 
 
 def _multi_executors(fn=None, stats=()):
-    """A single-device multi-field executor and its mesh twin."""
+    """A multi-field executor on one device and one on a mesh of two."""
     from windflow_tpu.parallel.mesh import make_mesh
-    kw = dict(stats=stats, jax_fn=fn,
-              acc_dtypes={"x": np.float32, "y": np.float32})
-    one = resident.MultiFieldResidentExecutor(("x", "y"), **kw)
-    mesh = resident.MeshMultiFieldResidentExecutor(
-        ("x", "y"), mesh=make_mesh(n_kf=2), **kw)
-    return one, mesh
+    return [resident.make_executor(
+        "multi", ("x", "y"), stats, {"x": np.float32, "y": np.float32},
+        jax_fn=fn, mesh=mesh) for mesh in (None, make_mesh(n_kf=2))]
 
 
 def _launch_one_window(ex, n):
@@ -366,15 +363,16 @@ def _launch_one_window(ex, n):
     return [np.asarray(o).ravel()[:1] for o in out]
 
 
-def test_the_mesh_twin_pads_a_function_bound_launch_as_the_executor_does():
+def test_a_function_bound_launch_is_padded_alike_on_a_mesh_and_on_one_device():
     def mine(keys, gwids, cols, mask):
         return spatial_wf.skyline(keys, gwids, cols, mask)
 
     one, mesh = _multi_executors(_skyline_fn(mine))
     outs = [_launch_one_window(ex, 1100) for ex in (one, mesh)]
     keys = list(resident._FN_STEP_CACHE[mine])
-    assert len(keys) == 2 and keys[0][0] != "mesh-multi" == keys[1][0]
-    assert keys[0][-1] == keys[1][-3] == 1152          # `pad` in both keys
+    assert len(keys) == 2 and keys[0].place.mesh is None
+    assert keys[1].place == (mesh.mesh, "kf")
+    assert keys[0].pad == keys[1].pad == 1152
     assert all(np.array_equal(a, b) for a, b in zip(*outs))
     lens = np.asarray([1100, 7, 1153])
     assert one._pad_for(lens) == mesh._pad_for(lens) == 1280
@@ -385,12 +383,13 @@ def test_a_stat_only_launch_still_keys_its_step_on_the_power_of_two():
     (mx, sm), (mx_m, sm_m) = (_launch_one_window(ex, 1100)
                               for ex in (one, mesh))
     assert mx == mx_m and sm == sm_m
-    # the parent's key, letter for letter: pad 2,048 last
-    key = (("x", "y"), (("max", "x"), ("sum", "y")), None, 4096, 2048, 8, 8,
-           ("<f4", "<f4"), ("<f4", "<f4"), 2048)
+    # PR 43's parent's key, field for field: pad 2,048
+    key = resident.StepKey(
+        "multi", resident._ANY_DEVICE, (("max", "x"), ("sum", "y")), 4096,
+        2048, 8, 8, ("<f4", "<f4"), ("<f4", "<f4"), 2048, ("x", "y"))
     assert key in resident._STEP_CACHE
-    assert ("mesh-multi",) + key[:6] + (16,) + key[7:] + (
-        mesh.mesh, "kf") in resident._STEP_CACHE        # 2 shards x 8 rows
+    assert key._replace(place=mesh.place, KP=16) \
+        in resident._STEP_CACHE                         # 2 shards x 8 rows
     assert one._pad_for(np.asarray([1100])) == 2048
     one.stats = (("sum", "x"),)                  # prefix sums gather nothing
     assert one._pad_for(np.asarray([1100])) == 0

@@ -247,7 +247,7 @@ def test_a_marker_row_moves_the_clock_and_folds_nothing(kind):
     (lambda: WinSeqTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
                        shards=2).make_core(), "one shard on one device"),
     (lambda: KeyFarmTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",
-                        use_pallas=True).replicas(), "host window cores"),
+                        use_resident=False).replicas(), "host window cores"),
     (lambda: WinSeqTPU(Reducer("sum"), 5, 10, WinType.TB,
                        fire_on="stream").make_core(), "hopping windows"),
     (lambda: WinSeqTPU(Reducer("sum"), 10, 5, WinType.TB, fire_on="stream",

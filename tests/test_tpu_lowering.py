@@ -1,13 +1,11 @@
 """AOT-compile every device step family for a TPU v5e, on the CPU.
 
 ``jax.experimental.topologies`` describes a v5e:2x2 host to the installed
-libtpu without a chip, so XLA:TPU and Mosaic run their whole pipeline here:
-a step or kernel the compiler refuses fails this suite instead of waiting for
-a chip run.  Shapes are the main-path buckets (bench.py / apps/pipe.py: CB
-256/64, 64 keys, flush_rows 2^19) and the ones chip_smoke.py drives.
+libtpu without a chip, so XLA:TPU runs its whole pipeline here: a step the
+compiler refuses fails this suite instead of waiting for a chip run.  Shapes
+are the main-path buckets (bench.py / apps/pipe.py: CB 256/64, 64 keys,
+flush_rows 2^19) and the ones chip_smoke.py drives.
 """
-
-import functools
 
 import numpy as np
 import pytest
@@ -20,11 +18,11 @@ from jax.sharding import PartitionSpec as P
 
 from windflow_tpu.ops import resident
 from windflow_tpu.ops.device import DeviceWindowExecutor, builtin_batch_fn
-from windflow_tpu.ops.pallas_kernels import windowed_reduce_pallas
 
 KP, CAP, RB, C, SLIDE = 64, 16384, 8192, 128, 64
 I8, I32, F32 = np.dtype(np.int8).str, np.dtype(np.int32).str, \
     np.dtype(np.float32).str
+ONE = resident._ANY_DEVICE
 
 
 @pytest.fixture(scope="module")
@@ -52,8 +50,8 @@ def _mesh(devices):
 @pytest.mark.parametrize("cap", [CAP, 2 * CAP])
 def test_regular_step_compiles(v5e, cap):
     S = _one(v5e)
-    fn = resident._make_regular_step(
-        ("reg", "sum", cap, RB, KP, C, I8, I32, SLIDE))
+    fn = resident._make_regular_step(resident.StepKey(
+        "regular", ONE, "sum", cap, RB, C, KP, I8, I32, slide=SLIDE))
     k = S((KP,), jnp.int32)
     fn.lower(S((KP, cap), jnp.int32), S((KP, RB), jnp.int8), k, k, k,
              k).compile()
@@ -65,7 +63,8 @@ def test_regular_step_compiles(v5e, cap):
 def test_irregular_step_compiles(v5e, ops, pad):
     S = _one(v5e)
     B = 8192
-    fn = resident._make_step((ops, CAP, RB, B, KP, I8, I32, pad))
+    fn = resident._make_step(resident.StepKey(
+        "append_eval", ONE, ops, CAP, RB, B, KP, I8, I32, pad))
     b = S((B,), jnp.int32)
     fn.lower(S((KP, CAP), jnp.int32), S((KP, RB), jnp.int8),
              S((KP,), jnp.int32), b, b, b).compile()
@@ -74,8 +73,9 @@ def test_irregular_step_compiles(v5e, ops, pad):
 def test_multi_step_compiles(v5e):
     S = _one(v5e)
     B = 8192
-    key = (("a", "b"), (("sum", "a"), ("max", "b")), None, CAP, RB, B, KP,
-           (I8, I8), (I32, I32), 256)
+    key = resident.StepKey(
+        "multi", ONE, (("sum", "a"), ("max", "b")), CAP, RB, B, KP, (I8, I8),
+        (I32, I32), 256, fields=("a", "b"))
     fn = resident._make_multi_step(key, None)
     ring, blk = S((KP, CAP), jnp.int32), S((KP, RB), jnp.int8)
     b = S((B,), jnp.int32)
@@ -99,8 +99,9 @@ def test_argext_step_compiles_and_fits(v5e, stage):
                  ("max", "lastUpdate"))
         cap, rb = 1 << 22, 8
     n = len(fields)
-    key = ("argext", fields, stats, cap, rb, 8, 1, (I32,) * n, (I32,) * n,
-           0, min(resident.ARGEXT_BLOCK, cap))
+    key = resident.StepKey(
+        "argext", ONE, stats, cap, rb, 8, 1, (I32,) * n, (I32,) * n,
+        fields=fields, eb=min(resident.ARGEXT_BLOCK, cap))
     fn = resident._make_argext_step(key)
     k, b = S((1,), jnp.int32), S((8,), jnp.int32)
     compiled = fn.lower((S((1, cap), jnp.int32),) * n,
@@ -114,8 +115,10 @@ def test_argext_step_compiles_and_fits(v5e, stage):
 
 def test_mesh_regular_step_compiles(v5e):
     mesh, S = _mesh(v5e)
-    fn = resident._make_mesh_regular_step(
-        ("mesh-reg", "sum", CAP, RB, KP, C, I8, I32, SLIDE, mesh, "kf"))
+    fn = resident._make_regular_step(resident.StepKey(
+        "regular", resident._OnMesh(mesh, "kf"), "sum", CAP, RB, C, KP, I8,
+        I32, slide=SLIDE))
+    assert fn.__name__ == "wf_step_regular_mesh"
     k = S((KP,), jnp.int32, "kf")
     fn.lower(S((KP, CAP), jnp.int32, "kf", None),
              S((KP, RB), jnp.int8, "kf", None), k, k, k, k).compile()
@@ -124,8 +127,10 @@ def test_mesh_regular_step_compiles(v5e):
 def test_mesh_irregular_step_compiles(v5e):
     mesh, S = _mesh(v5e)
     Bs = 2048
-    fn = resident._make_mesh_step(
-        ("mesh", ("max",), CAP, RB, Bs, KP, I8, I32, 256, mesh, "kf"))
+    fn = resident._make_step(resident.StepKey(
+        "append_eval", resident._OnMesh(mesh, "kf"), ("max",), CAP, RB, Bs,
+        KP, I8, I32, 256))
+    assert fn.__name__ == "wf_step_append_eval_mesh"
     d = S((4, Bs), jnp.int32, "kf", None)
     fn.lower(S((KP, CAP), jnp.int32, "kf", None),
              S((KP, RB), jnp.int8, "kf", None), S((KP,), jnp.int32, "kf"),
@@ -137,7 +142,7 @@ def test_restaging_gather_compiles(v5e):
     B 32768, pad 256, N 2^20."""
     S = _one(v5e)
     B, pad, N = 32768, 256, 1 << 20
-    ex = DeviceWindowExecutor(builtin_batch_fn("mean"), op="mean")
+    ex = DeviceWindowExecutor(builtin_batch_fn("mean"))
     b = S((B,), jnp.int32)
     ex._compiled(B, pad, N).lower({"value": S((N,), jnp.int32)}, b, b, b,
                                   b).compile()
@@ -180,8 +185,9 @@ def test_skyline_step_compiles(v5e, make, kp, cap, rb, B, pad):
     and reduce, so no buffer of a pair matrix's size exists, and the user's
     function stands under its own name in the compiled step."""
     S = _one(v5e)
-    key = (("x", "y"), (), ("x", "y"), cap, rb, B, kp, (F32, F32),
-           (F32, F32), pad)
+    key = resident.StepKey(
+        "multi", ONE, (), cap, rb, B, kp, (F32, F32), (F32, F32), pad,
+        fields=("x", "y"), fn_slot=("x", "y"))
     udf = make()             # held here: a step holds its function weakly
     fn = resident._make_multi_step(key, udf)
     ring, blk = S((kp, cap), jnp.float32), S((kp, rb), jnp.float32)
@@ -191,15 +197,3 @@ def test_skyline_step_compiles(v5e, make, kp, cap, rb, B, pad):
     # ... not even at one bit a pair
     assert compiled.memory_analysis().temp_size_in_bytes < B * pad * pad // 8
     assert "wf_udf" in compiled.as_text()
-
-
-@pytest.mark.parametrize("B,pad,N", [(8, 8, 1024), (8192, 256, 1 << 20),
-                                     (32768, 256, 1 << 22)])
-@pytest.mark.parametrize("op", ["sum", "max"])
-def test_pallas_kernel_compiles(v5e, B, pad, N, op):
-    """Mosaic accepts the window kernel (no lane-unaligned dynamic slice);
-    the largest shape is chip_smoke.py's leg D at batch_len 32768."""
-    S = _one(v5e)
-    fn = functools.partial(windowed_reduce_pallas, pad=pad, op=op)
-    b = S((B,), jnp.int32)
-    jax.jit(fn).lower(S((N,), jnp.int32), b, b).compile()

@@ -480,10 +480,6 @@ class _TPUMixin:
         self._kw["depth"] = int(depth)
         return self
 
-    def withPallas(self, flag: bool = True):
-        self._kw["use_pallas"] = flag
-        return self
-
     def withComputeDtype(self, dtype):
         self._kw["compute_dtype"] = dtype
         return self

@@ -12,9 +12,7 @@ window per CUDA thread (win_seq_gpu.hpp:429-501), synchronising per batch
   reference's refcounted host-side multicast, meta_utils.hpp:354).
 * **Compute**: one XLA computation evaluates all windows: a gather expands
   ``flat[start_i + j]`` into a (B, pad) tile, a mask kills the padding, and
-  the reduction runs on the VPU — or a Pallas kernel reduces each window
-  directly from VMEM without materialising the (B, pad) tile
-  (pallas_kernels.py).
+  the reduction runs on the VPU.
 * **Shapes**: XLA needs static shapes where CUDA took runtime sizes, so
   (B, pad, N) are bucketed to powers of two and jits are cached per bucket —
   the recompile-amortisation answer to win_seq_gpu.hpp:462-473's grow/shrink
@@ -103,15 +101,13 @@ class DeviceWindowExecutor:
     shapes and bounded asynchronous depth."""
 
     def __init__(self, batch_fn, fields=("value",), out_fields=("value",),
-                 device=None, depth: int = 4, use_pallas: bool = False,
-                 op: str = None, compute_dtype=None, out_dtypes=None,
-                 empty_fill=None):
+                 device=None, depth: int = 4, compute_dtype=None,
+                 out_dtypes=None, empty_fill=None):
         self.batch_fn = batch_fn
         self.fields = tuple(fields)
         self.out_fields = tuple(out_fields)
         self.device = device or default_device()
         self.depth = depth
-        self.op = op
         self.compute_dtype = compute_dtype
         # result dtypes per out_field: harvest casts into them so that
         # empty-window fills (below) can hold full-width identities
@@ -120,16 +116,12 @@ class DeviceWindowExecutor:
         # device path's empty-window results identical to the host path's
         # even when compute happens in a narrower dtype (int32 vs int64)
         self.empty_fill = dict(empty_fill or {})
-        # the kernel reduces one staged column with a built-in monoid
-        # ("count" stages no column and needs no kernel)
-        self.use_pallas = bool(use_pallas and op is not None and self.fields)
         # Executables compiled for process-lifetime functions (the lru-cached
         # builtins, or anything marked _windflow_shared) go in the process-
         # wide cache so new executor instances reuse them; ad-hoc user
         # functions keep a per-instance cache (a global entry keyed on a
         # short-lived lambda could never be reused but never dies either).
-        shared = (getattr(batch_fn, "_windflow_shared", False)
-                  or self.use_pallas)
+        shared = getattr(batch_fn, "_windflow_shared", False)
         self._jits = _JIT_CACHE if shared else {}
         self.launches = 0    # batches this executor sent to its device
         self._inflight = []  # [(meta, B, empty_mask, device_results)]
@@ -145,39 +137,20 @@ class DeviceWindowExecutor:
         # process-wide on the user function object so a new executor (a new
         # pattern instance, a re-run pipeline) reuses executables already
         # compiled for the same function and bucket.
-        if self.use_pallas:
-            key = ("pallas", self.op, self.fields[0], self.device.platform,
-                   pad, N)
-        else:
-            key = (self.batch_fn, pad, N)
+        key = (self.batch_fn, pad, N)
         fn = self._jits.get(key)
         if fn is not None:
             return fn
-        if self.use_pallas:
-            # a kernel Mosaic refuses raises out of launch(): use_pallas is
-            # an explicit request, never quietly served by the gather path
-            from .pallas_kernels import windowed_reduce_pallas
-            op = self.op
-            field = self.fields[0]
-            # Mosaic compiles for TPUs only; the CPU backend the tests
-            # choose runs the same kernel through the Pallas interpreter
-            interpret = self.device.platform != "tpu"
+        batch_fn = self.batch_fn
 
-            def run(flat_cols, starts, lens, keys, gwids):
-                out = windowed_reduce_pallas(flat_cols[field], starts, lens,
-                                             pad, op, interpret=interpret)
-                return (out,)
-        else:
-            batch_fn = self.batch_fn
-
-            def run(flat_cols, starts, lens, keys, gwids):
-                idx = starts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :]
-                idx = jnp.minimum(idx, N - 1)
-                mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < lens[:, None]
-                cols = {f: jnp.where(mask, flat_cols[f][idx], 0)
-                        for f in flat_cols}
-                out = batch_fn(keys, gwids, cols, mask)
-                return out if isinstance(out, tuple) else (out,)
+        def run(flat_cols, starts, lens, keys, gwids):
+            idx = starts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :]
+            idx = jnp.minimum(idx, N - 1)
+            mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < lens[:, None]
+            cols = {f: jnp.where(mask, flat_cols[f][idx], 0)
+                    for f in flat_cols}
+            out = batch_fn(keys, gwids, cols, mask)
+            return out if isinstance(out, tuple) else (out,)
 
         fn = jax.jit(run)
         self._jits[key] = fn
@@ -193,12 +166,7 @@ class DeviceWindowExecutor:
         Bb = _bucket(B)
         pad = _bucket(int(lens.max()) if len(lens) else 1)
         n = len(next(iter(flat_cols.values()))) if flat_cols else 1
-        if self.use_pallas:
-            # the kernel reads whole 128-lane rows covering each window
-            from .pallas_kernels import flat_slack
-            Nb = _bucket(n + flat_slack(pad), lo=1024)
-        else:
-            Nb = _bucket(max(n, 1) + pad)
+        Nb = _bucket(max(n, 1) + pad)
 
         def pad1(a, size, dtype=None):
             a = np.asarray(a)

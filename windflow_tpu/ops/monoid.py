@@ -1,7 +1,7 @@
 """One source of truth for the built-in monoid reductions: identities and
 reducer tables shared by the host path (numpy, ops/functions.py), the XLA
-device path (ops/device.py), the Pallas kernels (ops/pallas_kernels.py),
-and the mesh layer (parallel/mesh.py).
+device path (ops/device.py, ops/resident.py) and the mesh layer
+(parallel/mesh.py).
 
 Semantics of the identity (what an *empty* window produces, matching the
 reference's behaviour of leaving the result default-initialised): sum and

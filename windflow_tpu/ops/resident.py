@@ -33,6 +33,7 @@ from __future__ import annotations
 import threading
 import weakref
 from collections import deque
+from typing import NamedTuple
 
 import numpy as np
 
@@ -136,6 +137,11 @@ class RingSnapshot:
         return {"rings": rings, "KP": self.KP, "cap": self.cap}
 
 
+def _zeros(shape, dtype, where):
+    """Zeros on the device: `where` a device or a sharding."""
+    return jax.device_put(jnp.zeros(shape, dtype=dtype), where)
+
+
 def _pad2(a, rows, cols):
     out = np.zeros((rows, cols), dtype=a.dtype)
     out[:a.shape[0], :a.shape[1]] = a
@@ -148,6 +154,25 @@ def _pad1(a, size, dtype=np.int32):
     return out
 
 
+def _wire_dtype(vals, acc, as_float: bool) -> np.dtype:
+    """The narrowest wire dtype that holds `vals` exactly under a ring of
+    dtype `acc`: int8/int16/int32, int64 too under a 64-bit ring; a float
+    column (`as_float`) ships in the ring's precision."""
+    wide = acc.itemsize >= 8
+    if as_float:
+        return np.dtype(np.float64 if wide else np.float32)
+    if not len(vals):
+        return np.dtype(np.int8)
+    lo, hi = int(vals.min()), int(vals.max())
+    ladder = (np.int8, np.int16, np.int32) + ((np.int64,) if wide else ())
+    for dt in ladder:
+        info = np.iinfo(dt)
+        if info.min <= lo and hi <= info.max:
+            return np.dtype(dt)
+    return np.dtype(ladder[-1])  # wraps; the core warned at
+    # construction when the result dtype exceeds the accumulate dtype
+
+
 def _check_ring_overflow(offs, Rb, cap):
     """dynamic_update_slice clamps the start, which would silently
     overwrite live cells near the ring end — the host core's rebase
@@ -155,6 +180,217 @@ def _check_ring_overflow(offs, Rb, cap):
     if len(offs) and int(offs.max()) + Rb > cap:
         raise ValueError(
             f"ring overflow: offset {int(offs.max())} + {Rb} > {cap}")
+
+
+class StepKey(NamedTuple):
+    """The shape of one compiled step: what ``_STEP_CACHE`` and the dicts of
+    ``_FN_STEP_CACHE`` are keyed by.  A family sets the fields it has and
+    leaves the others at their defaults."""
+    #: ``regular``, ``append_eval``, ``multi`` or ``argext``
+    family: str
+    #: what the step is bound to: `_ANY_DEVICE` (a jit serves whichever
+    #: device its arguments sit on) or the `_OnMesh` it is mapped over
+    place: tuple
+    #: the op (``regular``), the ops over the one ring (``append_eval``),
+    #: the (op, field) pairs (``multi``, ``argext``)
+    stats: object
+    cap: int                # ring columns
+    Rb: int                 # the rectangle's columns, bucketed
+    #: the window bucket: a launch's windows (a shard's, on a mesh); of the
+    #: ``regular`` family the windows of a ring row (``C``)
+    Bb: int
+    KP: int                 # ring rows
+    wires: object           # wire dtype of the rectangle, one a field
+    accs: object            # ring dtype, one a field
+    pad: int = 0            # padded window length of a (Bb, pad) gather
+    fields: tuple = ()      # the ring fields, where each has its ring
+    fn_slot: tuple = None   # the fields of a bound user's function
+    slide: int = 0          # ``regular``: the windows' stride
+    eb: int = 0             # ``argext``: cells a block of the walk reads
+
+
+class _OneDevice(NamedTuple):
+    """Placement of rings that live whole on one device: what a launch
+    does there is what it costs every cell, so nothing here builds an
+    array the step does not take."""
+    device: object
+    mesh = None
+    n_shards = 1
+
+    @property
+    def in_key(self):
+        return _ANY_DEVICE
+
+    @property
+    def rect(self):
+        """Where a ring or a rectangle is put (`rows`: a per-row vector,
+        `wins`: a window descriptor)."""
+        return self.device
+
+    rows = wins = rect
+
+    def each(self, devices):
+        """The placements to warm a step of this key's on."""
+        return [_OneDevice(d) for d in devices]
+
+    def ring_rows(self, n_keys: int, lo: int) -> int:
+        return _bucket(n_keys, lo=lo)
+
+    def phys_rows(self, rows, KP):
+        """The ring row each dense key row lands on."""
+        return np.asarray(rows)
+
+    def win_shape(self, Bb: int):
+        return (Bb,)
+
+    def batch(self, wrows, B, lo):
+        """(window bucket, harvest's selector, the windows' rows as the
+        step takes them) of a launch of `B` windows."""
+        return _bucket(max(B, 1), lo=lo), B, wrows
+
+    def put(self, KP, Rb, Bb, sel, blks, rows, wins, heads=()):
+        """ONE transfer of one tuple: the rectangle (a tuple of them a ring
+        per field) padded to (KP, Rb) unless it comes so, per-row vectors to
+        KP, window descriptors to Bb, int64 header columns to Bb."""
+        def rect(b):
+            return b if b.shape == (KP, Rb) else _pad2(b, KP, Rb)
+        return jax.device_put(
+            (tuple(rect(b) for b in blks) if isinstance(blks, tuple)
+             else rect(blks),
+             *[_pad1(a, KP) for a in rows], *[_pad1(a, Bb) for a in wins],
+             *[_pad1(a if a is not None else (), Bb, dtype=np.int64)
+               for a in heads]),
+            self.device)
+
+    def compile(self, step, name, n_rows, n_wins, **jit_kw):
+        return _named_jit(step, name, **jit_kw)
+
+
+#: the placement in the key of every one-device step
+_ANY_DEVICE = _OneDevice(None)
+
+
+class _OnMesh(NamedTuple):
+    """Placement of rings sharded ``P(axis, None)`` over a
+    ``jax.sharding.Mesh``: ring rows are distributed over the mesh's
+    key-group axis, so ONE dispatch serves every key group — each chip holds
+    its groups' archives in its own HBM and evaluates its own windows (no
+    collectives; the kf axis is embarrassingly parallel, parallel/mesh.py).
+    The multi-chip form of the reference's per-worker GPU ownership
+    (win_farm_gpu.hpp:132-168) with the farm collapsed into one SPMD
+    program."""
+    mesh: object
+    axis: str
+
+    @property
+    def device(self):
+        return self.mesh.devices.flat[0]
+
+    @property
+    def n_shards(self) -> int:
+        return int(self.mesh.shape[self.axis])
+
+    @property
+    def in_key(self):
+        return self
+
+    @property
+    def rect(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P(self.axis, None))
+
+    wins = rect
+
+    @property
+    def rows(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P(self.axis))
+
+    def each(self, devices):
+        return [self]
+
+    def ring_rows(self, n_keys: int, lo: int) -> int:
+        S = self.n_shards
+        return S * _bucket(-(-n_keys // S), lo=lo)
+
+    def phys_rows(self, rows, KP):
+        # STRIDE dense key rows over shards (row r -> shard r % S, local
+        # slot r // S): the host assigns rows in key-arrival order, so a
+        # block mapping would concentrate all live keys on the low shards
+        # while the padded tail idles — striding balances any K
+        S = self.n_shards
+        rows = np.asarray(rows, dtype=np.int64)
+        return (rows % S) * (KP // S) + rows // S
+
+    def win_shape(self, Bb: int):
+        return (self.n_shards, Bb)
+
+    def batch(self, wrows, B, lo):
+        # a window goes to its row's shard, slots in arrival order per
+        # shard; the bucket is the fullest shard's, and harvest indexes the
+        # (S, Bs) result back to flat window order by (shard, slot)
+        S = self.n_shards
+        wrows = np.asarray(wrows, dtype=np.int64)
+        shard = wrows % S
+        slots = np.zeros(B, dtype=np.int64)
+        maxc = 0
+        for s in range(S):
+            m = shard == s
+            c = int(m.sum())
+            slots[m] = np.arange(c)
+            maxc = max(maxc, c)
+        return _bucket(max(maxc, 1), lo=lo), (shard, slots), wrows // S
+
+    def put(self, KP, Rb, Bb, sel, blks, rows, wins, heads=()):
+        """One transfer an argument, each with its sharding: rectangles and
+        per-row vectors scattered shard-major (`phys_rows`), a descriptor
+        laid out (S, Bs) by `sel`'s (shard, slot)."""
+        K, R = (blks[0] if isinstance(blks, tuple) else blks).shape
+        prow = self.phys_rows(np.arange(K), KP)
+        S, s2, s1 = self.n_shards, self.rect, self.rows
+
+        def rect(b):
+            bp = np.zeros((KP, Rb), dtype=b.dtype)
+            bp[prow, :R] = b
+            return jax.device_put(bp, s2)
+
+        def row(a):
+            out = np.zeros(KP, dtype=np.int32)
+            out[prow] = a[:K]
+            return jax.device_put(out, s1)
+
+        def win(a, dtype=np.int32):
+            out = np.zeros((S, Bb), dtype=dtype)
+            # a caller that binds no function sends empty header columns
+            if a is not None and len(a) == len(sel[0]):
+                out[sel] = a
+            return jax.device_put(out, s2)
+
+        return (tuple(rect(b) for b in blks) if isinstance(blks, tuple)
+                else rect(blks),
+                *[row(a) for a in rows], *[win(a) for a in wins],
+                *[win(a, np.int64) for a in heads])
+
+    def compile(self, step, name, n_rows, n_wins, **jit_kw):
+        """shard_map of a one-device `step` over the key-group axis: each
+        device appends to its row block of the ring(s) and evaluates its own
+        windows.  Per-shard views: rings and rectangles (rps, .), the
+        `n_rows` per-row vectors (rps,), the `n_wins` descriptors (1, Bs)."""
+        from jax.sharding import PartitionSpec as P
+        p2, p1 = P(self.axis, None), P(self.axis)
+
+        def local(rings, blks, *rest):
+            rings, outs = step(rings, blks, *rest[:n_rows],
+                               *[d[0] for d in rest[n_rows:]])
+            if n_wins:
+                outs = jax.tree.map(lambda o: o[None, :], outs)
+            return rings, outs
+
+        mapped = jax.shard_map(
+            local, mesh=self.mesh,
+            in_specs=(p2, p2) + (p1,) * n_rows + (p2,) * n_wins,
+            out_specs=(p2, p2))
+        return _named_jit(mapped, name + "_mesh", **jit_kw)
 
 
 def _regular_body(cap, C, slide, acc_dt, ring, blk, offs, rstart0, rlen):
@@ -177,35 +413,20 @@ def _regular_body(cap, C, slide, acc_dt, ring, blk, offs, rstart0, rlen):
 
 
 def _make_regular_step(key):
-    (_, _op, cap, R, KP, C, blk_dt, acc_dt, slide) = key
-    acc_dt = np.dtype(acc_dt)
+    if not isinstance(key, StepKey):
+        # the parent's positional key, which benchmarks/tests/
+        # record_wf_trace.py still passes (no PR but a `benchmark` one may
+        # edit it)
+        _t, op, cap, Rb, KP, C, wire, acc, slide = key
+        key = StepKey("regular", _ANY_DEVICE, op, cap, Rb, C, KP, wire, acc,
+                      slide=slide)
+    cap, C, slide, acc_dt = key.cap, key.Bb, key.slide, np.dtype(key.accs)
 
     def step(ring, blk, offs, rcount, rstart0, rlen):
         return _regular_body(cap, C, slide, acc_dt, ring, blk, offs,
                              rstart0, rlen)
 
-    return _named_jit(step, "wf_step_regular")
-
-
-def _make_mesh_regular_step(key):
-    """Sharded regular step: shard_map of :func:`_regular_body` over the
-    key-group axis — each device appends its row block and expands its own
-    per-key arithmetic window sequences (no collectives, like the plain
-    mesh step)."""
-    (_tag, _op, cap, Rb, KP, C, blk_dt, acc_dt, slide, mesh, axis) = key
-    acc_dt = np.dtype(acc_dt)
-    from jax.sharding import PartitionSpec as P
-
-    def local(ring, blk, offs, rcount, rstart0, rlen):
-        return _regular_body(cap, C, slide, acc_dt, ring, blk, offs,
-                             rstart0, rlen)
-
-    mapped = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None), P(axis), P(axis), P(axis),
-                  P(axis)),
-        out_specs=(P(axis, None), P(axis, None)))
-    return _named_jit(mapped, "wf_step_regular_mesh")
+    return key.place.compile(step, "wf_step_regular", 4, 0)
 
 
 def _ring_append(ring, blk, offs, acc_dt):
@@ -248,42 +469,14 @@ def _append_eval(ops, cap, pad, acc_dt, ring, blk, offs, rows, starts,
 
 def _make_step(key):
     """Build + jit the fused append+eval step for one shape bucket."""
-    (ops, cap, R, B, KP, blk_dt, acc_dt, pad) = key
-    acc_dt = np.dtype(acc_dt)
+    ops, cap, pad, acc_dt = key.stats, key.cap, key.pad, np.dtype(key.accs)
 
     def step(ring, blk, offs, wrows, wstarts, wlens):
         ring, outs = _append_eval(ops, cap, pad, acc_dt, ring, blk, offs,
                                   wrows, wstarts, wlens)
         return ring, (outs[0] if len(outs) == 1 else outs)
 
-    return _named_jit(step, "wf_step_append_eval")
-
-
-def _make_mesh_step(key):
-    """Build + jit the sharded fused append+eval step: shard_map over the
-    key-group axis — each device appends to and evaluates windows over its
-    own row block of the ring (key groups are embarrassingly parallel, so
-    the program has no collectives; the sharding just keeps each group's
-    archive in its own chip's HBM)."""
-    (_, ops, cap, Rb, Bs, KP, blk_dt, acc_dt, pad, mesh, axis) = key
-    acc_dt = np.dtype(acc_dt)
-    from jax.sharding import PartitionSpec as P
-
-    def local(ring, blk, offs, lrows, lstarts, llens):
-        # per-shard views: ring (rps, cap), blk (rps, Rb), offs (rps,),
-        # descriptors (1, Bs) — local rows/starts/lens of this shard's
-        # windows (host pre-grouped them per shard)
-        ring, outs = _append_eval(ops, cap, pad, acc_dt, ring, blk, offs,
-                                  lrows[0], lstarts[0], llens[0])
-        outs = tuple(o[None, :] for o in outs)
-        return ring, (outs[0] if len(outs) == 1 else outs)
-
-    mapped = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None), P(axis),
-                  P(axis, None), P(axis, None), P(axis, None)),
-        out_specs=(P(axis, None), P(axis, None)))
-    return _named_jit(mapped, "wf_step_append_eval_mesh")
+    return key.place.compile(step, "wf_step_append_eval", 1, 3)
 
 
 class ResidentWindowExecutor:
@@ -307,8 +500,10 @@ class ResidentWindowExecutor:
     #: core amends the record at its hand-over (the native core)
     handed = None
 
-    def __init__(self, op, device=None, depth: int = 8,
-                 acc_dtype=np.int32):
+    #: the smallest bucket of the ring's rows
+    _row_floor = 8
+
+    def __init__(self, op, place=None, depth: int = 8, acc_dtype=np.int32):
         # `op` is one reduce op or a tuple of them: every op evaluates over
         # the SAME ring in one fused dispatch (multi-stat windows — the
         # device side of ops.functions.MultiReducer)
@@ -320,12 +515,20 @@ class ResidentWindowExecutor:
         if not self.ops:
             raise ValueError("need at least one resident op")
         self.op = self.ops[0]
-        self.device = device or default_device()
-        self.depth = depth
         self.acc_dtype = np.dtype(acc_dtype)
+        self._ring = None
+        self._init_queue(place, depth)
+
+    def _init_queue(self, place, depth):
+        #: where the rings live (`_OneDevice`, `_OnMesh`): everything a
+        #: launch does differently on a mesh, it asks of this
+        self.place = (_OneDevice(default_device()) if place is None
+                      else place)
+        self.device = self.place.device    # a mesh's first
+        self.mesh = self.place.mesh        # None on one device
+        self.depth = depth
         self.cap = 0          # ring columns (set on first reset)
         self.KP = 0           # ring rows (padded key count)
-        self._ring = None
         # (meta, sel, device_out, t_dispatched_ns, (launch, shard, cause))
         self._inflight = deque()
         self._ready = []
@@ -336,25 +539,20 @@ class ResidentWindowExecutor:
     # ------------------------------------------------------------ lifecycle
 
     def reset(self, n_keys: int, cap: int):
-        """(Re)allocate an empty ring of at least (n_keys, cap); contents
-        are repopulated by the next launch's rectangle (host rebase)."""
-        self.KP = _bucket(max(n_keys, 1))
+        """(Re)allocate empty ring(s) of at least (n_keys, cap), lazily
+        zeros on the next launch; contents are repopulated by that launch's
+        rectangle (host rebase)."""
+        self.KP = self.place.ring_rows(max(n_keys, 1), self._row_floor)
         self.cap = _bucket(max(cap, 16))
-        self._ring = None  # lazily zeros on next launch
+        self._rings_assign(None)
 
     def _ring_arr(self):
         if self._ring is None:
-            self._ring = jax.device_put(
-                jnp.zeros((self.KP, self.cap), dtype=self.acc_dtype),
-                self.device)
+            self._ring = _zeros((self.KP, self.cap), self.acc_dtype,
+                                self.place.rect)
         return self._ring
 
     # ---------------------------------------------------- checkpoint/restore
-
-    def _ring_placement(self):
-        """Where restored rings land (mesh executors override with their
-        NamedSharding)."""
-        return self.device
 
     def _rings_tuple(self):
         """Current ring array(s) as a tuple, or None if lazily unbuilt
@@ -386,7 +584,7 @@ class ResidentWindowExecutor:
         self.cap = data["cap"]
         rings = data["rings"]
         self._rings_assign(None if rings is None else tuple(
-            jax.device_put(r, self._ring_placement()) for r in rings))
+            jax.device_put(r, self.place.rect) for r in rings))
 
     def invalidate(self):
         """Drop the ring(s) and launch queue entirely: the owning
@@ -406,20 +604,7 @@ class ResidentWindowExecutor:
         accumulate dtype: ints narrow to int8/int16/int32 (int64 allowed
         when accumulating in a 64-bit dtype); floats ship in the
         accumulate precision."""
-        wide = self.acc_dtype.itemsize >= 8
-        if vals.dtype.kind == "f":
-            return np.dtype(np.float64 if wide else np.float32)
-        if not len(vals):
-            return np.dtype(np.int8)
-        lo, hi = int(vals.min()), int(vals.max())
-        ladder = (np.int8, np.int16, np.int32, np.int64) if wide else \
-                 (np.int8, np.int16, np.int32)
-        for dt in ladder:
-            info = np.iinfo(dt)
-            if info.min <= lo and hi <= info.max:
-                return np.dtype(dt)
-        return np.dtype(ladder[-1])  # wraps; the core warned at
-        # construction when the result dtype exceeds the accumulate dtype
+        return _wire_dtype(vals, self.acc_dtype, vals.dtype.kind == "f")
 
     def launch(self, meta, blk: np.ndarray, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
@@ -432,27 +617,24 @@ class ResidentWindowExecutor:
         with the results at harvest.  Caller guarantees offs + R <= cap.
         `tag` is the launch's (id, shard, cause), carried on its spans.
         """
+        place = self.place
         K, R = blk.shape
         if K > self.KP:
             raise ValueError("rectangle exceeds ring rows; reset() first")
         B = len(wstarts)
         Rb = _bucket(max(R, 1))
-        Bb = _bucket(max(B, 1))
+        Bb, sel, wrows = place.batch(wrows, B, 8)
         _check_ring_overflow(offs, Rb, self.cap)
         pad = (_bucket(int(wlens.max()) if B else 1)
                if any(o != "sum" for o in self.ops) else 0)
-        key = (self.ops, self.cap, Rb, Bb, self.KP, blk.dtype.str,
-               self.acc_dtype.str, pad)
+        key = StepKey("append_eval", place.in_key, self.ops, self.cap, Rb,
+                      Bb, self.KP, blk.dtype.str, self.acc_dtype.str, pad)
         fn = _STEP_CACHE.get(key)
         if fn is None:
             fn = _STEP_CACHE[key] = _make_step(key)
         with profile.span("device_put", *tag):
-            blkp = (blk if blk.shape == (self.KP, Rb)
-                    else _pad2(blk, self.KP, Rb))
-            args = jax.device_put(
-                (blkp, _pad1(offs, self.KP),
-                 _pad1(wrows, Bb), _pad1(wstarts, Bb), _pad1(wlens, Bb)),
-                self.device)
+            args = place.put(self.KP, Rb, Bb, sel, blk, (offs,),
+                             (wrows, wstarts, wlens))
         profile.add("bytes_shipped", blk.nbytes)
         profile.add("rows_shipped", blk.size)
         profile.add("windows", B)
@@ -460,7 +642,7 @@ class ResidentWindowExecutor:
             self._ring, out = fn(self._ring_arr(), *args)
             for o in (out if isinstance(out, tuple) else (out,)):
                 o.copy_to_host_async()
-        self._dispatched(meta, B, out, sp, tag)
+        self._dispatched(meta, sel, out, sp, tag)
 
     def launch_regular(self, meta, blk: np.ndarray, offs: np.ndarray,
                        rcount: np.ndarray, rstart0: np.ndarray,
@@ -470,10 +652,13 @@ class ResidentWindowExecutor:
         row, windows i in [0, rcount[r]) start at rstart0[r] + i*slide with
         length rlen[r] — only 3 per-key scalars cross the wire instead of
         3 arrays of B int32 (sum only; the host maps the (KP, C) result
-        back to pending-window order via (wrows, widx))."""
+        back to pending-window order via (wrows, widx)).  On a mesh the
+        scalars shard with their rows and each device expands its own
+        arithmetic window sequences."""
         if not (self.single and self.op == "sum"):
             raise ValueError("regular descriptors implemented for "
                              "single-stat sum")
+        place = self.place
         K, R = blk.shape
         if K > self.KP:
             raise ValueError("rectangle exceeds ring rows; reset() first")
@@ -481,27 +666,24 @@ class ResidentWindowExecutor:
         C = _bucket(int(cmax) if cmax else
                     (int(rcount.max()) if len(rcount) else 1))
         _check_ring_overflow(offs, Rb, self.cap)
-        key = ("reg", self.op, self.cap, Rb, self.KP, C, blk.dtype.str,
-               self.acc_dtype.str, int(slide))
+        key = StepKey("regular", place.in_key, self.op, self.cap, Rb, C,
+                      self.KP, blk.dtype.str, self.acc_dtype.str,
+                      slide=int(slide))
         fn = _STEP_CACHE.get(key)
         if fn is None:
             fn = _STEP_CACHE[key] = _make_regular_step(key)
         with profile.span("device_put", *tag):
-            blkp = (blk if blk.shape == (self.KP, Rb)
-                    else _pad2(blk, self.KP, Rb))
-            args = jax.device_put(
-                (blkp, _pad1(offs, self.KP),
-                 _pad1(rcount, self.KP), _pad1(rstart0, self.KP),
-                 _pad1(rlen, self.KP)),
-                self.device)
+            args = place.put(self.KP, Rb, 0, None, blk,
+                             (offs, rcount, rstart0, rlen), ())
         profile.add("bytes_shipped", blk.nbytes)
         profile.add("rows_shipped", blk.size)
         profile.add("windows", len(wrows))
         with profile.span("dispatch", *tag) as sp:
             self._ring, out = fn(self._ring_arr(), *args)
             out.copy_to_host_async()
-        self._dispatched(meta, (np.asarray(wrows), np.asarray(widx)), out,
-                         sp, tag)
+        self._dispatched(
+            meta, (place.phys_rows(wrows, self.KP), np.asarray(widx)), out,
+            sp, tag)
 
     def _dispatched(self, meta, sel, out, sp, tag):
         """Queue a dispatched launch for harvest, stamped with the end of
@@ -694,10 +876,13 @@ def _make_multi_step(key, jax_fn):
     function (JaxWindowFunction) reads (B, pad) gathers of every field —
     the device-resident form of the reference's arbitrary device functor
     over whole POD tuples (win_seq_gpu.hpp:54-67): every column crosses
-    the wire once, the functor reads HBM."""
-    (fields, stats, _fnid, cap, Rb, Bb, KP, wires, accs, pad) = key
-    acc_dts = tuple(np.dtype(a) for a in accs)
-    fidx = {f: i for i, f in enumerate(fields)}
+    the wire once, the functor reads HBM.  On a mesh each device appends its
+    row block of EVERY field's ring and evaluates its own windows' stats and
+    function (windows are row-local: the multi-chip form of the whole-tuple
+    functor contract, SURVEY §2.8)."""
+    stats, cap, pad = key.stats, key.cap, key.pad
+    acc_dts = tuple(np.dtype(a) for a in key.accs)
+    fidx = {f: i for i, f in enumerate(key.fields)}
     udf = None if jax_fn is None else _bind_udf(jax_fn)
 
     def step(rings, blks, offs, wrows, wstarts, wlens, wkeys, wgwids):
@@ -712,7 +897,7 @@ def _make_multi_step(key, jax_fn):
                                   wlens, wkeys, wgwids))
         return rings, tuple(outs)
 
-    return _named_jit(step, "wf_step_multi")
+    return key.place.compile(step, "wf_step_multi", 1, 5)
 
 
 class MultiFieldResidentExecutor(ResidentWindowExecutor):
@@ -728,7 +913,7 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
     to its ring dtype."""
 
     def __init__(self, fields, stats=(), jax_fn=None, acc_dtypes=None,
-                 device=None, depth: int = 8):
+                 place=None, depth: int = 8):
         self.fields = tuple(fields)
         if not self.fields:
             raise ValueError("need at least one ring field")
@@ -746,16 +931,8 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         if not self.stats and jax_fn is None:
             raise ValueError("nothing to evaluate")
         self.acc_dtypes = {f: np.dtype(acc_dtypes[f]) for f in self.fields}
-        self.device = device or default_device()
-        self.depth = depth
-        self.cap = 0
-        self.KP = 0
         self._rings = None
-        self._inflight = deque()
-        self._ready = []
-        self._svc = deque(maxlen=32)
-        self._svc_mean = 0.0
-        self.dispatches = 0
+        self._init_queue(place, depth)
         #: the step key's function slot: the function's fields (the function
         #: itself keys _FN_STEP_CACHE), None for a step that binds none
         self._fn_slot = None if jax_fn is None else tuple(jax_fn.fields)
@@ -775,18 +952,11 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
     op = property(lambda self: tuple(op for op, _f in self.stats))
     single = False
 
-    def reset(self, n_keys: int, cap: int):
-        self.KP = _bucket(max(n_keys, 1))
-        self.cap = _bucket(max(cap, 16))
-        self._rings = None
-
     def _rings_arr(self):
         if self._rings is None:
             self._rings = tuple(
-                jax.device_put(
-                    jnp.zeros((self.KP, self.cap),
-                              dtype=self.acc_dtypes[f]), self.device)
-                for f in self.fields)
+                _zeros((self.KP, self.cap), self.acc_dtypes[f],
+                       self.place.rect) for f in self.fields)
         return self._rings
 
     def _rings_tuple(self):
@@ -799,25 +969,13 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         """Per-field wire narrowing (same ladder as the base class but
         bounded by that field's ring dtype)."""
         acc = self.acc_dtypes[field]
-        wide = acc.itemsize >= 8
         if len(vals) and vals.dtype.kind == "f" and acc.kind != "f":
             raise ValueError(
                 f"float column {field!r} headed into a {acc} ring would "
                 "silently truncate — declare a float ring dtype "
                 f"(JaxWindowFunction(field_dtypes={{{field!r}: "
                 "np.float32}}))")
-        if acc.kind == "f":
-            return np.dtype(np.float64 if wide else np.float32)
-        if not len(vals):
-            return np.dtype(np.int8)
-        lo, hi = int(vals.min()), int(vals.max())
-        ladder = (np.int8, np.int16, np.int32, np.int64) if wide else \
-                 (np.int8, np.int16, np.int32)
-        for dt in ladder:
-            info = np.iinfo(dt)
-            if info.min <= lo and hi <= info.max:
-                return np.dtype(dt)
-        return np.dtype(ladder[-1])
+        return _wire_dtype(vals, acc, acc.kind == "f")
 
     def _pad_for(self, wlens) -> int:
         """The padded window length of a launch, part of its step's shape:
@@ -842,18 +1000,20 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         """One fused dispatch: per-field rectangles `blks[f]` (K, R) append
         at `offs`, then every stat / the JAX fn evaluates the described
         windows.  `wkeys`/`wgwids` are required when a JAX fn is bound."""
+        place = self.place
         K, R = next(iter(blks.values())).shape
         if K > self.KP:
             raise ValueError("rectangle exceeds ring rows; reset() first")
         B = len(wstarts)
         Rb = _bucket(max(R, 1))
-        Bb = _bucket(max(B, 1), lo=self._batch_floor)
+        Bb, sel, wrows = place.batch(wrows, B, self._batch_floor)
         _check_ring_overflow(offs, Rb, self.cap)
         pad = self._pad_for(wlens)
-        wires = tuple(blks[f].dtype.str for f in self.fields)
-        key = (self.fields, self.stats, self._fn_slot, self.cap, Rb, Bb,
-               self.KP, wires,
-               tuple(self.acc_dtypes[f].str for f in self.fields), pad)
+        key = StepKey(
+            "multi", place.in_key, self.stats, self.cap, Rb, Bb, self.KP,
+            tuple(blks[f].dtype.str for f in self.fields),
+            tuple(self.acc_dtypes[f].str for f in self.fields), pad,
+            self.fields, self._fn_slot)
         # stat-only steps share the process-wide cache like the base class;
         # a step bound to a user's function is cached under that function
         if self.jax_fn is None:
@@ -864,28 +1024,20 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
             fn = _fn_step(self._step_cache, key, self.jax_fn,
                           _make_multi_step)
         with profile.span("device_put", *tag):
-            blkps = tuple(
-                (blks[f] if blks[f].shape == (self.KP, Rb)
-                 else _pad2(blks[f], self.KP, Rb)) for f in self.fields)
-            args = jax.device_put(
-                (blkps, _pad1(offs, self.KP), _pad1(wrows, Bb),
-                 _pad1(wstarts, Bb), _pad1(wlens, Bb),
-                 _pad1(wkeys if wkeys is not None else np.zeros(0), Bb,
-                       dtype=np.int64),
-                 _pad1(wgwids if wgwids is not None else np.zeros(0), Bb,
-                       dtype=np.int64)),
-                self.device)
+            args = place.put(self.KP, Rb, Bb, sel,
+                             tuple(blks[f] for f in self.fields), (offs,),
+                             (wrows, wstarts, wlens), (wkeys, wgwids))
         for f in self.fields:
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
         profile.add("windows", B)
         if self.jax_fn is not None:
-            self._count_udf(B, wlens, Bb * pad)
+            self._count_udf(B, wlens, place.n_shards * Bb * pad)
         with profile.span("dispatch", *tag) as sp:
             self._rings, out = fn(self._rings_arr(), *args)
             for o in out:
                 o.copy_to_host_async()
-        self._dispatched(meta, B, out, sp, tag)
+        self._dispatched(meta, sel, out, sp, tag)
 
     @staticmethod
     def _count_udf(B, wlens, cells):
@@ -967,9 +1119,9 @@ def _make_argext_step(key):
     copied per launch); ``argmax``/``argmin`` stats return (extremum, first
     index, count of cells at the extremum), ``max``/``min`` the extremum by
     the same walk, ``sum``/``prod`` as :func:`_ring_eval`."""
-    (_tag, fields, stats, cap, Rb, Bb, KP, wires, accs, pad, eb) = key
-    acc_dts = tuple(np.dtype(a) for a in accs)
-    fidx = {f: i for i, f in enumerate(fields)}
+    stats, cap, pad, eb = key.stats, key.cap, key.pad, key.eb
+    acc_dts = tuple(np.dtype(a) for a in key.accs)
+    fidx = {f: i for i, f in enumerate(key.fields)}
 
     def step(rings, blks, offs, shifts, wrows, wstarts, wlens):
         rings = tuple(_ring_append(_ring_compact(r, shifts), b, offs, dt)
@@ -988,7 +1140,7 @@ def _make_argext_step(key):
                                        wstarts, wlens))
         return rings, tuple(outs)
 
-    return _named_jit(step, "wf_step_argext", donate_argnums=0)
+    return key.place.compile(step, "wf_step_argext", 2, 3, donate_argnums=0)
 
 
 class ArgExtResidentExecutor(MultiFieldResidentExecutor):
@@ -1002,17 +1154,15 @@ class ArgExtResidentExecutor(MultiFieldResidentExecutor):
     function."""
 
     _OPS = _REDUCE_OPS + _ARG_OPS
+    _row_floor = 1
 
-    def __init__(self, fields, stats, acc_dtypes, device=None,
+    def __init__(self, fields, stats, acc_dtypes, place=None,
                  depth: int = 8):
         super().__init__(fields, stats=stats, acc_dtypes=acc_dtypes,
-                         device=device, depth=depth)
+                         place=place, depth=depth)
+        if self.mesh is not None:
+            raise ValueError("the arg-extremum family serves no mesh")
         self.eval_block = ARGEXT_BLOCK
-
-    def reset(self, n_keys: int, cap: int):
-        self.KP = _bucket(max(n_keys, 1), lo=1)
-        self.cap = _bucket(max(cap, 16))
-        self._rings = None
 
     def grow(self, cap: int):
         """Widen every ring to `cap` cells a row on the device, contents
@@ -1042,22 +1192,19 @@ class ArgExtResidentExecutor(MultiFieldResidentExecutor):
         _check_ring_overflow(offs, Rb, self.cap)
         pad = (_bucket(int(wlens.max()) if B else 1)
                if any(op == "prod" for op, _f in self.stats) else 0)
-        eb = min(self.eval_block, self.cap)
-        key = ("argext", self.fields, self.stats, self.cap, Rb, Bb, self.KP,
-               tuple(blks[f].dtype.str for f in self.fields),
-               tuple(self.acc_dtypes[f].str for f in self.fields), pad, eb)
+        key = StepKey(
+            "argext", _ANY_DEVICE, self.stats, self.cap, Rb, Bb, self.KP,
+            tuple(blks[f].dtype.str for f in self.fields),
+            tuple(self.acc_dtypes[f].str for f in self.fields), pad,
+            self.fields, eb=min(self.eval_block, self.cap))
         fn = _STEP_CACHE.get(key)
         if fn is None:
             fn = _STEP_CACHE[key] = _make_argext_step(key)
         with profile.span("device_put", *tag):
-            blkps = tuple(
-                (blks[f] if blks[f].shape == (self.KP, Rb)
-                 else _pad2(blks[f], self.KP, Rb)) for f in self.fields)
-            args = jax.device_put(
-                (blkps, _pad1(offs, self.KP),
-                 _pad1(shifts if shifts is not None else (), self.KP),
-                 _pad1(wrows, Bb), _pad1(wstarts, Bb), _pad1(wlens, Bb)),
-                self.device)
+            args = self.place.put(
+                self.KP, Rb, Bb, B, tuple(blks[f] for f in self.fields),
+                (offs, shifts if shifts is not None else ()),
+                (wrows, wstarts, wlens))
         for f in self.fields:
             profile.add("bytes_shipped", blks[f].nbytes)
             profile.add("rows_shipped", blks[f].size)
@@ -1072,322 +1219,6 @@ class ArgExtResidentExecutor(MultiFieldResidentExecutor):
         self._dispatched(meta, B, out, sp, tag)
 
 
-def _make_mesh_multi_step(key, jax_fn):
-    """Sharded fused multi-field append+eval: shard_map over the key-group
-    axis of the per-field rings — each device appends its row block of
-    EVERY field's ring and evaluates its own windows' stats/fn (windows
-    are row-local, so the program has no collectives; the multi-chip form
-    of the whole-tuple functor contract, win_seq_gpu.hpp:54-67 x SURVEY
-    §2.8)."""
-    (_tag, fields, stats, _fnid, cap, Rb, Bs, KP, wires, accs, pad, mesh,
-     axis) = key
-    acc_dts = tuple(np.dtype(a) for a in accs)
-    fidx = {f: i for i, f in enumerate(fields)}
-    udf = None if jax_fn is None else _bind_udf(jax_fn)
-    from jax.sharding import PartitionSpec as P
-
-    def local(rings, blks, offs, lrows, lstarts, llens, lkeys, lgwids):
-        # per-shard views: rings/blks (rps, .) per field, offs (rps,),
-        # descriptors (1, Bs) — this shard's windows, host pre-grouped
-        rings = tuple(_ring_append(r, b, offs, dt)
-                      for r, b, dt in zip(rings, blks, acc_dts))
-        wrows, wstarts, wlens = lrows[0], lstarts[0], llens[0]
-        outs = []
-        for op, f in stats:
-            outs.append(_ring_eval(op, cap, pad, acc_dts[fidx[f]],
-                                   rings[fidx[f]], wrows, wstarts, wlens))
-        if udf is not None:
-            outs.extend(_eval_udf(udf, rings, fidx, cap, pad, wrows, wstarts,
-                                  wlens, lkeys[0], lgwids[0]))
-        outs = tuple(o[None, :] for o in outs)
-        return rings, outs
-
-    n_f = len(fields)
-    mapped = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=((P(axis, None),) * n_f, (P(axis, None),) * n_f,
-                  P(axis), P(axis, None), P(axis, None), P(axis, None),
-                  P(axis, None), P(axis, None)),
-        out_specs=((P(axis, None),) * n_f, P(axis, None)))
-    return _named_jit(mapped, "wf_step_multi_mesh")
-
-
-class MeshMultiFieldResidentExecutor(MultiFieldResidentExecutor):
-    """Multi-field resident rings sharded ``P(kf, None)`` over a mesh:
-    the per-field-ring generalisation of :class:`MeshResidentExecutor` —
-    arbitrary multi-stat reducers and batched JAX window functions run
-    over key-group-sharded archives, one SPMD dispatch for every group
-    (the general whole-tuple functor contract, win_seq_gpu.hpp:54-67,
-    distributed over the ICI mesh)."""
-
-    def __init__(self, fields, stats=(), jax_fn=None, acc_dtypes=None,
-                 mesh=None, axis: str = "kf", depth: int = 8):
-        if mesh is None or axis not in mesh.shape:
-            raise ValueError(f"need a mesh with axis {axis!r}")
-        super().__init__(fields, stats=stats, jax_fn=jax_fn,
-                         acc_dtypes=acc_dtypes,
-                         device=mesh.devices.flat[0], depth=depth)
-        self.mesh = mesh
-        self.axis = axis
-        self.n_shards = int(mesh.shape[axis])
-
-    def _sharding(self, *spec):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(self.mesh, P(*spec))
-
-    def _ring_placement(self):
-        return self._sharding(self.axis, None)
-
-    def reset(self, n_keys: int, cap: int):
-        S = self.n_shards
-        rows_per_shard = _bucket(max(-(-max(n_keys, 1) // S), 1))
-        self.KP = S * rows_per_shard
-        self.cap = _bucket(max(cap, 16))
-        self._rings = None
-
-    def _rings_arr(self):
-        if self._rings is None:
-            self._rings = tuple(
-                jax.device_put(
-                    jnp.zeros((self.KP, self.cap),
-                              dtype=self.acc_dtypes[f]),
-                    self._sharding(self.axis, None))
-                for f in self.fields)
-        return self._rings
-
-    def launch(self, meta, blks: dict, offs: np.ndarray,
-               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
-               wkeys: np.ndarray = None, wgwids: np.ndarray = None,
-               tag=_NO_TAG):
-        S = self.n_shards
-        K, R = next(iter(blks.values())).shape
-        if K > self.KP:
-            raise ValueError("rectangle exceeds ring rows; reset() first")
-        rps = self.KP // S
-        B = len(wstarts)
-        wrows = np.asarray(wrows, dtype=np.int64)
-        # stride dense key rows over shards (MeshResidentExecutor.launch)
-        shard = wrows % S
-        local = wrows // S
-        slots = np.zeros(B, dtype=np.int64)
-        maxc = 0
-        for s in range(S):
-            m = shard == s
-            c = int(m.sum())
-            slots[m] = np.arange(c)
-            maxc = max(maxc, c)
-        Bs = _bucket(max(maxc, 1), lo=self._batch_floor)
-        lrows = np.zeros((S, Bs), dtype=np.int32)
-        lstarts = np.zeros((S, Bs), dtype=np.int32)
-        llens = np.zeros((S, Bs), dtype=np.int32)
-        lkeys = np.zeros((S, Bs), dtype=np.int64)
-        lgwids = np.zeros((S, Bs), dtype=np.int64)
-        if B:
-            lrows[shard, slots] = local.astype(np.int32)
-            lstarts[shard, slots] = wstarts
-            llens[shard, slots] = wlens
-            # the caller sends empty header columns when no fn is bound
-            if wkeys is not None and len(wkeys) == B:
-                lkeys[shard, slots] = wkeys
-            if wgwids is not None and len(wgwids) == B:
-                lgwids[shard, slots] = wgwids
-        Rb = _bucket(max(R, 1))
-        _check_ring_overflow(offs, Rb, self.cap)
-        pad = self._pad_for(wlens)
-        wires = tuple(blks[f].dtype.str for f in self.fields)
-        key = ("mesh-multi", self.fields, self.stats, self._fn_slot,
-               self.cap, Rb, Bs, self.KP, wires,
-               tuple(self.acc_dtypes[f].str for f in self.fields), pad,
-               self.mesh, self.axis)
-        if self.jax_fn is None:
-            fn = _STEP_CACHE.get(key)
-            if fn is None:
-                fn = _STEP_CACHE[key] = _make_mesh_multi_step(key, None)
-        else:
-            fn = _fn_step(self._step_cache, key, self.jax_fn,
-                          _make_mesh_multi_step)
-        # shard-major physical scatter (MeshResidentExecutor.launch)
-        rows = np.arange(K)
-        prow = (rows % S) * rps + rows // S
-        offsp = np.zeros(self.KP, dtype=np.int32)
-        offsp[prow] = offs
-        blkps = []
-        with profile.span("device_put", *tag):
-            for f in self.fields:
-                bp = np.zeros((self.KP, Rb), dtype=blks[f].dtype)
-                bp[prow, :R] = blks[f]
-                blkps.append(jax.device_put(bp, self._sharding(self.axis,
-                                                               None)))
-            s2 = self._sharding(self.axis, None)
-            args = (tuple(blkps),
-                    jax.device_put(offsp, self._sharding(self.axis)),
-                    jax.device_put(lrows, s2), jax.device_put(lstarts, s2),
-                    jax.device_put(llens, s2), jax.device_put(lkeys, s2),
-                    jax.device_put(lgwids, s2))
-        for f in self.fields:
-            profile.add("bytes_shipped", blks[f].nbytes)
-            profile.add("rows_shipped", blks[f].size)
-        profile.add("windows", B)
-        if self.jax_fn is not None:
-            self._count_udf(B, wlens, S * Bs * pad)
-        with profile.span("dispatch", *tag) as sp:
-            self._rings, out = fn(self._rings_arr(), *args)
-            for o in out:
-                o.copy_to_host_async()
-        self._dispatched(meta, (shard, slots), out, sp, tag)
-
-
-class MeshResidentExecutor(ResidentWindowExecutor):
-    """Resident ring sharded ``P(kf, None)`` over a ``jax.sharding.Mesh``:
-    dense-key ring rows are block-distributed over the mesh's key-group
-    axis, so ONE fused append+eval dispatch serves every key group — each
-    chip holds its groups' archives in its own HBM and evaluates its own
-    windows (no collectives; the kf axis is embarrassingly parallel,
-    parallel/mesh.py).  This is the multi-chip form of the reference's
-    per-worker GPU ownership (win_farm_gpu.hpp:132-168) with the farm
-    collapsed into one SPMD program."""
-
-    def __init__(self, op: str, mesh, axis: str = "kf", depth: int = 8,
-                 acc_dtype=np.int32):
-        if axis not in mesh.shape:
-            raise ValueError(f"mesh has no axis {axis!r}: {mesh.shape}")
-        super().__init__(op, device=mesh.devices.flat[0], depth=depth,
-                         acc_dtype=acc_dtype)
-        self.mesh = mesh
-        self.axis = axis
-        self.n_shards = int(mesh.shape[axis])
-
-    def _sharding(self, *spec):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(self.mesh, P(*spec))
-
-    def _ring_placement(self):
-        return self._sharding(self.axis, None)
-
-    def reset(self, n_keys: int, cap: int):
-        S = self.n_shards
-        rows_per_shard = _bucket(max(-(-max(n_keys, 1) // S), 1))
-        self.KP = S * rows_per_shard
-        self.cap = _bucket(max(cap, 16))
-        self._ring = None
-
-    def _ring_arr(self):
-        if self._ring is None:
-            self._ring = jax.device_put(
-                jnp.zeros((self.KP, self.cap), dtype=self.acc_dtype),
-                self._sharding(self.axis, None))
-        return self._ring
-
-    def launch(self, meta, blk: np.ndarray, offs: np.ndarray,
-               wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
-               tag=_NO_TAG):
-        S = self.n_shards
-        K, R = blk.shape
-        if K > self.KP:
-            raise ValueError("rectangle exceeds ring rows; reset() first")
-        rps = self.KP // S
-        B = len(wstarts)
-        wrows = np.asarray(wrows, dtype=np.int64)
-        # STRIDE dense key rows over shards (row r -> shard r % S, local
-        # slot r // S): the host assigns rows in key-arrival order, so a
-        # block mapping would concentrate all live keys on the low shards
-        # while the padded tail idles — striding balances any K
-        shard = wrows % S
-        local = wrows // S
-        # per-shard slot assignment, preserving original order per shard
-        slots = np.zeros(B, dtype=np.int64)
-        maxc = 0
-        for s in range(S):
-            m = shard == s
-            c = int(m.sum())
-            slots[m] = np.arange(c)
-            maxc = max(maxc, c)
-        Bs = _bucket(max(maxc, 1))
-        lrows = np.zeros((S, Bs), dtype=np.int32)
-        lstarts = np.zeros((S, Bs), dtype=np.int32)
-        llens = np.zeros((S, Bs), dtype=np.int32)
-        if B:
-            lrows[shard, slots] = local.astype(np.int32)
-            lstarts[shard, slots] = wstarts
-            llens[shard, slots] = wlens
-        Rb = _bucket(max(R, 1))
-        _check_ring_overflow(offs, Rb, self.cap)
-        pad = (_bucket(int(wlens.max()) if B else 1)
-               if any(o != "sum" for o in self.ops) else 0)
-        key = ("mesh", self.ops, self.cap, Rb, Bs, self.KP, blk.dtype.str,
-               self.acc_dtype.str, pad, self.mesh, self.axis)
-        fn = _STEP_CACHE.get(key)
-        if fn is None:
-            fn = _STEP_CACHE[key] = _make_mesh_step(key)
-        # scatter the rectangle so dense row r lands at physical ring row
-        # (r % S) * rps + r // S — shard-major, matching the window mapping
-        rows = np.arange(K)
-        prow = (rows % S) * rps + rows // S
-        blkp = np.zeros((self.KP, Rb), dtype=blk.dtype)
-        blkp[prow, :R] = blk
-        offsp = np.zeros(self.KP, dtype=np.int32)
-        offsp[prow] = offs
-        with profile.span("device_put", *tag):
-            args = (jax.device_put(blkp, self._sharding(self.axis, None)),
-                    jax.device_put(offsp, self._sharding(self.axis)),
-                    jax.device_put(lrows, self._sharding(self.axis, None)),
-                    jax.device_put(lstarts, self._sharding(self.axis, None)),
-                    jax.device_put(llens, self._sharding(self.axis, None)))
-        with profile.span("dispatch", *tag) as sp:
-            self._ring, out = fn(self._ring_arr(), *args)
-            for o in (out if isinstance(out, tuple) else (out,)):
-                o.copy_to_host_async()
-        # harvest indexes the (S, Bs) result back to flat window order
-        self._dispatched(meta, (shard, slots), out, sp, tag)
-
-    def launch_regular(self, meta, blk: np.ndarray, offs: np.ndarray,
-                       rcount: np.ndarray, rstart0: np.ndarray,
-                       rlen: np.ndarray, slide: int, wrows: np.ndarray,
-                       widx: np.ndarray, cmax: int = 0, tag=_NO_TAG):
-        """Regular-descriptor dispatch on the sharded ring: the per-key
-        (count, start0, len) scalars shard with their rows, and each device
-        expands its own arithmetic window sequences — the native core's
-        wire compression composes with mesh execution (r2 weak #3)."""
-        if not (self.single and self.op == "sum"):
-            raise ValueError("regular descriptors implemented for "
-                             "single-stat sum")
-        S = self.n_shards
-        K, R = blk.shape
-        if K > self.KP:
-            raise ValueError("rectangle exceeds ring rows; reset() first")
-        rps = self.KP // S
-        Rb = _bucket(max(R, 1))
-        C = _bucket(int(cmax) if cmax else
-                    (int(rcount.max()) if len(rcount) else 1))
-        _check_ring_overflow(offs, Rb, self.cap)
-        key = ("mesh-reg", self.op, self.cap, Rb, self.KP, C, blk.dtype.str,
-               self.acc_dtype.str, int(slide), self.mesh, self.axis)
-        fn = _STEP_CACHE.get(key)
-        if fn is None:
-            fn = _STEP_CACHE[key] = _make_mesh_regular_step(key)
-        # strided physical scatter, same mapping as launch()
-        rows = np.arange(K)
-        prow = (rows % S) * rps + rows // S
-        blkp = np.zeros((self.KP, Rb), dtype=blk.dtype)
-        blkp[prow, :R] = blk[:, :R]
-        def scat(a, dtype=np.int32):
-            out = np.zeros(self.KP, dtype=dtype)
-            out[prow] = a[:K]
-            return out
-        with profile.span("device_put", *tag):
-            args = (jax.device_put(blkp, self._sharding(self.axis, None)),
-                    jax.device_put(scat(offs), self._sharding(self.axis)),
-                    jax.device_put(scat(rcount), self._sharding(self.axis)),
-                    jax.device_put(scat(rstart0), self._sharding(self.axis)),
-                    jax.device_put(scat(rlen), self._sharding(self.axis)))
-        with profile.span("dispatch", *tag) as sp:
-            self._ring, out = fn(self._ring_arr(), *args)
-            out.copy_to_host_async()
-        wr = np.asarray(wrows, dtype=np.int64)
-        sel = ((wr % S) * rps + wr // S, np.asarray(widx))
-        self._dispatched(meta, sel, out, sp, tag)
-
-
 def make_executor(family: str, fields, stats, acc_dtypes, *, jax_fn=None,
                   mesh=None, device=None, depth: int = 8):
     """The resident executor of one step family — the one place that names
@@ -1397,84 +1228,57 @@ def make_executor(family: str, fields, stats, acc_dtypes, *, jax_fn=None,
     pairs and ``acc_dtypes`` maps each field to its ring dtype.  With
     ``mesh`` the rings shard ``P(kf, None)`` over it, else they live on
     ``device``."""
+    if mesh is None:
+        place = _OneDevice(device or default_device())
+    elif "kf" not in mesh.shape:
+        raise ValueError(f"mesh has no axis 'kf': {mesh.shape}")
+    else:
+        place = _OnMesh(mesh, "kf")
     if family == "argext":
-        return ArgExtResidentExecutor(fields, stats, acc_dtypes,
-                                      device=device, depth=depth)
+        return ArgExtResidentExecutor(fields, stats, acc_dtypes, place=place,
+                                      depth=depth)
     if family == "multi":
-        if mesh is not None:
-            return MeshMultiFieldResidentExecutor(
-                fields, stats=stats, jax_fn=jax_fn, acc_dtypes=acc_dtypes,
-                mesh=mesh, depth=depth)
         return MultiFieldResidentExecutor(
             fields, stats=stats, jax_fn=jax_fn, acc_dtypes=acc_dtypes,
-            device=device, depth=depth)
+            place=place, depth=depth)
     (field,) = fields
     ops = tuple(op for op, _f in stats)
-    op = ops[0] if len(ops) == 1 else ops
-    if mesh is not None:
-        return MeshResidentExecutor(op, mesh, depth=depth,
-                                    acc_dtype=acc_dtypes[field])
-    return ResidentWindowExecutor(op, device=device, depth=depth,
+    return ResidentWindowExecutor(ops[0] if len(ops) == 1 else ops,
+                                  place=place, depth=depth,
                                   acc_dtype=acc_dtypes[field])
 
 
 def prewarm_regular_ladder(mults=(2, 4, 8, 16), devices=None,
                            max_cells=1 << 24) -> int:
-    """Compile the coalesced-shape siblings of every step (regular,
-    irregular, mesh) already compiled in this process.
+    """Compile the coalesced-shape siblings of every one-ring step
+    (``regular``, ``append_eval``; one device or a mesh) already compiled in
+    this process.
 
     Deep launch coalescing dispatches merged shapes on the {2x, 4x, ...}
-    buddy ladder — diagonal (Rb*m, B*m) siblings for irregular steps, the
-    lower triangle {(Rb*m, C*b), b <= m} for regular steps (try_merge
-    admits window-bucket growth at most proportional to row-bucket
-    growth) — only when launches queue up behind a slow service, exactly
+    buddy ladder only when launches queue up behind a slow service, exactly
     when a cold mid-run compile hurts most.  A benchmark calls this once
-    after its warmup run: whatever regular buckets the warmup compiled,
-    their ladder siblings compile now, deterministically, regardless of
-    the launch service the warmup happened to see.  ``devices`` should
-    list every device the run's executors own (jit executables cache per
+    after its warmup run: whatever buckets the warmup compiled, their
+    ladder siblings compile now, deterministically, regardless of the
+    launch service the warmup happened to see.  ``devices`` should list
+    every device the run's executors own (jit executables cache per
     placement; a farm worker on another chip would otherwise cold-compile
     its first merged shape) — default is device 0 only.  Returns the
     number of steps compiled."""
     devices = list(devices) if devices else [default_device()]
+    factory = {"regular": _make_regular_step, "append_eval": _make_step}
     warmed = 0
     for key in list(_STEP_CACHE):
-        if key in _PREWARMED:
-            # a prewarmed sibling never seeds further ladders: the buddy
-            # multiplicity caps at 16x of a NATURAL launch shape, so
-            # ladders-of-ladders are undispatchable (and repeat calls
-            # must be no-ops)
-            continue
-        tag = key[0] if isinstance(key, tuple) and key else None
-        if tag == "reg":
-            _t, op, cap, Rb, KP, C, blk_dt, acc_dt, slide = key
-            mesh = axis = None
-        elif tag == "mesh-reg":
-            (_t, op, cap, Rb, KP, C, blk_dt, acc_dt, slide, mesh,
-             axis) = key
-        elif isinstance(tag, tuple) and len(key) == 8:
-            # plain (irregular-descriptor) step: TB windows and non-sum
-            # ops merge on explicit descriptors, so their ladder siblings
-            # double both the rectangle AND the window-count bucket.
-            # (multi-field keys are also tuple-tagged but 10-long — their
-            # executor is Python-core only, which never coalesces)
-            _ops, cap, Rb, Bb, KP, blk_dt, acc_dt, pad = key
-            mesh = axis = None
-        elif tag == "mesh":
-            # mesh irregular step: the coalescer merges irregular launches
-            # on the mesh-backed native path too (non-sum ops, TB windows),
-            # so merged (Rb*m, Bs*m) diagonal siblings must be warm as well
-            # (ADVICE r3).  The per-shard window bucket Bs tracks the total
-            # window count's bucket in the common case (strided shard
-            # assignment); the diagonal ladder covers exactly those.
-            (_t, ops_m, cap, Rb, Bb, KP, blk_dt, acc_dt, pad, mesh,
-             axis) = key
-        else:
+        # a prewarmed sibling never seeds further ladders: the buddy
+        # multiplicity caps at 16x of a NATURAL launch shape, so
+        # ladders-of-ladders are undispatchable (and repeat calls must be
+        # no-ops).  The families with a ring per field are not warmed: the
+        # coalescer merges one-ring launches only
+        if key in _PREWARMED or key.family not in factory:
             continue
         for m in mults:
             # a real merge can never exceed the ring (try_merge's offset
             # guard bounds bucket(newR) by cap) ...
-            if Rb * m > cap:
+            if key.Rb * m > key.cap:
                 continue
             # ... and its area guard counts LIVE keys (K2 * bucket(newR)
             # <= max_cells, wf_native.cpp:try_merge); the smallest live K
@@ -1482,93 +1286,51 @@ def prewarm_regular_ladder(mults=(2, 4, 8, 16), devices=None,
             # skip only shapes NO admissible merge could produce — a
             # padded-KP guard here would refuse shapes the coalescer then
             # builds and compiles cold mid-run
-            if (KP // 2 + 1) * Rb * m > max_cells:
+            if (key.KP // 2 + 1) * key.Rb * m > max_cells:
                 continue
-            if isinstance(tag, tuple):
-                sks = [(tag, cap, Rb * m, Bb * m, KP, blk_dt, acc_dt, pad)]
-            elif tag == "mesh":
-                # the mesh dispatch key's window bucket Bs is PER-SHARD
-                # (bucket of the fullest shard's window count,
-                # MeshResidentExecutor.launch) while try_merge guards the
-                # TOTAL window bucket — clamping decouples them (merged
-                # per-shard counts can sit under the lo=8 clamp while rows
-                # double), so merged mesh shapes live on the same lower
-                # triangle as regular ones: warm {(Rb*m, Bs*b), b <= m}
-                sks = []
-                b = 1
-                while b <= m:
-                    sks.append(("mesh", ops_m, cap, Rb * m, Bb * b, KP,
-                                blk_dt, acc_dt, pad, mesh, axis))
-                    b *= 2
+            if key.family == "append_eval" and key.place.mesh is None:
+                # explicit descriptors on one device (TB windows, non-sum
+                # ops): a merge doubles the rectangle AND the window bucket,
+                # the diagonal (Rb*m, Bb*m)
+                grow = [m]
             else:
-                # regular merges live on the LOWER TRIANGLE {(Rb*a, C*b),
-                # b <= a}: small per-key window counts can clamp the C
+                # the LOWER TRIANGLE {(Rb*m, Bb*b), b <= m}.  Regular
+                # merges: small per-key window counts can clamp the C
                 # bucket while rows double (try_merge admits rc <= rr), so
-                # the diagonal sibling alone would leave e.g. (2*Rb, C)
-                # cold exactly when the coalescer builds it mid-stall
-                # (ADVICE r3)
-                sks = []
-                b = 1
-                while b <= m:
-                    if mesh is None:
-                        sks.append(("reg", op, cap, Rb * m, KP, C * b,
-                                    blk_dt, acc_dt, slide))
-                    else:
-                        sks.append(("mesh-reg", op, cap, Rb * m, KP, C * b,
-                                    blk_dt, acc_dt, slide, mesh, axis))
-                    b *= 2
-            todo = [sk for sk in sks if sk not in _STEP_CACHE]
+                # the diagonal alone would leave e.g. (2*Rb, C) cold exactly
+                # when the coalescer builds it mid-stall (ADVICE r3).  A
+                # mesh's explicit descriptors: its window bucket is PER
+                # SHARD (the fullest shard's, `_OnMesh.batch`) while
+                # try_merge guards the TOTAL, so merged per-shard counts can
+                # sit under the lo=8 clamp while rows double
+                grow = [1 << i for i in range(m.bit_length())]
+            todo = [sk for sk in (key._replace(Rb=key.Rb * m, Bb=key.Bb * b)
+                                  for b in grow) if sk not in _STEP_CACHE]
             if not todo:
                 continue
             # the warm inputs depend only on (family, m), never on the
-            # triangle's C value (it shapes the OUTPUT only) — allocate
-            # them once per placement and reuse across siblings (a ring is
-            # up to 128 MB; re-shipping it per sibling would stretch the
-            # warmup window for nothing)
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                s2 = NamedSharding(mesh, P(axis, None))
-                s1 = NamedSharding(mesh, P(axis))
-                placements = [(s2, s1)]
-            else:
-                placements = [(dev, dev) for dev in devices]
-            bases = []
-            for p2, p1 in placements:
-                ring = jax.device_put(
-                    jnp.zeros((KP, cap), dtype=np.dtype(acc_dt)), p2)
-                blk = jax.device_put(
-                    jnp.zeros((KP, Rb * m), dtype=np.dtype(blk_dt)), p2)
-                zk = jax.device_put(jnp.zeros(KP, dtype=np.int32), p1)
-                bases.append((p2, p1, ring, blk, zk))
+            # sibling's window bucket (of the regular family it shapes the
+            # OUTPUT only) — allocate them once per placement and reuse
+            # across siblings (a ring is up to 128 MB; re-shipping it per
+            # sibling would stretch the warmup window for nothing)
+            bases = [
+                (place,
+                 _zeros((key.KP, key.cap), np.dtype(key.accs), place.rect),
+                 _zeros((key.KP, key.Rb * m), np.dtype(key.wires),
+                        place.rect),
+                 _zeros(key.KP, np.int32, place.rows))
+                for place in key.place.each(devices)]
             for sk in todo:
                 # cache only AFTER the warm dispatch succeeds: a transient
                 # device error mid-warm must leave the key retryable, not
                 # "warm" with a cold executable behind it
-                if tag == "mesh":
-                    fn = _make_mesh_step(sk)
-                elif isinstance(tag, tuple):
-                    fn = _make_step(sk)
-                elif mesh is None:
-                    fn = _make_regular_step(sk)
-                else:
-                    fn = _make_mesh_regular_step(sk)
-                for p2, p1, ring, blk, zk in bases:
-                    # the window-descriptor vectors are the one input whose
-                    # shape varies across mesh/plain irregular siblings
-                    # (sk[4] / sk[3] is that sibling's Bs); regular steps
-                    # take per-key scalars only
-                    if tag == "mesh":
-                        S = int(mesh.shape[axis])
-                        zb = jax.device_put(
-                            jnp.zeros((S, sk[4]), dtype=np.int32), p2)
-                        args = (ring, blk, zk, zb, zb, zb)
-                    elif isinstance(tag, tuple):
-                        zb = jax.device_put(
-                            jnp.zeros(sk[3], dtype=np.int32), p1)
-                        args = (ring, blk, zk, zb, zb, zb)
-                    else:
-                        args = (ring, blk, zk, zk, zk, zk)
-                    _ring2, out = fn(*args)
+                fn = factory[key.family](sk)
+                for place, ring, blk, zk in bases:
+                    # regular steps take per-key scalars only; the others'
+                    # descriptors are the one input shaped by the sibling
+                    zb = zk if key.family == "regular" else _zeros(
+                        place.win_shape(sk.Bb), np.int32, place.wins)
+                    _ring2, out = fn(ring, blk, zk, zb, zb, zb)
                     jax.block_until_ready(out)
                 _STEP_CACHE[sk] = fn
                 _PREWARMED.add(sk)

@@ -22,9 +22,9 @@ Deployment model: one engine process per host.  Host-side dataflow
 generates (or receives) only its own key range needs no cross-host hop at
 all, exactly like the reference's per-worker key partitioning
 (kf_nodes.hpp routing) lifted one level.  Device-side, the sharded
-executors (``MeshResidentExecutor``, ``MeshStreamStep``) run one SPMD
-program over the global mesh; XLA inserts the (absent, for kf) DCN
-collectives.
+executors (a resident executor placed on a mesh, ``MeshStreamStep``) run
+one SPMD program over the global mesh; XLA inserts the (absent, for kf)
+DCN collectives.
 """
 
 from __future__ import annotations

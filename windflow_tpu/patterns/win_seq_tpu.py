@@ -4,8 +4,7 @@ the TPU graft of the reference's Win_Seq_GPU (win_seq_gpu.hpp).
 Same window bookkeeping as the host core (it *is* the host core: one
 subclass hook), but fired NIC windows are not evaluated inline: their
 (start, len) ranges plus the staged archive slice are queued, and at
-``batch_len`` fired windows one XLA computation (or Pallas kernel)
-evaluates them all.  Result headers (key, renumbered id, result ts) are
+``batch_len`` fired windows one XLA computation evaluates them all.  Result headers (key, renumbered id, result ts) are
 computed host-side at fire time, exactly like the reference pre-fills
 ``host_results[i].setInfo(...)`` before the kernel (win_seq_gpu.hpp:447-449).
 Launches are asynchronous with bounded depth (vs the reference's per-batch
@@ -148,16 +147,14 @@ class DeviceWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
     def __init__(self, spec: WindowSpec, winfunc, batch_len: int = 512,
                  config: PatternConfig = None, role: Role = Role.SEQ,
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
-                 depth: int = 4, use_pallas: bool = False,
-                 compute_dtype=None):
+                 depth: int = 4, compute_dtype=None):
         host_fn = _host_standin(winfunc)
         if isinstance(winfunc, Reducer):
             executor = DeviceWindowExecutor(
                 builtin_batch_fn(winfunc.op, winfunc.field),
                 fields=winfunc.required_fields,
                 out_fields=tuple(winfunc.result_fields),
-                device=device, depth=depth, use_pallas=use_pallas,
-                op=winfunc.op, compute_dtype=compute_dtype,
+                device=device, depth=depth, compute_dtype=compute_dtype,
                 out_dtypes=winfunc.result_fields,
                 # empty windows must produce the host-path identity even
                 # though device compute may run in a narrower dtype
@@ -941,14 +938,13 @@ def _host_free(spec: WindowSpec, winfunc) -> bool:
                for p in _stat_parts(winfunc))
 
 
-def _multi_resident_ok(winfunc: MultiReducer, use_pallas: bool) -> bool:
+def _multi_resident_ok(winfunc: MultiReducer) -> bool:
     """Whether a MultiReducer can run on the resident path: >=1 non-count
     stat, all ops resident-evaluable, no float-sum.  Stats over ONE field
     share a single ring; stats over several fields get one ring each
     (MultiFieldResidentExecutor)."""
     dev = winfunc.device_parts
-    return (not use_pallas and bool(dev)
-            and all(p.op in _RESIDENT_OPS for p in dev)
+    return (bool(dev) and all(p.op in _RESIDENT_OPS for p in dev)
             and not any(p.op == "sum"
                         and np.issubdtype(p.dtype, np.floating)
                         for p in dev))
@@ -1019,8 +1015,8 @@ class CorePlan(NamedTuple):
     mesh: bool
 
 
-def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
-              mesh=None, shards=1, native=None) -> CorePlan:
+def plan_core(spec, winfunc, *, use_resident=None, mesh=None, shards=1,
+              native=None) -> CorePlan:
     """Which window core and executor family run ``winfunc`` over ``spec``
     — decided here and nowhere else, from the arguments alone: the same
     arguments give the same plan whatever ran earlier in the process.
@@ -1040,9 +1036,8 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
         # Whatever that cannot run raises -- there is no host route here
         others = [p for p in parts if not isinstance(p, ArgReducer)]
         why = None
-        if use_pallas or use_resident is False:
-            why = "it runs on the resident path (no use_pallas, no " \
-                  "use_resident=False)"
+        if use_resident is False:
+            why = "it runs on the resident path (no use_resident=False)"
         elif _argext_misplaced(mesh, shards):
             why = f"it runs {_ARGEXT_PLACEMENT}"
         elif any(p.op not in _RESIDENT_OPS + ("count",) for p in others) \
@@ -1058,23 +1053,19 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
         return CorePlan("native", "argext", False)
     if (isinstance(winfunc, (Reducer, MultiReducer))
             and use_resident is None and not on_mesh
-            and (isinstance(winfunc, MultiReducer) or not use_pallas)
             and _host_free(spec, winfunc)):
         # every stat is answerable from host bookkeeping (count from
         # window lengths; max over the position field from the
         # position-ordered archive) — shipping the column to the device
         # buys nothing but transfer traffic (YSB's count+MAX(ts) lost to
         # the host path for exactly this reason).  use_resident=True
-        # forces the device; a Reducer with use_pallas=True keeps the
-        # Pallas/restaging path (benchmarking) — MultiReducer has no
-        # Pallas path, so the flag does not block its host routing.
+        # forces the device.
         return CorePlan("host", None, False)
     if isinstance(winfunc, MultiReducer):
         # multi-stat windows are resident-only (the restaging executor has
         # no multi-output contract); count-only MultiReducers should be a
         # plain Reducer("count")
-        if use_resident is False or not _multi_resident_ok(winfunc,
-                                                           use_pallas):
+        if use_resident is False or not _multi_resident_ok(winfunc):
             raise ValueError(
                 "MultiReducer runs on the resident device path only: "
                 "needs >=1 non-count stat, ops in "
@@ -1089,7 +1080,7 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
         return CorePlan(core, _executor_family(core, dev_parts or pos_parts),
                         on_mesh)
     jax_fn = isinstance(winfunc, JaxWindowFunction)
-    if jax_fn and (use_resident or on_mesh) and not use_pallas:
+    if jax_fn and (use_resident or on_mesh):
         # arbitrary JAX window fns evaluate over multi-field resident
         # rings on request (use_resident=True); the default stays the
         # segment-restaging executor, whose staged columns carry each
@@ -1100,7 +1091,7 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
         return CorePlan("resident_py", "multi", on_mesh)
     resident = use_resident
     if resident is None:
-        resident = (not use_pallas and isinstance(winfunc, Reducer)
+        resident = (isinstance(winfunc, Reducer)
                     and winfunc.op in _RESIDENT_OPS
                     # a float cumsum accumulates rounding error the host
                     # path's per-window reduction does not; floats keep the
@@ -1115,10 +1106,9 @@ def plan_core(spec, winfunc, *, use_pallas=False, use_resident=None,
                 f"(one of {_RESIDENT_OPS}); got {winfunc!r}")
         if not resident:
             raise ValueError(
-                "mesh execution requires the resident path: drop "
-                "use_pallas, and for float sums opt in explicitly with "
-                "use_resident=True (cumsum rounding differs from the "
-                "host's per-window reduction)")
+                "mesh execution requires the resident path: for float "
+                "sums opt in explicitly with use_resident=True (cumsum "
+                "rounding differs from the host's per-window reduction)")
     if not resident:
         return CorePlan("restage", None, False)
     # the C++ bookkeeping feeds the ring, sharded or not: a real pod's
@@ -1210,10 +1200,10 @@ def make_device_core(worker, fn, dev_kw, index=0):
 
 def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                   role=Role.SEQ, map_indexes=(0, 1), result_ts_slide=None,
-                  device=None, depth=None, use_pallas=False,
-                  compute_dtype=None, use_resident=None,
-                  flush_rows=1 << 20, shards=1, worker_index=0, mesh=None,
-                  max_delay_ms=None, fire_on="key", holdback=0):
+                  device=None, depth=None, compute_dtype=None,
+                  use_resident=None, flush_rows=1 << 20, shards=1,
+                  worker_index=0, mesh=None, max_delay_ms=None,
+                  fire_on="key", holdback=0):
     """Build the window core :func:`plan_core` names.  With ``mesh`` the
     resident ring is sharded ``P('kf', None)`` across the mesh devices (one
     dispatch serves every key group over ICI); ``max_delay_ms`` is a timer
@@ -1221,10 +1211,9 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
     ``holdback`` is the host cores' and the native resident core's
     (:func:`stream_fire_plan` refuses the others)."""
     plan = stream_fire_plan(
-        plan_core(spec, winfunc, use_pallas=use_pallas,
-                  use_resident=use_resident, mesh=mesh, shards=shards,
-                  native=_native_core_fields()), fire_on, spec, config, role,
-        shards, max_delay_ms, holdback)
+        plan_core(spec, winfunc, use_resident=use_resident, mesh=mesh,
+                  shards=shards, native=_native_core_fields()),
+        fire_on, spec, config, role, shards, max_delay_ms, holdback)
     if plan.core == "host":
         from .win_seq import WinSeq
         return WinSeq(winfunc, spec.win_len, spec.slide_len,
@@ -1237,7 +1226,7 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
               compute_dtype=compute_dtype)
     if plan.core == "restage":
         return DeviceWinSeqCore(
-            spec, winfunc, use_pallas=use_pallas,
+            spec, winfunc,
             device=resolve_worker_device(device, worker_index),
             depth=depth if depth is not None else 4, **kw)
     kw.update(flush_rows=flush_rows, device=device,
@@ -1269,17 +1258,16 @@ class WinSeqTPU(_Pattern):
                  batch_len=512, name="win_seq_tpu",
                  config: PatternConfig = None, role: Role = Role.SEQ,
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
-                 depth=None, use_pallas=False, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, shards=1,
-                 mesh=None, max_delay_ms=None, fire_on="key", holdback=0):
+                 depth=None, compute_dtype=None, use_resident=None,
+                 flush_rows=1 << 20, shards=1, mesh=None, max_delay_ms=None,
+                 fire_on="key", holdback=0):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self._burst_rows = _stream_burst_rows(batch_len, flush_rows)
         self._kw = dict(batch_len=batch_len, config=config, role=role,
                         map_indexes=map_indexes,
                         result_ts_slide=result_ts_slide, device=device,
-                        depth=depth, use_pallas=use_pallas,
-                        compute_dtype=compute_dtype,
+                        depth=depth, compute_dtype=compute_dtype,
                         use_resident=use_resident, flush_rows=flush_rows,
                         shards=shards, mesh=mesh,
                         max_delay_ms=max_delay_ms, fire_on=fire_on,
@@ -1311,12 +1299,10 @@ class WinFarmTPU(_DeviceCoreFactory, WinFarm):
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, batch_len=512, name="win_farm_tpu",
                  ordered=True, n_emitters=1, config=None, role=Role.SEQ,
-                 device=None, depth=None, use_pallas=False,
-                 compute_dtype=None, use_resident=None, flush_rows=1 << 20,
-                 max_delay_ms=None):
+                 device=None, depth=None, compute_dtype=None,
+                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None):
         self._raw_fn = winfunc
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
-                            use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows,
                             max_delay_ms=max_delay_ms)
@@ -1333,12 +1319,11 @@ class KeyFarmTPU(_DeviceCoreFactory, KeyFarm):
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, batch_len=512, name="key_farm_tpu",
                  routing=None, config=None, role=Role.SEQ, device=None,
-                 depth=None, use_pallas=False, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
-                 fire_on="key", holdback=0):
+                 depth=None, compute_dtype=None, use_resident=None,
+                 flush_rows=1 << 20, max_delay_ms=None, fire_on="key",
+                 holdback=0):
         self._raw_fn = winfunc
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
-                            use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows,
                             max_delay_ms=max_delay_ms, fire_on=fire_on,
@@ -1359,12 +1344,10 @@ class PaneFarmTPU(PaneFarm):
     def __init__(self, plq_func, wlq_func, win_len, slide_len,
                  win_type=WinType.CB, plq_degree=1, wlq_degree=1,
                  name="pane_farm_tpu", plq_on_device=True, wlq_on_device=True,
-                 batch_len=512, device=None, depth=None, use_pallas=False,
-                 compute_dtype=None, use_resident=None, flush_rows=1 << 20,
-                 **kw):
+                 batch_len=512, device=None, depth=None, compute_dtype=None,
+                 use_resident=None, flush_rows=1 << 20, **kw):
         self._on_device = {"plq": plq_on_device, "wlq": wlq_on_device}
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
-                            use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows)
         super().__init__(plq_func, wlq_func, win_len, slide_len, win_type,
@@ -1405,12 +1388,10 @@ class WinMapReduceTPU(WinMapReduce):
                  win_type=WinType.CB, map_degree=2, reduce_degree=1,
                  name="win_mr_tpu", map_on_device=True,
                  reduce_on_device=False, batch_len=512, device=None,
-                 depth=None, use_pallas=False, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
-                 **kw):
+                 depth=None, compute_dtype=None, use_resident=None,
+                 flush_rows=1 << 20, max_delay_ms=None, **kw):
         self._on_device = {"map": map_on_device, "reduce": reduce_on_device}
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
-                            use_pallas=use_pallas,
                             compute_dtype=compute_dtype,
                             use_resident=use_resident, flush_rows=flush_rows,
                             max_delay_ms=max_delay_ms)
