@@ -239,3 +239,51 @@ def watermark_windows(rows, win_len, slide_len, holdback, value="value",
             k = (int(r[key]), w)
             sums[k] = sums.get(k, 0) + int(r[value])
     return sums, late
+
+
+def _frontier(points):
+    """The skyline of ``points`` (pairs, minimisation in both coordinates) by
+    the definition, point against every point: ``q`` dominates ``p`` iff it
+    is no greater in both coordinates and smaller in one, so identical points
+    leave each other alive.  Arrival order kept."""
+    return [p for p in points
+            if not any(q[0] <= p[0] and q[1] <= p[1]
+                       and (q[0] < p[0] or q[1] < p[1]) for q in points)]
+
+
+def skyline_windows(rows, win_len, slide_len, pos="ts", by_panes=False):
+    """Plain reference of the spatial suite's query (the reference's
+    ``test_spatial_wf.cpp`` / ``test_spatial_pf.cpp``) over one key-less
+    stream of points (dicts or structured rows with ``x``, ``y``): per
+    sliding window ``w`` = ``[w*slide_len, w*slide_len + win_len)`` of
+    ``pos`` (``ts``: time-based, ``id``: count-based) that holds a point,
+    the skyline of its points as ``{w: (size, sum of x + y over it)}``.
+
+    ``by_panes=False`` tests every pair of a WHOLE window's points.
+    ``by_panes=True`` is the pane form: the skyline of each tumbling pane of
+    ``gcd(win_len, slide_len)`` once, a window then the skyline of its panes'
+    skylines -- ``skyline(A + B) = skyline(skyline(A) + skyline(B))``, what
+    Pane_Farm's PLQ and WLQ compute.  The two have to agree.  Loops, the twin
+    of ``benchmarks/configs/spatial_pf_oracle.py``."""
+    pts = [(int(r[pos]), float(r["x"]), float(r["y"])) for r in rows]
+    if not pts:
+        return {}
+    last = max(p[0] for p in pts)
+    pane = math.gcd(win_len, slide_len)
+    fronts = {}
+    if by_panes:
+        for t, x, y in pts:
+            fronts.setdefault(t // pane, []).append((x, y))
+        fronts = {p: _frontier(v) for p, v in fronts.items()}
+    out = {}
+    for w in range(last // slide_len + 1):
+        lo, hi = w * slide_len, w * slide_len + win_len
+        if by_panes:
+            held = [q for p in range(lo // pane, hi // pane)
+                    for q in fronts.get(p, ())]
+        else:
+            held = [(x, y) for t, x, y in pts if lo <= t < hi]
+        if held:
+            sky = _frontier(held)
+            out[w] = (len(sky), sum(x + y for x, y in sky))
+    return out

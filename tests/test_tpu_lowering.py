@@ -166,6 +166,15 @@ def _cell_skyline():
     return spatial_wf.window_function()
 
 
+def _cell_pane_skyline():
+    """The pane function of the benchmark's ``spatial_pf`` configuration
+    (benchmarks/configs/spatial_pf.py): the all-pairs test, then the
+    frontier compacted into 64 slots -- rank-2 outputs."""
+    _cell_skyline()          # (puts benchmarks/ on the path)
+    from configs import spatial_pf
+    return spatial_pf.pane_function(64)
+
+
 @pytest.mark.parametrize("make,kp,cap,rb,B,pad", [
     (_app_skyline, 8, 8192, 2048, 256, 512),
     # what the cell spatial_wf.paced meets: a pipeline's first launch, the
@@ -178,7 +187,11 @@ def _cell_skyline():
     # of ops/device._bucket_fine: 102,400 points run as 106,496
     (_cell_skyline, 8, 262144, 131072, 1, 106496),
     (_cell_skyline, 8, 262144, 16384, 1, 106496),
-    (_cell_skyline, 8, 262144, 16384, 2, 106496)])
+    (_cell_skyline, 8, 262144, 16384, 2, 106496),
+    # what the cell spatial_pf.paced meets: a whole pane in one launch, and
+    # two panes in one where a worker fell behind
+    (_cell_pane_skyline, 8, 524288, 131072, 1, 106496),
+    (_cell_pane_skyline, 8, 524288, 131072, 2, 106496)])
 def test_skyline_step_compiles(v5e, make, kp, cap, rb, B, pad):
     """A device skyline, the (B, pad, pad) dominance test, on the multi-field
     resident step it runs on (use_resident=True): XLA has to fuse compare

@@ -15,6 +15,14 @@ the one schema shape every engine path (vectorised emitters, ordering,
 channels, device staging) already speaks.  A pane
 skyline of uniform points is O(log n) expected, so the default cap of 64
 is deep; an overflow raises loudly rather than truncating a result.
+
+Either stage's function also exists for the DEVICE: :func:`device_skyline`
+(the whole-window form, variant ``wf-tpu``) and :func:`device_skyline_plq`
+(the pane stage alone, variant ``pf-tpu``: per-pane frontiers compacted on
+the device into the same fixed-width payload, merged by the host
+:class:`SkylineWLQ` -- Pane_Farm_GPU's device-PLQ + host-WLQ family,
+pane_farm_gpu.hpp:176-201).  The timed deployments of the two are the
+benchmark's ``spatial_wf`` and ``spatial_pf`` (benchmarks/configs/).
 """
 
 from __future__ import annotations
@@ -159,6 +167,49 @@ def device_skyline():
                              # fn's on-device compute precision
                              field_dtypes={"x": np.float32,
                                            "y": np.float32})
+
+
+def device_skyline_plq(cap: int = PANE_CAP):
+    """The pane stage of the skyline as a *device* window function whose
+    result is a container (Pane_Farm_GPU's device-PLQ constructor family,
+    pane_farm_gpu.hpp:176-201): the all-pairs dominance test of
+    :func:`device_skyline` over a pane's points, then the frontier's points
+    compacted on the device into the ``cap`` slots of the fixed-width
+    payload (:func:`pane_payload_fields`), in arrival order, with their
+    count -- row for row what :class:`SkylinePLQ` packs on the host, so
+    :class:`SkylineWLQ` merges either.
+
+    A device function cannot raise: ``sk_n`` is the frontier's TRUE
+    cardinality, and the harvest raises on the host where it passes
+    ``cap`` (``JaxWindowFunction(count_field=)``) -- the slots hold the
+    first ``cap`` points then, and no window is ever built from them."""
+    import jax.numpy as jnp
+
+    from ..patterns.win_seq_tpu import JaxWindowFunction
+
+    slots = jnp.arange(cap, dtype=jnp.int32)
+
+    def fn(keys, gwids, cols, mask):
+        x, y = cols["x"], cols["y"]                       # (B, pad)
+        le = ((x[:, None, :] <= x[:, :, None])
+              & (y[:, None, :] <= y[:, :, None]))
+        lt = ((x[:, None, :] < x[:, :, None])
+              | (y[:, None, :] < y[:, :, None]))
+        alive = mask & ~jnp.any(le & lt & mask[:, None, :], axis=2)
+        # the frontier's k-th point in arrival order goes to slot k: a
+        # (B, cap, pad) one-hot of each alive cell's rank, summed over the
+        # pane (one term a slot, so the sum is the coordinate itself)
+        rank = jnp.cumsum(alive, axis=1, dtype=jnp.int32) - 1
+        put = alive[:, None, :] & (rank[:, None, :] == slots[None, :, None])
+        sk_x = jnp.sum(jnp.where(put, x[:, None, :], 0), axis=2)
+        sk_y = jnp.sum(jnp.where(put, y[:, None, :], 0), axis=2)
+        return sk_x, sk_y, jnp.sum(alive, axis=1, dtype=jnp.int32)
+
+    return JaxWindowFunction(fn, fields=("x", "y"),
+                             result_fields=pane_payload_fields(cap),
+                             field_dtypes={"x": np.float32,
+                                           "y": np.float32},
+                             count_field="sk_n")
 
 
 # ---------------------------------------------------------------- k-means
@@ -359,6 +410,10 @@ class SpatialSink:
                 "n_latency_samples": s["n"]}
 
 
+#: the variants whose window function runs on the device
+DEVICE_VARIANTS = ("wf-tpu", "pf-tpu")
+
+
 def build_spatial(variant: str, duration_sec: float, pardegree: int,
                   win_ms: float, slide_ms: float, chunk: int,
                   rate: float = 80_000.0, batches=None,
@@ -366,7 +421,10 @@ def build_spatial(variant: str, duration_sec: float, pardegree: int,
     """Assemble one spatial composition.  `variant`: 'wf' (whole-window
     skyline through Win_Farm, test_spatial_wf.cpp), 'pf' (pane
     decomposition, test_spatial_pf.cpp), 'nested' (WF(PF)), 'wf-tpu'
-    (the device skyline through WinFarmTPU)."""
+    (the device skyline through WinFarmTPU), 'pf-tpu' (the pane
+    decomposition with its pane stage on the device: per-pane frontiers by
+    :func:`device_skyline_plq`, their merge by :class:`SkylineWLQ` on the
+    host -- Pane_Farm_GPU's device-PLQ family)."""
     from ..api import MultiPipe
     from ..patterns.basic import Sink, Source
 
@@ -396,14 +454,22 @@ def build_spatial(variant: str, duration_sec: float, pardegree: int,
                          pardegree=pardegree, batch_len=batch_len,
                          use_resident=True, name="sky_wf_tpu",
                          max_delay_ms=max_delay_ms)
+    elif variant == "pf-tpu":
+        from ..patterns.win_seq_tpu import PaneFarmTPU
+        agg = PaneFarmTPU(device_skyline_plq(), SkylineWLQ(), win_us,
+                          slide_us, WinType.TB, plq_degree=pardegree,
+                          wlq_degree=max(pardegree // 2, 1),
+                          plq_on_device=True, wlq_on_device=False,
+                          batch_len=1, use_resident=True, name="sky_pf_tpu",
+                          max_delay_ms=max_delay_ms)
     else:
         raise ValueError(f"unknown spatial variant {variant!r}")
-    if max_delay_ms is not None and variant != "wf-tpu":
+    if max_delay_ms is not None and variant not in DEVICE_VARIANTS:
         # same guard as ysb.py: the host variants have no force-flush
         # timer — silently printing their latencies as "budget-bounded"
         # would misreport what bounded them (nothing)
-        raise ValueError("--max-delay-ms applies to the wf-tpu variant "
-                         f"only (got {variant!r})")
+        raise ValueError("--max-delay-ms applies to the device variants "
+                         f"{DEVICE_VARIANTS} only (got {variant!r})")
 
     start_wall = int(_time.time() * 1e6)
     sink = SpatialSink(start_wall)
@@ -450,7 +516,7 @@ def run(variant="wf", duration_sec=8.0, pardegree=2, win_ms=50.0,
                                      slide_ms, chunk, rate,
                                      max_delay_ms=max_delay_ms)
         wp.run_and_wait_end()
-        if variant == "wf-tpu":
+        if variant in DEVICE_VARIANTS:
             resident.prewarm_regular_ladder()
     pipe, sink, n_gen = build_spatial(variant, duration_sec, pardegree,
                                       win_ms, slide_ms, chunk, rate,
@@ -470,7 +536,7 @@ def run(variant="wf", duration_sec=8.0, pardegree=2, win_ms=50.0,
            "gen_events_per_sec": round(
                n_gen[0] / max(duration_sec, 1e-9), 1),
            **sink.stats()}
-    if variant == "wf-tpu":
+    if variant in DEVICE_VARIANTS:
         out.update({k: diag[k] for k in ("dispatches", "merges",
                                          "mean_launch_ms")})
     return out
@@ -480,7 +546,8 @@ def main(argv=None):
     import argparse
     import json
     ap = argparse.ArgumentParser(description="spatial_test benchmark")
-    ap.add_argument("-v", "--variants", default="wf,pf,nested,wf-tpu")
+    ap.add_argument("-v", "--variants",
+                    default="wf,pf,nested,wf-tpu,pf-tpu")
     ap.add_argument("-l", "--length", type=float, default=8.0)
     ap.add_argument("-p", "--pardegree", type=int, default=2)
     ap.add_argument("--win-ms", type=float, default=50.0)
@@ -502,7 +569,7 @@ def main(argv=None):
     ap.add_argument("--rates", default="2500,5000,10000,20000,40000,80000",
                     help="ascending rate ladder for --budget-ms mode")
     ap.add_argument("--max-delay-ms", type=float, default=None,
-                    help="device-core force-flush bound (wf-tpu); "
+                    help="device-core force-flush bound (wf-tpu, pf-tpu); "
                          "defaults to budget/2 in --budget-ms mode")
     a = ap.parse_args(argv)
     from ..ops.backend import cli_start
@@ -516,7 +583,7 @@ def main(argv=None):
         rates = [float(r) for r in a.rates.split(",") if r.strip()]
         for v in variants:
             dly = a.max_delay_ms
-            if dly is None and v == "wf-tpu":
+            if dly is None and v in DEVICE_VARIANTS:
                 dly = a.budget_ms / 2
             best = None
             for r in rates:
@@ -524,12 +591,13 @@ def main(argv=None):
                 # default 2048-chunk takes 0.8 s to FILL — pure source
                 # batching delay that would dominate any budget
                 chunk = min(a.chunk, max(64, int(r * a.slide_ms / 1e3)))
-                # wf-tpu re-warms at every rung: window cardinality grows
-                # with rate (32x across the default ladder), and a cold
-                # device-shape compile inside the timed window would end
-                # the climb on compile latency, not saturation
+                # a device variant re-warms at every rung: window cardinality
+                # grows with rate (32x across the default ladder), and a
+                # cold device-shape compile inside the timed window would
+                # end the climb on compile latency, not saturation
                 out = run(v, a.length, a.pardegree, a.win_ms, a.slide_ms,
-                          chunk, r, warm=(best is None or v == "wf-tpu"),
+                          chunk, r,
+                          warm=(best is None or v in DEVICE_VARIANTS),
                           max_delay_ms=dly)
                 out["rate"] = r
                 out["within_budget"] = bool(

@@ -75,6 +75,21 @@ def _bucket_fine(n: int) -> int:
     return -(-n // step) * step
 
 
+def cast_result(vals: np.ndarray, dt) -> np.ndarray:
+    """A device output's rows in their result field's dtype.  A sub-array
+    field, ``(base, shape)`` -- a container-valued result, as a pane's
+    frontier in ``shape`` slots -- takes an output of ``(B, *shape)`` in its
+    base dtype: ``astype`` with the sub-array dtype itself would broadcast
+    every element to ``shape``."""
+    dt = np.dtype(dt)
+    if vals.shape[1:] != dt.shape:
+        raise ValueError(
+            f"a window function's output of shape {vals.shape} does not fit "
+            f"its result field of dtype {dt}: want (B,"
+            f"{''.join(f' {n},' for n in dt.shape)})")
+    return vals.astype(dt.base, copy=False)
+
+
 @functools.lru_cache(maxsize=None)
 def builtin_batch_fn(op: str, field: str = "value"):
     """Batched window function for a built-in reduction, in JAX.  Cached so
@@ -230,8 +245,8 @@ class DeviceWindowExecutor:
         cols = {}
         for f, v in zip(self.out_fields, host):
             dt = self.out_dtypes.get(f)
-            if dt is not None and v.dtype != dt:
-                v = v.astype(dt)
+            if dt is not None:
+                v = cast_result(v, dt)
             if empty is not None and f in self.empty_fill:
                 v = v.copy() if v.base is not None else v
                 v[empty] = self.empty_fill[f]

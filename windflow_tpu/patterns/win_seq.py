@@ -16,6 +16,12 @@ from ..utils import profile
 from .basic import _Pattern
 
 
+#: the span a stage of a Pane_Farm emits its results under (profiling on)
+_STAGE_EMIT = {Role.PLQ: "pane_emit", Role.WLQ: "window_emit"}
+#: result ids a stage-emit record lists (a batch of more names its first)
+_EMIT_IDS = 64
+
+
 class WinSeqNode(Node):
     """Runtime node driving a WinSeqCore."""
 
@@ -130,7 +136,18 @@ class WinSeqNode(Node):
             st.bump("non_triggering_batches")
 
     def _emit_results(self, out):
-        if getattr(self.core, "fire_on", "key") == "stream":
+        phase = (_STAGE_EMIT.get(getattr(self.core, "role", None))
+                 if profile.ENABLED else None)
+        if phase is not None:
+            # a two-stage window pattern's hand-over, one record a batch of
+            # results in ``launches.jsonl``: when a pane's partial left its
+            # worker, when the window built from it had been handed on
+            with profile.span(phase) as sp:
+                sp.extra = {"key": int(out["key"][0]),
+                            "ids": out["id"][:_EMIT_IDS].tolist(),
+                            "rows": len(out)}
+                self.emit(out)
+        elif getattr(self.core, "fire_on", "key") == "stream":
             self._emit_fires(out)
         else:
             self.emit(out)
@@ -204,6 +221,11 @@ class WinSeqNode(Node):
                 self._emit_each(fb())
                 return
         out = self.core.flush()
+        if self.stats is not None:
+            # a container-valued device result's slots (win_seq_tpu.
+            # _count_slots), the flush's results among them
+            self._core_counters(self.stats, (
+                "pane_results", "pane_points_kept", "pane_overflow"))
         if len(out):
             if self.stats is not None:
                 self.stats.bump("windows_fired", len(out))

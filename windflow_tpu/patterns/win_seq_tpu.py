@@ -30,7 +30,8 @@ import numpy as np
 from ..core.tuples import Schema
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..core.winseq import WinSeqCore
-from ..ops.device import DeviceWindowExecutor, builtin_batch_fn
+from ..ops.device import (DeviceWindowExecutor, builtin_batch_fn,
+                          cast_result)
 from ..ops.functions import ArgReducer, MultiReducer, Reducer
 from ..runtime.node import RuntimeContext
 from ..utils import profile
@@ -65,10 +66,18 @@ class JaxWindowFunction:
     ``fn(keys, gwids, cols, mask) -> column(s)`` over a whole window batch
     — the TPU replacement for the reference's CUDA device functor
     ``F(key, gwid, data, res, size, scratch)`` (win_seq_gpu.hpp:54-67,
-    deduced at meta_utils.hpp:173-180)."""
+    deduced at meta_utils.hpp:173-180).
+
+    A result may be a container (the reference's arbitrary ``result_t``):
+    a result field of a sub-array dtype ``(base, (cap,))`` takes an output
+    of ``(B, cap)``, ``cap`` slots a window, and ``count_field`` names the
+    integer result field that says how many of them hold something.  A
+    device function cannot raise, so that count is the container's TRUE
+    size: the harvest raises on the host where it passes ``cap`` (the slots
+    are then cut short), and no result of that launch leaves."""
 
     def __init__(self, fn, fields=("value",), result_fields=None,
-                 field_dtypes=None):
+                 field_dtypes=None, count_field=None):
         self.fn = fn
         self.fields = tuple(fields)
         self.result_fields = dict(result_fields or {"value": np.int64})
@@ -77,6 +86,56 @@ class JaxWindowFunction:
         #: at allocation, unlike the restaging path which stages whatever
         #: dtype each launch carries)
         self.field_dtypes = dict(field_dtypes or {})
+        self.count_field = count_field
+        #: slots of a container-valued result (the narrowest sub-array
+        #: result field's), None without a ``count_field``
+        self.slot_cap = None
+        if count_field is not None:
+            widths = [np.dtype(dt).shape[0]
+                      for dt in self.result_fields.values()
+                      if np.dtype(dt).shape]
+            if count_field not in self.result_fields or not widths:
+                raise ValueError(
+                    f"count_field={count_field!r} counts the slots of a "
+                    "container-valued result: it must be a result field, "
+                    "beside at least one of a sub-array dtype "
+                    f"(got {self.result_fields})")
+            self.slot_cap = int(min(widths))
+
+
+def _init_slot_counters(core):
+    """The counters :func:`_count_slots` keeps, on a core whose function's
+    result is a container; None on every other (a node's log then leaves
+    them out)."""
+    fn = core._jax_fn
+    counted = fn is not None and fn.count_field is not None
+    core.pane_results = core.pane_points_kept = core.pane_overflow = (
+        0 if counted else None)
+
+
+def _count_slots(core, payload):
+    """One harvested payload (``{result field: rows}``) of a container-valued
+    result (``JaxWindowFunction(count_field=)``) onto its core's counters
+    -- ``pane_results``, ``pane_points_kept`` (the slots that hold
+    something), ``pane_overflow`` (results over the cap) -- and the raise
+    where one passed its cap: the device cut its slots short, so none of
+    the launch's results is handed on."""
+    fn = core._jax_fn
+    n, cap = payload[fn.count_field], fn.slot_cap
+    over = int(np.count_nonzero(n > cap))
+    kept = int(np.minimum(n, cap).sum())
+    core.pane_results += len(n)
+    core.pane_points_kept += kept
+    core.pane_overflow += over
+    profile.add("pane_results", len(n))
+    profile.add("pane_points_kept", kept)
+    if over:
+        profile.add("pane_overflow", over)
+        raise ValueError(
+            f"a window function's result holds {int(n.max())} entries, over "
+            f"the cap of {cap} slots its result fields have ({over} of "
+            f"{len(n)} results of this launch): widen the sub-array result "
+            "fields (cap); a truncated container is never handed on")
 
 
 def _host_standin(winfunc):
@@ -171,6 +230,9 @@ class DeviceWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                          map_indexes=map_indexes,
                          result_ts_slide=result_ts_slide)
         self.executor = executor
+        self._jax_fn = winfunc if isinstance(winfunc, JaxWindowFunction) \
+            else None
+        _init_slot_counters(self)
         self.batch_len = batch_len
         # pending windows: list of (segment_cols, starts, lens) + headers
         self._segs = []        # [(cols{f: np}, starts, lens)]
@@ -236,6 +298,8 @@ class DeviceWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
             for key, ids, ts in hdr:
                 n = len(ids)
                 payload = {f: v[off:off + n] for f, v in cols.items()}
+                if self.pane_results is not None:
+                    _count_slots(self, payload)
                 outs.append(self._make_results(key, ids, ts, payload))
                 off += n
         return outs
@@ -544,6 +608,7 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         #: launches ``collect`` took, and their result rows
         self.result_wakes = 0
         self.result_wake_rows = 0
+        _init_slot_counters(self)
 
     # ------------------------------------------------------------ the waker
 
@@ -796,8 +861,10 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                         p, stat_arrs[i][off:off + n], lens)
                     i += 1
                 for name, dt in fn_fields:
-                    payload[name] = stat_arrs[i][off:off + n].astype(dt)
+                    payload[name] = cast_result(stat_arrs[i][off:off + n], dt)
                     i += 1
+                if self.pane_results is not None:
+                    _count_slots(self, payload)
                 for p in self._count_parts:
                     payload[p.out_field] = lens.astype(p.dtype)
                 for p in self._pos_max_parts:
@@ -1239,6 +1306,25 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
     return ResidentWinSeqCore(spec, winfunc, **kw)
 
 
+def _refuse_container_input(winfunc, in_fields):
+    """Raise where a device stage's function reads a sub-array field of the
+    stage before it (a pane's container-valued partial): a device ring
+    holds one scalar a row and field."""
+    if isinstance(winfunc, JaxWindowFunction):
+        read = winfunc.fields
+    elif isinstance(winfunc, (Reducer, MultiReducer, ArgReducer)):
+        read = winfunc.required_fields
+    else:
+        return      # a host function: `_host_standin` refuses it by name
+    wide = [f for f in read if np.dtype(in_fields.get(f, np.int64)).shape]
+    if wide:
+        raise ValueError(
+            f"a device WLQ cannot read the container-valued pane fields "
+            f"{wide} (sub-array dtypes): a device ring holds one scalar a "
+            "row and field; merge such panes on the host "
+            "(wlq_on_device=False, pane_farm_gpu.hpp:176-201)")
+
+
 class _DeviceCoreFactory:
     """Mixin for farm variants whose workers are device-batched: the host
     farm builds its prototype workers, `_make_core` swaps in the device
@@ -1339,17 +1425,30 @@ class PaneFarmTPU(PaneFarm):
     """Pane_Farm with per-stage device placement — the 4 constructor
     families of Pane_Farm_GPU (pane_farm_gpu.hpp:176-480) become two
     booleans; an incremental stage always runs on the host (the reference
-    likewise pairs INC stages with host execution)."""
+    likewise pairs INC stages with host execution).
+
+    A device PLQ may be a user's function whose result is a container
+    (``JaxWindowFunction(count_field=)``: a pane's partial in fixed-width
+    sub-array slots, the reference's arbitrary ``result_t``) under a host
+    WLQ that merges them (pane_farm_gpu.hpp:176-201, the device-PLQ
+    families), at every ``opt_level``.  A device WLQ over such panes is
+    refused here: a ring holds one scalar a row and field."""
 
     def __init__(self, plq_func, wlq_func, win_len, slide_len,
                  win_type=WinType.CB, plq_degree=1, wlq_degree=1,
                  name="pane_farm_tpu", plq_on_device=True, wlq_on_device=True,
                  batch_len=512, device=None, depth=None, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, **kw):
+                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
+                 **kw):
         self._on_device = {"plq": plq_on_device, "wlq": wlq_on_device}
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
                             compute_dtype=compute_dtype,
-                            use_resident=use_resident, flush_rows=flush_rows)
+                            use_resident=use_resident, flush_rows=flush_rows,
+                            max_delay_ms=max_delay_ms)
+        if wlq_on_device and not kw.get("wlq_incremental"):
+            _refuse_container_input(
+                wlq_func, kw.get("plq_result_fields")
+                or getattr(plq_func, "result_fields", None) or {})
         super().__init__(plq_func, wlq_func, win_len, slide_len, win_type,
                          plq_degree=plq_degree, wlq_degree=wlq_degree,
                          name=name, **kw)
@@ -1360,6 +1459,7 @@ class PaneFarmTPU(PaneFarm):
             return super()._make_stage(which, func, win, slide, wt, degree,
                                        name, incremental, result_fields,
                                        ordered, role)
+        _host_standin(func)     # a host function is refused here, by name
         cfg = self.config
         if degree > 1:
             return WinFarmTPU(func, win, slide, wt, pardegree=degree,
