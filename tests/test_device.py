@@ -17,7 +17,8 @@ from windflow_tpu.patterns.win_seq_tpu import (DeviceWinSeqCore,
                                                PaneFarmTPU, WinFarmTPU,
                                                WinMapReduceTPU, WinSeqTPU)
 
-from test_farms import cb_stream_batches, tb_stream_batches, run_windowed
+from test_farms import (assert_wlq_fired_complete, cb_stream_batches,
+                        dense_fire_counts, run_windowed, tb_stream_batches)
 from test_pane_wmr import iv
 
 
@@ -135,12 +136,47 @@ def test_key_farm_tpu(pardegree):
                                              (True, True)])
 def test_pane_farm_tpu_stage_placement(plq_dev, wlq_dev):
     keys, n = 3, 120
-    got = iv(run_windowed(
+    graph = []
+    got = run_windowed(
         PaneFarmTPU(Reducer("sum"), Reducer("sum"), 12, 4, WinType.CB,
                     plq_degree=2, wlq_degree=2, plq_on_device=plq_dev,
                     wlq_on_device=wlq_dev, batch_len=16),
-        cb_stream_batches(keys, n)))
-    assert got == iv(ref(12, 4, WinType.CB, cb_stream_batches(keys, n)))
+        cb_stream_batches(keys, n), graph)
+    assert iv(got) == iv(ref(12, 4, WinType.CB, cb_stream_batches(keys, n)))
+    if wlq_dev:
+        # a built-in sum on the device is the native core's: it is handed
+        # the property and keeps the reference's trigger (its docstring)
+        assert dense_fire_counts(graph[0]) == []
+    else:
+        assert_wlq_fired_complete(graph[0], got, cb_stream_batches(keys, n),
+                                  12, 4, WinType.CB, 2)
+
+
+@pytest.mark.filterwarnings("ignore:resident device path accumulates")
+@pytest.mark.parametrize("use_resident", [True, False],
+                         ids=["resident_py", "restage"])
+@pytest.mark.parametrize("wlq", [1, 2])
+def test_a_device_wlq_on_the_python_cores_fires_with_its_last_pane(
+        monkeypatch, wlq, use_resident):
+    """``ResidentWinSeqCore`` and ``DeviceWinSeqCore`` inherit the host
+    core's triggerer, the property with it: the window is enqueued for its
+    launch by its last pane id."""
+    from windflow_tpu.patterns.win_seq import window_cores
+    monkeypatch.setenv("WF_NO_NATIVE_CORE", "1")
+    keys, n = 3, 120
+    graph = []
+    got = run_windowed(
+        PaneFarmTPU(Reducer("sum"), Reducer("sum"), 12, 4, WinType.CB,
+                    plq_degree=1, wlq_degree=wlq, plq_on_device=False,
+                    wlq_on_device=True, batch_len=4, flush_rows=64,
+                    use_resident=use_resident),
+        cb_stream_batches(keys, n), graph)
+    assert iv(got) == iv(ref(12, 4, WinType.CB, cb_stream_batches(keys, n)))
+    want = "ResidentWinSeqCore" if use_resident else "DeviceWinSeqCore"
+    assert [type(c).__name__ for c in window_cores(graph[0])][1:] \
+        == [want] * wlq
+    assert_wlq_fired_complete(graph[0], got, cb_stream_batches(keys, n),
+                              12, 4, WinType.CB, wlq)
 
 
 @pytest.mark.parametrize("map_dev,red_dev", [(True, False), (False, True),

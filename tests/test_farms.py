@@ -48,8 +48,9 @@ def tb_stream_batches(keys, n, chunk=32, seed=0):
     return out
 
 
-def run_windowed(pattern, batches):
-    """Run Source -> pattern -> Sink; returns per-key ordered results."""
+def run_windowed(pattern, batches, graph=None, trace_dir=None):
+    """Run Source -> pattern -> Sink; returns per-key ordered results.
+    ``graph``: a list the built Dataflow is appended to."""
     per_key = {}
 
     def snk(row):
@@ -57,11 +58,51 @@ def run_windowed(pattern, batches):
             per_key.setdefault(int(row["key"]), []).append(
                 (int(row["id"]), int(row["ts"]), int(row["value"])))
 
-    df = Dataflow()
+    df = Dataflow(trace_dir=trace_dir)
     build_pipeline(df, [Source(batches=iter(batches), schema=SCHEMA),
                         pattern, Sink(snk)])
     df.run_and_wait_end()
+    if graph is not None:
+        graph.append(df)
     return per_key
+
+
+def dense_fire_counts(df) -> list:
+    """``windows_fired_complete`` of every window core of ``df`` that was
+    told its input is dense (a Pane_Farm's WLQ cores): the windows each
+    fired with their last pane and not with the next one."""
+    from windflow_tpu.patterns.win_seq import window_cores
+    counts = [getattr(c, "windows_fired_complete", None)
+              for c in window_cores(df)]
+    return [c for c in counts if c is not None]
+
+
+def complete_windows(results, batches, win, slide, wt) -> int:
+    """How many of a Pane_Farm's ``results`` (per key, ``(id, ...)``) are
+    windows whose every pane exists: a key's panes run to the one that
+    holds its last row, whoever emits that one (the PLQ's flush too)."""
+    import math
+    pane = math.gcd(win, slide)
+    rows = np.concatenate(batches)
+    pos = rows["id" if wt is WinType.CB else "ts"]
+    n = 0
+    for key, rs in results.items():
+        panes = int(pos[rows["key"] == key].max()) // pane + 1
+        n += sum(1 for r in rs if (r[0] * slide + win) // pane <= panes)
+    return n
+
+
+def assert_wlq_fired_complete(df, results, batches, win, slide, wt,
+                              wlq_degree=None):
+    """Every window of ``results`` that was complete at its WLQ was fired
+    by its last pane's arrival; the flush is left with the others."""
+    counts = dense_fire_counts(df)
+    if wlq_degree is not None:
+        assert len(counts) == wlq_degree
+    want = complete_windows(results, batches, win, slide, wt)
+    assert sum(counts) == want > 0
+    assert want >= sum(len(rs) for rs in results.values()) \
+        - len(results) * -(-win // slide)
 
 
 CASES = [(8, 3), (8, 8), (3, 8), (5, 1), (16, 7)]

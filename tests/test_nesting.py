@@ -11,8 +11,20 @@ from windflow_tpu.patterns.pane_farm import PaneFarm
 from windflow_tpu.patterns.win_mapreduce import WinMapReduce
 from windflow_tpu.patterns.win_seq import WinSeq
 
-from test_farms import cb_stream_batches, tb_stream_batches, run_windowed
+from test_farms import (cb_stream_batches, dense_fire_counts, run_windowed,
+                        tb_stream_batches)
 from test_pane_wmr import iv
+
+
+def assert_inner_wlqs_fired_complete(df, got, win, slide, n_cores):
+    """Every inner Pane_Farm's WLQ cores were told their input is dense,
+    and all but the windows the stream's end cut short (at most
+    ceil(win/slide) a key, whichever replica owns them) were fired by their
+    last pane."""
+    counts = dense_fire_counts(df)
+    assert len(counts) == n_cores
+    total = sum(len(rs) for rs in got.values())
+    assert total >= sum(counts) >= total - len(got) * -(-win // slide) > 0
 
 
 def ref_results(win, slide, wt, batches):
@@ -26,9 +38,11 @@ def test_wf_of_pf_cb(outer, plq, wlq):
     win, slide, keys, n = 16, 4, 3, 140
     inner = PaneFarm(Reducer("sum"), Reducer("sum"), win, slide, WinType.CB,
                      plq_degree=plq, wlq_degree=wlq)
+    graph = []
     got = iv(run_windowed(WinFarmOf(inner, pardegree=outer),
-                          cb_stream_batches(keys, n)))
+                          cb_stream_batches(keys, n), graph))
     assert got == ref_results(win, slide, WinType.CB, cb_stream_batches(keys, n))
+    assert_inner_wlqs_fired_complete(graph[0], got, win, slide, outer * wlq)
 
 
 def test_wf_of_pf_tb():
@@ -56,9 +70,11 @@ def test_kf_of_pf_cb(outer, plq, wlq):
     win, slide, keys, n = 12, 4, 5, 120
     inner = PaneFarm(Reducer("sum"), Reducer("sum"), win, slide, WinType.CB,
                      plq_degree=plq, wlq_degree=wlq)
+    graph = []
     got = iv(run_windowed(KeyFarmOf(inner, pardegree=outer),
-                          cb_stream_batches(keys, n)))
+                          cb_stream_batches(keys, n), graph))
     assert got == ref_results(win, slide, WinType.CB, cb_stream_batches(keys, n))
+    assert_inner_wlqs_fired_complete(graph[0], got, win, slide, outer * wlq)
 
 
 @pytest.mark.parametrize("outer", [2, 3])

@@ -18,7 +18,8 @@ from windflow_tpu.patterns.win_seq import WinSeq
 from windflow_tpu.runtime.engine import Dataflow
 from windflow_tpu.runtime.farm import add_farm, build_pipeline
 
-from test_farms import (SCHEMA, cb_stream_batches, run_windowed,
+from test_farms import (SCHEMA, assert_wlq_fired_complete,
+                        cb_stream_batches, dense_fire_counts, run_windowed,
                         tb_stream_batches)
 
 KEYS, N = 3, 140
@@ -54,8 +55,11 @@ def test_pane_farm_opt_matches_seq(wt, level, inc):
                       plq_degree=degs[0], wlq_degree=degs[1],
                       plq_incremental=inc, wlq_incremental=inc,
                       opt_level=level)
-        got = run_windowed(pf, stream(wt))
+        graph = []
+        got = run_windowed(pf, stream(wt), graph)
         assert totals(got) == ref, f"degs={degs}"
+        assert_wlq_fired_complete(graph[0], got, stream(wt), WIN, SLIDE, wt,
+                                  degs[1])
 
 
 @pytest.mark.parametrize("wt", [WinType.CB, WinType.TB], ids=["cb", "tb"])
@@ -218,8 +222,39 @@ def test_pane_farm_tpu_opt_matches_seq(wt, level):
         pf = PaneFarmTPU(Reducer("sum"), Reducer("sum"), WIN, SLIDE, wt,
                          plq_degree=degs[0], wlq_degree=degs[1],
                          batch_len=16, flush_rows=128, opt_level=level)
-        got = run_windowed(pf, stream(wt))
+        graph = []
+        got = run_windowed(pf, stream(wt), graph)
         assert totals(got) == ref, f"degs={degs}"
+        # a device WLQ of a built-in sum is the native core, which takes
+        # the property and keeps the reference's trigger: it counts nothing
+        assert dense_fire_counts(graph[0]) == []
+
+
+@pytest.mark.filterwarnings("ignore:resident device path accumulates")
+@pytest.mark.parametrize("level", [0, LEVEL1, LEVEL2])
+@pytest.mark.parametrize("wlq", [1, 3])
+@pytest.mark.parametrize("plq_dev", [False, True], ids=["plq-host", "plq-dev"])
+def test_a_pane_farms_window_stage_fires_a_window_with_its_last_pane(
+        plq_dev, wlq, level):
+    """Whatever sits between the two stages — an ordered collector and an
+    emitter (level 0), the two in one thread (1), ordering merges in front
+    of the WLQ workers (2) — the WLQ cores are told their input is dense and
+    fire every complete window on its last pane id."""
+    from windflow_tpu.patterns.win_seq_tpu import PaneFarmTPU
+    wt = WinType.CB
+    ref = run_windowed(WinSeq(Reducer("sum"), WIN, SLIDE, wt), stream(wt))
+    if plq_dev:
+        pf = PaneFarmTPU(Reducer("sum"), Reducer("sum"), WIN, SLIDE, wt,
+                         plq_degree=2, wlq_degree=wlq, wlq_on_device=False,
+                         batch_len=16, flush_rows=128, opt_level=level)
+    else:
+        pf = PaneFarm(Reducer("sum"), Reducer("sum"), WIN, SLIDE, wt,
+                      plq_degree=2, wlq_degree=wlq, opt_level=level)
+    graph = []
+    got = run_windowed(pf, stream(wt), graph)
+    assert {k: [(i, v) for i, _t, v in rs] for k, rs in got.items()} \
+        == {k: [(i, v) for i, _t, v in rs] for k, rs in ref.items()}
+    assert_wlq_fired_complete(graph[0], got, stream(wt), WIN, SLIDE, wt, wlq)
 
 
 @pytest.mark.filterwarnings("ignore:resident device path accumulates")

@@ -113,6 +113,16 @@ GEOMETRY = {"TB": (4000, 1000, WinType.TB, "ts"),
             "CB": (300, 100, WinType.CB, "id")}
 
 
+def _complete(ids, win, slide, panes=30):
+    """Of the windows ``ids`` those whose every pane exists, of ``panes``
+    panes of ``gcd(win, slide)`` (N rows at 10 a row: 30 of 1000 time
+    units, 30 of 100 ids): what the window stage fires with a window's
+    last pane; the flush brings the rest."""
+    pane = np.gcd(win, slide)
+    return int(np.count_nonzero(
+        (np.asarray(ids) * slide + win) // pane <= panes))
+
+
 @pytest.mark.parametrize("deg", [1, 2])
 @pytest.mark.parametrize("kind", sorted(GEOMETRY))
 def test_device_plq_and_host_wlq_equal_the_host_pane_farm_and_brute_force(
@@ -127,6 +137,11 @@ def test_device_plq_and_host_wlq_equal_the_host_pane_farm_and_brute_force(
     assert [type(c).__name__ for c in cores] \
         == ["ResidentWinSeqCore"] * deg + ["WinSeqCore"]
     assert all(c.executor.dispatches > 0 for c in cores[:deg])
+    # the pane workers are over the user's stream and told nothing; the
+    # window worker is told its input is dense and fires on the last pane
+    assert [c.windows_fired_complete for c in cores] == [None] * deg \
+        + [_complete(dev["id"], win, slide)]
+    assert 0 < cores[-1].windows_fired_complete < len(dev)
     rows = np.concatenate(batches)
     whole = oracle.skyline_windows(rows, win, slide, pos)
     assert oracle.skyline_windows(rows, win, slide, pos, by_panes=True) \
@@ -143,8 +158,11 @@ def test_every_opt_level_runs_a_device_plq(opt_level, deg):
     fused, pipe = _run(_device(4000, 1000, WinType.TB, deg,
                                opt_level=opt_level), batches)
     assert np.array_equal(plain, fused)
-    assert [type(c).__name__ for c in window_cores(pipe._df)] \
+    cores = window_cores(pipe._df)
+    assert [type(c).__name__ for c in cores] \
         == ["ResidentWinSeqCore"] * deg + ["WinSeqCore"]
+    assert cores[-1].windows_fired_complete \
+        == _complete(fused["id"], 4000, 1000) == len(fused) - 3
 
 
 @pytest.mark.parametrize("deg", [1, 2])
@@ -404,6 +422,14 @@ def test_the_pane_counters_and_the_two_stage_emit_spans(tmp_path):
     assert all(n["pane_overflow"] == 0 for n in plq)
     assert not any("pane_results" in n for name, n in logs.items()
                    if "_plq" not in name)
+    # the window stage alone says how many windows it fired with their
+    # last pane: all but the three the stream's end cut short
+    wlq = {name: n for name, n in logs.items()
+           if "windows_fired_complete" in n}
+    assert len(wlq) == 1 and "_wlq" in next(iter(wlq))
+    wlq = next(iter(wlq.values()))
+    assert (wlq["windows_fired_complete"], wlq["windows_fired"]) \
+        == (len(dev) - 3, len(dev))
     # one record a batch of results: every pane id once from the pane
     # stage, every window id once from the window stage, a window after the
     # pane that closed it

@@ -13,7 +13,8 @@ from windflow_tpu.patterns.pane_farm import PaneFarm
 from windflow_tpu.patterns.win_mapreduce import WinMapReduce
 from windflow_tpu.patterns.win_seq import WinSeq
 
-from test_farms import cb_stream_batches, tb_stream_batches, run_windowed
+from test_farms import (assert_wlq_fired_complete, cb_stream_batches,
+                        dense_fire_counts, run_windowed, tb_stream_batches)
 
 
 def iv(per_key):
@@ -32,12 +33,15 @@ def test_pane_farm_cb(win, slide, plq, wlq, inc):
     ref = run_windowed(
         WinSeq(Reducer("sum"), win, slide, WinType.CB, incremental=inc),
         cb_stream_batches(keys, n))
+    graph = []
     got = run_windowed(
         PaneFarm(Reducer("sum"), Reducer("sum"), win, slide, WinType.CB,
                  plq_degree=plq, wlq_degree=wlq, plq_incremental=inc,
                  wlq_incremental=inc),
-        cb_stream_batches(keys, n))
+        cb_stream_batches(keys, n), graph)
     assert iv(got) == iv(ref)
+    assert_wlq_fired_complete(graph[0], got, cb_stream_batches(keys, n),
+                              win, slide, WinType.CB, wlq)
 
 
 @pytest.mark.parametrize("win,slide", CASES_TB)
@@ -46,11 +50,52 @@ def test_pane_farm_tb(win, slide, plq, wlq):
     keys, n = 2, 150
     ref = run_windowed(WinSeq(Reducer("sum"), win, slide, WinType.TB),
                        tb_stream_batches(keys, n))
+    graph = []
     got = run_windowed(
         PaneFarm(Reducer("sum"), Reducer("sum"), win, slide, WinType.TB,
                  plq_degree=plq, wlq_degree=wlq),
-        tb_stream_batches(keys, n))
+        tb_stream_batches(keys, n), graph)
     assert iv(got) == iv(ref)
+    assert_wlq_fired_complete(graph[0], got, tb_stream_batches(keys, n),
+                              win, slide, WinType.TB, wlq)
+
+
+@pytest.mark.parametrize("wlq,level", [(1, 0), (2, 0), (1, 1), (2, 2)])
+def test_a_window_reaches_the_sink_with_its_last_pane_and_no_later_row(
+        wlq, level):
+    """Windows of 12 ids every 4: panes of 4, three a window.  The PLQ runs
+    over the user's stream and keeps the reference's rule, so window 0's
+    last pane, ids [8, 12), is closed by id 12.  The source sends ids 0..12
+    and then NOTHING until the sink holds window 0.  Under the reference's
+    rule in the WLQ too, window 0 waits for pane 3's result, that is for
+    id 16, which this source never sends: it would see the end of the
+    stream first."""
+    import threading
+    from windflow_tpu.api import MultiPipe
+    from windflow_tpu.core.tuples import Schema, batch_from_columns
+    from windflow_tpu.patterns.basic import Sink, Source
+    schema = Schema(value=np.int64)
+    at_sink, waited, got = threading.Event(), [], []
+
+    def src(shipper):
+        ids = np.arange(13)
+        shipper.push_batch(batch_from_columns(
+            schema, key=np.zeros(13), id=ids, ts=ids * 7, value=ids))
+        waited.append(at_sink.wait(timeout=60))    # a bound, not a pace
+
+    def snk(rows):
+        if rows is not None and len(rows):
+            got.extend(rows["id"].tolist())
+            if 0 in rows["id"]:
+                at_sink.set()
+
+    (MultiPipe("pf_live")
+     .add_source(Source(src, schema, fresh=True))
+     .add(PaneFarm(Reducer("sum"), Reducer("sum"), 12, 4, WinType.CB,
+                   plq_degree=1, wlq_degree=wlq, opt_level=level))
+     .chain_sink(Sink(snk, vectorized=True))).run_and_wait_end(timeout=120)
+    assert waited == [True]
+    assert got == [0, 1, 2, 3]      # the flush brought the cut-short ones
 
 
 def test_pane_farm_rejects_non_sliding():
@@ -66,12 +111,15 @@ def test_win_mapreduce_cb(win, slide, map_d, red_d, inc):
     ref = run_windowed(
         WinSeq(Reducer("sum"), win, slide, WinType.CB, incremental=inc),
         cb_stream_batches(keys, n))
+    graph = []
     got = run_windowed(
         WinMapReduce(Reducer("sum"), Reducer("sum"), win, slide, WinType.CB,
                      map_degree=map_d, reduce_degree=red_d,
                      map_incremental=inc, reduce_incremental=inc),
-        cb_stream_batches(keys, n))
+        cb_stream_batches(keys, n), graph)
     assert iv(got) == iv(ref)
+    # a REDUCE stage is told nothing of its input: the reference's rule
+    assert dense_fire_counts(graph[0]) == []
 
 
 @pytest.mark.parametrize("win,slide", CASES_TB + [(10, 25)])

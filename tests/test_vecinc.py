@@ -49,10 +49,13 @@ def make_stream(rng, n_keys, n_chunks, rows_per_chunk, *, ooo_frac=0.0,
     return chunks
 
 
+def run_calls(core, chunks):
+    """The result batch of every ``process`` call, then the flush's."""
+    return [core.process(c) for c in chunks] + [core.flush()]
+
+
 def run_core(core, chunks):
-    outs = [core.process(c) for c in chunks]
-    outs.append(core.flush())
-    outs = [o for o in outs if len(o)]
+    outs = [o for o in run_calls(core, chunks) if len(o)]
     return (np.concatenate(outs) if outs
             else np.zeros(0, dtype=core.result_schema.dtype()))
 
@@ -441,3 +444,82 @@ def test_sliding_crossover_is_derived_not_encoded():
         got = np.sort(got, order=["key", "id"])
         want = np.sort(want, order=["key", "id"])
         np.testing.assert_array_equal(got, want, err_msg=f"nk={nk}")
+
+
+# ------------------------------------------------------------------------
+# dense_positions on the vectorised cores: the closing position moves one
+# ahead (a Pane_Farm's WLQ that is a monoid Reducer lands here), call for
+# call what WinSeqCore does with the property on, row for row what either
+# does with it off.
+
+def _vec_for(spec, red, **kw):
+    from windflow_tpu.core.vecinc import VecIncSlidingCore
+    cls = VecIncTumblingCore if spec.is_tumbling else VecIncSlidingCore
+    return cls(spec, red, **kw)
+
+
+DENSE_ROLES = {
+    "seq": (Role.SEQ, None),
+    # worker 1 of a WLQ farm of 2: rows below its first window are dropped
+    "wlq_worker": (Role.WLQ, (0, 1, 0, 1, 2, 0)),
+}
+
+
+@pytest.mark.parametrize("shape", [(5, 40, 7), (3, 6, 100), (2, 150, 1)],
+                         ids=["chunks_of_7", "chunks_of_100", "single_rows"])
+@pytest.mark.parametrize("who,win,slide", [
+    ("seq", 4, 4), ("seq", 8, 4), ("seq", 20, 1), ("seq", 7, 3),
+    ("wlq_worker", 4, 4), ("wlq_worker", 8, 4), ("wlq_worker", 20, 2),
+    ("wlq_worker", 12, 6)])
+def test_vec_dense_positions_fire_with_the_last_row(who, win, slide, shape):
+    role, cfg_t = DENSE_ROLES[who]
+    spec = WindowSpec(win, slide, WinType.CB)
+    cfg = None
+    if cfg_t is not None:
+        # the farm's slide is half the worker's private one
+        cfg = PatternConfig(*(cfg_t[:2] + (slide // 2,) + cfg_t[3:5]
+                              + (slide // 2,)))
+    chunks = make_stream(np.random.default_rng(win * 31 + slide), *shape)
+    red = Reducer("sum")
+    kw = dict(config=cfg, role=role)
+    vec_on = _vec_for(spec, red, dense_positions=True, **kw)
+    ref_on = WinSeqCore(spec, red, dense_positions=True,
+                        **kw).use_incremental()
+    vec_off = _vec_for(spec, red, **kw)
+    on_calls, ref_calls = run_calls(vec_on, chunks), run_calls(ref_on, chunks)
+    off_calls = run_calls(vec_off, chunks)
+    for got, want in zip(on_calls, ref_calls):       # call for call
+        assert_equivalent(got, want)
+    assert_equivalent(np.concatenate(on_calls), np.concatenate(off_calls))
+    assert vec_on.windows_fired_complete \
+        == ref_on.windows_fired_complete \
+        == sum(len(o) for o in on_calls[:-1]) > 0
+    assert vec_off.windows_fired_complete is None
+    # the reference's rule leaves a window that ends with a key's last id
+    # to the flush; fired with that id it is in no flush
+    assert len(on_calls[-1]) <= len(off_calls[-1])
+
+
+def test_lazy_sliding_core_carries_dense_positions_across_its_escalation():
+    from windflow_tpu.core.vecinc import LazySlidingCore, VecIncSlidingCore
+    spec = WindowSpec(8, 4, WinType.CB)
+    few = make_stream(np.random.default_rng(3), 2, 4, 20,
+                      markers_at_end=False)
+    many = make_stream(np.random.default_rng(4), 40, 6, 400)
+    seen = np.concatenate(few)["key"]
+    for c in many:                  # the two early keys carry on, densely
+        for k in (0, 1):
+            c["id"][c["key"] == k] += np.count_nonzero(seen == k)
+    chunks = few + many
+    idle = LazySlidingCore(spec, Reducer("sum"), dense_positions=True)
+    assert idle.windows_fired_complete == 0
+    assert LazySlidingCore(spec, Reducer("sum")).windows_fired_complete \
+        is None
+    lazy = LazySlidingCore(spec, Reducer("sum"), threshold=16,
+                           dense_positions=True)
+    ref = WinSeqCore(spec, Reducer("sum"),
+                     dense_positions=True).use_incremental()
+    for got, want in zip(run_calls(lazy, chunks), run_calls(ref, chunks)):
+        assert_equivalent(got, want)
+    assert isinstance(lazy._core, VecIncSlidingCore)
+    assert lazy.windows_fired_complete == ref.windows_fired_complete > 0

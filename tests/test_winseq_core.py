@@ -18,10 +18,10 @@ from oracle import OracleWinSeq
 SCHEMA = Schema(value=np.int64)
 
 
-def run_core(core, stream, chunk):
-    """Feed `stream` (list of (key,id,ts,value[,marker])) in chunks; return
-    per-key result lists."""
-    results = []
+def feed(core, stream, chunk):
+    """Feed `stream` (list of (key,id,ts,value[,marker])) in chunks: the
+    result batch of every ``process`` call, then the flush's."""
+    outs = []
     for i in range(0, len(stream), chunk):
         part = stream[i:i + chunk]
         b = batch_from_columns(
@@ -29,9 +29,14 @@ def run_core(core, stream, chunk):
             key=[r[0] for r in part], id=[r[1] for r in part],
             ts=[r[2] for r in part], value=[r[3] for r in part])
         b["marker"] = [len(r) > 4 and r[4] for r in part]
-        results.append(core.process(b))
-    results.append(core.flush())
-    out = np.concatenate(results)
+        outs.append(core.process(b))
+    return outs, core.flush()
+
+
+def run_core(core, stream, chunk):
+    """Feed `stream` in chunks (``feed``); return per-key result lists."""
+    outs, flushed = feed(core, stream, chunk)
+    out = np.concatenate(outs + [flushed])
     per_key = {}
     for r in out:
         per_key.setdefault(int(r["key"]), []).append(
@@ -257,3 +262,154 @@ def test_sum_invariant_totals():
         for rs in per_key.values():
             assert [r[0] for r in rs] == list(range(len(rs)))
     assert len(totals) == 1
+
+
+# ------------------------------------------------------------------------
+# dense_positions: a stage the library wired over its own pane stream (a
+# Pane_Farm's WLQ) fires a window with the row at its LAST position, not
+# with the first one behind it.  Same rows; only the call that returns them
+# moves.
+
+def by_key(batches):
+    """Rows of ``batches`` in arrival order, grouped by key (stable): which
+    key's window leaves first inside one chunk is not part of the result."""
+    out = np.concatenate(batches)
+    return out[np.argsort(out["key"], kind="stable")]
+
+
+def dense_pair(spec, nic, **kw):
+    cores = [WinSeqCore(spec, Reducer("sum"), dense_positions=d, **kw)
+             for d in (True, False)]
+    if not nic:
+        for c in cores:
+            c.use_incremental()
+    return cores
+
+
+DENSE_GEOMETRY = [(20, 1), (6, 3), (4, 4)]
+
+
+@pytest.mark.parametrize("keys", [1, 3])
+@pytest.mark.parametrize("win,slide", DENSE_GEOMETRY)
+@pytest.mark.parametrize("nic", [True, False], ids=["nic", "inc"])
+@pytest.mark.parametrize("chunk", [1, 7, 1000])
+def test_dense_positions_fire_a_window_with_its_last_row(chunk, nic, win,
+                                                         slide, keys):
+    n = 60
+    stream = make_cb_stream(keys, n)
+    on, off = dense_pair(WindowSpec(win, slide, WinType.CB), nic)
+    on_outs, on_flush = feed(on, stream, chunk)
+    off_outs, off_flush = feed(off, stream, chunk)
+    # the same rows, in the same order a key
+    assert np.array_equal(by_key(on_outs + [on_flush]),
+                          by_key(off_outs + [off_flush]))
+    # the flush is left with the windows the stream's end cut short only
+    assert all(g * slide + win > n for g in on_flush["id"])
+    fired = sum(len(o) for o in on_outs)
+    assert fired == sum((n - win) // slide + 1 for _k in range(keys)) > 0
+    assert on.windows_fired_complete == fired
+    assert off.windows_fired_complete is None
+    if chunk == 1:
+        # window w leaves with the call that carries id end - 1; under
+        # the reference's rule with the call that carries id end
+        for (key, i, _ts, _v), got, ref in zip(stream, on_outs, off_outs):
+            last = [g for g in range(n) if g * slide + win - 1 == i]
+            nxt = [g for g in range(n) if g * slide + win <= i
+                   and g * slide + win > i - 1]
+            assert got["id"].tolist() == last and set(got["key"]) <= {key}
+            assert ref["id"].tolist() == nxt
+
+
+def worker_stream(stream, cfg, spec):
+    """What a Win_Farm emitter hands the worker of ``cfg``: every id of each
+    of its windows, and at the end a marker copy of each key's last row."""
+    kept = []
+    for r in stream:
+        rel = r[1] - cfg.initial_id(r[0], Role.WLQ)
+        if rel >= 0 and bool(spec.in_any_window(rel)):
+            kept.append(r)
+    last = {}
+    for r in stream:
+        last[r[0]] = r
+    return kept + [r[:4] + (True,) for _k, r in sorted(last.items())]
+
+
+@pytest.mark.parametrize("win,slide,n_workers", [
+    (20, 1, 3),     # private slide 3: sliding
+    (6, 3, 2),      # private slide 6: tumbling
+    (6, 3, 3),      # private slide 9: hopping, ids between its windows
+])
+@pytest.mark.parametrize("nic", [True, False], ids=["nic", "inc"])
+@pytest.mark.parametrize("chunk", [1, 7, 1000])
+def test_dense_positions_on_a_wlq_farm_worker_with_eos_markers(
+        chunk, nic, win, slide, n_workers):
+    stream = make_cb_stream(3, 60, seed=5)
+    spec = WindowSpec(win, slide * n_workers, WinType.CB)
+    early = 0
+    for i in range(n_workers):
+        cfg = PatternConfig(0, 1, slide, i, n_workers, slide)
+        on, off = dense_pair(spec, nic, config=cfg, role=Role.WLQ)
+        mine = worker_stream(stream, cfg, spec)
+        on_outs, on_flush = feed(on, mine, chunk)
+        off_outs, off_flush = feed(off, mine, chunk)
+        # ids, ts (a marker overwrites the ts of a window it falls below,
+        # window.hpp:149-154) and values: equal
+        assert np.array_equal(by_key(on_outs + [on_flush]),
+                              by_key(off_outs + [off_flush]))
+        assert len(on_flush) <= len(off_flush)
+        early += len(off_flush) - len(on_flush)
+        assert on.windows_fired_complete == sum(len(o) for o in on_outs)
+    # some worker's window ends with the stream's last id: the reference's
+    # rule left it to the flush, under the marker's ts
+    assert early > 0
+
+
+@pytest.mark.parametrize("chunk", [1, 1000])
+def test_a_window_fired_with_its_last_row_has_the_ts_the_marker_gave_it(
+        chunk):
+    # ids 0..11, windows of 6 every 3: window 2 is [6, 12), its last id the
+    # stream's last.  The reference's rule never fires it: the marker (a
+    # copy of row 11) is not past its end, so the flush emits it with the
+    # marker's ts.  Fired with row 11 it carries row 11's own: the same.
+    stream = [(0, i, 100 + 7 * i, i) for i in range(12)]
+    stream.append(stream[-1] + (True,))
+    on, off = dense_pair(WindowSpec(6, 3, WinType.CB), True)
+    on_outs, on_flush = feed(on, stream, chunk)
+    off_outs, off_flush = feed(off, stream, chunk)
+    assert np.array_equal(np.concatenate(on_outs + [on_flush]),
+                          np.concatenate(off_outs + [off_flush]))
+    got = np.concatenate(on_outs)
+    assert got["id"].tolist() == [0, 1, 2]
+    assert got["ts"].tolist() == [100 + 7 * 5, 100 + 7 * 8, 100 + 7 * 11]
+    assert 2 in off_flush["id"] and 2 not in on_flush["id"]
+
+
+def test_a_wlq_core_over_a_users_stream_keeps_the_next_id_trigger():
+    """Why the property is handed down by the pattern and never read off
+    ``Role.WLQ``: a stream that is not the library's own may repeat an id,
+    and the row that repeats it belongs to the window the first one ends."""
+    stream = [(0, 0, 0, 1), (0, 1, 10, 2), (0, 2, 20, 4), (0, 3, 30, 8),
+              (0, 3, 31, 16), (0, 4, 40, 32), (0, 5, 50, 64)]
+    spec = WindowSpec(4, 4, WinType.CB)
+    cfg = (0, 1, 4, 0, 1, 4)
+    core = WinSeqCore(spec, Reducer("sum"), config=PatternConfig(*cfg),
+                      role=Role.WLQ)
+    assert core.dense_positions is False
+    oracle = OracleWinSeq(4, 4, "CB", nic_sum, True, config=cfg, role="WLQ")
+    got = run_core(core, stream, 1)
+    assert got == run_oracle(oracle, stream)
+    assert got[0][0] == (0, 31, 1 + 2 + 4 + 8 + 16)
+    # told what is not true of this stream, the core closes window 0 on
+    # the first id 3 and the second one's value is in no window
+    wrong = WinSeqCore(spec, Reducer("sum"), config=PatternConfig(*cfg),
+                       role=Role.WLQ, dense_positions=True)
+    assert run_core(wrong, stream, 1)[0][0] == (0, 30, 1 + 2 + 4 + 8)
+
+
+def test_dense_positions_are_a_count_based_keyed_stages():
+    with pytest.raises(ValueError, match="dense_positions"):
+        WinSeqCore(WindowSpec(10, 5, WinType.TB), Reducer("sum"),
+                   dense_positions=True)
+    with pytest.raises(ValueError, match="dense_positions"):
+        WinSeqCore(WindowSpec(10, 5, WinType.TB), Reducer("sum"),
+                   fire_on="stream", dense_positions=True)

@@ -39,7 +39,8 @@ import numpy as np
 from .slots import segments as _segments
 from .tuples import MARKER_FIELD, Schema, progress_row
 from .windows import (PatternConfig, Role, WindowSpec, WinType,
-                      check_stream_fire, run_stream_clock, start_stream_clock)
+                      check_dense_positions, check_stream_fire,
+                      run_stream_clock, start_stream_clock)
 from .. import native
 from ..ops.functions import MultiReducer, Reducer
 from ..ops.monoid import NP_UFUNCS, identity as monoid_identity
@@ -83,13 +84,19 @@ def make_vec_core(spec: WindowSpec, winfunc, fire_on: str = "key", **kw):
 
 
 class VecIncTumblingCore:
-    """Drop-in for WinSeqCore (process/flush/use_incremental contract)."""
+    """Drop-in for WinSeqCore (process/flush/use_incremental contract),
+    ``dense_positions`` included: a window whose every position arrives
+    once and in order fires with the row at its last one."""
 
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
-                 result_ts_slide: int = None):
+                 result_ts_slide: int = None, dense_positions: bool = False):
         assert vec_core_supported(spec, winfunc)
+        check_dense_positions(dense_positions, spec)
         self.spec = spec
+        #: as ``WinSeqCore``'s: the closing position moves one ahead
+        self._ahead = 1 if dense_positions else 0
+        self.windows_fired_complete = 0 if dense_positions else None
         self.winfunc = winfunc
         self.config = config or PatternConfig.plain(spec.slide_len)
         self.role = role
@@ -296,10 +303,17 @@ class VecIncTumblingCore:
                         for (of, _f, _u, dt, _i) in self._parts}
         # --- firing: windows [n_fired, w_max) fire; w_max stays pending ---
         u = s[starts]                       # unique slots, ascending
-        w_max = w[ends - 1]                 # fired_before(max_rel), tumbling
         fired_lo = self._nfired[u]
+        if self._ahead:
+            # fired_through(max_rel): the pending window is open only if a
+            # row lies in it
+            w_max = (rel[ends - 1] + 1) // self._L
+            self._seen[u] = w_max == w[ends - 1]
+            self._count_complete(w_max, fired_lo, w[starts])
+        else:
+            w_max = w[ends - 1]             # fired_before(max_rel), tumbling
+            self._seen[u] = True
         m = w_max - fired_lo                # >= 0: kept rows are in-order
-        self._seen[u] = True
         total = int(m.sum())
         offs = np.concatenate(([0], np.cumsum(m)))
         out_slot = np.repeat(u, m)
@@ -351,6 +365,13 @@ class VecIncTumblingCore:
         if total == 0:
             return np.zeros(0, dtype=self._result_dtype)
         return self._make_results(out_slot, out_lwid, out_ts, out_vals)
+
+    def _count_complete(self, fired_hi, fired_lo, first_done):
+        """``windows_fired_complete``: of each slot's windows ``[fired_lo,
+        fired_hi)`` those from ``first_done`` on, the first whose last
+        position lies at or behind the chunk's first row of that slot."""
+        self.windows_fired_complete += int(np.maximum(
+            fired_hi - np.maximum(fired_lo, first_done), 0).sum())
 
     # ------------------------------------------------------------------- emit
 
@@ -502,11 +523,12 @@ class VecIncSlidingCore(VecIncTumblingCore):
 
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
-                 result_ts_slide: int = None):
+                 result_ts_slide: int = None, dense_positions: bool = False):
         assert spec.slide_len < spec.win_len, "sliding only (see tumbling)"
         super().__init__(spec, winfunc, config=config, role=role,
                          map_indexes=map_indexes,
-                         result_ts_slide=result_ts_slide)
+                         result_ts_slide=result_ts_slide,
+                         dense_positions=dense_positions)
         self._W = -(-self._L // self._S)
         # reshape the pending state to (cap, W) lanes + created-window count
         self._ncreated = np.zeros(self._cap, dtype=np.int64)
@@ -579,10 +601,14 @@ class VecIncSlidingCore(VecIncTumblingCore):
         # --- firing: windows [n_fired, new_fired) fire, in window order ---
         u = s[starts]                        # unique slots, ascending
         max_rel = rel[ends - 1]              # kept rows are in-order per key
-        new_fired = np.maximum(self._nfired[u],
-                               np.maximum((max_rel - L) // S + 1, 0))
+        new_fired = np.maximum(
+            self._nfired[u],
+            np.maximum((max_rel + self._ahead - L) // S + 1, 0))
         self._ncreated[u] = np.maximum(self._ncreated[u], max_rel // S + 1)
         fired_lo = self._nfired[u]
+        if self._ahead:
+            self._count_complete(new_fired, fired_lo,
+                                 (rel[starts] - L + S) // S)
         m = new_fired - fired_lo
         self._seen[u] = True
         total = int(m.sum())
@@ -1216,8 +1242,16 @@ class LazySlidingCore:
                             vec._acc[of][slot, lane] = ufunc.reduce(
                                 seg[field].astype(dt, copy=False))
                     vec._acc_ts[slot, lane] = int(seg["ts"][-1])
+        vec.windows_fired_complete = old.windows_fired_complete
         self._core = vec
         self._perkey = False
+
+    @property
+    def windows_fired_complete(self):
+        """The backing core's count (None where the input is not dense)."""
+        if self._core is not None:
+            return self._core.windows_fired_complete
+        return 0 if self._kw.get("dense_positions") else None
 
     def process(self, batch):
         core = self._core

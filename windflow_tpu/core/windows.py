@@ -182,6 +182,16 @@ class WindowSpec:
             np.int64(0),
         )
 
+    def fired_through(self, pos):
+        """Number of windows COMPLETE once position `pos` has been seen, on
+        a stream in which every position of a window arrives exactly once
+        and in order (``WinSeqCore(dense_positions=True)``): window w's last
+        position is w*slide + win - 1, and nothing behind it can add to w.
+        One position ahead of :meth:`fired_before`, the reference's rule,
+        which has to wait for the first pos >= w*slide + win because a
+        user's stream may repeat a position."""
+        return self.fired_before(np.asarray(pos, dtype=np.int64) + 1)
+
     def win_start(self, lwid):
         return np.asarray(lwid, dtype=np.int64) * self.slide_len
 
@@ -221,6 +231,20 @@ def check_fire_on(fire_on: str, spec: WindowSpec, config: PatternConfig,
     elif holdback:
         raise ValueError("holdback= belongs to fire_on='stream': a key's "
                          "own next row closes its window otherwise")
+
+
+def check_dense_positions(dense_positions: bool, spec: WindowSpec,
+                          fire_on: str = "key"):
+    """``dense_positions`` states that every position of each of a stage's
+    windows reaches it exactly once and in order.  Only a count-based stage
+    that fires on its key's rows can be told so: a timestamp may repeat or
+    be skipped, and a stream-time stage closes on its clock."""
+    if dense_positions and (spec.win_type is not WinType.CB
+                            or fire_on != "key"):
+        raise ValueError(
+            "dense_positions describes a count-based window stage that "
+            f"fires on its key's rows, not {spec.win_type} / "
+            f"fire_on={fire_on!r}")
 
 
 def start_stream_clock(core, ts0: int):

@@ -21,6 +21,16 @@ Here the same semantics are derived in closed form over *chunks*:
 * at EOS every still-open window is flushed over the archive tail
   (win_seq.hpp:433-474).
 
+One departure from ``window.hpp``'s triggerer, and only where the library
+itself wired the stage (``dense_positions=True``: a Pane_Farm's window stage
+over its own pane stream, patterns/pane_farm.py): there every position of a
+window arrives exactly once and in order, so window ``w`` is fired by the
+row at its LAST position, ``fired_through(max_pos)``, not by the first row
+behind it.  The archive range, the result ts and every result are those the
+reference's rule gives one position later; a window the stream's end leaves
+incomplete still waits for the flush.  A stage over a user's stream keeps
+the reference's rule: a later row may carry the same id.
+
 All per-chunk work is numpy array arithmetic; the per-window evaluation
 either loops (arbitrary host functions) or batches (monoid reducers / JAX
 functions via ``apply_batch``) — the batched form is exactly what the TPU
@@ -33,7 +43,7 @@ import numpy as np
 
 from .tuples import MARKER_FIELD, Schema, progress_row
 from .windows import (PatternConfig, Role, WindowSpec, WinType,
-                      check_fire_on, run_stream_clock)
+                      check_dense_positions, check_fire_on, run_stream_clock)
 from ..ops.functions import WindowFunction, WindowUpdate
 from ..utils import profile
 
@@ -69,9 +79,19 @@ class WinSeqCore:
     def __init__(self, spec: WindowSpec, winfunc, config: PatternConfig = None,
                  role: Role = Role.SEQ, map_indexes=(0, 1),
                  result_ts_slide: int = None, fire_on: str = "key",
-                 holdback: int = 0):
+                 holdback: int = 0, dense_positions: bool = False):
         self.spec = spec
         check_fire_on(fire_on, spec, config, role, holdback)
+        check_dense_positions(dense_positions, spec, fire_on)
+        #: every position of each of this core's windows arrives exactly
+        #: once and in order (the pattern that wired the stage says so, never
+        #: a user): a window fires with the row at its last position
+        #: (``WindowSpec.fired_through``) instead of the first one behind it
+        self.dense_positions = bool(dense_positions)
+        #: windows fired by the call that carried their last position, as
+        #: opposed to a later id or the flush; None where the input is not
+        #: known to be dense, so a node reports it for such a stage alone
+        self.windows_fired_complete = 0 if dense_positions else None
         #: ``key``: a key's window fires when that key's next row arrives
         #: (win_seq.hpp's triggerer).  ``stream``: on the stage's time, the
         #: highest position taken in on any key, less ``holdback``; a row is
@@ -405,12 +425,17 @@ class WinSeqCore:
                         self.winfunc.update_many(key, gw, real[lo:hi], st.inc_accs[lw])
                         st.inc_last_ts[lw] = int(real["ts"][hi - 1])
         # --- firing ---
-        n_fireable = int(spec.fired_before(max_rel))
+        dense = self.dense_positions
+        n_fireable = int(spec.fired_through(max_rel) if dense
+                         else spec.fired_before(max_rel))
         n_fire_to = min(max(n_fireable, st.n_fired), st.next_lwid)
         if n_fire_to <= st.n_fired:
             return None
         lwids = np.arange(st.n_fired, n_fire_to, dtype=np.int64)
         st.n_fired = n_fire_to
+        if dense:
+            self.windows_fired_complete += int(np.count_nonzero(
+                np.isin(spec.win_end(lwids) - 1, rel)))
         return self._emit_windows(key, st, lwids, eos=False)
 
     def _on_append(self, key, st: _KeyState, rows: np.ndarray):

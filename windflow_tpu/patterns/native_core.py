@@ -128,7 +128,15 @@ def _ship_loop(core_ref, ship_q, shard, shard_id):
 
 
 class NativeResidentCore:
-    """Drop-in for ResidentWinSeqCore with the hot loop in C++."""
+    """Drop-in for ResidentWinSeqCore with the hot loop in C++.
+
+    ``dense_positions`` (a Pane_Farm's window stage over its own pane
+    stream, core/winseq.py) is taken and NOT acted on: the C++ triggerer
+    (``wf_native.cpp``, role code 2) fires a count-based window on the
+    first id at or past its end, the reference's rule, so such a stage's
+    result leaves one pane later than from the Python cores — the same
+    rows either way.  Only the Python delegate (``_fall_back``) is handed
+    it on."""
 
     def __init__(self, spec: WindowSpec, reducer: Reducer,
                  batch_len: int = 8192, flush_rows: int = 1 << 20,
@@ -137,7 +145,7 @@ class NativeResidentCore:
                  depth: int = 8, compute_dtype=None, shards: int = 1,
                  overlap: bool = True, worker_index: int = 0,
                  max_delay_ms=None, mesh=None, fire_on: str = "key",
-                 holdback: int = 0):
+                 holdback: int = 0, dense_positions: bool = False):
         from ..native import load
         from ..ops.functions import MultiReducer
         from ..ops.resident import make_executor
@@ -234,7 +242,8 @@ class NativeResidentCore:
                           result_ts_slide=result_ts_slide, device=device,
                           depth=depth, compute_dtype=compute_dtype,
                           worker_index=worker_index,
-                          max_delay_ms=max_delay_ms, mesh=mesh)
+                          max_delay_ms=max_delay_ms, mesh=mesh,
+                          dense_positions=dense_positions)
         # latency bound (checked per process() call, chunk cadence)
         self.max_delay_s = (None if max_delay_ms is None
                             else max_delay_ms / 1e3)

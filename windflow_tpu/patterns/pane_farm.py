@@ -12,6 +12,20 @@ ordered Win_Farm (degree > 1), and each stage independently accepts a
 non-incremental or incremental user function (the reference's 4 constructor
 families, pane_farm.hpp:105-418).
 
+Where this port departs from ``window.hpp``'s count-based triggerer: the
+reference fires the WLQ's window ``w`` on the first pane id ``>= end``
+(window.hpp:63-66), i.e. with the NEXT pane's result, one pane length after
+``w`` was complete.  That rule is the only sound one for a user's stream,
+where a later row may carry the same id; the pane stream is the library's
+own: its ids come from the PLQ's counter (each id once a key, empty panes
+too, win_seq.hpp:401-404), the PLQ's collector is always ordered,
+``fuse_two_stage`` restores id order in front of the WLQ workers and a WLQ
+farm's emitter hands a worker every id of each of its windows.  So this
+pattern builds its WLQ stage with ``dense_positions=True`` and the cores
+fire ``w`` with pane id ``end - 1`` (core/winseq.py).  Same windows, same
+rows, same ``ts``; only the moment differs.  The PLQ, over the user's
+stream, keeps the reference's rule.
+
 This is the streaming analog of a two-level blockwise reduction — on the
 TPU it maps onto segmented partial reductions per core merged over ICI
 (SURVEY.md §5 long-context note).
@@ -70,24 +84,31 @@ class PaneFarm:
         self.wlq = self._make_stage(
             "wlq", wlq_func, win_len // pane, slide_len // pane, WinType.CB,
             wlq_degree, name=f"{name}_wlq", incremental=wlq_incremental,
-            result_fields=wlq_result_fields, ordered=ordered, role=Role.WLQ)
+            result_fields=wlq_result_fields, ordered=ordered, role=Role.WLQ,
+            # its input is the PLQ's renumbered, ordered pane stream
+            dense_positions=True)
 
     def _make_stage(self, which, func, win, slide, wt, degree, name,
-                    incremental, result_fields, ordered, role):
+                    incremental, result_fields, ordered, role,
+                    dense_positions=False):
         """Build one stage as Win_Seq (degree 1) or ordered Win_Farm —
         overridable for device placement (Pane_Farm_GPU's 4 constructor
-        families, pane_farm_gpu.hpp:176-480, become a per-stage override)."""
+        families, pane_farm_gpu.hpp:176-480, become a per-stage override).
+        ``dense_positions``: what this pattern knows of the stage's input
+        (the module docstring), handed to the cores."""
         cfg = self.config
         if degree > 1:
             return WinFarm(func, win, slide, wt, pardegree=degree, name=name,
                            incremental=incremental,
                            result_fields=result_fields, ordered=ordered,
-                           config=cfg, role=role)
+                           config=cfg, role=role,
+                           dense_positions=dense_positions)
         seq_cfg = PatternConfig(cfg.id_inner, cfg.n_inner, cfg.slide_inner,
                                 0, 1, slide)
         return WinSeq(func, win, slide, wt, name=name,
                       incremental=incremental, result_fields=result_fields,
-                      config=seq_cfg, role=role)
+                      config=seq_cfg, role=role,
+                      dense_positions=dense_positions)
 
     @property
     def result_schema(self):

@@ -280,7 +280,8 @@ class WinFarm(_Pattern):
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, name="win_farm", incremental=None,
                  result_fields=None, ordered=True, n_emitters=1,
-                 config: PatternConfig = None, role: Role = Role.SEQ):
+                 config: PatternConfig = None, role: Role = Role.SEQ,
+                 dense_positions: bool = False):
         super().__init__(name, pardegree)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self.ordered = ordered
@@ -302,7 +303,9 @@ class WinFarm(_Pattern):
                 winfunc, win_len, slide_len * pardegree, win_type,
                 name=f"{name}_wf.{i}", incremental=incremental,
                 result_fields=result_fields, config=cfg, role=role,
-                result_ts_slide=slide_len))
+                result_ts_slide=slide_len,
+                # the emitter hands a worker every id of each of its windows
+                dense_positions=dense_positions))
 
     @property
     def result_schema(self):

@@ -8,7 +8,7 @@ import numpy as np
 
 from ..core.tuples import MARKER_FIELD
 from ..core.windows import (PatternConfig, Role, WindowSpec, WinType,
-                            check_fire_on)
+                            check_dense_positions, check_fire_on)
 from ..core.winseq import WinSeqCore
 from ..ops.functions import WindowFunction, WindowUpdate, as_window_function, as_window_update
 from ..runtime.node import Node, RuntimeContext
@@ -212,9 +212,12 @@ class WinSeqNode(Node):
         if self.stats is not None:
             # what a core says of its paths, now that every row is in: the
             # bytes one took in a native core's archive, the rows its bulk
-            # path took, the chunks a stream-time host core folded natively
+            # path took, the chunks a stream-time host core folded natively,
+            # the windows a stage over dense positions fired with their last
+            # row (the flush fires only what the stream's end left open)
             self._core_counters(self.stats, (
-                "archive_row_bytes", "fast_rows", "fold_native_batches"))
+                "archive_row_bytes", "fast_rows", "fold_native_batches",
+                "windows_fired_complete"))
         if self._recov is not None:
             fb = getattr(self.core, "flush_batches", None)
             if fb is not None:
@@ -258,10 +261,17 @@ class WinSeq(_Pattern):
                  incremental: bool = None, result_fields=None,
                  config: PatternConfig = None, role: Role = Role.SEQ,
                  map_indexes=(0, 1), result_ts_slide: int = None,
-                 fire_on: str = "key", holdback: int = 0):
+                 fire_on: str = "key", holdback: int = 0,
+                 dense_positions: bool = False):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
         self.result_ts_slide = result_ts_slide
+        #: what the pattern that wires this stage knows of its input (a
+        #: Pane_Farm of its own pane stream; no user option): every position
+        #: of each window arrives once and in order, so the cores fire a
+        #: window with its last row (core/winseq.py)
+        check_dense_positions(dense_positions, self.spec, fire_on)
+        self.dense_positions = bool(dense_positions)
         #: ``"key"``: a key's window closes on that key's next row (the
         #: reference's triggerer).  ``"stream"`` (time-based windows): on
         #: the stage's time, the highest ``ts`` taken in on any key; quiet
@@ -298,6 +308,8 @@ class WinSeq(_Pattern):
         from ..core.vecinc import make_vec_core, vec_core_supported
         stream = ({"holdback": self.holdback}
                   if self.fire_on == "stream" else {})
+        if self.dense_positions:
+            stream["dense_positions"] = True
         if (vec_core_supported(self.spec, self.winfunc)
                 and not os.environ.get("WF_NO_VECCORE")):
             return make_vec_core(

@@ -28,7 +28,8 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from ..core.tuples import Schema
-from ..core.windows import PatternConfig, Role, WindowSpec, WinType
+from ..core.windows import (PatternConfig, Role, WindowSpec, WinType,
+                            check_dense_positions)
 from ..core.winseq import WinSeqCore
 from ..ops.device import (DeviceWindowExecutor, builtin_batch_fn,
                           cast_result)
@@ -206,7 +207,8 @@ class DeviceWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
     def __init__(self, spec: WindowSpec, winfunc, batch_len: int = 512,
                  config: PatternConfig = None, role: Role = Role.SEQ,
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
-                 depth: int = 4, compute_dtype=None):
+                 depth: int = 4, compute_dtype=None,
+                 dense_positions: bool = False):
         host_fn = _host_standin(winfunc)
         if isinstance(winfunc, Reducer):
             executor = DeviceWindowExecutor(
@@ -228,7 +230,8 @@ class DeviceWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
             self._stage_fields = winfunc.fields
         super().__init__(spec, host_fn, config=config, role=role,
                          map_indexes=map_indexes,
-                         result_ts_slide=result_ts_slide)
+                         result_ts_slide=result_ts_slide,
+                         dense_positions=dense_positions)
         self.executor = executor
         self._jax_fn = winfunc if isinstance(winfunc, JaxWindowFunction) \
             else None
@@ -495,7 +498,7 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
                  role: Role = Role.SEQ, map_indexes=(0, 1),
                  result_ts_slide=None, device=None, depth: int = 8,
                  compute_dtype=None, worker_index: int = 0, mesh=None,
-                 max_delay_ms=None):
+                 max_delay_ms=None, dense_positions: bool = False):
         from ..ops.resident import make_executor
         self._jax_fn = None
         self._pos_max_parts = []
@@ -539,7 +542,8 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         host_fn = _host_standin(reducer)
         super().__init__(spec, host_fn, config=config, role=role,
                          map_indexes=map_indexes,
-                         result_ts_slide=result_ts_slide)
+                         result_ts_slide=result_ts_slide,
+                         dense_positions=dense_positions)
         self.reducer = reducer
         family = _executor_family(
             "resident_py", None if self._jax_fn is not None
@@ -1262,7 +1266,8 @@ def make_device_core(worker, fn, dev_kw, index=0):
     return make_core_for(worker.spec, fn, config=worker.config,
                          role=worker.role, map_indexes=worker.map_indexes,
                          result_ts_slide=worker.result_ts_slide,
-                         worker_index=index, **dev_kw)
+                         worker_index=index,
+                         dense_positions=worker.dense_positions, **dev_kw)
 
 
 def make_core_for(spec, winfunc, *, batch_len=512, config=None,
@@ -1270,13 +1275,16 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                   device=None, depth=None, compute_dtype=None,
                   use_resident=None, flush_rows=1 << 20, shards=1,
                   worker_index=0, mesh=None, max_delay_ms=None,
-                  fire_on="key", holdback=0):
+                  fire_on="key", holdback=0, dense_positions=False):
     """Build the window core :func:`plan_core` names.  With ``mesh`` the
     resident ring is sharded ``P('kf', None)`` across the mesh devices (one
     dispatch serves every key group over ICI); ``max_delay_ms`` is a timer
     on that core, whichever it is; ``fire_on="stream"`` with its
     ``holdback`` is the host cores' and the native resident core's
-    (:func:`stream_fire_plan` refuses the others)."""
+    (:func:`stream_fire_plan` refuses the others); ``dense_positions``
+    (what a Pane_Farm knows of its window stage's input, core/winseq.py)
+    goes to whichever core is built, and the native one does not act on
+    it."""
     plan = stream_fire_plan(
         plan_core(spec, winfunc, use_resident=use_resident, mesh=mesh,
                   shards=shards, native=_native_core_fields()),
@@ -1287,10 +1295,11 @@ def make_core_for(spec, winfunc, *, batch_len=512, config=None,
                       spec.win_type, config=config, role=role,
                       map_indexes=map_indexes,
                       result_ts_slide=result_ts_slide,
-                      fire_on=fire_on, holdback=holdback).make_core()
+                      fire_on=fire_on, holdback=holdback,
+                      dense_positions=dense_positions).make_core()
     kw = dict(batch_len=batch_len, config=config, role=role,
               map_indexes=map_indexes, result_ts_slide=result_ts_slide,
-              compute_dtype=compute_dtype)
+              compute_dtype=compute_dtype, dense_positions=dense_positions)
     if plan.core == "restage":
         return DeviceWinSeqCore(
             spec, winfunc,
@@ -1346,9 +1355,10 @@ class WinSeqTPU(_Pattern):
                  map_indexes=(0, 1), result_ts_slide=None, device=None,
                  depth=None, compute_dtype=None, use_resident=None,
                  flush_rows=1 << 20, shards=1, mesh=None, max_delay_ms=None,
-                 fire_on="key", holdback=0):
+                 fire_on="key", holdback=0, dense_positions=False):
         super().__init__(name, parallelism=1)
         self.spec = WindowSpec(win_len, slide_len, win_type)
+        check_dense_positions(dense_positions, self.spec, fire_on)
         self._burst_rows = _stream_burst_rows(batch_len, flush_rows)
         self._kw = dict(batch_len=batch_len, config=config, role=role,
                         map_indexes=map_indexes,
@@ -1357,7 +1367,7 @@ class WinSeqTPU(_Pattern):
                         use_resident=use_resident, flush_rows=flush_rows,
                         shards=shards, mesh=mesh,
                         max_delay_ms=max_delay_ms, fire_on=fire_on,
-                        holdback=holdback)
+                        holdback=holdback, dense_positions=dense_positions)
         self.winfunc = winfunc
 
     def make_core(self):
@@ -1386,7 +1396,8 @@ class WinFarmTPU(_DeviceCoreFactory, WinFarm):
                  pardegree=2, batch_len=512, name="win_farm_tpu",
                  ordered=True, n_emitters=1, config=None, role=Role.SEQ,
                  device=None, depth=None, compute_dtype=None,
-                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None):
+                 use_resident=None, flush_rows=1 << 20, max_delay_ms=None,
+                 dense_positions=False):
         self._raw_fn = winfunc
         self._dev_kw = dict(batch_len=batch_len, device=device, depth=depth,
                             compute_dtype=compute_dtype,
@@ -1394,7 +1405,8 @@ class WinFarmTPU(_DeviceCoreFactory, WinFarm):
                             max_delay_ms=max_delay_ms)
         super().__init__(_host_standin(winfunc), win_len, slide_len, win_type,
                          pardegree=pardegree, name=name, ordered=ordered,
-                         n_emitters=n_emitters, config=config, role=role)
+                         n_emitters=n_emitters, config=config, role=role,
+                         dense_positions=dense_positions)
 
 
 class KeyFarmTPU(_DeviceCoreFactory, KeyFarm):
@@ -1454,21 +1466,25 @@ class PaneFarmTPU(PaneFarm):
                          name=name, **kw)
 
     def _make_stage(self, which, func, win, slide, wt, degree, name,
-                    incremental, result_fields, ordered, role):
+                    incremental, result_fields, ordered, role,
+                    dense_positions=False):
         if not self._on_device.get(which) or incremental:
             return super()._make_stage(which, func, win, slide, wt, degree,
                                        name, incremental, result_fields,
-                                       ordered, role)
+                                       ordered, role,
+                                       dense_positions=dense_positions)
         _host_standin(func)     # a host function is refused here, by name
         cfg = self.config
         if degree > 1:
             return WinFarmTPU(func, win, slide, wt, pardegree=degree,
                               name=name, ordered=ordered, config=cfg,
-                              role=role, **self._dev_kw)
+                              role=role, dense_positions=dense_positions,
+                              **self._dev_kw)
         seq_cfg = PatternConfig(cfg.id_inner, cfg.n_inner, cfg.slide_inner,
                                 0, 1, slide)
         return WinSeqTPU(func, win, slide, wt, name=name, config=seq_cfg,
-                         role=role, **self._dev_kw)
+                         role=role, dense_positions=dense_positions,
+                         **self._dev_kw)
 
     def clone_with(self, name, slide_len=None, config=None, ordered=False):
         kw = dict(self._proto)
