@@ -321,3 +321,96 @@ def test_filter_counts_rows_in_and_out_when_traced():
             assert snap["filter_rows_out"] == 32
         else:
             assert node.stats is None
+
+
+# ------------------------------------------- the farm emitter's progress rows
+
+def _farm_emitter(win, slide, wt, n):
+    from windflow_tpu.core.windows import WindowSpec
+    from windflow_tpu.patterns.win_farm import WFEmitterNode
+    from windflow_tpu.utils.tracing import NodeStats
+
+    em = WFEmitterNode(WindowSpec(win, slide, wt), n, name="em")
+    taps = [_Tap() for _ in range(n)]
+    em._outputs = [(t, 0) for t in taps]
+    em.stats = NodeStats("em")
+    return em, taps
+
+
+def _named_by_the_rule(pos_before, pos_after, key, win, slide, n):
+    """The workers that must hear of ``key``'s move from ``pos_before`` to
+    ``pos_after``, window by window: those that own a window which ended
+    in ``(pos_before, pos_after]`` less those whose own window holds
+    ``pos_after`` (they were sent that row)."""
+    def owner(w):
+        return (key % n + w) % n
+
+    ended = {owner(w) for w in range(pos_after // slide + 1)
+             if pos_before < w * slide + win <= pos_after}
+    holding = {owner(w) for w in range(pos_after // slide + 1)
+               if w * slide <= pos_after < w * slide + win}
+    return ended - holding
+
+
+@pytest.mark.parametrize("chunk", [1, 7, 1000])
+@pytest.mark.parametrize("keys", [1, 3])
+@pytest.mark.parametrize("n", [2, 3])
+@pytest.mark.parametrize("win,slide", [(30, 30), (15, 40)])
+def test_farm_emitter_sends_a_progress_row_where_the_rule_names_a_worker(
+        win, slide, n, keys, chunk):
+    """Time-based tumbling and hopping windows: when a batch takes a key
+    past a window's end, exactly the workers that own such a window and
+    were not sent the key's newest row get that row as a marker, behind
+    the batch's own rows; a batch that passes no end sends none."""
+    from windflow_tpu.core.tuples import MARKER_FIELD
+
+    em, taps = _farm_emitter(win, slide, WinType.TB, n)
+    last = {}
+    sent = quiet = 0
+    for b in tb_stream_batches(keys, 120, chunk=chunk, seed=chunk + keys):
+        seen = [len(t.got) for t in taps]
+        em.svc(b)
+        want = {}                       # worker -> {key: position}
+        for key in np.unique(b["key"]).tolist():
+            after = int(b["ts"][b["key"] == key].max())
+            before = last.get(key, -1)
+            last[key] = after
+            for d in _named_by_the_rule(before, after, key, win, slide, n):
+                want.setdefault(d, {})[key] = after
+        quiet += not want
+        for d, tap in enumerate(taps):
+            new = tap.got[seen[d]:]
+            marks = [x for x in new if x[MARKER_FIELD].any()]
+            # a worker's rows of the batch first, then its one marker batch
+            assert marks == new[len(new) - len(marks):] and len(marks) <= 1
+            got = ({int(r["key"]): int(r["ts"]) for r in marks[0]}
+                   if marks else {})
+            assert got == want.get(d, {})
+            if marks:
+                assert marks[0][MARKER_FIELD].all()
+                sent += len(marks[0])
+    assert sent >= (1 if chunk == 1000 else 10)
+    assert quiet >= 50 or chunk > 1
+    assert em.stats.snapshot()["progress_sent"] == sent
+
+
+@pytest.mark.parametrize("win,slide,wt", [
+    (40, 15, WinType.TB),       # sliding: every row to every worker
+    (8, 8, WinType.CB), (3, 8, WinType.CB), (8, 3, WinType.CB)])
+def test_farm_emitter_sends_none_on_the_multicast_branch_nor_for_cb_windows(
+        win, slide, wt):
+    """Where every worker gets every row nobody waits, and a marker in
+    mid-stream would overwrite a count-based window's result ts: the only
+    markers such an emitter sends are the end-of-stream replay's."""
+    from windflow_tpu.core.tuples import MARKER_FIELD
+
+    em, taps = _farm_emitter(win, slide, wt, 2)
+    stream = (tb_stream_batches(2, 150, chunk=7) if wt is WinType.TB
+              else cb_stream_batches(2, 150, chunk=7))
+    for b in stream[6:] if wt is WinType.TB else stream:
+        em.svc(b)
+    assert sum(len(t.got) for t in taps) > 40
+    assert not any(x[MARKER_FIELD].any() for t in taps for x in t.got)
+    assert "progress_sent" not in em.stats.snapshot()
+    em.eosnotify()
+    assert all(t.got[-1][MARKER_FIELD].all() for t in taps)

@@ -92,6 +92,15 @@ class WinSeqCore:
         #: opposed to a later id or the flush; None where the input is not
         #: known to be dense, so a node reports it for such a stage alone
         self.windows_fired_complete = 0 if dense_positions else None
+        #: windows fired by a call whose closing position was a marker's
+        #: alone and no row's: a farm emitter's progress row
+        #: (patterns/win_farm.py; the end-of-stream replay of a key's last
+        #: tuple closes nothing such a row has not closed already).  None,
+        #: and not counted, unless the farm that built the core set it to
+        #: 0 because its emitter sends such rows
+        #: (``WFEmitterNode.sends_progress``): which nodes report it is a
+        #: matter of the graph's structure, not of what a run brought
+        self.windows_fired_by_progress = None
         #: ``key``: a key's window fires when that key's next row arrives
         #: (win_seq.hpp's triggerer).  ``stream``: on the stage's time, the
         #: highest position taken in on any key, less ``holdback``; a row is
@@ -396,9 +405,15 @@ class WinSeqCore:
             st.marker_ts = int(mrows["ts"][-1])
             real = rows[~marker]
             real_pos = pos[~marker]
+            # (kept rows are in order: the group's last one closes)
+            by_progress = (self.windows_fired_by_progress is not None
+                           and bool(marker[-1])
+                           and not (len(real_pos)
+                                    and real_pos[-1] == pos[-1]))
         else:
             real = rows
             real_pos = pos
+            by_progress = False
         # --- archive (NIC only, non-marker rows; win_seq.hpp:340) ---
         if self.is_nic and len(real):
             st.archive.append(real)
@@ -436,6 +451,8 @@ class WinSeqCore:
         if dense:
             self.windows_fired_complete += int(np.count_nonzero(
                 np.isin(spec.win_end(lwids) - 1, rel)))
+        if by_progress:
+            self.windows_fired_by_progress += len(lwids)
         return self._emit_windows(key, st, lwids, eos=False)
 
     def _on_append(self, key, st: _KeyState, rows: np.ndarray):

@@ -11,21 +11,57 @@ analog goes further: the archive slice is staged once, see ops/device).
 At EOS the emitter replays each key's last tuple to ALL workers as an EOS
 marker (wf_nodes.hpp:177-191) so every worker opens/fires the same trailing
 windows Win_Seq would have.
+
+One departure from wf_nodes.hpp, for time-based windows below the multicast
+test (``WFEmitterNode.sends_progress`` says for which farms that can come
+about): a row the reference never sends.  A worker closes window ``w`` on the
+first row at or past its end, and under the reference's routing it gets the
+key's next row only with its own next window — ``pardegree - 1`` window
+lengths later on tumbling windows — or at EOS.  So when a batch takes a key
+past the end of a window, every worker that owns such a window and was not
+sent the key's newest row gets that row as a marker, the form of the EOS
+replay (``_send_progress``): it is tracked, never archived and never folded,
+and it promises what ``KeyedStreamState.filter`` has already enforced — no
+later row of the key lies behind it — so the worker closes on it exactly what
+the key's next row would have closed had it been routed there.  No result
+changes, only the moment it leaves.  Count-based windows keep the reference's
+path: an in-band marker overwrites the result ts of every count-based window
+it falls below (core/winseq.py:_result_ts), which is right at EOS only.
+
+Where the workers dispatch device launches (``WinFarm.hands_over``), the
+emitter then stands still until each worker it sent such a row has served
+it — the worker's turn.  The row sets off a launch whose host part is a few
+milliseconds of interpreter work; a worker that had no row to take in used
+to run it ahead of its own intake, and now runs it beside the emitter's
+routing and its sibling's intake, three threads after one interpreter lock:
+on the benchmark's chip host the launch took twice as long and slowed both
+(PERF.md section 6, PR 49).  Given the turn, it runs alone and the stream is a
+launch behind for the moment, as it was behind that worker's own launch.
 """
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
-from ..core.tuples import select_rows, take_rows
+from ..core.tuples import MARKER_FIELD, select_rows, take_rows
 from ..core.windows import PatternConfig, Role, WindowSpec, WinType
 from ..runtime.emitters import Collector, KeyedStreamState
 from ..runtime.node import Node, RuntimeContext
 from ..runtime.ordering import OrderingCore, OrderingMode
+from ..utils import profile
 from .basic import _Pattern
 from .win_seq import WinSeq, WinSeqNode
 
 _NEG_INF = np.int64(-(2 ** 62))
+
+
+#: the longest an emitter stands still for one worker's turn: far over a
+#: launch's host part (5-20 ms on the chip's host), so that a worker which
+#: is itself held up (a full ring of launches, a failed graph) cannot hold
+#: the stream for good
+_TURN_WAIT_S = 0.25
 
 
 class WFEmitterNode(Node):
@@ -50,7 +86,8 @@ class WFEmitterNode(Node):
         self._state.state_restore(snap)
 
     def __init__(self, spec: WindowSpec, pardegree: int, id_outer=0, n_outer=1,
-                 slide_outer=None, role: Role = Role.SEQ, name="wf_emitter"):
+                 slide_outer=None, role: Role = Role.SEQ, name="wf_emitter",
+                 turns=None):
         super().__init__(name)
         self.spec = spec
         self.pardegree = pardegree
@@ -60,6 +97,24 @@ class WFEmitterNode(Node):
         self.role = role
         self.pos_field = "id" if spec.win_type is WinType.CB else "ts"
         self._state = KeyedStreamState(self.pos_field)
+        self._progress = self.sends_progress(spec, pardegree)
+        #: an event a worker, which that worker sets when it has served a
+        #: batch that ends in a marker (``WinFarm.hands_over``); None: the
+        #: emitter waits for nobody
+        self._turns = turns
+
+    @staticmethod
+    def sends_progress(spec: WindowSpec, pardegree: int) -> bool:
+        """Whether a farm emitter of ``pardegree`` workers over ``spec``
+        ever sends a progress row (``_send_progress``; the module's
+        docstring has the reasons): time-based windows, and fewer windows
+        over a row than workers — where ``win_len // slide_len`` reaches
+        ``pardegree`` every row at or past the first window's end goes to
+        every worker, so nobody is ever left out.  A matter of the graph's
+        structure, which the farm reads too: it is the workers of such an
+        emitter that count ``windows_fired_by_progress``."""
+        return (spec.win_type is WinType.TB
+                and spec.win_len // spec.slide_len < pardegree)
 
     def _initial_id(self, keys: np.ndarray) -> np.ndarray:
         first_gwid = (self.id_outer - (keys % self.n_outer) + self.n_outer) % self.n_outer
@@ -69,11 +124,16 @@ class WFEmitterNode(Node):
         return init
 
     def svc(self, batch, channel=0):
-        spec = self.spec
         # marker absorption + out-of-order drop (wf_nodes.hpp:104-121)
         batch = self._state.filter(batch)
-        if len(batch) == 0:
-            return
+        every = len(batch) and self._route(batch)
+        if self._progress and not every:
+            self._send_progress()
+
+    def _route(self, batch) -> bool:
+        """Send each row to the workers whose windows hold it; True where
+        that was every row to every worker."""
+        spec = self.spec
         pos = self._state.pos_cache   # contiguous copy filter already made
         if pos is None:
             pos = batch[self.pos_field].astype(np.int64)
@@ -90,7 +150,7 @@ class WFEmitterNode(Node):
             rel = rel[keep]
             keys = keys[keep]
         if len(batch) == 0:
-            return
+            return False
         # window range per row (wf_nodes.hpp:134-157)
         first_w = spec.first_win_containing(rel)
         last_w = spec.last_win_containing(rel)
@@ -104,7 +164,7 @@ class WFEmitterNode(Node):
         if count.min() >= n:
             for d in range(n):
                 self.emit_to(d, batch)
-            return
+            return True
         start_dst = (keys & (n - 1)) if n & (n - 1) == 0 else keys % n
         for d in range(n):
             # worker d gets the row iff some w in [first, first+min(count,n))
@@ -114,6 +174,62 @@ class WFEmitterNode(Node):
             sub = select_rows(batch, m)
             if len(sub):
                 self.emit_to(d, sub)
+        return False
+
+    def _send_progress(self):
+        """A key whose position the batch took past the end of a window:
+        the window's worker closes it on a row at or past that end, and
+        gets the key's next one only where it also holds a later window of
+        its own.  So every worker that owns a window the key has just left
+        and was NOT sent the key's newest row gets that row as a marker.
+        Read off what ``filter`` holds of each distinct key: a batch that
+        passes no end pays these few array operations a key, none a row."""
+        state = self._state
+        slots = state.key_slots
+        if slots is None:
+            return
+        spec = self.spec
+        prev, new = state.key_prev, state.key_now
+        if self.n_outer > 1:
+            init = self._initial_id(state.last_keys(slots))
+            new, prev = new - init, prev - init
+        lo, hi = spec.fired_before(prev), spec.fired_before(new)
+        passed = np.flatnonzero(hi > lo)
+        if not len(passed):
+            return
+        rows = state.last_rows(slots[passed])
+        new, lo, hi = new[passed], lo[passed], hi[passed]
+        # whom _route sent the key's newest row: the workers of the windows
+        # that hold it, where it lies in a window at all
+        first_w = spec.first_win_containing(new)
+        held = np.where(spec.in_any_window(new),
+                        spec.last_win_containing(new) - first_w + 1, 0)
+        rows[MARKER_FIELD] = True
+        n = self.pardegree
+        start_dst = rows["key"] % n
+        turns = self._turns
+        sent, told = 0, []
+        for d in range(n):
+            # window w of a key is worker (key%n + w) % n's: d owns one of
+            # [lo, hi) and none of [first_w, first_w + held)
+            m = (((d - start_dst - lo) % n < hi - lo)
+                 & ((d - start_dst - first_w) % n >= held))
+            if m.any():
+                if turns is not None:
+                    turns[d].clear()
+                    told.append(turns[d])
+                self.emit_to(d, rows if m.all() else select_rows(rows, m))
+                sent += int(np.count_nonzero(m))
+        if sent:
+            profile.add("progress_sent", sent)
+            if self.stats is not None:
+                self.stats.bump("progress_sent", sent)
+        for turn in told:
+            # the worker's turn (the module's docstring): blocked, not busy
+            if self.stats is not None:
+                self.stats.timed_wait(turn, _TURN_WAIT_S)
+            else:
+                turn.wait(_TURN_WAIT_S)
 
     def eosnotify(self):
         # per-key EOS markers to every worker (wf_nodes.hpp:177-191)
@@ -225,7 +341,10 @@ class WFCollectorNode(Node):
 class _OrderedWorkerNode(WinSeqNode):
     """OrderingCore fused in front of a window core — the
     ff_comb(OrderingNode, Win_Seq) worker used behind multiple emitters
-    (win_farm.hpp:157-162)."""
+    (win_farm.hpp:157-162).  It does not act on an emitter's progress row:
+    one emitter's promise says nothing of the other channels, and the merge
+    sets every marker aside until the end of the stream, so such a worker
+    closes a window on its own next row, as before them."""
 
     def __init__(self, core, n_channels, mode, name, per_key=False):
         super().__init__(core, name)
@@ -277,6 +396,14 @@ class _OrderedWorkerNode(WinSeqNode):
 class WinFarm(_Pattern):
     """Window-parallel farm of sequential cores (win_farm.hpp)."""
 
+    #: whether the emitter stands still while a worker serves a progress
+    #: row (the module's docstring).  Not here: a host worker's window
+    #: function is the worker's own work, long or short, and runs beside
+    #: the intake as the farm means it to; the farm of device workers says
+    #: True, where that work is a launch's host part and the device does
+    #: the rest
+    hands_over = False
+
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, name="win_farm", incremental=None,
                  result_fields=None, ordered=True, n_emitters=1,
@@ -291,6 +418,11 @@ class WinFarm(_Pattern):
         self.ordering_per_key = False
         self.config = config or PatternConfig.plain(slide_len)
         self.role = role
+        #: an event a worker where the emitter gives a worker its turn
+        #: (``hands_over``, the module's docstring), else None
+        self._turns = ([threading.Event() for _ in range(pardegree)]
+                       if self.hands_over and WFEmitterNode.sends_progress(
+                           self.spec, pardegree) else None)
         # worker template: private slide, nested PatternConfig
         # (win_farm.hpp:134-143)
         self._workers = []
@@ -316,7 +448,11 @@ class WinFarm(_Pattern):
                              id_outer=self.config.id_inner,
                              n_outer=self.config.n_inner,
                              slide_outer=self.config.slide_inner,
-                             role=self.role, name=f"{self.name}.emitter")
+                             role=self.role, name=f"{self.name}.emitter",
+                             # (one of several emitters gives no turn: a
+                             # worker behind a merge sets its rows aside)
+                             turns=(self._turns if self.n_emitters == 1
+                                    else None))
 
     def collector(self):
         if self.ordered:
@@ -330,6 +466,10 @@ class WinFarm(_Pattern):
 
     def _make_replica(self, i):
         core = self._make_core(self._workers[i], i)
+        if (self.n_emitters == 1
+                and WFEmitterNode.sends_progress(self.spec, self.parallelism)
+                and hasattr(core, "windows_fired_by_progress")):
+            core.windows_fired_by_progress = 0      # (a core that counts)
         if self.n_emitters > 1:
             mode = OrderingMode.ID if self.spec.win_type is WinType.CB else OrderingMode.TS
             node = _OrderedWorkerNode(core, self.n_emitters, mode,
@@ -337,5 +477,7 @@ class WinFarm(_Pattern):
                                       per_key=self.ordering_per_key)
         else:
             node = WinSeqNode(core, f"{self.name}.{i}")
+            if self._turns is not None:
+                node.turn = self._turns[i]
         node.ctx = RuntimeContext(self.parallelism, i, self.name)
         return node

@@ -41,6 +41,10 @@ class WinSeqNode(Node):
     #: this many rows (``_emit_fires``); a pattern that knows its stage's
     #: ``flush_rows`` sets a sixteenth of it, which is this default's too
     burst_rows = 1 << 16
+    #: set when a batch that ends in a marker has been served, for the farm
+    #: emitter that waits for it (patterns/win_farm.py ``hands_over``); the
+    #: farm gives its workers one each, every other node has none
+    turn = None
 
     def __init__(self, core: WinSeqCore, name="win_seq"):
         super().__init__(name)
@@ -110,6 +114,12 @@ class WinSeqNode(Node):
                 self.core.state_restore(snap)
 
     def svc(self, batch, channel=0):
+        self._serve(batch)
+        if (self.turn is not None and len(batch)
+                and batch[MARKER_FIELD][-1]):
+            self.turn.set()
+
+    def _serve(self, batch):
         if self._recov is not None:
             # recovery mode + async device core: emit ONE batch per
             # completed launch, in launch order.  Launch boundaries are
@@ -214,10 +224,11 @@ class WinSeqNode(Node):
             # bytes one took in a native core's archive, the rows its bulk
             # path took, the chunks a stream-time host core folded natively,
             # the windows a stage over dense positions fired with their last
-            # row (the flush fires only what the stream's end left open)
+            # row, those a farm worker closed on a marker and not on a row
+            # (the flush fires only what the stream's end left open)
             self._core_counters(self.stats, (
                 "archive_row_bytes", "fast_rows", "fold_native_batches",
-                "windows_fired_complete"))
+                "windows_fired_complete", "windows_fired_by_progress"))
         if self._recov is not None:
             fb = getattr(self.core, "flush_batches", None)
             if fb is not None:

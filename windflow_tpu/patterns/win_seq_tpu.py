@@ -1392,6 +1392,10 @@ class WinFarmTPU(_DeviceCoreFactory, WinFarm):
     streams); multi-chip distribution is the mesh layer's job
     (parallel/)."""
 
+    #: a progress row sets off a worker's launch: the emitter gives that
+    #: worker its turn (patterns/win_farm.py)
+    hands_over = True
+
     def __init__(self, winfunc, win_len, slide_len, win_type=WinType.CB,
                  pardegree=2, batch_len=512, name="win_farm_tpu",
                  ordered=True, n_emitters=1, config=None, role=Role.SEQ,

@@ -42,7 +42,8 @@ class KeyedStreamState:
     the emitter differential tests)."""
 
     __slots__ = ("pos_field", "_slots", "_last_pos", "_rows", "_n", "_cap",
-                 "_lib", "_km", "_last_idx", "_touched", "_nt", "pos_cache")
+                 "_lib", "_km", "_last_idx", "_touched", "_nt", "pos_cache",
+                 "key_slots", "key_prev", "key_now")
 
     def __init__(self, pos_field: str):
         from ..native import load
@@ -67,6 +68,13 @@ class KeyedStreamState:
         #: in-order fast path) — callers reuse it instead of re-gathering
         #: the strided field; None whenever rows were dropped/changed
         self.pos_cache = None
+        #: after filter(): the slots of the surviving rows' keys, and each
+        #: one's position before the batch (_NEG_INF: a new key) and after
+        #: it — one entry a distinct key, numbers filter holds anyway; a
+        #: farm emitter asks them whether a key passed a window's end
+        #: (patterns/win_farm.py).  None where no row survived; valid until
+        #: the next filter()
+        self.key_slots = self.key_prev = self.key_now = None
 
     def __del__(self):
         km = getattr(self, "_km", None)
@@ -139,6 +147,7 @@ class KeyedStreamState:
         """Absorb marker rows and drop out-of-order rows; returns the
         surviving (real) rows, arrival order preserved."""
         self.pos_cache = None
+        self.key_slots = self.key_prev = self.key_now = None
         mk = batch[MARKER_FIELD]
         if np.any(mk):
             mrows = select_rows(batch, mk)
@@ -172,7 +181,7 @@ class KeyedStreamState:
                 # (tiny gathers — one row per distinct key)
                 buf = self._rows_buf(batch.dtype)
                 buf[t] = take_rows(batch, li)
-                self._last_pos[t] = pos[li]
+                self._moved(t, pos[li])
                 self.pos_cache = pos
                 return batch
         return self._filter_general(batch, slots, pos)
@@ -189,28 +198,43 @@ class KeyedStreamState:
         seg_first[starts] = True
         within_bad = np.zeros(len(s), dtype=bool)
         within_bad[1:] = (np.diff(ps) < 0) & ~seg_first[1:]
-        head_bad = ps[starts] < self._last_pos[s[starts]]
+        heads = s[starts]
+        prev = self._last_pos[heads]
+        head_bad = ps[starts] < prev
         if not within_bad.any() and not head_bad.any():
             # in-order fast path: store each key's last row, done
-            lasts = ends - 1
-            self._last_pos[s[lasts]] = ps[lasts]
+            self._moved(heads, ps[ends - 1])
             self._store_last(slots, batch, sorted_order=order)
             self.pos_cache = pos
             return batch
         # out-of-order: the shared segmented exclusive running max
         # (core/slots.py; also the vecinc drop pass)
-        excl = segmented_excl_running_max(s, ps, starts,
-                                          self._last_pos[s[starts]])
+        excl = segmented_excl_running_max(s, ps, starts, prev)
         keep_sorted = ps >= excl
         liv = np.flatnonzero(keep_sorted)
         if len(liv):
             ls, le = segments(s[liv])
-            self._last_pos[s[liv[ls]]] = ps[liv[le - 1]]
+            self._moved(s[liv[ls]], ps[liv[le - 1]])
             self._store_last(slots[order[liv]], take_rows(batch, order[liv]),
                              sorted_order=np.arange(len(liv)))
         keep = np.empty(len(batch), dtype=bool)
         keep[order] = keep_sorted
         return batch if keep.all() else select_rows(batch, keep)
+
+    def _moved(self, slots, now):
+        """The batch's surviving rows took ``slots``' keys to ``now``."""
+        self.key_slots, self.key_prev, self.key_now = \
+            slots, self._last_pos[slots], now
+        self._last_pos[slots] = now
+
+    def last_keys(self, slots) -> np.ndarray:
+        """The key of each of ``slots``."""
+        return self._rows["key"][slots]
+
+    def last_rows(self, slots) -> np.ndarray:
+        """Each of ``slots``' keys' last tuple as filter() holds it, an
+        owned copy."""
+        return take_rows(self._rows, slots)
 
     def state_snapshot(self):
         """Recovery snapshot of the per-key bookkeeping, numpy path only
@@ -234,6 +258,7 @@ class KeyedStreamState:
         self._n = snap["n"]
         self._cap = snap["cap"]
         self.pos_cache = None
+        self.key_slots = self.key_prev = self.key_now = None
 
     def marker_batch(self) -> np.ndarray | None:
         """One marker row per key (its last tuple), for EOS replay."""

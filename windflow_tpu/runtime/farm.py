@@ -235,6 +235,10 @@ def fuse_two_stage(df: Dataflow, stage1, stage2, upstreams: list[Node],
     if level >= 2:
         # ---- stage 1 workers, each with a fused stage-2 emitter clone ----
         s1_workers = _apply_error_budget(stage1, stage1.replicas())
+        if isinstance(stage2, WinFarm):
+            # (before its emitters are made: one of several acts as such)
+            stage2.n_emitters = P   # replicas become _OrderedWorkerNodes
+            stage2.ordering_per_key = True
         need_emitter = (W > 1
                         and not _is_passthrough_emitter(stage2.emitter()))
         combs = []
@@ -260,8 +264,6 @@ def fuse_two_stage(df: Dataflow, stage1, stage2, upstreams: list[Node],
         # per-key watermarks: stage-1 workers emit per-key renumbered ids
         # (PLQ/MAP role), which are NOT globally monotone per channel
         if isinstance(stage2, WinFarm):
-            stage2.n_emitters = P   # replicas become _OrderedWorkerNodes
-            stage2.ordering_per_key = True
             s2_workers = _apply_error_budget(stage2, stage2.replicas())
         else:  # degree-1 sequential stage
             mode = (OrderingMode.ID

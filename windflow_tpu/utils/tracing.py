@@ -193,11 +193,30 @@ class NodeStats:
             self._followed(self.fused_cpu.setdefault(name, [0, 0]),
                            dt - below, cpu - below_cpu)
         else:
-            self.blocked_ns += dt
-            self._followed(self.blocked_cpu, dt, cpu)
-            if dt > self.blocked_max_ns:
-                self.blocked_max_ns = dt
-                self.blocked_max_inbox = getattr(inbox, "owner", None)
+            self._blocked(dt, cpu, getattr(inbox, "owner", None))
+        if self._fused_open:
+            above = self._fused_open[-1]
+            above[0] += dt
+            above[1] += cpu
+
+    def _blocked(self, dt, cpu, on):
+        self.blocked_ns += dt
+        self._followed(self.blocked_cpu, dt, cpu)
+        if dt > self.blocked_max_ns:
+            self.blocked_max_ns = dt
+            self.blocked_max_inbox = on
+
+    def timed_wait(self, event, timeout):
+        """``event.wait`` on the node's clocks: blocked time, as a put into
+        a full inbox is (a farm emitter that waits out a worker's turn,
+        patterns/win_farm.py)."""
+        if not self._fused_open:
+            self.cpu_turn()
+        t0, c0 = self.clocks()
+        event.wait(timeout)
+        t1, c1 = self.clocks()
+        dt, cpu = t1 - t0, c1 - c0
+        self._blocked(dt, cpu, "a worker's turn")
         if self._fused_open:
             above = self._fused_open[-1]
             above[0] += dt
