@@ -287,3 +287,42 @@ def skyline_windows(rows, win_len, slide_len, pos="ts", by_panes=False):
             sky = _frontier(held)
             out[w] = (len(sky), sum(x + y for x, y in sky))
     return out
+
+
+def join_windows(rows, win_len, side="side", left=(0, "lk"),
+                 right=(1, "rk"), left_fields=(), right_fields=(), ts="ts"):
+    """Plain reference of a tumbling-window inner equi-join over one stream
+    whose rows carry their side (NEXMark Q8's ``CoGroupByKey`` of persons and
+    auctions in ``FixedWindows``), rows in ARRIVAL order (dicts or structured
+    rows): per window ``w`` = ``[w*win_len, (w+1)*win_len)`` a dictionary of
+    its LEFT rows by their join key, then a loop over its RIGHT rows; one
+    result per right row whose key the dictionary holds, in the right rows'
+    arrival order, as ``(key, w, the later of the two ts, the right row's
+    right_fields..., the left row's left_fields...)``.  A left row that
+    comes after its right rows in the window matches them all the same; one
+    of the window before matches nothing.  A second left row of a key in one
+    window raises ``KeyError``: the left side is unique per key and window.
+    Windows are listed in order.  The twin of
+    ``benchmarks/configs/q8_new_users_oracle.py``."""
+    (l_val, l_key), (r_val, r_key) = left, right
+    lefts, rights = {}, {}
+    for r in rows:
+        w = int(r[ts]) // win_len
+        if r[side] == l_val:
+            held = lefts.setdefault(w, {})
+            if int(r[l_key]) in held:
+                raise KeyError(f"window {w}: a second left row of key "
+                               f"{int(r[l_key])}")
+            held[int(r[l_key])] = r
+        elif r[side] == r_val:
+            rights.setdefault(w, []).append(r)
+    out = []
+    for w in sorted(rights):
+        held = lefts.get(w, {})
+        for r in rights[w]:
+            m = held.get(int(r[r_key]))
+            if m is not None:
+                out.append((int(r[r_key]), w, max(int(r[ts]), int(m[ts])))
+                           + tuple(int(r[f]) for f in right_fields)
+                           + tuple(int(m[f]) for f in left_fields))
+    return out

@@ -154,6 +154,15 @@ def _recovery_sink_pipe(opt_in):
     return p
 
 
+def _join_pipe(recovery):
+    from windflow_tpu.patterns.win_join_tpu import WinJoinTPU
+    join = WinJoinTPU(1000, side_field="value", left=(0, "key"),
+                      right=(1, "key"), key_range=(0, 1 << 20),
+                      window_rows=1024)
+    return _pipe(join, name="joined",
+                 recovery=RecoveryPolicy() if recovery else None)
+
+
 def _race_pipe(guarded):
     counts = [0]
     lock = threading.Lock()
@@ -345,6 +354,7 @@ CORPUS = {
     "WF215": (lambda t: _native_df(), lambda t: _native_df(abi=True)),
     "WF217": (lambda t: _fed_pipe(t),
               lambda t: _fed_pipe(t, obs=True)),
+    "WF218": (lambda t: _join_pipe(True), lambda t: _join_pipe(False)),
     "WF216": (lambda t: PlanePolicy(wire=WireConfig.hardened()),
               lambda t: PlanePolicy(wire=WireConfig(
                   connect_deadline=60.0, heartbeat=2.0,

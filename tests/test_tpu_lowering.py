@@ -210,3 +210,64 @@ def test_skyline_step_compiles(v5e, make, kp, cap, rb, B, pad):
     # ... not even at one bit a pair
     assert compiled.memory_analysis().temp_size_in_bytes < B * pad * pad // 8
     assert "wf_udf" in compiled.as_text()
+
+
+def _join_core(window_rows, max_results, flush_rows):
+    """The join worker of NEXMark Q8's shape (a key, a side, a time and two
+    carried fields: five int32 rings of one row)."""
+    from windflow_tpu.patterns.win_join_tpu import WinJoinTPU
+    return WinJoinTPU(
+        10_000_000, side_field="event_type", left=(0, "person"),
+        right=(1, "seller"), key_range=(0, 2_000_000_000),
+        right_fields=("auction", "reserve"),
+        field_ranges={"auction": (0, 2_000_000_000),
+                      "reserve": (0, 200_000_001)},
+        window_rows=window_rows, max_results=max_results,
+        flush_rows=flush_rows).make_core()
+
+
+def test_join_step_compiles(v5e):
+    """The step that closes a window of the join (ops/join.py on the
+    multi-field resident step): two sorts that carry the rows' fields and two
+    running maxima, at a sixteenth of the cell's window;
+    its temporaries stay a small multiple of the window's columns, and the
+    function stands under its own name inside the user-function scope."""
+    S = _one(v5e)
+    core = _join_core(1_900_000, 1_500_000, 1 << 16)
+    ex, n = core.executor, len(core.fields)
+    pad = ex._pad_for(np.array([1_000_000]))
+    assert pad == 1_966_080 and core.cap == 1_572_864 and ex.KP == 1
+    wires = tuple(core._wire[f].str for f in core.fields)
+    key = resident.StepKey(
+        "multi", ONE, (), ex.cap, 1 << 16, 1, 1, wires, (I32,) * n, pad,
+        fields=core.fields, fn_slot=core.fields)
+    fn = resident._make_multi_step(key, core.fn)
+    rings = tuple(S((1, ex.cap), jnp.int32) for _ in core.fields)
+    blks = tuple(S((1, 1 << 16), core._wire[f]) for f in core.fields)
+    b = S((1,), jnp.int32)
+    compiled = fn.lower(rings, blks, S((1,), jnp.int32), b, b, b, b,
+                        b).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * pad * 4
+    text = compiled.as_text()
+    assert "wf_udf" in text and "wf_join" in text
+
+
+def test_join_append_step_compiles_in_place(v5e):
+    """The append-only step at the cell's own widths: five rings of 2^25
+    cells, rectangles of 2^20 rows, the rings donated, so a launch that
+    ships rows copies no ring."""
+    S = _one(v5e)
+    core = _join_core(30_000_000, 24_000_000, 1 << 20)
+    ex, n = core.executor, len(core.fields)
+    assert ex.cap == 1 << 25 and core.cap == 25_165_824
+    key = resident.StepKey(
+        "append", ONE, (), ex.cap, 1 << 20, 0, 1,
+        tuple(core._wire[f].str for f in core.fields), (I32,) * n,
+        fields=core.fields)
+    fn = resident._make_append_step(key)
+    rings = tuple(S((1, ex.cap), jnp.int32) for _ in core.fields)
+    blks = tuple(S((1, 1 << 20), core._wire[f]) for f in core.fields)
+    compiled = fn.lower(rings, blks, S((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= n * ex.cap * 4
+    assert mem.temp_size_in_bytes <= 64 << 20

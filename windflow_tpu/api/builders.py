@@ -36,6 +36,7 @@ from ..patterns.nesting import KeyFarmOf, WinFarmOf
 from ..patterns.pane_farm import PaneFarm
 from ..patterns.win_farm import WinFarm
 from ..patterns.win_mapreduce import WinMapReduce
+from ..patterns.win_join_tpu import WinJoinTPU
 from ..patterns.win_seq import WinSeq
 from ..patterns.win_seq_tpu import (KeyFarmTPU, PaneFarmTPU, WinFarmTPU,
                                     WinMapReduceTPU, WinSeqTPU)
@@ -543,3 +544,51 @@ class WinMapReduceTPU_Builder(WinMapReduce_Builder, _TPUMixin):
     def reduceOnDevice(self, flag: bool = True):
         self._kw["reduce_on_device"] = flag
         return self
+
+
+class WinJoinTPU_Builder(_Builder):
+    """The two-sided window stage (patterns/win_join_tpu.py; the reference
+    has no join): a tumbling time-based inner equi-join of two sides of one
+    stream, on the device.  ``withTBWindow(win, win)``; ``withCBWindow`` and
+    a slide other than the window are refused by the pattern, by name."""
+    _pattern_cls = WinJoinTPU
+
+    withTBWindow = _WindowMixin.withTBWindow
+    withCBWindow = _WindowMixin.withCBWindow
+    withFlushRows = _TPUMixin.withFlushRows
+    withDevice = _TPUMixin.withDevice
+    withDepth = _TPUMixin.withDepth
+
+    def withSides(self, side_field: str, left, right):
+        """The field that says which side a row is on, and per side ``(its
+        value of that field, the field that is its join key)``; the left
+        side is unique per key and window."""
+        self._kw.update(side_field=side_field, left=tuple(left),
+                        right=tuple(right))
+        return self
+
+    def withKeyRange(self, lo: int, hi: int):
+        """The join key's declared range ``[lo, hi)``: the device holds it
+        as int32, and the pattern holds every chunk to the range."""
+        self._kw["key_range"] = (int(lo), int(hi))
+        return self
+
+    def withFields(self, left=(), right=(), ranges=None):
+        """The fields of the left and of the right row a result carries,
+        and each one's declared range ``[lo, hi)``."""
+        self._kw.update(left_fields=tuple(left), right_fields=tuple(right),
+                        field_ranges=dict(ranges or {}))
+        return self
+
+    def withWindowRows(self, rows: int):
+        """The rows of both sides one window holds: sizes the rings and pins
+        the join step's shape; a longer window grows both."""
+        self._kw["window_rows"] = int(rows)
+        return self
+
+    def withMaxResults(self, rows: int):
+        """The matches one window may give (default: ``withWindowRows``): a
+        window over it raises, nothing is cut."""
+        self._kw["max_results"] = int(rows)
+        return self
+

@@ -264,7 +264,8 @@ class MultiPipe:
                 # windowed results carry fresh per-key window ids; ordered
                 # collectors (default) restore emission order
                 ordered = getattr(p, "ordered", True)
-                dense = True
+                # (a join's results share their window's id)
+                dense = getattr(p, "dense_ids", True)
                 continue
             cls = type(p).__name__
             if cls in ("Filter", "FlatMap"):

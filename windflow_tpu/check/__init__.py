@@ -63,11 +63,13 @@ def validate(target) -> CheckReport:
     if hasattr(target, "_build") and hasattr(target, "_stages"):
         # a MultiPipe: pre-build knob checks first — a fatal knob
         # conflict (WF208 at the Dataflow constructor, WF210/WF211 at
-        # the control-plane wiring) means _build() itself would raise,
+        # the control-plane wiring, WF218 at a window join's own wiring)
+        # means _build() itself would raise,
         # so the static report must not attempt it
         pre = check_pipe_config(target)
         report.extend(pre)
-        if any(d.code in ("WF208", "WF210", "WF211") for d in pre):
+        if any(d.code in ("WF208", "WF210", "WF211", "WF218")
+               for d in pre):
             return report.finish()
         with warnings.catch_warnings():
             # the Dataflow constructor re-warns the WF207/WF209

@@ -199,6 +199,20 @@ def check_pipe_config(pipe) -> list[Diagnostic]:
             f"0, got {pipe.capacity}): an unbounded queue never sheds "
             f"and never times out"))
     diags.extend(check_pipe_control(pipe))
+    if pipe.recovery is not None:
+        # duck-typed by the hook the wiring calls (runtime/farm.add_farm):
+        # the build itself raises, so this must be reportable before it
+        for pattern in _iter_pipe_patterns(pipe):
+            if type(pattern).__name__ == "WinJoinTPU":
+                diags.append(Diagnostic(
+                    "WF218",
+                    f"recovery= over the window join {pattern.name!r}: its "
+                    f"open window's rows live in device rings that no "
+                    f"checkpoint holds, so the pattern refuses the graph at "
+                    f"its build (patterns/win_join_tpu.py) -- run the join "
+                    f"without recovery=, or keep it in a pipe of its own",
+                    node=pattern.name,
+                    anchor=getattr(pattern, "anchor", None)))
     from ..utils.tracing import default_trace_dir
     # judged on the pipe's OWN (merged) knobs only: union_multipipes has
     # already hoisted the operands' trace_dir/metrics/overload onto the

@@ -95,6 +95,10 @@ CATALOG: dict[str, tuple[str, str]] = {
               "federate= set without metrics=/sample_period=: the "
               "shipper's only source is the sampler, so no snapshot is "
               "ever shipped and federation is silently inert"),
+    "WF218": (ERROR,
+              "recovery= over a window join: the open window's rows live "
+              "in device rings that no checkpoint holds, and the graph's "
+              "build refuses the pair"),
     # -- WF22x: plane topology (cross-process, check/plane.py) ----------
     "WF220": (ERROR,
               "plane topology broken: a host ships rows to a pid with "

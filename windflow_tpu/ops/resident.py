@@ -186,7 +186,7 @@ class StepKey(NamedTuple):
     """The shape of one compiled step: what ``_STEP_CACHE`` and the dicts of
     ``_FN_STEP_CACHE`` are keyed by.  A family sets the fields it has and
     leaves the others at their defaults."""
-    #: ``regular``, ``append_eval``, ``multi`` or ``argext``
+    #: ``regular``, ``append_eval``, ``multi``, ``argext`` or ``append``
     family: str
     #: what the step is bound to: `_ANY_DEVICE` (a jit serves whichever
     #: device its arguments sit on) or the `_OnMesh` it is mapped over
@@ -857,14 +857,20 @@ def _eval_udf(udf, rings, fidx, cap, pad, wrows, wstarts, wlens, wkeys,
     tuple of outputs; its operations read ``wf_udf`` in a device trace,
     apart from the append and the gathers around them."""
     fields, fn_ref = udf
-    idx = jnp.minimum(
-        wstarts[:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :],
-        cap - 1)
     mask = jnp.arange(pad, dtype=jnp.int32)[None, :] < wlens[:, None]
-    cols = {}
-    for f in fields:
-        vals = rings[fidx[f]][wrows[:, None], idx]
-        cols[f] = jnp.where(mask, vals, 0)
+
+    def windows(ring):
+        # a window is ONE stretch of its ring row: `pad` cells sliced from
+        # its start, not a gather cell by cell (9 ns a cell on a v5e: 0.57 s
+        # a column of a window of 3 x 10^7 rows, PERF.md PR 50).  The row is
+        # widened by `pad` zeros first, so a stretch that reaches past the
+        # ring's end reads them there -- cells the mask drops -- and is not
+        # moved back
+        wide = jnp.pad(ring, ((0, 0), (0, pad)))
+        return jax.vmap(lambda r, s: lax.dynamic_slice(
+            wide, (r, s), (1, pad))[0])(wrows, wstarts)
+
+    cols = {f: jnp.where(mask, windows(rings[fidx[f]]), 0) for f in fields}
     with jax.named_scope("wf_udf"):
         res = fn_ref()(wkeys, wgwids, cols, mask)
     return res if isinstance(res, tuple) else (res,)
@@ -900,6 +906,21 @@ def _make_multi_step(key, jax_fn):
     return key.place.compile(step, "wf_step_multi", 1, 5)
 
 
+def _make_append_step(key):
+    """Append alone, for a launch that evaluates nothing: each field's
+    rectangle into its ring, the rings donated so that it is in place.  A
+    core that ships a window's rows long before the window closes (the
+    join's, patterns/win_join_tpu.py) sends them so and keeps the step bound
+    to its function for the launch that closes the window."""
+    acc_dts = tuple(np.dtype(a) for a in key.accs)
+
+    def step(rings, blks, offs):
+        return tuple(_ring_append(r, b, offs, dt)
+                     for r, b, dt in zip(rings, blks, acc_dts)), ()
+
+    return key.place.compile(step, "wf_step_append", 1, 0, donate_argnums=0)
+
+
 class MultiFieldResidentExecutor(ResidentWindowExecutor):
     """Resident launch queue with one ring PER FIELD: multi-field
     reducer stats (e.g. sum(a) + max(b)) and arbitrary batched JAX window
@@ -913,7 +934,7 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
     to its ring dtype."""
 
     def __init__(self, fields, stats=(), jax_fn=None, acc_dtypes=None,
-                 place=None, depth: int = 8):
+                 place=None, depth: int = 8, row_floor: int = None):
         self.fields = tuple(fields)
         if not self.fields:
             raise ValueError("need at least one ring field")
@@ -933,6 +954,10 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         self.acc_dtypes = {f: np.dtype(acc_dtypes[f]) for f in self.fields}
         self._rings = None
         self._init_queue(place, depth)
+        if row_floor is not None:
+            # a core that knows its keys (the join's one) sizes the rings'
+            # rows itself: eight rows of a window's length are eight windows
+            self._row_floor = int(row_floor)
         #: the step key's function slot: the function's fields (the function
         #: itself keys _FN_STEP_CACHE), None for a step that binds none
         self._fn_slot = None if jax_fn is None else tuple(jax_fn.fields)
@@ -988,10 +1013,54 @@ class MultiFieldResidentExecutor(ResidentWindowExecutor):
         PERF.md PR 43)."""
         longest = int(wlens.max()) if len(wlens) else 1
         if self.jax_fn is not None:
-            return _bucket_fine(longest)
+            # a function that declares its window's rows keeps ONE shape
+            # while the windows stay under it
+            return _bucket_fine(max(longest, self.jax_fn.window_rows or 0))
         if any(op != "sum" for op, _f in self.stats):
             return _bucket(longest)
         return 0
+
+    def grow(self, cap: int):
+        """Widen every ring to `cap` cells a row on the device, contents
+        kept (a core asks when its live rows outgrow the ring)."""
+        if cap <= self.cap:
+            return
+        old = self._rings_arr()
+        self.cap = cap
+        self._rings = None
+        # fresh rings as :meth:`_rings_arr` makes them, the old contents
+        # written at the front
+        self._rings = tuple(lax.dynamic_update_slice(z, r, (0, 0))
+                            for z, r in zip(self._rings_arr(), old))
+
+    def append(self, blks: dict, offs: np.ndarray, tag=_NO_TAG):
+        """A dispatch that only appends the per-field rectangles `blks[f]`
+        (K, R) at `offs`: nothing is evaluated, nothing comes back, nothing
+        waits for a harvest (``jit_wf_step_append``)."""
+        place = self.place
+        K, R = next(iter(blks.values())).shape
+        if K > self.KP:
+            raise ValueError("rectangle exceeds ring rows; reset() first")
+        Rb = _bucket(max(R, 1))
+        _check_ring_overflow(offs, Rb, self.cap)
+        key = StepKey(
+            "append", place.in_key, (), self.cap, Rb, 0, self.KP,
+            tuple(blks[f].dtype.str for f in self.fields),
+            tuple(self.acc_dtypes[f].str for f in self.fields),
+            fields=self.fields)
+        fn = _STEP_CACHE.get(key)
+        if fn is None:
+            fn = _STEP_CACHE[key] = _make_append_step(key)
+        with profile.span("device_put", *tag):
+            args = place.put(self.KP, Rb, 0, None,
+                             tuple(blks[f] for f in self.fields), (offs,), ())
+        for f in self.fields:
+            profile.add("bytes_shipped", blks[f].nbytes)
+            profile.add("rows_shipped", blks[f].size)
+        with profile.span("dispatch", *tag):
+            self._rings, _none = fn(self._rings_arr(), *args)
+        self.dispatches += 1
+        stats_add("dispatches")
 
     def launch(self, meta, blks: dict, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
@@ -1164,19 +1233,6 @@ class ArgExtResidentExecutor(MultiFieldResidentExecutor):
             raise ValueError("the arg-extremum family serves no mesh")
         self.eval_block = ARGEXT_BLOCK
 
-    def grow(self, cap: int):
-        """Widen every ring to `cap` cells a row on the device, contents
-        kept (the core asks when its live rows outgrow half the ring)."""
-        if cap <= self.cap:
-            return
-        old = self._rings_arr()
-        self.cap = cap
-        self._rings = None
-        # fresh rings as :meth:`_rings_arr` makes them, the old contents
-        # written at the front
-        self._rings = tuple(lax.dynamic_update_slice(z, r, (0, 0))
-                            for z, r in zip(self._rings_arr(), old))
-
     def launch(self, meta, blks: dict, offs: np.ndarray,
                wrows: np.ndarray, wstarts: np.ndarray, wlens: np.ndarray,
                shifts: np.ndarray = None, tag=_NO_TAG):
@@ -1220,14 +1276,16 @@ class ArgExtResidentExecutor(MultiFieldResidentExecutor):
 
 
 def make_executor(family: str, fields, stats, acc_dtypes, *, jax_fn=None,
-                  mesh=None, device=None, depth: int = 8):
+                  mesh=None, device=None, depth: int = 8,
+                  row_floor: int = None):
     """The resident executor of one step family — the one place that names
     the executor classes.  ``family`` is ``"regular"`` (one ring, every op
     of ``stats`` over it), ``"multi"`` (a ring per field; the only family
     that takes a ``jax_fn``) or ``"argext"``; ``stats`` are (op, field)
     pairs and ``acc_dtypes`` maps each field to its ring dtype.  With
     ``mesh`` the rings shard ``P(kf, None)`` over it, else they live on
-    ``device``."""
+    ``device``.  ``row_floor`` (``multi`` alone) is the smallest bucket of
+    the rings' rows where it is not the family's own."""
     if mesh is None:
         place = _OneDevice(device or default_device())
     elif "kf" not in mesh.shape:
@@ -1240,7 +1298,7 @@ def make_executor(family: str, fields, stats, acc_dtypes, *, jax_fn=None,
     if family == "multi":
         return MultiFieldResidentExecutor(
             fields, stats=stats, jax_fn=jax_fn, acc_dtypes=acc_dtypes,
-            place=place, depth=depth)
+            place=place, depth=depth, row_floor=row_floor)
     (field,) = fields
     ops = tuple(op for op, _f in stats)
     return ResidentWindowExecutor(ops[0] if len(ops) == 1 else ops,

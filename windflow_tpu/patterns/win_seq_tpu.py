@@ -78,7 +78,7 @@ class JaxWindowFunction:
     are then cut short), and no result of that launch leaves."""
 
     def __init__(self, fn, fields=("value",), result_fields=None,
-                 field_dtypes=None, count_field=None):
+                 field_dtypes=None, count_field=None, window_rows=None):
         self.fn = fn
         self.fields = tuple(fields)
         self.result_fields = dict(result_fields or {"value": np.int64})
@@ -88,6 +88,13 @@ class JaxWindowFunction:
         #: dtype each launch carries)
         self.field_dtypes = dict(field_dtypes or {})
         self.count_field = count_field
+        #: what the caller knows of the stream, as ``ArgReducer.window_rows``
+        #: is: the rows a window holds.  The function's step then keeps one
+        #: padded length for every window up to it (ops/resident ``_pad_for``)
+        #: instead of a shape a step of the ladder; no result depends on it
+        if window_rows is not None and int(window_rows) <= 0:
+            raise ValueError(f"window_rows must be positive: {window_rows}")
+        self.window_rows = None if window_rows is None else int(window_rows)
         #: slots of a container-valued result (the narrowest sub-array
         #: result field's), None without a ``count_field``
         self.slot_cap = None
@@ -471,7 +478,82 @@ def _watch_loop(launches, wake):
         wake()
 
 
-class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
+class _ResultWatch:
+    """The wake of a core that dispatches and harvests on its node's own
+    thread: a watcher thread waits on each launch's device result and wakes
+    the node, which takes the result in ``collect`` between two chunks.  The
+    core has an ``executor`` and calls ``_init_watch`` once, ``_watch`` after
+    a dispatch and ``_stop_watcher`` when every launch is in."""
+
+    def _init_watch(self, worker_index: int):
+        #: wakes the node thread that drives this core (``set_waker``), so
+        #: a launch whose result lands between two chunks is taken by
+        #: ``collect`` then and not with the next chunk
+        self._waker = None
+        #: the watcher thread and what ends it (``_watch``)
+        self._watcher = None
+        self._watch_q = None
+        self._watch_stop = None
+        self._watch_name = f"wf-watch.{worker_index}"
+        #: launches ``collect`` took, and their result rows
+        self.result_wakes = 0
+        self.result_wake_rows = 0
+
+    def set_waker(self, wake) -> bool:
+        """Take ``wake``, a callable for any thread that gets the thread
+        driving this core to call ``collect`` if it is idle (the node's
+        own ``Node._wake``; it must not pin that node): the contract of
+        ``NativeResidentCore.set_waker``.  Refused, with False, under
+        ``max_delay_ms``: that core keeps its timer.  Two more never get
+        here: the native core hands its Python delegate no waker, and the
+        engine gives a node under ``recovery=`` no ``_wake`` to hand on."""
+        # (a watcher of an earlier stream would call that stream's waker)
+        self._stop_watcher()
+        if getattr(self, "max_delay_s", None) is not None:
+            return False
+        self._waker = wake
+        return True
+
+    def _watch(self, out):
+        """Hand a dispatched launch's device result to the watcher
+        thread, which the first one starts."""
+        if self._watcher is None:
+            self._watch_q = q = queue.SimpleQueue()
+            # the thread holds neither the core nor its executor: a core
+            # that is dropped ends it, as the stream's end does
+            self._watch_stop = weakref.finalize(self, q.put, None)
+            self._watcher = threading.Thread(
+                target=_watch_loop, args=(q, self._waker), daemon=True,
+                name=self._watch_name)
+            self._watcher.start()
+        self._watch_q.put((self.executor.wait_ready, out))
+
+    def _stop_watcher(self):
+        th, self._watcher = self._watcher, None
+        if th is not None:
+            self._watch_stop()
+            th.join(timeout=10)
+
+    def _poll_woken(self) -> list:
+        """The launches that became ready since the node thread last
+        looked, harvested as ``handed`` by a wake."""
+        ex = self.executor
+        ex.handed = "wake"
+        try:
+            harvested = ex.poll()
+        finally:
+            ex.handed = "svc"
+        return harvested
+
+    def _count_wakes(self, launches: int, rows: int):
+        if launches:
+            self.result_wakes += launches
+            self.result_wake_rows += rows
+            profile.add("result_wakes", launches)
+            profile.add("result_wake_rows", rows)
+
+
+class ResidentWinSeqCore(_ResultWatch, _AsyncLaunchRecovery, WinSeqCore):
     """Window core whose archive lives in device HBM (ops/resident.py).
 
     Host-side it is the same Win_Seq bookkeeping as every other core; the
@@ -600,74 +682,17 @@ class ResidentWinSeqCore(_AsyncLaunchRecovery, WinSeqCore):
         # this core harvests on the thread that drives it, so it says
         # itself which call took a launch (ops/resident ``handed``)
         self.executor.handed = "svc"
-        #: wakes the node thread that drives this core (``set_waker``), so
-        #: a launch whose result lands between two chunks is taken by
-        #: ``collect`` then and not with the next chunk
-        self._waker = None
-        #: the watcher thread and what ends it (``_watch``)
-        self._watcher = None
-        self._watch_q = None
-        self._watch_stop = None
-        self._watch_name = f"wf-watch.{worker_index}"
-        #: launches ``collect`` took, and their result rows
-        self.result_wakes = 0
-        self.result_wake_rows = 0
+        self._init_watch(worker_index)
         _init_slot_counters(self)
-
-    # ------------------------------------------------------------ the waker
-
-    def set_waker(self, wake) -> bool:
-        """Take ``wake``, a callable for any thread that gets the thread
-        driving this core to call ``collect`` if it is idle (the node's
-        own ``Node._wake``; it must not pin that node): the contract of
-        ``NativeResidentCore.set_waker``.  Refused, with False, under
-        ``max_delay_ms``: that core keeps its timer.  Two more never get
-        here: the native core hands its Python delegate no waker, and the
-        engine gives a node under ``recovery=`` no ``_wake`` to hand on."""
-        # (a watcher of an earlier stream would call that stream's waker)
-        self._stop_watcher()
-        if self.max_delay_s is not None:
-            return False
-        self._waker = wake
-        return True
-
-    def _watch(self, out):
-        """Hand a dispatched launch's device result to the watcher
-        thread, which the first one starts."""
-        if self._watcher is None:
-            self._watch_q = q = queue.SimpleQueue()
-            # the thread holds neither the core nor its executor: a core
-            # that is dropped ends it, as the stream's end does
-            self._watch_stop = weakref.finalize(self, q.put, None)
-            self._watcher = threading.Thread(
-                target=_watch_loop, args=(q, self._waker), daemon=True,
-                name=self._watch_name)
-            self._watcher.start()
-        self._watch_q.put((self.executor.wait_ready, out))
-
-    def _stop_watcher(self):
-        th, self._watcher = self._watcher, None
-        if th is not None:
-            self._watch_stop()
-            th.join(timeout=10)
 
     def collect(self) -> np.ndarray:
         """The results of the launches that became ready since the node
         thread last looked, for that thread between two ``process`` calls
         (``WinSeqNode.on_wake``).  ``process`` keeps its own poll:
         whichever comes first takes a launch, the other finds nothing."""
-        ex = self.executor
-        ex.handed = "wake"
-        try:
-            harvested = ex.poll()
-        finally:
-            ex.handed = "svc"
+        harvested = self._poll_woken()
         out = self._concat(self._build_results(harvested))
-        if harvested:
-            self.result_wakes += len(harvested)
-            self.result_wake_rows += len(out)
-            profile.add("result_wakes", len(harvested))
-            profile.add("result_wake_rows", len(out))
+        self._count_wakes(len(harvested), len(out))
         return out
 
     # ------------------------------------------------------------ bookkeeping

@@ -135,6 +135,12 @@ def add_farm(df: Dataflow, pattern, upstreams: list[Node],
                 df.connect(r, collector)
             return [collector]
         return replicas
+    refuse = getattr(pattern, "check_dataflow", None)
+    if refuse is not None:
+        # a pattern that cannot run under one of the graph's own knobs
+        # (a window join under recovery=) says so here, by name, before a
+        # node of it exists
+        refuse(df)
     rescale_width = _provision_rescale(df, pattern)
     try:
         replicas = _apply_error_budget(pattern, pattern.replicas())
